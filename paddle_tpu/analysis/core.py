@@ -23,7 +23,8 @@ import tempfile
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
-from jax import core as jax_core
+from jax._src import source_info_util as _siu
+from jax.extend import core as jax_core
 
 from .exemptions import apply_exemptions
 from .findings import Finding, Report
@@ -58,19 +59,21 @@ def walk_eqns(jaxpr, _stack: Tuple = ()) -> Iterator[Tuple[Any, Tuple]]:
             yield from walk_eqns(inner, _stack + (eqn,))
 
 
+def _plain_name(qualname: str) -> str:
+    """``f.<locals>.g`` -> ``g``: frames carry qualified names; region
+    and exemption tables match on the plain function name."""
+    return qualname.rsplit(".", 1)[-1]
+
+
 def eqn_source(eqn) -> Tuple[str, int, str]:
     """(file, line, function) provenance of an eqn, from its traceback.
     Returns ("", 0, "") when jax carries no source info (e.g. synthetic
     eqns from transposition)."""
-    try:
-        from jax._src import source_info_util as siu
-
-        frame = siu.user_frame(eqn.source_info)
-        if frame is None:
-            return "", 0, ""
-        return frame.file_name, int(frame.start_line), frame.function_name
-    except Exception:  # pragma: no cover - jax-internal API drift
+    frame = _siu.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return "", 0, ""
+    return (frame.file_name, int(frame.start_line),
+            _plain_name(frame.function_name))
 
 
 def format_where(eqn) -> Tuple[Optional[str], Dict[str, Any]]:
@@ -82,14 +85,8 @@ def format_where(eqn) -> Tuple[Optional[str], Dict[str, Any]]:
     fname, line, func = eqn_source(eqn)
     if not fname:
         return None, {}
-    stack: Tuple[str, ...] = ()
-    try:
-        from jax._src import source_info_util as siu
-
-        stack = tuple(fr.function_name
-                      for fr in siu.user_frames(eqn.source_info))
-    except Exception:  # pragma: no cover - jax-internal API drift
-        stack = (func,)
+    stack = tuple(_plain_name(fr.function_name)
+                  for fr in _siu.user_frames(eqn.source_info.traceback))
     short = os.path.join(*fname.split(os.sep)[-2:]) if os.sep in fname \
         else fname
     return f"{short}:{line} ({func})", {"function": func, "file": fname,

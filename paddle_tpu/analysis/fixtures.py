@@ -54,7 +54,7 @@ def seeded_collective_order() -> Report:
     branch does not — ranks disagreeing on the predicate deadlock."""
     from jax.sharding import PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = _mesh(1)
 
@@ -63,7 +63,8 @@ def seeded_collective_order() -> Report:
                             lambda u: jax.lax.psum(u, "x"),
                             lambda u: u, v)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P("x"),), out_specs=P("x"))
+    fn = shard_map(body, mesh=mesh, in_specs=(P("x"),), out_specs=P("x"),
+                   check_vma=False)
     x = jnp.ones((8 * mesh.shape["x"],), jnp.float32)
     return check(fn, x, passes=["collective_order"], exemptions=(),
                  target="seeded:COLL001")
@@ -73,7 +74,7 @@ def seeded_ppermute_race() -> Report:
     """COLL002: a ppermute with two sources targeting one destination."""
     from jax.sharding import PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = _mesh(2)
 
@@ -106,12 +107,11 @@ def seeded_fp32_matmul() -> Report:
 
 def seeded_f64_leak() -> Report:
     """DT002: an x64-enabled input drags float64 through the program."""
-    from jax.experimental import enable_x64
 
     def bug(a):
         return (a * np.float64(2.0)).sum()
 
-    with enable_x64():
+    with jax.enable_x64(True):
         return check(bug, np.ones((64, 64), np.float64),
                      passes=["dtype_promotion"], exemptions=(),
                      target="seeded:DT002")
@@ -245,12 +245,14 @@ def seeded_collective_budget() -> Report:
     the bucketed overlap engine exists to prevent)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = _mesh(2)
 
     def body(a, b):
-        return jax.lax.psum(a, "x") + jax.lax.psum(b * 2.0, "x")
+        # the second reduce consumes the first: XLA's all-reduce
+        # combiner cannot merge the pair into one
+        return jax.lax.psum(b * jax.lax.psum(a, "x"), "x")
 
     fn = shard_map(body, mesh=mesh, in_specs=(P("x"), P("x")),
                    out_specs=P(), check_vma=False)
@@ -267,7 +269,7 @@ def seeded_unscheduled_collective() -> Report:
     region functions — traffic the engine never scheduled."""
     from jax.sharding import PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = _mesh(1)
 
@@ -287,7 +289,7 @@ def seeded_ppermute_ring_order() -> Report:
     (+1, +1, +2, 0) — stage pairings drift across ticks."""
     from jax.sharding import PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = _mesh(4)
     n = mesh.shape["x"]
@@ -317,7 +319,7 @@ def seeded_codec_disabled() -> Report:
     possible: one dropped ``codec=`` kwarg re-inflates every DCN hop)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
+    from jax import shard_map
     from ..distributed.topology import hierarchical_axis
     from ..parallel.codec import CollectiveCodec
     from ..parallel.overlap import hier_psum_scatter
@@ -360,7 +362,7 @@ def seeded_moe_dispatch_codec_off() -> Report:
     blowing the post-codec contract the EP step is pinned to)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
+    from jax import shard_map
     from ..distributed.topology import hierarchical_axis
     from ..parallel.codec import CollectiveCodec
     from ..parallel.expert import make_ep_all_to_all
@@ -404,7 +406,7 @@ def seeded_moe_dropless_codec_off() -> Report:
     budget the dropless step is pinned to."""
     from jax.sharding import PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
+    from jax import shard_map
     from ..distributed.topology import hierarchical_axis
     from ..parallel.codec import CollectiveCodec
     from ..parallel.expert import make_ep_all_to_all
@@ -464,20 +466,17 @@ def seeded_host_round_trip() -> Report:
     monolithic pair of transfers against a streaming budget sized for
     half of it — the accidental full-state movement the size-capped
     bucket engine exists to prevent."""
-    from ..common.jax_compat import transfer_to_memory_kind
-    from ..core.device import default_memory_kind, host_memory_kind
+    from ..core.device import host_memory_kind
+    from ..parallel.memory import place_on_device, place_on_host
 
-    kind = host_memory_kind()
-    if kind is None or transfer_to_memory_kind(kind) is None:
+    if host_memory_kind() is None:
         raise FixtureUnavailable(
-            "toolchain/backend exposes no host memory kind to transfer "
-            "to (very old jax)")
-    from ..common.jax_compat import device_put_memory_kind
+            "backend exposes no host memory kind to transfer to")
 
     @jax.jit
     def bug(a):
-        h = device_put_memory_kind(a, kind)                 # all out...
-        back = device_put_memory_kind(h, default_memory_kind())
+        h = place_on_host(a)                                # all out...
+        back = place_on_device(h)
         return back * 2.0                                   # ...all back
 
     a = jnp.ones((512, 512), jnp.float32)          # 1 MB each direction
@@ -663,7 +662,7 @@ def seeded_collective_health_probe() -> Report:
     collective is the regression)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..common.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = _mesh(2)
 

@@ -32,6 +32,8 @@ from __future__ import annotations
 
 from typing import List
 
+import jax
+
 from ..core import (AnalysisContext, AnalysisPass, SkipPass, aval_size,
                     format_where, register_pass, walk_eqns)
 from ..findings import Finding
@@ -42,6 +44,8 @@ def _transfer_memory_kind(eqn):
     transfer carries no explicit memory-kind (plain device placement /
     sharding constraint)."""
     for dev in eqn.params.get("devices", ()):
+        if isinstance(dev, jax.memory.Space):
+            return dev.name.lower()
         kind = getattr(dev, "memory_kind", None)
         if kind is not None:
             return str(kind)

@@ -34,7 +34,7 @@ from ..findings import Finding, Report
 def _leaf_sig(x) -> Tuple:
     """(kind, shape, dtype, weak) fingerprint of one argument leaf."""
     try:
-        aval = jax.core.get_aval(x)
+        aval = jax.typeof(x)
         return ("array", tuple(aval.shape), str(aval.dtype),
                 bool(getattr(aval, "weak_type", False)))
     except Exception:

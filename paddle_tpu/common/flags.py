@@ -446,10 +446,6 @@ define_flag("FLAGS_selected_gpus", "",
             "comma-separated accelerator indices visible to this process "
             "(reference: device selection for the trainer); filters "
             "paddle.device accelerator enumeration.")
-define_flag("FLAGS_enable_api_kernel_fallback", True,
-            "allow a failing Pallas kernel to fall back to the XLA "
-            "path (the phi fallback-to-CPU-kernel analog). False makes "
-            "kernel errors raise.")
 define_flag("FLAGS_sync_nccl_allreduce", True,
             "eager collectives block until the result is ready "
             "(XLA dispatch is async; the wait is block_until_ready, "

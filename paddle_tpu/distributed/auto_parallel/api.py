@@ -155,7 +155,7 @@ def resolve_partial(val, partial_axes, default_mesh=None, op: Optional[str] = No
             x = F._reduce(x, op or reduce_type, ax)
         return x
 
-    from ...common.jax_compat import shard_map as _shard_map
+    from jax import shard_map as _shard_map
 
     return jax.jit(_shard_map(body, mesh=m, in_specs=(spec,),
                               out_specs=spec))(val)

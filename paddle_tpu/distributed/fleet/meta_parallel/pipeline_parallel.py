@@ -182,7 +182,7 @@ class PipelineParallel:
                 return pipeline_train_step(stage_fn, loss_fn, sched, sp,
                                            xb, yb, axis="pp")
 
-            from ....common.jax_compat import shard_map as _shard_map
+            from jax import shard_map as _shard_map
 
             fn = jax.jit(_shard_map(
                 body, mesh=mesh, in_specs=(pspec, P(None), P(None)),

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 
-from ..common.jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 def _unwrap(x):
     from ..core.tensor import Tensor

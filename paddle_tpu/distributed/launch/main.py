@@ -3,9 +3,15 @@
 Analog of the reference's launch CLI (python/paddle/distributed/launch/
 main.py:23, __main__.py; collective controller launch/controllers/
 collective.py:126-132 which sets the env contract, master rendezvous
-controllers/master.py).  TPU-native notes: on a TPU pod each HOST runs ONE
-process (jax.distributed + PJRT own the per-chip fan-out), so
-``--nproc_per_node`` defaults to 1; the env contract (PADDLE_TRAINER_ID /
+controllers/master.py).  TPU-native notes: on a TPU host ONE process drives
+all local chips (jax.distributed + PJRT own the per-chip fan-out), which
+is what the rest of the code assumes, so ``--nproc_per_node`` defaults to
+1 and more than one local worker on a host with TPU chips is an ERROR: a
+chip belongs to one process, and a second worker would fail or hang at
+backend start-up (nothing here gives each child a chip of its own).
+Several local workers remain what the CPU gang tests use
+(``JAX_PLATFORMS=cpu``).  The launcher itself never touches JAX, so it
+holds no chip.  The env contract (PADDLE_TRAINER_ID /
 PADDLE_TRAINERS_NUM / PADDLE_CURRENT_ENDPOINT / PADDLE_TRAINER_ENDPOINTS /
 PADDLE_RANK_IN_NODE / PADDLE_MASTER — SURVEY §5 launcher contract) is kept
 verbatim so reference scripts port unchanged, and is also mapped onto
@@ -65,6 +71,38 @@ def build_env(rank: int, local_rank: int, world: int, endpoints: List[str],
         "MASTER_PORT": master.split(":")[-1],
     })
     return env
+
+
+def local_tpu_chips() -> List[str]:
+    """Device files of the TPU chips on this host, found without
+    starting a JAX backend (the launcher must not hold the chip):
+    ``/dev/vfio/<n>`` on v5e and newer, ``/dev/accel<n>`` before."""
+    import glob
+
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def check_process_layout(local_workers: int, env=None) -> None:
+    """One process drives all local chips.  Several local workers that
+    would each reach for the TPU are refused with the reason, instead
+    of hanging at backend start-up."""
+    env = os.environ if env is None else env
+    if local_workers <= 1:
+        return
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return                      # workers are pinned off the TPU
+    chips = local_tpu_chips()
+    if chips:
+        raise SystemExit(
+            f"paddle_tpu.distributed.launch: {local_workers} local "
+            f"workers on a host with {len(chips)} TPU chip(s) "
+            f"({chips[0]}...): a chip belongs to one process, so the "
+            f"second worker would fail or hang at start-up.  Run ONE "
+            f"worker per host (--nproc_per_node 1; it drives all local "
+            f"chips through one mesh), or pin the workers to the CPU "
+            f"with JAX_PLATFORMS=cpu.")
 
 
 def _run_gang(args, world: int, nproc: int, endpoints: List[str],
@@ -170,6 +208,7 @@ def launch(args=None) -> int:
     # re-rendezvous-at-smaller-world path, fleet/elastic/manager.py:125)
     nnodes = mgr.max_nodes if single_host else mgr.min_nodes
     world = nnodes * nproc
+    check_process_layout(world if single_host else nproc)
     master = args.master or "127.0.0.1:49178"
     base_port = 52700
     os.makedirs(args.log_dir, exist_ok=True)

@@ -32,7 +32,7 @@ MOE_BLOCK_SPECS = {
 }
 
 
-from .....common.jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 def init_pipelined_moe_params(mesh: Mesh, num_layers: int, num_expert: int,
                               d_model: int, d_hidden: int,
@@ -82,17 +82,16 @@ def pipelined_moe_forward(params: Dict[str, Any], x, mesh: Mesh,
                 == _axis_size("pp") - 1).astype(outs.dtype)
         return jax.lax.psum(outs * last, "pp")
 
-    from .....common.jax_compat import set_mesh as _set_mesh, \
-        shard_map as _shard_map
+    from jax import shard_map as _shard_map
+    from .....parallel.specs import ambient_mesh
 
     # FULL-manual region (round-9): every mesh axis is named, so the
-    # jax-0.4.x SPMD partitioner never sees a partial-manual shard_map
-    # (the PartitionId lowering it rejects).  The expert stacks keep
+    # SPMD partitioner never sees a partial-manual shard_map.  The expert stacks keep
     # their Shard(ep)/Shard(mp) AT-REST placement; the P("pp") in_specs
     # gather them over ep/mp at the region boundary and the block
     # compute runs expert-replicated inside — the parity-friendly
     # setting this harness targets (capacity = full batch, no drops).
-    with _set_mesh(mesh):
+    with ambient_mesh(mesh, params, x):
         return jax.jit(_shard_map(
             body, mesh=mesh, axis_names=set(mesh.axis_names),
             in_specs=(P("pp"), P(None)), out_specs=P(None),
@@ -154,8 +153,8 @@ def pipelined_moe_forward_ep(params: Dict[str, Any], x, mesh: Mesh,
                 == _axis_size("pp") - 1).astype(outs.dtype)
         return jax.lax.psum(outs * last, "pp")
 
-    from .....common.jax_compat import set_mesh as _set_mesh, \
-        shard_map as _shard_map
+    from jax import shard_map as _shard_map
+    from .....parallel.specs import ambient_mesh
 
     in_specs = ({
         "gate_w": P("pp", None, None),
@@ -164,7 +163,7 @@ def pipelined_moe_forward_ep(params: Dict[str, Any], x, mesh: Mesh,
         "w_down": P("pp", "ep", None, None),
         "b_down": P("pp", "ep", None),
     }, P(None))
-    with _set_mesh(mesh):
+    with ambient_mesh(mesh, params, x):
         return jax.jit(_shard_map(
             body, mesh=mesh, axis_names=set(mesh.axis_names),
             in_specs=in_specs, out_specs=P(None),

@@ -23,6 +23,7 @@ TPU-first design decisions:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -52,7 +53,10 @@ class LlamaConfig:
     rope_theta: float = 500000.0
     rms_norm_eps: float = 1e-5
     tie_word_embeddings: bool = False
-    dtype: str = "float32"  # param dtype; compute casts via amp
+    # param dtype at rest.  Sub-blocks are cast as they are built, so a
+    # bf16 model never holds more than one block's fp32 init values
+    # (an 8B-width stack built whole in fp32 does not fit one chip).
+    dtype: str = "float32"
     # round-18 sparse-serving surface: a checkpoint whose decoder FFNs
     # are mixtures of experts (stacked ``model.layers.i.mlp.experts.*``
     # weights + a ``mlp.router.weight`` gate per MoE layer).  The layer
@@ -191,6 +195,13 @@ def _tag_saveable(t: Tensor, name: str) -> Tensor:
     return Tensor(tag_saveable(t._value, name))
 
 
+def _cast_params(layer: Layer, cfg: LlamaConfig) -> None:
+    """Cast a freshly built sub-block's params to ``cfg.dtype``."""
+    if cfg.dtype != "float32":
+        for p in layer.parameters():
+            p.set_value(p._value.astype(cfg.dtype))
+
+
 class LlamaDecoderLayer(Layer):
     def __init__(self, cfg: LlamaConfig):
         super().__init__()
@@ -198,6 +209,7 @@ class LlamaDecoderLayer(Layer):
         self.self_attn = LlamaAttention(cfg)
         self.post_attention_layernorm = LlamaRMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
         self.mlp = LlamaMLP(cfg)
+        _cast_params(self, cfg)
 
     def forward(self, x, cos, sin, attn_mask=None,
                 startend_row_indices=None):
@@ -223,9 +235,11 @@ class LlamaModel(Layer):
         # dryrun's "involuntary full rematerialization" warnings)
         self.act_sharding = None
         self.embed_tokens = nn.Embedding(cfg.vocab_size, cfg.hidden_size)
+        _cast_params(self.embed_tokens, cfg)
         self.layers = nn.LayerList([LlamaDecoderLayer(cfg)
                                     for _ in range(cfg.num_hidden_layers)])
         self.norm = LlamaRMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+        _cast_params(self.norm, cfg)
         cos, sin = _rope_tables(cfg.head_dim, cfg.max_position_embeddings,
                                 cfg.rope_theta)
         self.register_buffer("rope_cos", Tensor(cos), persistable=False)
@@ -340,6 +354,7 @@ class LlamaForCausalLM(Layer):
             self.lm_head = None
         else:
             self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size, bias_attr=False)
+            _cast_params(self.lm_head, cfg)
 
     def forward(self, input_ids, position_ids=None, attention_mask=None,
                 startend_row_indices=None):
@@ -668,6 +683,13 @@ def build_train_step(model: LlamaForCausalLM, optimizer, mesh: Optional[Mesh] = 
                                             remat, remat_policy,
                                             schedule=schedule)
 
+    def _kernel_partition():
+        if mesh is None:
+            return contextlib.nullcontext()
+        from ..ops.pallas.flash_attention import kernel_mesh
+
+        return kernel_mesh(mesh, data_axes, "mp")
+
     def loss_fn(params: Dict[str, Any], input_ids, labels, attn_mask=None):
         cast = {k: (v.astype(compute_dtype)
                     if jnp.issubdtype(v.dtype, jnp.floating) else v)
@@ -692,7 +714,9 @@ def build_train_step(model: LlamaForCausalLM, optimizer, mesh: Optional[Mesh] = 
             model.model.act_sharding = NamedSharding(
                 mesh, lead_batch_spec(batch_sharding.spec, 3))
         try:
-            with no_grad():  # tape off: jax.grad provides the gradients
+            # tape off: jax.grad provides the gradients; under a mesh
+            # the flash kernel runs per (batch, head) shard
+            with no_grad(), _kernel_partition():
                 logits = model.functional_call(
                     cast, Tensor(input_ids),
                     attention_mask=None if attn_mask is None
@@ -718,7 +742,7 @@ def build_train_step(model: LlamaForCausalLM, optimizer, mesh: Optional[Mesh] = 
     flat_layout = None
     if mesh is not None:
         flat_layout = schedule.flat_update_layout()
-        flat_sharding = NamedSharding(mesh, flat_layout.flat_spec())
+        flat_sharding = flat_layout.flat_sharding()
         if not flat_layout.axes:
             flat_layout = None      # single-device mesh: nothing to cut
 

@@ -56,7 +56,7 @@ HYBRID_AXES = ("pp", "dp", "sharding", "sep", "mp")
 _LAYER_PREFIX = "model.layers."
 
 
-from ..common.jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 def hybrid_mesh(devices, pp=1, dp=1, sharding=1, sep=1, mp=1) -> Mesh:
     """Build the 5-axis hybrid mesh (reference: topology.py:189 order
@@ -387,7 +387,7 @@ def build_hybrid_train_step(cfg: LlamaConfig, optimizer, mesh: Mesh,
                                       cfg.max_position_embeddings,
                                       cfg.rope_theta)
 
-    from ..common.jax_compat import shard_map as _shard_map
+    from jax import shard_map as _shard_map
 
     stacked_in_specs = {
         sfx: _ov.leaf_partition_spec(layout[sfx], lead="pp")
@@ -771,14 +771,14 @@ def build_hybrid_train_step(cfg: LlamaConfig, optimizer, mesh: Mesh,
 
     def step(params, opt_state, step_no, lr, input_ids, labels,
              health_gates=None):
-        from ..common.jax_compat import set_mesh as _set_mesh
+        from ..parallel.specs import ambient_mesh
 
         kw = {}
         if health is not None:
             from ..distributed import health as _health
 
             kw["health_gates"] = _health.normalize_gates(health_gates)
-        with _set_mesh(mesh):
+        with ambient_mesh(mesh, params):
             return jstep(params, opt_state, step_no, lr, input_ids,
                          labels, **kw)
 

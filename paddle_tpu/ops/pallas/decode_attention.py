@@ -39,7 +39,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import NEG_INF, _CompilerParams, _sds
+from ...core.device import pallas_interpret
+
+from .flash_attention import NEG_INF, _sds
 
 
 def _decode_kernel(*refs, block_k: int, scale: float):
@@ -122,7 +124,7 @@ def flash_decode_raw(q, k_cache, v_cache, seq_lens, scale=None,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = pallas_interpret()
     rep = h // kvh
     rp = -(-rep // 8) * 8                   # sublane-pad the head group
     # the whole head axis rides in one block, so the k/v block footprint
@@ -167,7 +169,7 @@ def flash_decode_raw(q, k_cache, v_cache, seq_lens, scale=None,
                           scale=float(scale)),
         grid_spec=grid_spec,
         out_shape=_sds((b, kvh, rp, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(seq, qg, k_cache, v_cache)
@@ -284,7 +286,7 @@ def tune_pages_per_step(b, kvh, page, d, max_pages, dtype=jnp.bfloat16):
     cached = _at.AutoTuneCache.instance().lookup(key)
     if cached is not None:
         return cached
-    if not _at.enabled() or jax.default_backend() == "cpu":
+    if not _at.enabled() or pallas_interpret():
         return default
 
     npages = b * max_pages
@@ -330,7 +332,7 @@ def paged_decode_raw(q, key_cache, value_cache, seq_lens, block_tables,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = pallas_interpret()
     rep = h // kvh
     rp = -(-rep // 8) * 8
     max_pages = block_tables.shape[1]
@@ -378,7 +380,7 @@ def paged_decode_raw(q, key_cache, value_cache, seq_lens, block_tables,
                           scale=float(scale)),
         grid_spec=grid_spec,
         out_shape=_sds((b, kvh, rp, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(seq, tables, qg, *([key_cache] * pp), *([value_cache] * pp))
@@ -426,7 +428,7 @@ def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = pallas_interpret()
     rep = h // kvh
     rp = -(-rep // 8) * 8
     max_pages = block_tables.shape[1]
@@ -479,7 +481,7 @@ def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
                           scale=float(scale), nsp=3),
         grid_spec=grid_spec,
         out_shape=_sds((T, kvh, rp, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lens, slots, tables, qg, *([key_cache] * pp), *([value_cache] * pp))

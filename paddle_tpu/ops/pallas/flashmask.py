@@ -29,10 +29,10 @@ from __future__ import annotations
 
 import math
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...core.device import pallas_interpret
 from .flash_attention import (FlashUnsupportedError, flash_attention_raw,
                               segment_ids_from_cu_seqlens)
 
@@ -263,7 +263,7 @@ def flash_attn_varlen_qkvpacked_raw(qkv, cu_seqlens_q, cu_seqlens_k,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = pallas_interpret()
 
     if varlen_padded:
         if max_seqlen_q is None:

@@ -61,7 +61,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _CompilerParams, _sds
+from ...core.device import pallas_interpret
+
+from .flash_attention import _sds
 
 
 def align_rows(n, block_rows: int):
@@ -126,7 +128,7 @@ def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
     if R % bm:
         raise ValueError(f"rows {R} not a multiple of block_rows {bm}")
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = pallas_interpret()
     if R == 0 or S == 0:
         return jnp.zeros((R, N), x.dtype)
     pad_blk = R // bm                       # the appended safe block
@@ -170,34 +172,48 @@ def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
         grid_spec=grid_spec,
         out_shape=_sds((R + bm, N), x.dtype),
         # segments share the PAD output block, so si is not parallel
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(starts, lens, wids, *operands)
     return out[:R]
 
 
-def _outer_kernel(starts_ref, lens_ref, x_ref, dy_ref, o_ref, acc_scr, *,
+def _outer_kernel(starts_ref, lens_ref, x_ref, dy_ref, o_ref, *,
                   block_rows: int):
     si = pl.program_id(0)
-    j = pl.program_id(1)
+    j = pl.program_id(3)
     nblk = (lens_ref[si] + block_rows - 1) // block_rows
 
+    # the (si, kt, nt) output tile stays resident in VMEM across the
+    # innermost j steps and is the fp32 accumulator itself; zeroing it
+    # at j == 0 UNCONDITIONALLY makes empty segments emit exact zeros
+    # and leaves no tile with stale VMEM
     @pl.when(j == 0)
     def _():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(j < nblk)
     def _():
-        acc_scr[:] += jax.lax.dot_general(
+        o_ref[0] += jax.lax.dot_general(
             x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    # store UNCONDITIONALLY: every (si, j) step rewrites segment si's
-    # output block, so empty segments emit exact zeros and skipped steps
-    # just restate the running value — no block is ever left with stale
-    # VMEM (the output-coverage dual of the PAD trick above)
-    o_ref[0] = acc_scr[:]
+
+# fp32 elements of one (bk, bn) output tile: 4 MiB, so the tile and its
+# double buffer stay well inside the 16 MiB a kernel may scope in VMEM
+_OUTER_TILE_ELEMS = 1 << 20
+
+
+def _lane_tile(dim: int, cap: int) -> int:
+    """Largest divisor of ``dim`` that is a multiple of 128 and <= cap;
+    ``dim`` itself when it already fits or has no such divisor."""
+    if dim <= cap:
+        return dim
+    for t in range(cap - cap % 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return dim
 
 
 def grouped_outer_raw(x, dy, seg_starts, seg_lens, block_rows: int = 128,
@@ -206,7 +222,9 @@ def grouped_outer_raw(x, dy, seg_starts, seg_lens, block_rows: int = 128,
     the dW half of the grouped matmul backward.  x [R, K]; dy [R, N];
     returns [S, K, N] float32.  Alignment-slack rows of x are zero by
     the module contract, so they contribute exact zeros regardless of
-    dy's content there."""
+    dy's content there.  ``(K, N)`` is tiled in the grid (N whole up to
+    2048 columns, K cut so a tile holds _OUTER_TILE_ELEMS) — expert
+    widths do not fit VMEM whole."""
     R, K = x.shape
     Rd, N = dy.shape
     if Rd != R:
@@ -216,39 +234,43 @@ def grouped_outer_raw(x, dy, seg_starts, seg_lens, block_rows: int = 128,
     if R % bm:
         raise ValueError(f"rows {R} not a multiple of block_rows {bm}")
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = pallas_interpret()
     if S == 0:
         return jnp.zeros((0, K, N), jnp.float32)
     if R == 0:
         return jnp.zeros((S, K, N), jnp.float32)
     pad_blk = R // bm
     nbmax = R // bm
+    bn = _lane_tile(N, 2048)
+    bk = _lane_tile(K, max(_OUTER_TILE_ELEMS // bn, 128))
 
     xp = jnp.concatenate([x, jnp.zeros((bm, K), x.dtype)], axis=0)
     dyp = jnp.concatenate([dy, jnp.zeros((bm, N), dy.dtype)], axis=0)
     starts = seg_starts.astype(jnp.int32)
     lens = seg_lens.astype(jnp.int32)
 
-    def row_map(si, j, starts_ref, lens_ref):
+    def row_blk(si, j, starts_ref, lens_ref):
         nblk = (lens_ref[si] + bm - 1) // bm
-        return (jnp.where(j < nblk, starts_ref[si] // bm + j, pad_blk), 0)
+        return jnp.where(j < nblk, starts_ref[si] // bm + j, pad_blk)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, nbmax),
+        grid=(S, K // bk, N // bn, nbmax),
         in_specs=[
-            pl.BlockSpec((bm, K), row_map),
-            pl.BlockSpec((bm, N), row_map),
+            pl.BlockSpec((bm, bk), lambda si, kt, nt, j, s, l:
+                         (row_blk(si, j, s, l), kt)),
+            pl.BlockSpec((bm, bn), lambda si, kt, nt, j, s, l:
+                         (row_blk(si, j, s, l), nt)),
         ],
-        out_specs=pl.BlockSpec((1, K, N), lambda si, j, s, l: (si, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((K, N), jnp.float32)],
+        out_specs=pl.BlockSpec((1, bk, bn), lambda si, kt, nt, j, s, l:
+                               (si, kt, nt)),
     )
     return pl.pallas_call(
         functools.partial(_outer_kernel, block_rows=bm),
         grid_spec=grid_spec,
         out_shape=_sds((S, K, N), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 4),
         interpret=interpret,
     )(starts, lens, xp, dyp)
 

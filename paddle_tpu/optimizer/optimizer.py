@@ -13,6 +13,7 @@ support, optimizer.py:127) and adamw.py:49 etc. Two execution modes:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -408,14 +409,19 @@ class Adam(Optimizer):
         st = {}
         for g in self._flat_groups(params, decay_mask, flat_layout):
             n = sum(g["sizes"])
-            gs = {"moment1": jnp.zeros((n,), jnp.float32),
-                  "moment2": jnp.zeros((n,), jnp.float32)}
+            # a shard-major group is BORN on its layout's own sharding:
+            # eager zeros/concats would land whole on the first device,
+            # which a state sized for the mesh does not fit
+            sh = g["layout"].flat_sharding() if "layout" in g else None
+            gs = {"moment1": jnp.zeros((n,), jnp.float32, device=sh),
+                  "moment2": jnp.zeros((n,), jnp.float32, device=sh)}
             if self._multi_precision and g["dtype"] != "float32":
                 src = master_from if master_from is not None else params
                 if "layout" in g:
-                    gs["master"] = g["layout"].pack_group(
-                        g["plans"], g["keys"],
-                        {k: src[k] for k in g["keys"]})
+                    gs["master"] = jax.jit(
+                        functools.partial(g["layout"].pack_group,
+                                          g["plans"], g["keys"]),
+                        out_shardings=sh)({k: src[k] for k in g["keys"]})
                 else:
                     gs["master"] = jnp.concatenate(
                         [jnp.asarray(src[k]).astype(jnp.float32)

@@ -69,7 +69,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..common.jax_compat import shard_map
+from jax import shard_map
 from . import compat as _compat
 from .codec import CollectiveCodec, decode_rows, encode_rows
 from .overlap import OverlapConfig

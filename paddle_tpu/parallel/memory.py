@@ -42,14 +42,13 @@ engineered, inspectable artifact, three levers + a meter:
    lever, which is why host residency sorts BEFORE heavier remat in the
    lattice.
 
-CPU fallback contract: hosts without a distinct ``pinned_host`` space
-(the CPU backend, old jax wheels) degrade through
-core/device.host_memory_kind() — on CPU the fallback kind is the
-backend default, so every transfer is a traced alias: zero bytes move,
-but the bucket plan, the streaming apply, the policy selection and the
-MEM002 transfer audit all exercise the REAL code path, and every
-lattice point is loss-parity-tested against the flat baseline
-(tests/test_memory_engine.py).
+CPU contract: the traced host<->device transfers are emitted on every
+backend (place_on_host / place_on_device), so the bucket plan, the
+streaming apply, the policy selection and the MEM002 transfer audit all
+exercise the REAL code path on CPU, and every lattice point is
+loss-parity-tested against the flat baseline
+(tests/test_memory_engine.py).  Only the eager at-rest placement is
+TPU-only (see _put_memory_space).
 """
 
 from __future__ import annotations
@@ -60,7 +59,6 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..common import jax_compat as _jc
 
 # checkpoint_name tags planted in the decoder layer (models/llama.py
 # LlamaDecoderLayer, models/llama_hybrid._decoder_layer and
@@ -107,6 +105,20 @@ def offload_names_policy():
         names_which_can_be_saved=[],
         names_which_can_be_offloaded=list(SAVEABLE_NAMES),
         offload_src="device", offload_dst=dst)
+
+
+def _dots_saved_names_offloaded():
+    """dots_saveable for matmuls, offload_names_policy() for the rest
+    (jax's save_from_both_policies refuses to mix a boolean policy with
+    one that answers in offload decisions)."""
+    dots = jax.checkpoint_policies.dots_saveable
+    names = offload_names_policy()
+
+    def policy(prim, *avals, **params):
+        if dots(prim, *avals, **params):
+            return True
+        return names(prim, *avals, **params)
+    return policy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -190,14 +202,11 @@ class MemoryConfig:
                 return False, None
             # no-remat + offload: dots stay saved on device (no matmul
             # recompute) while the tagged residual stream parks on host
-            return True, cp.save_from_both_policies(
-                cp.dots_saveable, offload_names_policy())
+            return True, _dots_saved_names_offloaded()
         if self.remat == "dots":
-            pol = cp.dots_saveable
-            if self.activation_offload:
-                pol = cp.save_from_both_policies(pol,
-                                                 offload_names_policy())
-            return True, pol
+            return True, (_dots_saved_names_offloaded()
+                          if self.activation_offload
+                          else cp.dots_saveable)
         if self.remat == "names":
             return True, (offload_names_policy()
                           if self.activation_offload
@@ -247,25 +256,48 @@ MEMORY_LATTICE: Tuple[MemoryConfig, ...] = (
 # ---------------------------------------------------------------------------
 
 
+def _put_memory_space(x, space: "jax.memory.Space", kind):
+    """Move ``x`` to a memory space: under a trace by the space (the
+    form jit accepts, visible to the MEM002 audit as a device_put eqn),
+    on a concrete array by its sharding's memory kind (the form eager
+    device_put places for real).  Identity when ``kind`` is None (the
+    backend has no such space) or ``x`` already lives there.
+
+    Concrete arrays are only re-labelled on a TPU: XLA:CPU executables
+    do not report their outputs' memory kinds, so jit labels every
+    output ``device`` whatever the program placed, and a donated input
+    labelled ``pinned_host`` then aliases an output of another label
+    and the runtime aborts.  On CPU the at-rest label stays ``device``;
+    the traced transfers (and so the audited program) are the same."""
+    from ..core.device import is_tpu
+
+    if kind is None:
+        return x
+    if isinstance(x, jax.core.Tracer):
+        return jax.device_put(x, space)
+    if not is_tpu() or x.sharding.memory_kind == kind:
+        return x
+    return jax.device_put(x, x.sharding.with_memory_kind(kind))
+
+
 def place_on_host(x):
     """Place ``x`` in the host (``pinned_host``) memory space — THE
     residency primitive of the offload engine, shared since round 16
     with the serving prefix cache's host tier (inference/serving.py
-    demotes cold full pages through this instead of evicting them).
-    Identity on toolchains/backends without memory kinds."""
+    demotes cold full pages through this instead of evicting them)."""
     from ..core.device import host_memory_kind
 
-    return _jc.device_put_memory_kind(x, host_memory_kind())
+    return _put_memory_space(x, jax.memory.Space.Host, host_memory_kind())
 
 
 def place_on_device(x):
-    """Fetch ``x`` back into the compute-resident memory kind; on CPU
-    this equals the host kind, so the fetch is a traced alias — still
-    routed through device_put_memory_kind so the transfer eqn is
-    visible to the MEM002 audit on every backend."""
+    """Fetch ``x`` back into the compute-resident memory space — host
+    state must pass through here before any arithmetic (jax refuses to
+    mix memory spaces in one op)."""
     from ..core.device import default_memory_kind
 
-    return _jc.device_put_memory_kind(x, default_memory_kind())
+    return _put_memory_space(x, jax.memory.Space.Device,
+                             default_memory_kind())
 
 
 # internal aliases (the optimizer-offload stream predates the public
@@ -310,9 +342,6 @@ def offload_flat_state(flat_state: Dict[str, Any],
             and set(flat_state) == {"__flat__"}):
         raise ValueError("offload_flat_state expects a state from "
                          "init_flat_state ({'__flat__': ...})")
-    from ..core.device import host_memory_kind
-
-    kind = host_memory_kind()
     out: Dict[str, Dict[str, Tuple]] = {}
     for gname, gs in flat_state["__flat__"].items():
         og: Dict[str, Tuple] = {}
@@ -320,23 +349,8 @@ def offload_flat_state(flat_state: Dict[str, Any],
             arr = jnp.asarray(arr)
             plan = stream_bucket_plan(arr.shape[0], arr.dtype.itemsize,
                                       bucket_bytes)
-            buckets = []
-            for off, size in plan:
-                b = arr[off:off + size]
-                cur = getattr(getattr(b, "sharding", None),
-                              "memory_kind", None)
-                if kind is not None and kind != cur:
-                    # a REAL residency change (TPU: device -> pinned
-                    # host).  When the kinds already agree (CPU
-                    # fallback: host IS the default memory) the
-                    # device_put is skipped so the leaves stay
-                    # placement-uncommitted and compose with any mesh
-                    # the train step constrains them onto.
-                    b = jax.device_put(
-                        b, _jc.sharding_with_memory_kind(b.sharding,
-                                                         kind))
-                buckets.append(b)
-            og[key] = tuple(buckets)
+            og[key] = tuple(place_on_host(arr[off:off + size])
+                            for off, size in plan)
         out[gname] = og
     return {"__offload__": out}
 

@@ -74,7 +74,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..common.jax_compat import shard_map, axis_size
+from jax import shard_map
+from jax.lax import axis_size
 from . import compat as _compat
 from .codec import CollectiveCodec, decode_rows, encode_rows
 

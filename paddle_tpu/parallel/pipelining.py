@@ -27,7 +27,7 @@ from jax import lax
 from . import compat as _compat
 
 
-from ..common.jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jnp.ndarray,
                    axis: str = "pp", num_microbatches: int | None = None,

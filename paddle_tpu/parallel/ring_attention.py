@@ -37,10 +37,9 @@ from jax import lax
 from . import compat as _compat
 
 
-from ..common.jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
-def _interpret():
-    return jax.default_backend() == "cpu"
+from ..core.device import pallas_interpret as _interpret
 
 
 def _varying(x, axis):

@@ -60,6 +60,7 @@ __all__ = [
     "predict_peak_bytes", "StepTimeEstimate", "estimate_step_time",
     "estimate_joint_config", "joint_estimator",
     "enumerate_partitionings", "rank_partitionings",
+    "DEVICE_KIND_TO_CHIP", "chip_spec_for_device",
 ]
 
 
@@ -95,6 +96,31 @@ CHIP_SPECS: Dict[str, ChipSpec] = {
     "v5p": ChipSpec("v5p", 459e12, 2765e9, 95 << 30, 100e9, 6.25e9),
     "v6e": ChipSpec("v6e", 918e12, 1640e9, 32 << 30, 90e9, 6.25e9),
 }
+
+
+#: ``jax.devices()[0].device_kind`` -> CHIP_SPECS key, as the runtime
+#: spells it (a v5e reports "TPU v5 lite").
+DEVICE_KIND_TO_CHIP: Dict[str, str] = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+    "TPU v6e": "v6e",
+}
+
+
+def chip_spec_for_device(device_kind: str) -> ChipSpec:
+    """Peaks of the attached device, from its ``device_kind``.  A device
+    that is not in the table is an error, never a default."""
+    try:
+        return CHIP_SPECS[DEVICE_KIND_TO_CHIP[device_kind]]
+    except KeyError:
+        raise KeyError(
+            f"no peaks known for device_kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_KIND_TO_CHIP)} (add it to "
+            f"parallel/roofline.DEVICE_KIND_TO_CHIP)") from None
 
 
 def chip_spec(chip) -> ChipSpec:

@@ -322,11 +322,14 @@ class FlatUpdateLayout:
             off += pl.local
         return out
 
+    def flat_sharding(self) -> NamedSharding:
+        """The layout's OWN sharding of a 1-D flat buffer."""
+        return NamedSharding(self.mesh, self.flat_spec())
+
     def pin(self, flat):
         """The SHARD005 cross-replica update pin, in the shard-major
         layout's OWN sharding (so the pin is a no-op relayout)."""
-        return jax.lax.with_sharding_constraint(
-            flat, NamedSharding(self.mesh, self.flat_spec()))
+        return jax.lax.with_sharding_constraint(flat, self.flat_sharding())
 
 
 # ---------------------------------------------------------------------------
@@ -528,7 +531,15 @@ class PartitionSchedule:
 
     def named_sharding(self, name: str, shape: Sequence[int]
                        ) -> NamedSharding:
-        return NamedSharding(self.mesh, self.spec_for(name, shape))
+        """The sharding a leaf is PLACED with: ``spec_for`` less its
+        trailing Nones, the form in which jit spells the shardings it
+        chooses for outputs.  ``P(None) != P()`` in jit's cache key, so
+        a step fed its own outputs would otherwise compile a second
+        time."""
+        spec = tuple(self.spec_for(name, shape))
+        while spec and spec[-1] is None:
+            spec = spec[:-1]
+        return NamedSharding(self.mesh, P(*spec))
 
     def reshard_specs(self) -> Dict[str, P]:
         """Per-canonical-name at-rest specs in reshard-planner form

@@ -25,7 +25,7 @@ from jax import lax
 from . import compat as _compat
 
 
-from ..common.jax_compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 
 def ulysses_attention(q, k, v, axis: str = "sep", causal: bool = True,
                       scale: Optional[float] = None):

@@ -137,6 +137,19 @@ def token_batch_spec(batch, sep=None) -> P:
 # ---------------------------------------------------------------------------
 
 
+def ambient_mesh(mesh: Mesh, *args):
+    """Context binding ``mesh`` as the ambient mesh around a jitted call
+    with ``args``: ``jax.sharding.set_mesh`` eagerly; under a trace (the
+    doctor tracing a step wrapper) jax refuses ``set_mesh``, and the
+    abstract mesh is what the traced body needs."""
+    import jax
+
+    if any(isinstance(leaf, jax.core.Tracer)
+           for leaf in jax.tree_util.tree_leaves(args)):
+        return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+    return jax.sharding.set_mesh(mesh)
+
+
 def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:
     """{axis name: size} for every mesh axis (size-1 axes included —
     callers that only care about real parallelism filter on > 1)."""

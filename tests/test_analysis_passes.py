@@ -285,7 +285,7 @@ def test_cond_branches_with_different_perms_flagged():
     a deadlock (ranks consult different send/recv pairs)."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.common.jax_compat import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     if len(devs) < 2:
@@ -311,7 +311,7 @@ def test_collective_order_clean_on_symmetric_cond():
     positive on e.g. add-vs-multiply cond bodies that both psum)."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.common.jax_compat import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     mesh = Mesh(np.asarray(devs[:2], dtype=object), ("x",))

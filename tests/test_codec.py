@@ -25,7 +25,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import paddle_tpu as paddle
-from paddle_tpu.common.jax_compat import shard_map
+from jax import shard_map
 from paddle_tpu.distributed.topology import hierarchical_axis
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM, build_train_step
 from paddle_tpu.models.llama import apply_llama_sharding

@@ -10,7 +10,7 @@ import pytest
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
 from paddle_tpu.distributed import Partial, Replicate, Shard
-from paddle_tpu.common.jax_compat import shard_map  # jax 0.4.x compat
+from jax import shard_map
 
 
 def test_process_mesh_basics():

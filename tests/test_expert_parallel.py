@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 import paddle_tpu as paddle  # noqa: F401 (registers ops)
-from paddle_tpu.common.jax_compat import shard_map
+from jax import shard_map
 from paddle_tpu.distributed.topology import hierarchical_axis
 from paddle_tpu.parallel import compat as _compat
 from paddle_tpu.parallel.codec import CollectiveCodec

@@ -416,10 +416,3 @@ def test_new_wired_flags_have_effects():
         assert D.device_count("cpu") == n
     finally:
         paddle.set_flags({"FLAGS_selected_gpus": ""})
-
-    # kernel-fallback gate exists and round-trips
-    paddle.set_flags({"FLAGS_enable_api_kernel_fallback": False})
-    try:
-        assert not F.get_flag("FLAGS_enable_api_kernel_fallback")
-    finally:
-        paddle.set_flags({"FLAGS_enable_api_kernel_fallback": True})

@@ -42,7 +42,8 @@ def test_llama_sharding_plan_applied():
     apply_llama_sharding(model, mesh)
     specs = {n: tuple(p._value.sharding.spec)
              for n, p in model.named_parameters()}
-    assert specs["model.embed_tokens.weight"] == (("mp", "sharding"), None)
+    # placed specs drop trailing Nones (the form jit hands back)
+    assert specs["model.embed_tokens.weight"] == (("mp", "sharding"),)
     assert specs["model.layers.0.self_attn.q_proj.weight"] == ("sharding", "mp")
     assert specs["model.layers.0.mlp.down_proj.weight"] == ("mp", "sharding")
     assert specs["model.norm.weight"] in ((), (None,))

@@ -198,7 +198,7 @@ def test_incubate_routing_padding_mask_uses_pallas(monkeypatch):
     back to the XLA softmax path) when Pallas is available."""
     import paddle_tpu.incubate.nn.attention as attn_mod
 
-    monkeypatch.setattr(attn_mod, "_PALLAS_OK", True)
+    monkeypatch.setattr(attn_mod, "is_tpu", lambda device=None: True)
     calls = {}
     from paddle_tpu.ops.registry import dispatch as real_dispatch
 
@@ -232,10 +232,10 @@ def test_incubate_bool_mask_same_numerics_on_fallback(monkeypatch):
     q, k, v = _rand_qkv(b=2, s=64, h=2, d=32)
     mask_np = np.arange(64)[None, :] < np.array([50, 30])[:, None]
     args = [paddle.to_tensor(np.asarray(t)) for t in (q, k, v)]
-    monkeypatch.setattr(attn_mod, "_PALLAS_OK", True)
+    monkeypatch.setattr(attn_mod, "is_tpu", lambda device=None: True)
     a = attn_mod.flash_attention(*args, causal=False,
                                  attn_mask=paddle.to_tensor(mask_np))
-    monkeypatch.setattr(attn_mod, "_PALLAS_OK", False)
+    monkeypatch.setattr(attn_mod, "is_tpu", lambda device=None: False)
     b = attn_mod.flash_attention(*args, causal=False,
                                  attn_mask=paddle.to_tensor(mask_np))
     m = mask_np[:, :, None, None]
@@ -297,10 +297,10 @@ def test_incubate_padded_rows_agree_between_paths(monkeypatch):
     q, k, v = _rand_qkv(b=2, s=64, h=2, d=32)
     mask_np = np.arange(64)[None, :] < np.array([50, 30])[:, None]
     args = [paddle.to_tensor(np.asarray(t)) for t in (q, k, v)]
-    monkeypatch.setattr(attn_mod, "_PALLAS_OK", True)
+    monkeypatch.setattr(attn_mod, "is_tpu", lambda device=None: True)
     a = attn_mod.flash_attention(*args, causal=False,
                                  attn_mask=paddle.to_tensor(mask_np))
-    monkeypatch.setattr(attn_mod, "_PALLAS_OK", False)
+    monkeypatch.setattr(attn_mod, "is_tpu", lambda device=None: False)
     b = attn_mod.flash_attention(*args, causal=False,
                                  attn_mask=paddle.to_tensor(mask_np))
     np.testing.assert_allclose(np.asarray(a._value), np.asarray(b._value),

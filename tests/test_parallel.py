@@ -11,7 +11,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import paddle_tpu as paddle
 from paddle_tpu.ops.pallas.flash_attention import _attn_reference
-from paddle_tpu.common.jax_compat import shard_map  # jax 0.4.x compat
+from jax import shard_map
 
 
 def _mesh1d(n, name):

@@ -22,7 +22,7 @@ from paddle_tpu.models.llama_hybrid import _decoder_layer, _rope_tables
 from paddle_tpu.parallel.pipelining import (pipeline_train_step,
                                             stack_stage_params)
 from paddle_tpu.parallel.schedules import build_schedule
-from paddle_tpu.common.jax_compat import shard_map  # jax 0.4.x compat
+from jax import shard_map
 
 PP, M, MB, S = 4, 4, 2, 8
 

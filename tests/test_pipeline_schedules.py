@@ -14,7 +14,7 @@ from paddle_tpu.parallel.pipelining import (pipeline_train_step,
                                             stack_stage_params,
                                             stack_stage_params_interleaved)
 from paddle_tpu.parallel.schedules import build_schedule
-from paddle_tpu.common.jax_compat import shard_map  # jax 0.4.x compat
+from jax import shard_map
 
 PP = 4
 M = 8          # micro-batches

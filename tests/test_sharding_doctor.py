@@ -277,7 +277,7 @@ def test_shard001_counts_manual_collectives_as_declared():
     devs = jax.devices()
     if len(devs) < 2:
         pytest.skip("needs >= 2 devices")
-    from paddle_tpu.common.jax_compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.asarray(devs[:2], dtype=object), ("x",))
 
