@@ -1045,8 +1045,7 @@ def serving_trace(smoke: bool = False, seed: int = 0):
         now = time.perf_counter() - t0
         while pending and pending[0][0] <= now:
             arr, prompt = pending.pop(0)
-            rid = eng.add_request(prompt, max_new_tokens=max_new,
-                                  arrival=arr)
+            rid = eng.add_request(prompt, max_new_tokens=max_new)
             arrival_of[rid] = arr
         ts = time.perf_counter()
         produced = eng.step()

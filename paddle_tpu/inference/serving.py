@@ -81,6 +81,16 @@ Round 13 (the serving resilience plane, inference/fleet.py):
   router's migration/retry primitive) and ``throttle()`` exposes the
   runtime shed knobs (speculative_k, prefill_token_budget) under the
   constructor's static compiled shapes.
+
+Measurement inside the unified step: every phase of ``_step_unified``
+is a ``profiler.RecordEvent`` (``serving.step`` > ``serving.admit``,
+``serving.propose``, ``serving.pack``, ``serving.launch``,
+``serving.fetch_logits``, ``serving.commit``), so a profiler trace that
+runs, whoever started it, holds them on the device's clock; one marker
+a step (``serving.step_counts``) and one per request at admission and
+at its first token (``serving.admit_request``, ``serving.first_token``)
+carry the counts.  The same counts are summed in
+``serving_stats()["steps"]`` whether or not anything traces.
 """
 
 from __future__ import annotations
@@ -88,6 +98,7 @@ from __future__ import annotations
 import dataclasses
 import heapq
 import threading
+import time
 from collections import deque
 from functools import partial
 from typing import Any, Deque, Dict, List, Optional
@@ -98,6 +109,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.device import is_tpu as _is_tpu
+from ..profiler import RecordEvent
 
 
 @dataclasses.dataclass
@@ -105,10 +117,14 @@ class Request:
     rid: int
     prompt: np.ndarray                  # [S] int32
     max_new_tokens: int
-    arrival: float = 0.0
     temperature: float = 0.0            # 0 = greedy
     seed: int = 0                       # per-request sampling stream
     rng: Any = None                     # np.random.Generator at admission
+    # time.perf_counter() at add_request and at admission (the queue
+    # wait lies between them; admission to the first token is prefill)
+    submitted: float = 0.0
+    admitted: float = 0.0
+    chunks: int = 0                     # prefill chunks launched so far
 
 
 @dataclasses.dataclass
@@ -770,6 +786,13 @@ class ContinuousBatchingEngine:
         # requests must show prefilled == prompt_len - cached; run-scoped
         # by design — bench/tests sum it over the whole trace)
         self.prefill_stats: Dict[int, Dict[str, int]] = {}
+        # what the unified steps did, summed over the engine's life
+        # (serving_stats()["steps"]); the waits in whole microseconds,
+        # as the spans' arguments carry them
+        self.step_totals: Dict[str, int] = dict.fromkeys(
+            ("steps", "rows", "rows_cap", "decode_rows", "prefill_rows",
+             "admitted", "queue_wait_us", "queue_wait_us_max",
+             "prefill_us", "prefill_us_max"), 0)
         # spec telemetry: one entry per verify window, bounded so a
         # long-running server doesn't grow it without limit
         self.accepted_lengths: Deque[int] = deque(maxlen=65536)
@@ -1078,47 +1101,56 @@ class ContinuousBatchingEngine:
         sin = jnp.take(sin_tab, pos, axis=0)[:, None, :].astype(x.dtype)
         new_k, new_v = list(k_pages), list(v_pages)
         rep_ = h // kvh
+        # the SAME scope names in every layer, so that a trace viewer
+        # adds a layer's parts up across layers; scopes are metadata and
+        # change nothing that is compiled
         for i in range(L):
-            xin = _rms_norm(x, w.layer(i, "input_layernorm.weight"),
-                            cfg.rms_norm_eps)
-            q = (xin @ w.layer(i, "self_attn.q_proj.weight")
-                 ).reshape(T, h, d)
-            k = (xin @ w.layer(i, "self_attn.k_proj.weight")
-                 ).reshape(T, kvh, d)
-            v = (xin @ w.layer(i, "self_attn.v_proj.weight")
-                 ).reshape(T, kvh, d)
-            q, k = _apply_rope(q, k, cos, sin)
-            kw_, vw_, qd = k, v, q
-            if new_k[i].dtype == jnp.int8:
-                kw_ = _round_int8(kw_.astype(jnp.float32)
-                                  * kv_scales["kq"][i][None, :, None])
-                vw_ = _round_int8(vw_.astype(jnp.float32)
-                                  * kv_scales["vq"][i][None, :, None])
-                kdq = jnp.repeat(kv_scales["kdq"][i], rep_)
-                qd = (qd.astype(jnp.float32)
-                      * kdq[None, :, None]).astype(q.dtype)
-            # scatter ALL rows' K/V first (a chunk row must see its
-            # in-chunk predecessors), then one ragged kernel launch
-            kp = new_k[i].at[phys, :, off, :].set(
-                kw_.astype(new_k[i].dtype))
-            vp = new_v[i].at[phys, :, off, :].set(
-                vw_.astype(new_v[i].dtype))
-            new_k[i], new_v[i] = kp, vp
-            ctx = ragged_paged_decode_raw(qd, kp, vp, lens, slot, tables,
-                                          scale=d ** -0.5,
-                                          pages_per_step=pages_per_step)
-            if kp.dtype == jnp.int8:
-                vdq = jnp.repeat(kv_scales["vdq"][i], rep_)
-                ctx = ctx.astype(jnp.float32) * vdq[None, :, None]
-            x = x + (ctx.reshape(T, h * d).astype(x.dtype)
-                     @ w.layer(i, "self_attn.o_proj.weight"))
-            xm = _rms_norm(x, w.layer(i, "post_attention_layernorm"
-                                         ".weight"), cfg.rms_norm_eps)
-            # round-18 sparse serving: the shared FFN entry routes MoE
-            # layers through top-k expert gather-then-dequant (the int8
-            # _Weights expert view), dense layers through SwiGLU — the
-            # unified ragged step serves sparse checkpoints unchanged
-            x = x + _ffn(w, i, xm)
+            with jax.named_scope("attn_qkv"):
+                xin = _rms_norm(x, w.layer(i, "input_layernorm.weight"),
+                                cfg.rms_norm_eps)
+                q = (xin @ w.layer(i, "self_attn.q_proj.weight")
+                     ).reshape(T, h, d)
+                k = (xin @ w.layer(i, "self_attn.k_proj.weight")
+                     ).reshape(T, kvh, d)
+                v = (xin @ w.layer(i, "self_attn.v_proj.weight")
+                     ).reshape(T, kvh, d)
+                q, k = _apply_rope(q, k, cos, sin)
+            with jax.named_scope("kv_scatter"):
+                kw_, vw_, qd = k, v, q
+                if new_k[i].dtype == jnp.int8:
+                    kw_ = _round_int8(kw_.astype(jnp.float32)
+                                      * kv_scales["kq"][i][None, :, None])
+                    vw_ = _round_int8(vw_.astype(jnp.float32)
+                                      * kv_scales["vq"][i][None, :, None])
+                    kdq = jnp.repeat(kv_scales["kdq"][i], rep_)
+                    qd = (qd.astype(jnp.float32)
+                          * kdq[None, :, None]).astype(q.dtype)
+                # scatter ALL rows' K/V first (a chunk row must see its
+                # in-chunk predecessors), then one ragged kernel launch
+                kp = new_k[i].at[phys, :, off, :].set(
+                    kw_.astype(new_k[i].dtype))
+                vp = new_v[i].at[phys, :, off, :].set(
+                    vw_.astype(new_v[i].dtype))
+                new_k[i], new_v[i] = kp, vp
+            with jax.named_scope("paged_attn"):
+                ctx = ragged_paged_decode_raw(
+                    qd, kp, vp, lens, slot, tables, scale=d ** -0.5,
+                    pages_per_step=pages_per_step)
+                if kp.dtype == jnp.int8:
+                    vdq = jnp.repeat(kv_scales["vdq"][i], rep_)
+                    ctx = ctx.astype(jnp.float32) * vdq[None, :, None]
+            with jax.named_scope("attn_out"):
+                x = x + (ctx.reshape(T, h * d).astype(x.dtype)
+                         @ w.layer(i, "self_attn.o_proj.weight"))
+            with jax.named_scope("mlp"):
+                xm = _rms_norm(x, w.layer(i, "post_attention_layernorm"
+                                             ".weight"), cfg.rms_norm_eps)
+                # round-18 sparse serving: the shared FFN entry routes
+                # MoE layers through top-k expert gather-then-dequant
+                # (the int8 _Weights expert view), dense layers through
+                # SwiGLU — the unified ragged step serves sparse
+                # checkpoints unchanged
+                x = x + _ffn(w, i, xm)
         if not with_head:
             # draft cache-mirror launches only need the K/V scatter side
             # effect: skip the [T, hidden] x [hidden, vocab] head matmul
@@ -1133,15 +1165,15 @@ class ContinuousBatchingEngine:
             # rows exist only for their K/V scatter and never produce
             # (or transfer) logits
             x = jnp.take(x, gather, axis=0)
-        x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
-        logits = w.head(x).astype(jnp.float32)        # [G, vocab]
+        with jax.named_scope("lm_head"):
+            x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
+            logits = w.head(x).astype(jnp.float32)    # [G, vocab]
         return tuple(new_k), tuple(new_v), logits
 
     # ---------------- host scheduler ----------------
 
     def add_request(self, prompt, max_new_tokens: int = 32, rid=None,
-                    arrival: float = 0.0, temperature: float = 0.0,
-                    seed: int = 0):
+                    temperature: float = 0.0, seed: int = 0):
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if len(prompt) + max_new_tokens > self.max_seq_len:
             raise ValueError(
@@ -1172,7 +1204,8 @@ class ContinuousBatchingEngine:
             rid = self._next_rid
             self._next_rid += 1
         self.queue.append(Request(int(rid), prompt, int(max_new_tokens),
-                                  arrival, float(temperature), int(seed)))
+                                  float(temperature), int(seed),
+                                  submitted=time.perf_counter()))
         return rid
 
     def _pages_needed(self, tokens: int) -> int:
@@ -1574,6 +1607,16 @@ class ContinuousBatchingEngine:
             if self.prefix_cache is not None:
                 self.prefix_cache.record_hit(matched)
             admitted.append((slot, plen))
+            req.admitted = time.perf_counter()
+            wait_us = int((req.admitted - req.submitted) * 1e6)
+            tot = self.step_totals
+            tot["admitted"] += 1
+            tot["queue_wait_us"] += wait_us
+            tot["queue_wait_us_max"] = max(tot["queue_wait_us_max"], wait_us)
+            with RecordEvent("serving.admit_request", rid=req.rid,
+                             queue_wait_us=wait_us, prompt_len=plen,
+                             cached_tokens=matched):
+                pass
         return admitted
 
     def _sample_row(self, logits_row: np.ndarray, req: Request) -> int:
@@ -1713,27 +1756,97 @@ class ContinuousBatchingEngine:
         target once, sample host-side, commit.  Decode slots emit at
         least one token EVERY step regardless of any co-scheduled
         prompt's length: that is the latency contract chunked prefill
-        exists for."""
-        admitted = self._admit_unified()
+        exists for.
+
+        Each phase is a span under ``serving.step``; a step's counts
+        are known only when its work is done, so they ride on ONE
+        marker at its end (an annotation takes its arguments when it
+        opens) and the phase spans carry none."""
+        tot = self.step_totals
+        tot["steps"] += 1
+        with RecordEvent("serving.step", step=tot["steps"]):
+            with RecordEvent("serving.admit"):
+                admitted = self._admit_unified()
+            decoding = [s for s in range(self.max_slots)
+                        if self.active[s] and s not in self.pending_prompt
+                        and s not in self.handoff_ready]
+            props = {}
+            if self.draft is not None and self.spec_k > 0 and decoding:
+                with RecordEvent("serving.propose"):
+                    props = self._propose(decoding)
+            with RecordEvent("serving.pack"):
+                rows, gather, gathered, metas, enc, counts = \
+                    self._pack_unified(decoding, props)
+            this_dec = np.zeros(self.max_slots, np.int32)
+            dec = np.zeros(self.max_slots, np.int32)
+            n_finished = len(self.finished)
+            produced = 0
+            if counts["rows"]:
+                dec = np.where(self.active, self.seq_lens, 0).astype(np.int32)
+                with RecordEvent("serving.launch"):
+                    # called from HERE, not from a helper: JAX writes the
+                    # Python call stack into every operation's location,
+                    # and one more frame under the first call cost 0.9 s
+                    # of lowering at 16 layers (PERF.md, PR 24)
+                    self.k_pages, self.v_pages, logits = \
+                        ContinuousBatchingEngine._unified_step_jit(
+                            self.params, self.k_pages, self.v_pages,
+                            jnp.asarray(rows), jnp.asarray(self.tables),
+                            self.cos_tab, self.sin_tab,
+                            self_cfg_id=self.cfg_id,
+                            pages_per_step=self.pages_per_step,
+                            kv_scales=self.kv_scales,
+                            gather=jnp.asarray(gather))
+                    if self.draft is not None:
+                        # mirror the SAME rows through the draft: its
+                        # paged cache tracks the target's committed
+                        # stream (prefill chunks included), so the next
+                        # proposal round starts in sync — rejected-draft
+                        # positions land above the rolled-back length,
+                        # exactly like the target's own window writes
+                        self._draft_launch(rows, need_logits=False)
+                with RecordEvent("serving.fetch_logits"):
+                    # the host blocks here until the device has run the
+                    # step, then copies the gathered rows back
+                    logits = np.asarray(logits)
+                self.last_logits = (gathered, logits[:len(gathered)])
+                with RecordEvent("serving.commit"):
+                    produced = self._commit_unified(metas, logits, props,
+                                                    this_dec)
+            self.last_report = {
+                "seq_lens_encoder": enc,
+                "seq_lens_decoder": dec,
+                "seq_lens_this_time": enc + this_dec,
+            }
+            for k in ("rows", "rows_cap", "decode_rows", "prefill_rows"):
+                tot[k] += counts[k]
+            with RecordEvent(
+                    "serving.step_counts", step=tot["steps"],
+                    admitted=len(admitted), queued=len(self.queue),
+                    free_pages=self.alloc.available,
+                    prefill_backlog=sum(
+                        len(p) for p in self.pending_prompt.values()),
+                    produced=produced,
+                    finished=len(self.finished) - n_finished, **counts):
+                pass
+        return produced
+
+    def _pack_unified(self, decoding: List[int], props: Dict[int, tuple]):
+        """The step's packed row schedule (``_unified_step_jit``'s
+        ``rows`` and ``gather``), what each gathered row is, the
+        commit loop's ``metas``, the prompt tokens scheduled by slot,
+        and the step's counts for ``serving.step_counts``."""
         enc = np.zeros(self.max_slots, np.int32)
-        this_dec = np.zeros(self.max_slots, np.int32)
-
-        decoding = [s for s in range(self.max_slots)
-                    if self.active[s] and s not in self.pending_prompt
-                    and s not in self.handoff_ready]
-        props = {}
-        if self.draft is not None and self.spec_k > 0 and decoding:
-            props = self._propose(decoding)
-
         rows = np.zeros((self.rows_cap, 5), np.int32)
         rows[:, 1] = self.trash_page
         rows[:, 4] = -1
         # consumed-row gather schedule: metas carry GATHERED offsets, so
-        # the commit loop below indexes the gathered logits directly
+        # the commit loop indexes the gathered logits directly
         gather = np.zeros(self.gather_cap, np.int32)
         gathered = []                 # (rid, position) per gathered row
         g = 0
         r = 0
+        kv_ctx = 0      # context each scheduled slot attends to, once each
         metas = []
         for s in decoding:
             base = int(self.seq_lens[s])
@@ -1748,7 +1861,9 @@ class ContinuousBatchingEngine:
                 gathered.append((int(self.slot_rid[s]), p))
                 g += 1
                 r += 1
+            kv_ctx += base + len(window)
             metas.append(("verify", s, gstart, len(window)))
+        decode_rows = r
         left = self.prefill_budget
         for s in list(self.prefill_order):
             if left <= 0:
@@ -1763,39 +1878,29 @@ class ContinuousBatchingEngine:
                 r += 1
             left -= chunk
             enc[s] = chunk
+            kv_ctx += base + chunk
             # only the chunk's FINAL row can seed generation — it is
             # the one prefill row the gather hands to the host
             gather[g] = r - 1
             gathered.append((int(self.slot_rid[s]), base + chunk - 1))
             metas.append(("prefill", s, g, chunk))
             g += 1
-        if r == 0:
-            self.last_report = {
-                "seq_lens_encoder": enc,
-                "seq_lens_decoder": np.zeros(self.max_slots, np.int32),
-                "seq_lens_this_time": enc + this_dec,
-            }
-            return 0
+        counts = {
+            "rows": r, "rows_cap": self.rows_cap,
+            "decode_rows": decode_rows, "prefill_rows": r - decode_rows,
+            "slots": len(metas), "gathered": g,
+            # sum of the rows' visibilities: the attention's arithmetic
+            "attn_row_ctx": int(rows[:r, 3].sum()),
+            # the K/V the step has to read at least: its bytes
+            "kv_ctx_tokens": kv_ctx,
+        }
+        return rows, gather, gathered, metas, enc, counts
 
-        dec = np.where(self.active, self.seq_lens, 0).astype(np.int32)
-        rows_j = jnp.asarray(rows)
-        self.k_pages, self.v_pages, logits = \
-            ContinuousBatchingEngine._unified_step_jit(
-                self.params, self.k_pages, self.v_pages, rows_j,
-                jnp.asarray(self.tables), self.cos_tab, self.sin_tab,
-                self_cfg_id=self.cfg_id,
-                pages_per_step=self.pages_per_step,
-                kv_scales=self.kv_scales, gather=jnp.asarray(gather))
-        if self.draft is not None:
-            # mirror the SAME rows through the draft: its paged cache
-            # tracks the target's committed stream (prefill chunks
-            # included), so the next proposal round starts in sync —
-            # rejected-draft positions land above the rolled-back
-            # length, exactly like the target's own window writes
-            self._draft_launch(rows, need_logits=False)
-        logits = np.asarray(logits)
-        self.last_logits = (gathered, logits[:len(gathered)])
-
+    def _commit_unified(self, metas, logits: np.ndarray, props,
+                        this_dec: np.ndarray) -> int:
+        """Sample and commit every scheduled slot from the gathered
+        logits; returns the tokens produced and fills ``this_dec`` (the
+        tokens each slot emitted)."""
         produced = 0
         for kind, s, gstart, n in metas:
             rid = int(self.slot_rid[s])
@@ -1807,6 +1912,7 @@ class ContinuousBatchingEngine:
                 continue
             # prefill chunk: commit the scattered prompt K/V
             req = self.req_info[s]
+            req.chunks += 1
             self.seq_lens[s] += n
             self.prefill_stats[rid]["prefilled"] += n
             pend = self.pending_prompt[s]
@@ -1821,6 +1927,13 @@ class ContinuousBatchingEngine:
             if self.prefix_cache is not None:
                 self.prefix_cache.insert(req.prompt, self.slot_pages[s])
             tok = self._sample_row(logits[gstart], req)
+            prefill_us = int((time.perf_counter() - req.admitted) * 1e6)
+            tot = self.step_totals
+            tot["prefill_us"] += prefill_us
+            tot["prefill_us_max"] = max(tot["prefill_us_max"], prefill_us)
+            with RecordEvent("serving.first_token", rid=rid,
+                             prefill_us=prefill_us, chunks=req.chunks):
+                pass
             if self.prefill_only:
                 # park for KV handoff: pages stay reserved, the first
                 # sampled token rides the handoff record (committed by
@@ -1848,11 +1961,6 @@ class ContinuousBatchingEngine:
             produced += 1
             if tok == self.eos_id or self.budget[s] <= 0:
                 self._finish(s)
-        self.last_report = {
-            "seq_lens_encoder": enc,
-            "seq_lens_decoder": dec,
-            "seq_lens_this_time": enc + this_dec,
-        }
         return produced
 
     def shutdown(self) -> None:
@@ -1874,12 +1982,29 @@ class ContinuousBatchingEngine:
 
     def serving_stats(self) -> Dict[str, Any]:
         """Serving-plane telemetry: prefix-cache counters, per-request
-        prefill accounting (the FLOPs-skip contract) and speculative
-        accepted-length distribution."""
+        prefill accounting (the FLOPs-skip contract), speculative
+        accepted-length distribution and, for a unified engine,
+        ``"steps"``: what its steps did since it was built, for an
+        operator who never traces (how full the steps are: ``rows`` over
+        ``rows_cap``; how long requests queue and prefill, sum and max
+        in seconds).  The same numbers, per step and per request, ride
+        on the ``serving.step_counts``, ``serving.admit_request`` and
+        ``serving.first_token`` markers of a profiler trace."""
         out: Dict[str, Any] = {
             "prefill": dict(self.prefill_stats),
             "accepted_lengths": list(self.accepted_lengths),
         }
+        if self.unified:
+            t = self.step_totals
+            out["steps"] = {
+                **{k: t[k] for k in ("steps", "rows", "rows_cap",
+                                     "decode_rows", "prefill_rows",
+                                     "admitted")},
+                "queue_wait_s": {"sum": t["queue_wait_us"] / 1e6,
+                                 "max": t["queue_wait_us_max"] / 1e6},
+                "prefill_s": {"sum": t["prefill_us"] / 1e6,
+                              "max": t["prefill_us_max"] / 1e6},
+            }
         if self.accepted_lengths:
             out["mean_accepted_len"] = float(
                 np.mean(self.accepted_lengths))

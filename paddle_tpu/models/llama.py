@@ -123,6 +123,7 @@ class LlamaAttention(Layer):
         self.v_proj = nn.Linear(h, cfg.num_key_value_heads * d, bias_attr=False)
         self.o_proj = nn.Linear(cfg.num_attention_heads * d, h, bias_attr=False)
 
+    @jax.named_scope("attention")
     def forward(self, x, cos, sin, attn_mask=None,
                 startend_row_indices=None):
         cfg = self.cfg
@@ -175,6 +176,7 @@ class LlamaMLP(Layer):
         self.up_proj = nn.Linear(cfg.hidden_size, cfg.intermediate_size, bias_attr=False)
         self.down_proj = nn.Linear(cfg.intermediate_size, cfg.hidden_size, bias_attr=False)
 
+    @jax.named_scope("mlp")
     def forward(self, x):
         return self.down_proj(swiglu(self.gate_proj(x), self.up_proj(x)))
 
@@ -211,6 +213,9 @@ class LlamaDecoderLayer(Layer):
         self.mlp = LlamaMLP(cfg)
         _cast_params(self, cfg)
 
+    # the same scope name in every layer (and in a trace: decoder_layer >
+    # attention | mlp); scopes are metadata and compile to nothing
+    @jax.named_scope("decoder_layer")
     def forward(self, x, cos, sin, attn_mask=None,
                 startend_row_indices=None):
         attn = self.self_attn(self.input_layernorm(x), cos, sin,
@@ -481,6 +486,7 @@ def llama_decay_mask(model: Layer) -> Dict[str, bool]:
             for n, _ in model.named_parameters()}
 
 
+@jax.named_scope("loss")
 def _ce_loss(lv, labels, attn_mask, batch_sharding, mesh):
     """Streaming CE: lse + label-logit pick, fp32 accumulation over bf16
     logits — never materializes a full fp32 log_softmax copy
@@ -763,6 +769,7 @@ def build_train_step(model: LlamaForCausalLM, optimizer, mesh: Optional[Mesh] = 
                                        new_params, new_opt_state,
                                        health_gates, health)
 
+    @jax.named_scope("optimizer")
     def apply_update(params, grads, opt_state, lr, step_no):
         # host-offloaded bucketed state (parallel/memory.py) routes the
         # streamed fused AdamW; flat (fused multi-tensor) state the

@@ -43,6 +43,12 @@ from ...core.device import pallas_interpret
 
 from .flash_attention import NEG_INF, _sds
 
+# The kernels' names in a compiled program and a device trace (the
+# benchmark's readers find a kernel's events by them): one place.
+FLASH_DECODE_KERNEL = "flash_decode_attention"
+PAGED_DECODE_KERNEL = "paged_decode_attention"
+RAGGED_PAGED_KERNEL = "ragged_paged_attention"
+
 
 def _decode_kernel(*refs, block_k: int, scale: float):
     """Online-softmax decode body for the DENSE cache layout (the paged
@@ -171,6 +177,7 @@ def flash_decode_raw(q, k_cache, v_cache, seq_lens, scale=None,
         out_shape=_sds((b, kvh, rp, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name=FLASH_DECODE_KERNEL,
         interpret=interpret,
     )(seq, qg, k_cache, v_cache)
     return out[:, :, :rep].reshape(b, h, d)
@@ -382,6 +389,7 @@ def paged_decode_raw(q, key_cache, value_cache, seq_lens, block_tables,
         out_shape=_sds((b, kvh, rp, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name=PAGED_DECODE_KERNEL,
         interpret=interpret,
     )(seq, tables, qg, *([key_cache] * pp), *([value_cache] * pp))
     return out[:, :, :rep].reshape(b, h, d)
@@ -483,6 +491,7 @@ def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
         out_shape=_sds((T, kvh, rp, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name=RAGGED_PAGED_KERNEL,
         interpret=interpret,
     )(lens, slots, tables, qg, *([key_cache] * pp), *([value_cache] * pp))
     return out[:, :, :rep].reshape(T, h, d)

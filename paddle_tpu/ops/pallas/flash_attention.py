@@ -35,6 +35,16 @@ from ...core.device import pallas_interpret
 
 NEG_INF = -1e30
 
+# The kernels' names in a compiled program and a device trace (without
+# them the trace prints the jit or autodiff scope around the call, as
+# ``jvp__`` and ``transpose_jvp___``): one place.
+FWD_KERNEL = "flash_attention_fwd"
+FWD_HEADBATCHED_KERNEL = "flash_attention_fwd_headbatched"
+BWD_HEADBATCHED_KERNEL = "flash_attention_bwd_headbatched"
+BWD_FUSED_KERNEL = "flash_attention_bwd_fused"
+BWD_DQ_KERNEL = "flash_attention_bwd_dq"
+BWD_DKV_KERNEL = "flash_attention_bwd_dkv"
+
 
 def _sds(shape, dtype):
     """ShapeDtypeStruct that works inside shard_map bodies: when manual
@@ -418,6 +428,7 @@ def _flash_forward(q, k, v, causal: bool, scale: float, h: int, kvh: int,
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=FWD_KERNEL,
         interpret=interpret,
     )(cnt, kx, *inputs)
 
@@ -573,6 +584,7 @@ def _hb_flash_forward(q, k, v, causal, scale, block_q=256, block_k=1024,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=FWD_HEADBATCHED_KERNEL,
         interpret=interpret,
     )(q, k, v)
 
@@ -744,6 +756,7 @@ def _hb_flash_backward(q, k, v, o, lse, do, causal, scale, interpret=False):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name=BWD_HEADBATCHED_KERNEL,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk[:, :sk], dv[:, :sk]
@@ -1150,6 +1163,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, scale: float,
                        _sds((bkv, sk_pad, d), v.dtype)),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            name=BWD_FUSED_KERNEL,
             interpret=interpret,
         )(cnt, kx, *inputs)
         return dq, dk[:, :sk], dv[:, :sk]
@@ -1195,6 +1209,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, scale: float,
         out_shape=_sds((bh, sq, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=BWD_DQ_KERNEL,
         interpret=interpret,
     )(cnt, kx, *dq_inputs)
 
@@ -1245,6 +1260,7 @@ def _flash_backward(q, k, v, o, lse, do, causal: bool, scale: float,
                    _sds((bkv, sk, d), v.dtype)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=BWD_DKV_KERNEL,
         interpret=interpret,
     )(cntq, qx, *kv_inputs)
     return dq, dk, dv

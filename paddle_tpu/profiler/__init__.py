@@ -1,8 +1,13 @@
 """paddle_tpu.profiler (analog of python/paddle/profiler/profiler.py:358).
 
-TPU-native: host-side RecordEvent spans + jax.profiler (XLA/TPU trace) into
-one Perfetto/chrome trace; plus the in-training throughput meter
-(reference: python/paddle/profiler/timer.py).
+``RecordEvent`` is the program's one span.  Entering it opens a
+``jax.profiler.TraceAnnotation``, so the span is in any profiler trace
+that runs, whoever started it, on the same clock as the device's
+operations and with its keyword arguments as the event's stats; while a
+``Profiler`` of this module records, the span is also kept in memory
+for ``summary()`` and the chrome trace.  With no trace running a span
+is a no-op.  Plus the in-training throughput meter (reference:
+python/paddle/profiler/timer.py).
 """
 
 from __future__ import annotations
@@ -10,6 +15,8 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import tempfile
+import threading
 import time
 from dataclasses import dataclass, field
 from enum import Enum
@@ -35,34 +42,59 @@ class ProfilerState(Enum):
 
 _host_events: List[Dict[str, Any]] = []
 _recording = [False]
+_open_spans = threading.local()     # .stack: names of this thread's open spans
 
 
 class RecordEvent:
-    """Host event span (analog of paddle/fluid/platform/profiler/event_tracing.h
-    RecordEvent)."""
+    """A span (analog of paddle/fluid/platform/profiler/event_tracing.h
+    RecordEvent).  ``args`` are Python numbers or strings; they ride on
+    the trace event as its stats and on the recorded host event as
+    ``args``, beside the enclosing span's name (``parent``)."""
 
-    def __init__(self, name: str, event_type: str = "UserDefined"):
+    def __init__(self, name: str, event_type: str = "UserDefined", **args):
         self.name = name
         self.event_type = event_type
-        self._begin = None
+        self.args = args
+        self._ann = None
+        self._begin = None          # set only while a Profiler records
+        self._parent = None
 
     def begin(self):
-        self._begin = time.perf_counter_ns()
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        if _recording[0]:
+            stack = getattr(_open_spans, "stack", None)
+            if stack is None:
+                stack = _open_spans.stack = []
+            self._parent = stack[-1] if stack else None
+            stack.append(self.name)
+            self._begin = time.perf_counter_ns()
 
     def end(self):
-        if self._begin is None or not _recording[0]:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._begin is None:
             return
-        import threading
-
-        _host_events.append({
+        begin, self._begin = self._begin, None
+        _open_spans.stack.pop()
+        if not _recording[0]:
+            return
+        event = {
             "name": self.name, "cat": self.event_type, "ph": "X",
-            "ts": self._begin / 1000.0,
-            "dur": (time.perf_counter_ns() - self._begin) / 1000.0,
+            "ts": begin / 1000.0,
+            "dur": (time.perf_counter_ns() - begin) / 1000.0,
             # full ident: masking could collide two threads into one
             # (pid, tid) sweep lane and corrupt the per-thread self-time
             # subtraction in summarize_events
             "pid": os.getpid(), "tid": threading.get_ident(),
-        })
+        }
+        args = dict(self.args)
+        if self._parent:
+            args["parent"] = self._parent
+        if args:
+            event["args"] = args
+        _host_events.append(event)
 
     def __enter__(self):
         self.begin()
@@ -78,7 +110,8 @@ class Profiler:
                  timer_only=False, record_shapes=False, profile_memory=False,
                  with_flops=False):
         self.timer_only = timer_only
-        self._jax_trace_dir = None
+        self.trace_dir = None       # where the device's trace was written
+        self._tracing = False
         self._running = False
 
     def start(self):
@@ -86,20 +119,19 @@ class Profiler:
         _host_events.clear()
         self._running = True
         if not self.timer_only and _is_tpu():
-            self._jax_trace_dir = "/tmp/paddle_tpu_profile"
-            try:
-                jax.profiler.start_trace(self._jax_trace_dir)
-            except Exception:
-                self._jax_trace_dir = None
+            # the device's trace, with every RecordEvent in it
+            self.trace_dir = tempfile.mkdtemp(prefix="paddle_tpu_profile_")
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
+            self._tracing = True
 
     def stop(self):
         _recording[0] = False
         self._running = False
-        if self._jax_trace_dir:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+        if self._tracing:
+            self._tracing = False
+            jax.profiler.stop_trace()
 
     def step(self):
         from ..common import flags as _flags
@@ -147,7 +179,9 @@ def summarize_events(events, time_unit="ms", top_n: int = 30) -> str:
     dicts (ph == 'X'): nested span durations are subtracted from their
     parent (a RecordEvent wrapping ten op spans reports only its own
     overhead), so per-name ratios sum to <= 100% of the profiled wall
-    span.  Also works on an EXPORTED trace: ``summarize_chrome_trace``."""
+    span.  A row is one name under one enclosing span (the last column;
+    as ``RecordEvent`` recorded it, else as the intervals nest).  Also
+    works on an EXPORTED trace: ``summarize_chrome_trace``."""
     div = {"s": 1e6, "ms": 1e3, "us": 1.0}[time_unit]
     # interval sweep PER (pid, tid): nesting only holds within one
     # thread — mixing threads would subtract unrelated concurrent spans
@@ -157,7 +191,7 @@ def summarize_events(events, time_unit="ms", top_n: int = 30) -> str:
         if e.get("ph") == "X":
             by_thread.setdefault((e.get("pid", 0), e.get("tid", 0)),
                                  []).append(e)
-    stats: Dict[str, list] = {}
+    stats: Dict[tuple, list] = {}
     lo, hi = float("inf"), 0.0
     for spans in by_thread.values():
         spans.sort(key=lambda e: (e["ts"], -e["dur"]))
@@ -166,6 +200,7 @@ def summarize_events(events, time_unit="ms", top_n: int = 30) -> str:
         # self time (direct children only; grandchildren already
         # reduced the child)
         self_time = [e["dur"] for e in spans]
+        parent = [None] * len(spans)
         open_stack: list = []
         for i, e in enumerate(spans):
             ts, dur = e["ts"], e["dur"]
@@ -174,12 +209,15 @@ def summarize_events(events, time_unit="ms", top_n: int = 30) -> str:
                 open_stack.pop()
             if open_stack:
                 self_time[open_stack[-1]] -= dur
+                parent[i] = spans[open_stack[-1]]["name"]
+            parent[i] = (e.get("args") or {}).get("parent", parent[i])
             open_stack.append(i)
             lo = min(lo, ts)
             hi = max(hi, ts + dur)
         for i, e in enumerate(spans):
             st = max(self_time[i], 0.0)
-            s = stats.setdefault(e["name"], [0, 0.0, 0.0, float("inf")])
+            s = stats.setdefault((e["name"], parent[i]),
+                                 [0, 0.0, 0.0, float("inf")])
             s[0] += 1
             s[1] += st
             s[2] = max(s[2], st)
@@ -187,13 +225,14 @@ def summarize_events(events, time_unit="ms", top_n: int = 30) -> str:
     wall = max(hi - lo, 1e-9)
     header = (f"{'Name':<36}{'Calls':>8}{'Total(' + time_unit + ')':>14}"
               f"{'Avg(' + time_unit + ')':>12}{'Max(' + time_unit + ')':>12}"
-              f"{'Min(' + time_unit + ')':>12}{'Ratio(%)':>10}")
+              f"{'Min(' + time_unit + ')':>12}{'Ratio(%)':>10}  Under")
     lines = ["-" * len(header), header, "-" * len(header)]
     rows = sorted(stats.items(), key=lambda kv: -kv[1][1])[:top_n]
-    for name, (calls, total, mx, mn) in rows:
+    for (name, under), (calls, total, mx, mn) in rows:
         lines.append(f"{name[:35]:<36}{calls:>8}{total / div:>14.3f}"
                      f"{total / calls / div:>12.3f}{mx / div:>12.3f}"
-                     f"{mn / div:>12.3f}{100.0 * total / wall:>10.2f}")
+                     f"{mn / div:>12.3f}{100.0 * total / wall:>10.2f}"
+                     f"  {under or '-'}")
     lines.append("-" * len(header))
     return "\n".join(lines)
 

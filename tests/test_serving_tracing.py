@@ -1,0 +1,340 @@
+"""The measurement inside the unified serving step: ``serving.*`` spans
+in whatever profiler trace runs (``profiler.RecordEvent`` opens a
+``jax.profiler.TraceAnnotation``), their per-step counts and per-request
+stamps, the same counts kept in ``serving_stats()["steps"]``, and the
+``jax.named_scope``s of the step, which change nothing that is compiled.
+"""
+
+import contextlib
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu import profiler
+from paddle_tpu.inference.serving import ContinuousBatchingEngine
+from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.models.generation import self_draft_params
+
+PHASES = ("serving.admit", "serving.pack", "serving.launch",
+          "serving.fetch_logits", "serving.commit")
+MARKERS = ("serving.admit_request", "serving.first_token",
+           "serving.step_counts")
+STEP_COUNTS = ("step", "admitted", "queued", "free_pages", "rows", "rows_cap",
+               "decode_rows", "prefill_rows", "slots", "prefill_backlog",
+               "attn_row_ctx", "kv_ctx_tokens", "gathered", "produced",
+               "finished")
+PROMPT_LENS = (20, 9, 13, 30)
+NEW_TOKENS = 5
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    import paddle_tpu as paddle
+
+    state = paddle.get_rng_state()
+    paddle.seed(20240924)
+    cfg = LlamaConfig.debug(vocab=64, hidden=32, layers=2, heads=4,
+                            kv_heads=2, inter=64, max_pos=128)
+    model = LlamaForCausalLM(cfg)
+    params = {k: jnp.asarray(v) for k, v in model.functional_state().items()}
+    paddle.set_rng_state(state)
+    return cfg, params
+
+
+def _engine(cfg, params, draft=False):
+    kw = {}
+    if draft:
+        dcfg, dparams = self_draft_params(cfg, params, 1)
+        kw = dict(draft_cfg=dcfg, draft_params=dparams, speculative_k=2)
+    # two slots for four requests: the last two wait for a slot
+    return ContinuousBatchingEngine(
+        cfg, params, max_slots=2, num_pages=33, page_size=16, max_seq_len=128,
+        prefill_token_budget=8, enable_prefix_cache=True, **kw)
+
+
+def _serve(eng):
+    rng = np.random.default_rng(0)
+    rids = [eng.add_request(rng.integers(1, 64, n).astype(np.int32),
+                            max_new_tokens=NEW_TOKENS) for n in PROMPT_LENS]
+    return rids, {f.rid: f.tokens.tolist() for f in eng.run()}
+
+
+def _program_spans(trace_dir):
+    """``[(name, start_ns, end_ns, stats)]`` of the ``serving.*`` events
+    of the one trace under ``trace_dir``, in time order."""
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(path)
+    out = []
+    for plane in data.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("serving."):
+                    out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda sp: (sp[1], -sp[2]))
+
+
+@contextlib.contextmanager
+def _trace(trace_dir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+@pytest.fixture(scope="module", params=["plain", "draft"])
+def traced(request, tiny, tmp_path_factory):
+    """One traced run of the tiny engine (with and without a draft
+    model): its spans, its counters, what it served, and what the same
+    engine serves with no trace running."""
+    draft = request.param == "draft"
+    _, untraced_tokens = _serve(_engine(*tiny, draft=draft))
+    eng = _engine(*tiny, draft=draft)
+    trace_dir = tmp_path_factory.mktemp(f"trace-{request.param}")
+    with _trace(trace_dir):
+        rids, tokens = _serve(eng)
+        eng.step()                  # nothing left to do: the early return
+    return {"draft": draft, "spans": _program_spans(trace_dir), "rids": rids,
+            "tokens": tokens, "untraced_tokens": untraced_tokens,
+            "stats": eng.serving_stats(), "rows_cap": eng.rows_cap}
+
+
+def _named(traced, name):
+    return [sp for sp in traced["spans"] if sp[0] == name]
+
+
+@pytest.mark.parametrize("name", PHASES + ("serving.propose",) + MARKERS)
+def test_every_span_is_in_the_trace_under_a_step(traced, name):
+    spans = _named(traced, name)
+    if name == "serving.propose" and not traced["draft"]:
+        assert not spans            # only where a draft model proposes
+        return
+    assert spans, f"no {name} in the trace"
+    steps = _named(traced, "serving.step")
+    for _, a, b, _ in spans:
+        assert any(s <= a and b <= e for _, s, e, _ in steps), (name, a, b)
+
+
+def test_the_phases_cover_their_step(traced):
+    """A step's time is its phases': what lies between them (building
+    the list of decoding slots, the report, the marker) stays small."""
+    working = {c["step"] for _, _, _, c in _named(traced, "serving.step_counts")
+               if c["rows"]}
+    step_ns = phase_ns = 0
+    for _, s, e, arg in _named(traced, "serving.step"):
+        if arg["step"] not in working:
+            continue
+        step_ns += e - s
+        phase_ns += sum(b - a for name, a, b, _ in traced["spans"]
+                        if name in PHASES + ("serving.propose",)
+                        and s <= a and b <= e)
+    assert phase_ns >= 0.9 * step_ns
+
+
+def test_step_numbers_run_on_and_each_step_ends_with_its_counts(traced):
+    steps = _named(traced, "serving.step")
+    counts = _named(traced, "serving.step_counts")
+    assert [a["step"] for *_, a in steps] == list(range(1, len(steps) + 1))
+    assert [a["step"] for *_, a in counts] == [a["step"] for *_, a in steps]
+    for (_, s, e, _), (_, a, b, c) in zip(steps, counts):
+        assert s <= a and b <= e and set(c) == set(STEP_COUNTS)
+        inside = [sp for sp in traced["spans"] if s <= sp[1] and sp[2] <= e]
+        assert max(inside, key=lambda sp: sp[1])[0] == "serving.step_counts"
+
+
+@pytest.mark.parametrize("key", ["rows", "slots", "gathered", "context",
+                                 "backlog", "produced", "empty"])
+def test_per_step_counts_hold_together(traced, key):
+    counts = [c for *_, c in _named(traced, "serving.step_counts")]
+    last = counts[-1]               # the step with nothing left to do
+    if key == "rows":
+        for c in counts:
+            assert c["rows"] == c["decode_rows"] + c["prefill_rows"] \
+                <= c["rows_cap"] == traced["rows_cap"]
+        assert any(c["decode_rows"] and c["prefill_rows"] for c in counts)
+    elif key == "slots":
+        assert all(c["slots"] <= 2 and (c["slots"] > 0) == (c["rows"] > 0)
+                   for c in counts)
+    elif key == "gathered":
+        # every verify-window row and one row per prefill chunk
+        assert all(c["decode_rows"] <= c["gathered"]
+                   <= c["decode_rows"] + c["slots"] for c in counts)
+    elif key == "context":
+        # a slot's context is read once, each of its rows attends to it
+        assert all(c["kv_ctx_tokens"] <= c["attn_row_ctx"]
+                   <= c["kv_ctx_tokens"] * max(c["rows"], 1) for c in counts)
+        assert all(c["kv_ctx_tokens"] >= c["rows"] for c in counts)
+    elif key == "backlog":
+        # prompt tokens admitted and not yet prefilled: ends at nothing
+        assert counts[0]["prefill_backlog"] > 0
+        assert last["prefill_backlog"] == 0 and last["queued"] == 0
+    elif key == "produced":
+        assert sum(c["produced"] for c in counts) \
+            == NEW_TOKENS * len(PROMPT_LENS)
+        assert sum(c["finished"] for c in counts) == len(PROMPT_LENS)
+    else:
+        assert last["rows"] == last["slots"] == last["produced"] == 0
+        assert last["rows_cap"] == traced["rows_cap"]
+        assert last["free_pages"] >= counts[0]["free_pages"]
+
+
+@pytest.mark.parametrize("key", ["steps", "rows", "rows_cap", "decode_rows",
+                                 "prefill_rows", "admitted", "queue_wait_s",
+                                 "prefill_s"])
+def test_the_spans_arguments_add_up_to_serving_stats(traced, key):
+    kept = traced["stats"]["steps"][key]
+    counts = [c for *_, c in _named(traced, "serving.step_counts")]
+    if key == "steps":
+        assert kept == len(counts) == counts[-1]["step"]
+    elif key == "queue_wait_s":
+        us = [a["queue_wait_us"] for *_, a in _named(traced, "serving.admit_request")]
+        assert kept == {"sum": sum(us) / 1e6, "max": max(us) / 1e6}
+    elif key == "prefill_s":
+        us = [a["prefill_us"] for *_, a in _named(traced, "serving.first_token")]
+        assert kept == {"sum": sum(us) / 1e6, "max": max(us) / 1e6}
+    else:
+        assert kept == sum(c[key] for c in counts)
+
+
+def test_each_request_is_stamped_once_at_admission_and_first_token(traced):
+    admits = [a for *_, a in _named(traced, "serving.admit_request")]
+    firsts = [a for *_, a in _named(traced, "serving.first_token")]
+    assert sorted(a["rid"] for a in admits) == sorted(traced["rids"])
+    assert sorted(a["rid"] for a in firsts) == sorted(traced["rids"])
+    by_rid = {a["rid"]: a for a in admits}
+    for rid, n in zip(traced["rids"], PROMPT_LENS):
+        assert by_rid[rid]["prompt_len"] == n
+        assert by_rid[rid]["cached_tokens"] == \
+            traced["stats"]["prefill"][rid]["cached_tokens"]
+    # the last two were submitted while both slots were taken: they
+    # waited for a whole request to finish, the first two for nothing
+    waits = [by_rid[rid]["queue_wait_us"] for rid in traced["rids"]]
+    assert min(waits) >= 0 and min(waits[2:]) > 10 * max(waits[:2]) > 0
+    for a in firsts:
+        # 8 prompt tokens a step: a prompt of n takes ceil(n / 8) chunks
+        # when it prefills alone, fewer tokens a chunk when it shares
+        n = by_rid[a["rid"]]["prompt_len"] - by_rid[a["rid"]]["cached_tokens"]
+        assert a["chunks"] >= -(-n // 8) and a["prefill_us"] > 0
+
+
+def test_greedy_outputs_do_not_depend_on_a_trace_running(traced):
+    assert traced["tokens"] == traced["untraced_tokens"]
+    assert all(len(t) == NEW_TOKENS for t in traced["tokens"].values())
+
+
+def test_no_span_is_recorded_without_a_trace_and_stats_are_always_on(tiny):
+    eng = _engine(*tiny)
+    before = len(profiler._host_events)
+    _serve(eng)
+    assert len(profiler._host_events) == before
+    steps = eng.serving_stats()["steps"]
+    assert steps["steps"] > 0 and steps["admitted"] == len(PROMPT_LENS)
+    assert 0 < steps["rows"] < steps["rows_cap"]
+    assert steps["queue_wait_s"]["max"] <= steps["queue_wait_s"]["sum"]
+    # the legacy chunked engine has no unified step to count
+    legacy = ContinuousBatchingEngine(*tiny, max_slots=2, num_pages=33,
+                                      page_size=16, max_seq_len=128)
+    assert "steps" not in legacy.serving_stats()
+
+
+def test_profiler_records_the_engines_spans_with_their_nesting(tiny, tmp_path):
+    """``Profiler`` + ``RecordEvent`` is the same one system: the
+    engine's spans land in ``summary()`` and the chrome trace, each with
+    its arguments and the span that enclosed it."""
+    eng = _engine(*tiny)
+    with profiler.Profiler(timer_only=True) as prof:
+        with profiler.RecordEvent("serve_all", "UserDefined", requests=4):
+            _serve(eng)
+    events = {e["name"]: e for e in profiler._host_events}
+    assert events["serve_all"]["args"] == {"requests": 4}
+    assert events["serving.step"]["args"]["parent"] == "serve_all"
+    assert events["serving.pack"]["args"] == {"parent": "serving.step"}
+    assert events["serving.admit_request"]["args"]["parent"] == "serving.admit"
+    assert events["serving.first_token"]["args"]["parent"] == "serving.commit"
+    assert events["serving.step_counts"]["args"]["rows_cap"] == eng.rows_cap
+    table = prof.summary(top_n=40)
+    rows = {tuple(ln.split()[i] for i in (0, -1)) for ln in table.splitlines()
+            if ln.startswith(("serv", "serve_all"))}
+    assert {("serve_all", "-"), ("serving.step", "serve_all"),
+            ("serving.fetch_logits", "serving.step"),
+            ("serving.first_token", "serving.commit")} <= rows
+    path = tmp_path / "trace.json"
+    prof.export_chrome_tracing(str(path))
+    assert ("serving.launch", "serving.step") in {
+        tuple(ln.split()[i] for i in (0, -1))
+        for ln in profiler.summarize_chrome_trace(str(path), top_n=40).splitlines()
+        if ln.startswith("serving.")}
+
+
+def test_a_span_left_open_when_recording_stops_is_dropped():
+    with profiler.Profiler(timer_only=True):
+        span = profiler.RecordEvent("left_open")
+        span.begin()
+        with profiler.RecordEvent("inside"):
+            pass
+    span.end()
+    names = [e["name"] for e in profiler._host_events]
+    assert names == ["inside"]
+    assert profiler._host_events[0]["args"] == {"parent": "left_open"}
+    with profiler.Profiler(timer_only=True):
+        with profiler.RecordEvent("after"):
+            pass
+    assert "args" not in profiler._host_events[0]     # no stale parent
+
+
+def test_the_jitted_step_is_called_from_the_step_itself(tiny, monkeypatch):
+    """JAX writes the Python call stack into every operation's location:
+    one helper frame between ``_step_unified`` and the jitted call cost
+    0.6 s of lowering at 16 layers on the chip (PERF.md, PR 24)."""
+    import sys
+
+    real = ContinuousBatchingEngine._unified_step_jit
+    callers = []
+
+    def spy(*args, **kw):
+        callers.append(sys._getframe(1).f_code.co_name)
+        return real(*args, **kw)
+
+    monkeypatch.setattr(ContinuousBatchingEngine, "_unified_step_jit",
+                        staticmethod(spy))
+    _serve(_engine(*tiny))
+    assert callers and set(callers) == {"_step_unified"}
+
+
+def _optimized_hlo(eng):
+    """The optimized HLO of the engine's unified step with everything
+    that names a source or a scope taken out: the tables of files and
+    stack frames at its head and each instruction's ``metadata=``."""
+    raw = ContinuousBatchingEngine._unified_step_jit.__wrapped__
+    # a function of its own each time, or jit hands back the cached trace
+    fn = jax.jit(lambda *a, **k: raw(*a, **k),
+                 static_argnames=("self_cfg_id", "pages_per_step", "with_head"))
+    text = fn.lower(
+        eng.params, eng.k_pages, eng.v_pages,
+        jnp.zeros((eng.rows_cap, 5), jnp.int32), jnp.asarray(eng.tables),
+        eng.cos_tab, eng.sin_tab, self_cfg_id=eng.cfg_id,
+        pages_per_step=eng.pages_per_step,
+        gather=jnp.zeros(eng.gather_cap, jnp.int32)).compile().as_text()
+    scoped = "attn_qkv" in text and "lm_head" in text
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    if "\nStackFrames" in text:
+        text = text[text.index("\n\n", text.index("\nStackFrames")):]
+    return text, scoped
+
+
+def test_named_scopes_change_nothing_that_is_compiled(tiny, monkeypatch):
+    eng = _engine(*tiny)
+    with_scopes, scoped = _optimized_hlo(eng)
+    assert scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without, scoped = _optimized_hlo(eng)
+    assert not scoped
+    assert with_scopes == without

@@ -13,6 +13,7 @@ library, and every xdist worker imports every test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,12 +55,20 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, one_chip, *shapes, kernels=()):
     """Compile ``fn`` for the described chip; the kernel must be in the
-    program as a Mosaic custom call."""
+    program as a Mosaic custom call, and each of ``kernels`` (the
+    ``name=`` of a ``pallas_call``) as the name of such an instruction:
+    a device trace prints the instruction, and the benchmark's readers
+    find a kernel's events by it."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for name in kernels:
+        # under autodiff the scope wraps the name: jvp_<name>_
+        assert any(re.match(rf"\s*(ROOT )?%(\w+_)?{name}_*(\.\d+)? = ", ln)
+                   for ln in calls), f"no instruction named after {name}"
     return text
 
 
@@ -81,12 +90,15 @@ def test_flash_forward_compiles(one_chip, d):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_raw
 
     _compile(lambda q, k, v: flash_attention_raw(
-        q, k, v, causal=True, interpret=False), one_chip, *_qkv(d))
+        q, k, v, causal=True, interpret=False), one_chip, *_qkv(d),
+        kernels=["flash_attention_fwd_headbatched"])
 
 
 @pytest.mark.parametrize("d", HEAD_DIMS)
 def test_flash_forward_backward_compiles(one_chip, d):
-    _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)), one_chip, *_qkv(d))
+    _compile(jax.grad(_flash_loss, argnums=(0, 1, 2)), one_chip, *_qkv(d),
+             kernels=["flash_attention_fwd_headbatched",
+                      "flash_attention_bwd_headbatched"])
 
 
 @pytest.mark.parametrize("d", HEAD_DIMS)
@@ -99,7 +111,9 @@ def test_flash_forward_backward_in_scan_compiles(one_chip, d):
             return qc - 1e-3 * g.astype(qc.dtype), val
         return lax.scan(body, q, None, length=2)
 
-    _compile(prog, one_chip, *_qkv(d))
+    _compile(prog, one_chip, *_qkv(d),
+             kernels=["flash_attention_fwd_headbatched",
+                      "flash_attention_bwd_headbatched"])
 
 
 @pytest.mark.parametrize("d", HEAD_DIMS)
@@ -115,7 +129,51 @@ def test_ragged_paged_decode_compiles(one_chip, d, cache_dtype):
         q, kc, vc, lens, slot, tables, interpret=False), one_chip,
         ((rows, HEADS, d), jnp.bfloat16), cache, cache,
         ((rows,), jnp.int32), ((rows,), jnp.int32),
-        ((slots, 16), jnp.int32))
+        ((slots, 16), jnp.int32), kernels=["ragged_paged_attention"])
+
+
+@pytest.mark.parametrize("d", HEAD_DIMS)
+def test_paged_decode_compiles(one_chip, d):
+    """The legacy chunked serving path's kernel (one query row a slot)."""
+    from paddle_tpu.ops.pallas.decode_attention import paged_decode_raw
+
+    slots, pages, page = 8, 129, 128
+    cache = ((pages, KV_HEADS, page, d), jnp.bfloat16)
+    _compile(lambda q, kc, vc, lens, tables: paged_decode_raw(
+        q, kc, vc, lens, tables, interpret=False), one_chip,
+        ((slots, HEADS, d), jnp.bfloat16), cache, cache,
+        ((slots,), jnp.int32), ((slots, 16), jnp.int32),
+        kernels=["paged_decode_attention"])
+
+
+@pytest.mark.parametrize("d", HEAD_DIMS)
+def test_flash_decode_compiles(one_chip, d):
+    """``generate()``'s decode step over a dense cache."""
+    from paddle_tpu.ops.pallas.decode_attention import flash_decode_raw
+
+    b, t_max = 8, 2048
+    cache = ((b, KV_HEADS, t_max, d), jnp.bfloat16)
+    _compile(lambda q, kc, vc, lens: flash_decode_raw(
+        q, kc, vc, lens, interpret=False), one_chip,
+        ((b, HEADS, d), jnp.bfloat16), cache, cache, ((b,), jnp.int32),
+        kernels=["flash_decode_attention"])
+
+
+@pytest.mark.parametrize("d", HEAD_DIMS)
+def test_flash_packed_forward_backward_compiles(one_chip, d):
+    """The general kernels (segment ids: padded or packed batches) and
+    the backward that goes with them, by name."""
+    from paddle_tpu.ops.pallas import flash_attention as F
+
+    def loss(q, k, v, ids):
+        o = F.flash_attention_raw(q, k, v, causal=True, interpret=False,
+                                  q_segment_ids=ids, kv_segment_ids=ids)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *_qkv(d),
+                    ((2, 2048), jnp.int32), kernels=[F.FWD_KERNEL])
+    assert f"{F.BWD_FUSED_KERNEL}_" in text or (
+        f"{F.BWD_DQ_KERNEL}_" in text and f"{F.BWD_DKV_KERNEL}_" in text)
 
 
 # expert widths of the dropless-MoE path (PR 17): K=2048, N=1408
