@@ -33,7 +33,9 @@ floor):
   previous [L, pages, ...] slab forced a slice + whole-layer
   dynamic-update per layer per step, which XLA materialised as layer-pool
   copies (~2x the pool's HBM bytes per step on top of the weight
-  stream);
+  stream).  The write (``_write_kv_rows``) indexes (page, head, offset),
+  so it scatters rows of ``d`` in place; with the head left as a slice
+  XLA relaid the whole pool around the kernel (PERF.md section 6, PR 25);
 - the paged kernel iterates ``pages_per_step`` physical pages per grid
   step (tune_pages_per_step), recovering the dense decode kernel's
   ~512-token window instead of paying one grid trip per page;
@@ -181,6 +183,19 @@ def _round_int8(x):
     quantization)."""
     y = jnp.sign(x) * jnp.floor(jnp.abs(x) + 0.5)
     return jnp.clip(y, -127, 127).astype(jnp.int8)
+
+
+def _write_kv_rows(pool, phys, off, x):
+    """Write token rows ``x`` [T, kvh, d] into ``pool`` [pages, kvh,
+    page, d]: row ``t`` to page ``phys[t]``, in-page offset ``off[t]``.
+    The index carries the HEAD too, so the update window is one
+    contiguous row of ``d`` and XLA scatters in place into the donated
+    pool.  ``pool.at[phys, :, off, :]`` has a strided window [kvh, d],
+    for which XLA copied the WHOLE pool into another layout and back
+    (PERF.md section 6, PR 25)."""
+    heads = jnp.arange(pool.shape[1])
+    return pool.at[phys[:, None], heads[None, :], off[:, None], :].set(
+        x.astype(pool.dtype))
 
 
 class PageAllocator:
@@ -898,12 +913,11 @@ class ContinuousBatchingEngine:
                     kdq = jnp.repeat(kv_scales["kdq"][i], rep_)
                     qd = (qd.astype(jnp.float32)
                           * kdq[None, :, None]).astype(q.dtype)
-                # ONE scatter into this layer's pool (per-layer pools:
-                # no [L, ...] slab slice/update on the hot path)
-                kp = k_pages[i].at[phys, :, slot, :].set(
-                    kw_.astype(k_pages[i].dtype))
-                vp = v_pages[i].at[phys, :, slot, :].set(
-                    vw_.astype(v_pages[i].dtype))
+                # ONE in-place row scatter into this layer's pool
+                # (per-layer pools: no [L, ...] slab slice/update on
+                # the hot path)
+                kp = _write_kv_rows(k_pages[i], phys, slot, kw_)
+                vp = _write_kv_rows(v_pages[i], phys, slot, vw_)
                 new_k.append(kp)
                 new_v.append(vp)
                 ctx = paged_decode_raw(qd, kp, vp,
@@ -1126,11 +1140,11 @@ class ContinuousBatchingEngine:
                     qd = (qd.astype(jnp.float32)
                           * kdq[None, :, None]).astype(q.dtype)
                 # scatter ALL rows' K/V first (a chunk row must see its
-                # in-chunk predecessors), then one ragged kernel launch
-                kp = new_k[i].at[phys, :, off, :].set(
-                    kw_.astype(new_k[i].dtype))
-                vp = new_v[i].at[phys, :, off, :].set(
-                    vw_.astype(new_v[i].dtype))
+                # in-chunk predecessors), then one ragged kernel launch.
+                # The index carries the head: rows of d land in place, no
+                # whole-pool relayout (PERF.md section 6, PR 25)
+                kp = _write_kv_rows(new_k[i], phys, off, kw_)
+                vp = _write_kv_rows(new_v[i], phys, off, vw_)
                 new_k[i], new_v[i] = kp, vp
             with jax.named_scope("paged_attn"):
                 ctx = ragged_paged_decode_raw(

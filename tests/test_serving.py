@@ -749,3 +749,48 @@ def test_unified_throttle_sheds_and_restores(tiny_model):
     with pytest.raises(ValueError):
         eng.throttle(prefill_token_budget=32)    # above the static cap
     eng.shutdown()
+
+
+# (phys, off) of one step's packed rows at 7 pages of 16, the last the
+# trash page: what ``_pack_unified`` builds for each kind of row
+_KV_WRITE_TRASH = 6
+_KV_WRITE_CASES = {
+    # a 24-token prefill chunk from position 10: page 2, then page 4
+    "chunk_over_page_boundary": (np.where(np.arange(10, 34) < 16, 2, 4),
+                                 np.arange(10, 34) % 16),
+    # 3 decode rows, then padding: all at the trash page's offset 0
+    "duplicate_padding_rows": ([0, 3, 5] + [_KV_WRITE_TRASH] * 13,
+                               [7, 15, 0] + [0] * 13),
+    # two slots' k+1 = 4 rows each, the second crossing into page 1
+    "verify_window": ([3, 3, 3, 3, 5, 5, 1, 1],
+                      [4, 5, 6, 7, 14, 15, 0, 1]),
+}
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("case", list(_KV_WRITE_CASES))
+def test_write_kv_rows_equals_the_window_scatter(case, cache_dtype):
+    """``_write_kv_rows`` (the head carried in the index, rows of ``d``
+    scattered in place) against the spelling it replaced,
+    ``pool.at[phys, :, off, :].set(x)``: the same values at the same
+    addresses, bit for bit."""
+    from paddle_tpu.inference.serving import _write_kv_rows
+
+    pages, kvh, page, d = 7, 2, 16, 8
+    phys, off = (jnp.asarray(a, jnp.int32) for a in _KV_WRITE_CASES[case])
+    rng = np.random.default_rng(len(case))
+    # whole numbers up to 127: exact in bf16 and in int8 alike
+    pool = jnp.asarray(rng.integers(-127, 128, (pages, kvh, page, d)),
+                       cache_dtype)
+    x = jnp.asarray(rng.integers(-127, 128, (len(phys), kvh, d)),
+                    cache_dtype)
+    want = np.asarray(pool.at[phys, :, off, :].set(x))
+    got = np.asarray(_write_kv_rows(pool, phys, off, x))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    # padding rows race for the trash page's first row in both
+    # spellings; nothing reads it
+    live = np.ones(got.shape, bool)
+    live[_KV_WRITE_TRASH, :, 0, :] = False
+    np.testing.assert_array_equal(got[live], want[live])
+    assert not np.array_equal(got, np.asarray(pool))

@@ -146,6 +146,92 @@ def test_paged_decode_compiles(one_chip, d):
         kernels=["paged_decode_attention"])
 
 
+# the serving cell's engine (benchmarks/configs/
+# mistral-7b-v0.3-serve-l16.json): 705 pages of 128, 8 KV heads x 128,
+# 32 slots of 22 pages, 32 decode rows + a 256-token prefill chunk
+_CELL_PAGES, _CELL_PAGE, _CELL_SLOTS, _CELL_SEQ, _CELL_BUDGET = (
+    705, 128, 32, 2816, 256)
+
+
+def _serving_step_text(one_chip, monkeypatch, cache_dtype, unified):
+    """Optimized HLO of the engine's step program (the unified ragged
+    step, or the legacy decode chunk) for the described chip, 2 layers
+    at the cell's KV geometry.  The engine is built small (24 pages, on
+    the CPU) and gives the call through ``analysis_entry()``; the shapes
+    it is lowered with carry the cell's 705 pages."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.ops.pallas import decode_attention
+
+    # the engine's own call leaves ``interpret`` to the backend, which
+    # is the CPU here: steer it to the compiled kernel from the test
+    monkeypatch.setattr(decode_attention, "pallas_interpret",
+                        lambda: False)
+    layers = 2
+    cfg = LlamaConfig.debug(vocab=256, hidden=KV_HEADS * 128, layers=layers,
+                            heads=KV_HEADS, kv_heads=KV_HEADS, inter=256,
+                            max_pos=_CELL_SEQ)
+    params = {k: jnp.asarray(v, jnp.bfloat16) for k, v in
+              LlamaForCausalLM(cfg).functional_state().items()}
+    small = 24
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=_CELL_SLOTS, num_pages=small,
+        page_size=_CELL_PAGE, max_seq_len=_CELL_SEQ, pages_per_step=4,
+        prefill_token_budget=_CELL_BUDGET if unified else 0,
+        cache_dtype=cache_dtype)
+    if cache_dtype == jnp.int8 and not unified:
+        # the legacy path calibrates at its first prefill
+        ones = jnp.ones((layers, KV_HEADS), jnp.float32)
+        eng.kv_scales = {"kq": ones, "kdq": ones, "vq": ones, "vdq": ones}
+    fn, args, kwargs, _ = eng.analysis_entry()
+    if unified:
+        assert args[3].shape == (_CELL_SLOTS + _CELL_BUDGET, 5)
+    pool = (_CELL_PAGES, KV_HEADS, _CELL_PAGE, 128)
+
+    def described(x):
+        shape = pool if x.shape == (small,) + pool[1:] else x.shape
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=one_chip)
+
+    static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step",
+                                         "chunk") if k in kwargs}
+    text = fn.lower(*jax.tree.map(described, args), **static,
+                    **jax.tree.map(described, kwargs)).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text, pool, 2 * layers
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("unified", [True, False],
+                         ids=["unified_step", "decode_chunk"])
+def test_serving_step_writes_kv_in_place(one_chip, monkeypatch, unified,
+                                         cache_dtype):
+    """The step's K/V write touches the rows it writes: no instruction
+    of the compiled step copies or transposes a whole pool (the old
+    write, ``pool.at[phys, :, off, :].set``, had XLA move each pool into
+    a layout of its own and back around the kernel: 64 copies of 185 MB
+    a step in the serving cell, PERF.md section 6, PR 25), and every
+    pool is updated in the buffer it came in."""
+    text, pool, npools = _serving_step_text(one_chip, monkeypatch,
+                                            cache_dtype, unified)
+    count = int(np.prod(pool))
+    moved = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"(copy|transpose)\(", ln)
+        if m and np.prod([int(x) for x in m.group(1).split(",")]) == count:
+            moved.append(ln.strip()[:160])
+    assert not moved, "\n".join(moved)
+    dims = ",".join(map(str, pool))
+    entry = text[text.index("\nENTRY "):]
+    pools = {int(n) for n in re.findall(
+        rf"= \w+\[{dims}\]\S* parameter\((\d+)\)", entry)}
+    header = next(ln for ln in text.splitlines() if "HloModule" in ln)
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    assert len(pools) == npools and pools <= aliased, (pools, aliased)
+
+
 @pytest.mark.parametrize("d", HEAD_DIMS)
 def test_flash_decode_compiles(one_chip, d):
     """``generate()``'s decode step over a dense cache."""
