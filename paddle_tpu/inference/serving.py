@@ -198,6 +198,50 @@ def _write_kv_rows(pool, phys, off, x):
         x.astype(pool.dtype))
 
 
+@dataclasses.dataclass(frozen=True)
+class PagedLayout:
+    """What a model tells the engine of its paged state and of its part
+    of the unified step.  Every layer has TWO pools, and one page id
+    names a page in each pool of every layer, so slots, tables, the
+    allocator and the prefix cache never know what a page holds.
+
+    ``rows``: the shape of one token's row in each pool.  A Llama-shaped
+    decoder's (``kv_layout``) are its K and V rows ``[kvh, d]``, laid
+    out head-major as the paged kernels read them (``[pages, kvh, page,
+    d]``); a model with ``paged_layout()`` on its config brings its own
+    (DeepSeek-V3.2: a latent row ``[640]`` and an index key ``[128]``,
+    ``[pages, page, n]``) and ``step``, a jitted function under
+    ``_unified_step_jit``'s signature whose third result is ``(logits,
+    device counts)``.  ``row_counts(rows, ctx_tokens)`` gives the
+    step's counts the packed rows determine, ``device_counts`` names
+    those the step returns; both ride on ``serving.step_counts`` and
+    are summed in ``serving_stats()["steps"]``.
+
+    Pools other than K and V are served by the unified step alone;
+    what else the engine can do with K/V pages (the legacy chunked
+    path, a draft model's mirror, an int8 cache, the host tier, the
+    prefill-only handoff) refuses them at construction."""
+    name: str
+    rows: tuple
+    head_major: bool = True
+    step: Any = None
+    row_counts: Any = None
+    device_counts: tuple = ()
+    count_names: tuple = ()             # row_counts' keys + device_counts
+    pages_per_step: Any = None          # page size -> pages a kernel turn
+
+    def pool_shapes(self, num_pages: int, page_size: int):
+        if self.head_major:
+            return tuple((num_pages, r[0], page_size, *r[1:])
+                         for r in self.rows)
+        return tuple((num_pages, page_size, *r) for r in self.rows)
+
+
+def kv_layout(cfg) -> PagedLayout:
+    row = (cfg.num_key_value_heads, cfg.head_dim)
+    return PagedLayout(name="kv", rows=(row, row))
+
+
 class PageAllocator:
     """Host-side physical-page free list with EXPLICIT refcounts (reuse
     is LIFO so hot pages stay cache/TLB friendly).
@@ -644,12 +688,15 @@ class ContinuousBatchingEngine:
         self.params = params
         self.cfg_id = register_config(cfg)
         _, self.cos_tab, self.sin_tab = _CFGS[self.cfg_id]
+        self.layout = (cfg.paged_layout() if hasattr(cfg, "paged_layout")
+                       else kv_layout(cfg))
+        kv = self.layout.name == "kv"
         self.max_slots = int(max_slots)
         self.max_seq_len = int(max_seq_len or cfg.max_position_embeddings)
         if page_size == "auto":
             page_size = tune_page_size(
                 self.max_slots, cfg.num_key_value_heads, cfg.head_dim,
-                self.max_seq_len)
+                self.max_seq_len) if kv else 128
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
         # the LAST physical page is a reserved scribble target: masked
@@ -661,7 +708,6 @@ class ContinuousBatchingEngine:
         self.eos_id = int(eos_id)
 
         L = cfg.num_hidden_layers
-        kvh, d = cfg.num_key_value_heads, cfg.head_dim
         dt = next(iter(v for k, v in params.items()
                        if not k.endswith("._scale"))).dtype
         if not jnp.issubdtype(dt, jnp.floating):
@@ -671,9 +717,28 @@ class ContinuousBatchingEngine:
         self.cache_dtype = dt
         if pages_per_step == "auto":
             pages_per_step = tune_pages_per_step(
-                self.max_slots, kvh, self.page_size, d, self.pages_per_seq,
-                dt)
+                self.max_slots, cfg.num_key_value_heads, self.page_size,
+                cfg.head_dim, self.pages_per_seq, dt) if kv \
+                else self.layout.pages_per_step(self.page_size)
         self.pages_per_step = int(pages_per_step)
+        if not kv:
+            # the unified step is all that knows these pools (PagedLayout)
+            for what, asked in (
+                    ("the legacy chunked path (prefill_token_budget unset): "
+                     "its prefill and decode-chunk programs are Llama's",
+                     not prefill_token_budget),
+                    ("a draft model: its mirror launches assume the "
+                     "target's K/V geometry", draft_params is not None
+                     or speculative_k),
+                    ("an int8 cache: the scales are calibrated per KV head",
+                     dt == jnp.int8),
+                    ("the host tier: demotion copies K and V pages",
+                     host_tier_pages),
+                    ("prefill_only and the KV handoff: the wire format is "
+                     "K and V pages", prefill_only)):
+                if asked:
+                    raise ValueError(f"{self.layout.name} pools do not "
+                                     f"support {what}")
         # int8 cache: frozen per-(layer, kv-head) scales, auto-calibrated
         # from the FIRST prefill's K/V absmax (2x headroom) — a single
         # self-consistent quant/dequant pair for the whole run (the
@@ -683,12 +748,11 @@ class ContinuousBatchingEngine:
         # PER-LAYER pools: each decode-step cache write is one direct
         # scatter into its layer's pool (a fused [L, ...] slab would cost
         # a slice + whole-layer dynamic-update per layer per step)
-        self.k_pages = tuple(
-            jnp.zeros((self.num_pages, kvh, self.page_size, d), dt)
-            for _ in range(L))
-        self.v_pages = tuple(
-            jnp.zeros((self.num_pages, kvh, self.page_size, d), dt)
-            for _ in range(L))
+        # (whatever the layout's two pools hold, they go by k_pages and
+        # v_pages here: for "latent", latent rows and index keys)
+        ka, vb = self.layout.pool_shapes(self.num_pages, self.page_size)
+        self.k_pages = tuple(jnp.zeros(ka, dt) for _ in range(L))
+        self.v_pages = tuple(jnp.zeros(vb, dt) for _ in range(L))
         # host-side slot state
         self.tables = np.full((self.max_slots, self.pages_per_seq), -1,
                               np.int32)
@@ -807,7 +871,7 @@ class ContinuousBatchingEngine:
         self.step_totals: Dict[str, int] = dict.fromkeys(
             ("steps", "rows", "rows_cap", "decode_rows", "prefill_rows",
              "admitted", "queue_wait_us", "queue_wait_us_max",
-             "prefill_us", "prefill_us_max"), 0)
+             "prefill_us", "prefill_us_max", *self.layout.count_names), 0)
         # spec telemetry: one entry per verify window, bounded so a
         # long-running server doesn't grow it without limit
         self.accepted_lengths: Deque[int] = deque(maxlen=65536)
@@ -1434,6 +1498,9 @@ class ContinuousBatchingEngine:
         if not self.unified or self.prefill_only:
             raise ValueError("adopt_request needs a decode-capable "
                              "unified engine")
+        if self.layout.name != "kv":
+            raise ValueError(f"{self.layout.name} pools do not support the "
+                             f"KV handoff: the wire format is K and V pages")
         plen = int(meta["seq_len"])
         first = int(meta["first_token"])
         if int(meta["page_size"]) != self.page_size:
@@ -1802,8 +1869,10 @@ class ContinuousBatchingEngine:
                     # Python call stack into every operation's location,
                     # and one more frame under the first call cost 0.9 s
                     # of lowering at 16 layers (PERF.md, PR 24)
+                    step_jit = self.layout.step \
+                        or ContinuousBatchingEngine._unified_step_jit
                     self.k_pages, self.v_pages, logits = \
-                        ContinuousBatchingEngine._unified_step_jit(
+                        step_jit(
                             self.params, self.k_pages, self.v_pages,
                             jnp.asarray(rows), jnp.asarray(self.tables),
                             self.cos_tab, self.sin_tab,
@@ -1822,6 +1891,10 @@ class ContinuousBatchingEngine:
                 with RecordEvent("serving.fetch_logits"):
                     # the host blocks here until the device has run the
                     # step, then copies the gathered rows back
+                    if self.layout.device_counts:
+                        logits, dev = logits
+                        counts.update(zip(self.layout.device_counts,
+                                          (int(v) for v in np.asarray(dev))))
                     logits = np.asarray(logits)
                 self.last_logits = (gathered, logits[:len(gathered)])
                 with RecordEvent("serving.commit"):
@@ -1834,6 +1907,11 @@ class ContinuousBatchingEngine:
             }
             for k in ("rows", "rows_cap", "decode_rows", "prefill_rows"):
                 tot[k] += counts[k]
+            for k in self.layout.count_names:
+                if k.endswith("_max"):
+                    tot[k] = max(tot[k], counts.get(k, 0))
+                else:
+                    tot[k] += counts.get(k, 0)
             with RecordEvent(
                     "serving.step_counts", step=tot["steps"],
                     admitted=len(admitted), queued=len(self.queue),
@@ -1908,6 +1986,8 @@ class ContinuousBatchingEngine:
             # the K/V the step has to read at least: its bytes
             "kv_ctx_tokens": kv_ctx,
         }
+        if self.layout.row_counts is not None:
+            counts.update(self.layout.row_counts(rows[:r], kv_ctx))
         return rows, gather, gathered, metas, enc, counts
 
     def _commit_unified(self, metas, logits: np.ndarray, props,
@@ -2013,7 +2093,7 @@ class ContinuousBatchingEngine:
             out["steps"] = {
                 **{k: t[k] for k in ("steps", "rows", "rows_cap",
                                      "decode_rows", "prefill_rows",
-                                     "admitted")},
+                                     "admitted", *self.layout.count_names)},
                 "queue_wait_s": {"sum": t["queue_wait_us"] / 1e6,
                                  "max": t["queue_wait_us_max"] / 1e6},
                 "prefill_s": {"sum": t["prefill_us"] / 1e6,
@@ -2199,7 +2279,7 @@ class ContinuousBatchingEngine:
                              self.cfg.num_key_value_heads), jnp.float32)
             kv_scales = {"kq": ones, "kdq": ones,
                          "vq": ones, "vdq": ones}
-        fn = ContinuousBatchingEngine._unified_step_jit
+        fn = self.layout.step or ContinuousBatchingEngine._unified_step_jit
         args = (self.params, self.k_pages, self.v_pages,
                 jnp.asarray(rows), jnp.asarray(self.tables),
                 self.cos_tab, self.sin_tab)

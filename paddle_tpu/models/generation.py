@@ -210,12 +210,146 @@ def self_draft_params(cfg, params, num_layers: int):
 _MOE_FFN_BLOCK_ROWS = 8
 
 
-def _moe_ffn(w: _Weights, i, xm):
+def _route_softmax_topk(cfg, logits, bias=None):
+    """Top-k of the softmax over all experts, weights normalised over
+    the k chosen (the reference ``fused_moe`` semantics)."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_ids = lax.top_k(probs, int(cfg.moe_top_k))
+    return top_ids, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+
+def _route_sigmoid_groups(cfg, logits, bias=None):
+    """Group-limited top-k over sigmoid scores (DeepSeek-V3's
+    ``noaux_tc``): selection runs on ``score + bias`` (the bias steers
+    load and never reaches a gate): the experts form ``n_group``
+    groups of consecutive ids, a group scores the sum of its 2 best,
+    the ``topk_group`` best groups stay, and the k best experts among
+    them are chosen.  Gates are the UNBIASED scores, normalised over
+    all k chosen and scaled by ``routed_scaling_factor``."""
+    k, e = int(cfg.moe_top_k), logits.shape[-1]
+    scores = jax.nn.sigmoid(logits)
+    pick = scores if bias is None else scores + bias.astype(jnp.float32)
+    groups = pick.reshape(-1, cfg.n_group, e // cfg.n_group)
+    gscore = jnp.sum(lax.top_k(groups, 2)[0], axis=-1)
+    _, gkeep = lax.top_k(gscore, int(cfg.topk_group))
+    gmask = jnp.zeros_like(gscore, bool).at[
+        jnp.arange(gscore.shape[0])[:, None], gkeep].set(True)
+    pick = jnp.where(jnp.repeat(gmask, e // cfg.n_group, axis=-1), pick,
+                     -jnp.inf)
+    _, top_ids = lax.top_k(pick, k)
+    gates = jnp.take_along_axis(scores, top_ids, axis=-1)
+    if cfg.norm_topk_prob:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    return top_ids, gates * cfg.routed_scaling_factor
+
+
+_MOE_ROUTES = {"softmax": _route_softmax_topk,
+               "sigmoid_groups": _route_sigmoid_groups}
+
+
+@jax.named_scope("moe_experts")
+def _moe_experts(w: _Weights, i, x2, top_ids, top_p, lo, hi, e_all, stats):
+    """The held experts' part of ``_moe_ffn``: the sorted ragged
+    dispatch of the token copies, three grouped matmuls, the weighted
+    combine.  ``x2`` [T, hidden]; ``top_ids`` / ``top_p`` [T, k] over
+    the router's ``e_all`` experts, of which [lo, hi) are in the bank."""
+    from ..ops.pallas.grouped_matmul import (align_rows,
+                                             grouped_matmul_raw,
+                                             segment_starts)
+
+    cfg = w.cfg
+    pre = f"model.layers.{i}.mlp."
+    e, k = hi - lo, top_ids.shape[-1]
+    # ---- sorted ragged dispatch: copies argsorted by expert tile the
+    # block-aligned segment windows the kernel contract wants.  A copy
+    # of an absent expert sorts behind every segment (id ``e``), lands
+    # in a row of its own past them and carries weight 0
+    bm = int(getattr(cfg, "moe_block_rows", _MOE_FFN_BLOCK_ROWS))
+    tk = x2.shape[0] * k
+    held = (top_ids >= lo) & (top_ids < hi)
+    if stats is not None:
+        held = held & stats["valid"][:, None]
+    absent = e < e_all or stats is not None   # may a copy have no segment?
+    flat_ids = (jnp.where(held, top_ids - lo, e) if absent
+                else top_ids).reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(flat_ids)                     # stable; absent last
+    counts = jnp.bincount(flat_ids, length=e + 1).astype(jnp.int32)[:e]
+    seg_st = segment_starts(counts, bm)
+    run_st = jnp.cumsum(counts) - counts              # unaligned starts
+    if stats is not None:
+        stats["moe_rows_routed"].append(jnp.sum(stats["valid"]) * k)
+        stats["moe_rows_held"].append(jnp.sum(held))
+        stats["moe_expert_rows_max"].append(jnp.max(counts))
+
+    def bank(proj):
+        name = pre + f"experts.{proj}.weight"
+        wq = w.p[name]
+        sc = w.p.get(name + "._scale")
+        if sc is None:
+            return wq.astype(x2.dtype), None
+        return wq, sc                                 # int8 + [E, out]
+
+    wids = jnp.arange(e, dtype=jnp.int32)
+
+    def gmm(xin, proj):
+        wq, sc = bank(proj)
+        return grouped_matmul_raw(xin, wq, seg_st, counts, wids,
+                                  block_rows=bm, w_scale=sc)
+
+    def experts_of(n):
+        """The layer's routed part from the first ``n`` sorted copies
+        (they must hold every copy that has a segment)."""
+        order_n = order[:n]
+        token_of = order_n // k
+        sorted_ids = flat_ids[order_n]
+        wsorted = jnp.where(held, top_p, 0.0).reshape(-1)[order_n]
+        rpad = int(align_rows(n, bm) + e * bm)        # static worst case
+        if absent:
+            seg_of = jnp.minimum(sorted_ids, e - 1)
+            pos = jnp.arange(n, dtype=jnp.int32) - run_st[seg_of]
+            dest = jnp.where(sorted_ids < e, seg_st[seg_of] + pos, rpad)
+            rpad += bm
+        else:
+            pos = jnp.arange(n, dtype=jnp.int32) - run_st[sorted_ids]
+            dest = seg_st[sorted_ids] + pos
+        xr = jnp.zeros((rpad, x2.shape[1]), x2.dtype).at[dest].set(
+            x2[token_of])
+        gate = gmm(xr, "gate_proj")
+        up = gmm(xr, "up_proj")
+        eo = gmm(jax.nn.silu(gate) * up, "down_proj")     # [rpad, h]
+        # ---- combine: gather each copy's expert output, weighted
+        # scatter-add back into token order
+        ys = eo[dest]
+        if absent:             # the absent copies' row is unspecified
+            ys = jnp.where((sorted_ids < e)[:, None], ys, 0)
+        return jnp.zeros_like(x2).at[token_of].add(
+            ys * wsorted.astype(x2.dtype)[:, None])
+
+    # a chip that holds e of e_all experts sees about tk * e / e_all of
+    # the copies: the dispatch is sized to twice that, and to all tk
+    # (dropless: nothing is ever left out) only in a step where more
+    # copies than that chose a held expert
+    few = int(align_rows(2 * tk * e // e_all, bm))
+    if not absent or few >= tk:
+        return experts_of(tk)
+    return lax.cond(jnp.sum(counts) <= few, lambda: experts_of(few),
+                    lambda: experts_of(tk))
+
+
+@jax.named_scope("shared_expert")
+def _shared_expert(w: _Weights, i, x2):
+    sg = x2 @ w.layer(i, "mlp.shared_expert.gate_proj.weight")
+    su = x2 @ w.layer(i, "mlp.shared_expert.up_proj.weight")
+    return (jax.nn.silu(sg) * su) @ w.layer(
+        i, "mlp.shared_expert.down_proj.weight")
+
+
+def _moe_ffn(w: _Weights, i, xm, stats=None):
     """Top-k expert routing for one MoE layer on the ``_Weights`` view
-    (round-20 dropless serving): fp32 router logits -> top-k softmax
-    weights (normalized over the selected experts, the reference
-    ``fused_moe`` semantics) -> token copies argsorted by expert into
-    block-aligned ragged segments -> ONE grouped-matmul launch per
+    (round-20 dropless serving): fp32 router logits -> the chosen
+    experts and their gates (``cfg.moe_scoring``: ``softmax``, the
+    default, or ``sigmoid_groups``) -> token copies argsorted by expert
+    into block-aligned ragged segments -> ONE grouped-matmul launch per
     projection (ops/pallas/grouped_matmul) applying each expert's
     ``[in, out]`` slice to its row window, SwiGLU, then a weighted
     scatter back to token order.
@@ -232,89 +366,60 @@ def _moe_ffn(w: _Weights, i, xm):
     dequantized slice ever materialized in HBM.  ``xm`` is any
     [..., hidden] batch (the unified step's packed [T, h] rows, a
     decode chunk's [slots, 1, h], prefill's [b, s, h]); routing is per
-    token row."""
-    from ..ops.pallas.grouped_matmul import (align_rows,
-                                             grouped_matmul_raw,
-                                             segment_starts)
+    token row.
 
+    THE expert-share layer: the router keeps its full width, and
+    ``cfg.experts_held = (lo, hi)`` says which of its experts the bank
+    holds (expert parallelism's share of one chip; absent: all).  A
+    copy routed to an absent expert takes part in the gates'
+    normalisation and adds nothing: what the other chips would add is
+    left out, and nothing stands in for them.  A checkpoint with
+    ``mlp.shared_expert.*`` leaves adds that always-on SwiGLU once;
+    ``mlp.router.bias`` is the selection bias of ``sigmoid_groups``.
+    ``stats`` (a dict with the rows' ``valid`` mask) takes the layer's
+    counts: copies routed, copies of held experts, the fullest expert;
+    rows that are not valid count nowhere and reach no expert."""
     cfg = w.cfg
     shape = xm.shape
     x2 = xm.reshape(-1, shape[-1])
+    pre = f"model.layers.{i}.mlp."
     router = w.layer(i, "mlp.router.weight")          # [h, E], fp
     # E comes from the CHECKPOINT (MoE-ness is checkpoint-driven, via
     # is_moe_layer) — a cfg.num_experts desync must be loud, not a
     # silently zeroed expert output
-    e = int(router.shape[-1])
-    bank_e = int(
-        w.p[f"model.layers.{i}.mlp.experts.gate_proj.weight"].shape[0])
-    if bank_e != e:
+    e_all = int(router.shape[-1])
+    lo, hi = getattr(cfg, "experts_held", None) or (0, e_all)
+    e = hi - lo
+    bank_e = int(w.p[pre + "experts.gate_proj.weight"].shape[0])
+    if bank_e != e or not 0 <= lo < hi <= e_all:
         raise ValueError(
-            f"layer {i}: router routes {e} experts but the stacked bank "
-            f"holds {bank_e}")
+            f"layer {i}: router routes {e_all} experts, experts "
+            f"[{lo}, {hi}) are held, but the stacked bank holds {bank_e}")
     k = int(cfg.moe_top_k)
-    if not 1 <= k <= e:
+    if not 1 <= k <= e_all:
         raise ValueError(
-            f"layer {i}: moe_top_k={k} outside [1, {e}] — set "
+            f"layer {i}: moe_top_k={k} outside [1, {e_all}] — set "
             f"LlamaConfig.moe_top_k for this sparse checkpoint")
-    logits = x2.astype(jnp.float32) @ router.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_ids = lax.top_k(probs, k)              # [T, k]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    with jax.named_scope("moe_route"):
+        logits = x2.astype(jnp.float32) @ router.astype(jnp.float32)
+        top_ids, top_p = _MOE_ROUTES[getattr(cfg, "moe_scoring", "softmax")](
+            cfg, logits, w.p.get(pre + "router.bias"))    # [T, k] each
 
-    # ---- sorted ragged dispatch: copies argsorted by expert tile the
-    # block-aligned segment windows the kernel contract wants
-    bm = _MOE_FFN_BLOCK_ROWS
-    tk = x2.shape[0] * k
-    flat_ids = top_ids.reshape(-1).astype(jnp.int32)
-    order = jnp.argsort(flat_ids)                     # stable
-    token_of = order // k
-    sorted_ids = flat_ids[order]
-    wsorted = top_p.reshape(-1)[order]
-    counts = jnp.bincount(flat_ids, length=e).astype(jnp.int32)
-    seg_st = segment_starts(counts, bm)
-    run_st = jnp.cumsum(counts) - counts              # unaligned starts
-    pos = jnp.arange(tk, dtype=jnp.int32) - run_st[sorted_ids]
-    dest = seg_st[sorted_ids] + pos
-    rpad = int(align_rows(tk, bm) + e * bm)           # static worst case
-    xr = jnp.zeros((rpad, x2.shape[1]), x2.dtype).at[dest].set(
-        x2[token_of])
-
-    def bank(proj):
-        name = f"model.layers.{i}.mlp.experts.{proj}.weight"
-        wq = w.p[name]
-        sc = w.p.get(name + "._scale")
-        if sc is None:
-            return wq.astype(x2.dtype), None
-        return wq, sc                                 # int8 + [E, out]
-
-    wids = jnp.arange(e, dtype=jnp.int32)
-
-    def gmm(xin, proj):
-        wq, sc = bank(proj)
-        return grouped_matmul_raw(xin, wq, seg_st, counts, wids,
-                                  block_rows=bm, w_scale=sc)
-
-    gate = gmm(xr, "gate_proj")
-    up = gmm(xr, "up_proj")
-    eo = gmm(jax.nn.silu(gate) * up, "down_proj")     # [rpad, h]
-
-    # ---- combine: gather each copy's expert output, weighted
-    # scatter-add back into token order
-    ys = eo[dest]
-    y = jnp.zeros_like(x2).at[token_of].add(
-        ys * wsorted.astype(x2.dtype)[:, None])
+    y = _moe_experts(w, i, x2, top_ids, top_p, lo, hi, e_all, stats)
+    if pre + "shared_expert.gate_proj.weight" in w.p:
+        y = y + _shared_expert(w, i, x2)
     return y.reshape(shape)
 
 
-def _ffn(w: _Weights, i, xm):
+def _ffn(w: _Weights, i, xm, stats=None):
     """Layer ``i``'s FFN on the ``_Weights`` view: dense SwiGLU, or —
     when the checkpoint carries this layer's stacked expert weights —
-    top-k expert routing (``_moe_ffn``).  The ONE implementation the
+    top-k expert routing (``_moe_ffn``, which ``stats`` is for).  The ONE implementation the
     prefill/decode ``_block``, the serving decode chunk and the
     unified ragged step all share, so a sparse checkpoint serves
     through every path that serves a dense one."""
     if w.is_moe_layer(i):
-        return _moe_ffn(w, i, xm)
+        return _moe_ffn(w, i, xm, stats)
     gate = xm @ w.layer(i, "mlp.gate_proj.weight")
     up = xm @ w.layer(i, "mlp.up_proj.weight")
     return (jax.nn.silu(gate) * up) @ w.layer(i, "mlp.down_proj.weight")
@@ -578,9 +683,12 @@ def register_config(cfg):
     if cfg_key not in _CFGS:
         from .llama import _rope_tables
 
-        cos_tab, sin_tab = _rope_tables(cfg.head_dim,
-                                        cfg.max_position_embeddings,
-                                        cfg.rope_theta)
+        if hasattr(cfg, "rope_tables"):     # its own (YaRN, partial)
+            cos_tab, sin_tab = cfg.rope_tables()
+        else:
+            cos_tab, sin_tab = _rope_tables(cfg.head_dim,
+                                            cfg.max_position_embeddings,
+                                            cfg.rope_theta)
         _CFGS[cfg_key] = (cfg, cos_tab, sin_tab)
     return cfg_key
 

@@ -40,9 +40,12 @@ Contract (callers: parallel/expert.py dropless body, models/generation.py
   contribute exact zeros to dW;
 - output rows outside ``[start, start+len)`` of some segment are
   unspecified; callers only gather valid rows.
-- the whole [K, N] weight slice rides in one block (no K/N tiling): fine
-  for MoE FFN slices up to a few MB of VMEM; tile before lifting to
-  multi-thousand hidden sizes.
+- a weight slice of up to ``_WHOLE_SLICE_BYTES`` rides in one block of
+  the ``(S, nbmax)`` grid above.  A larger one (7168 x 2048 in bf16 is
+  29 MB) takes the BLOCK-MAJOR form instead: the grid is (N tiles, row
+  blocks), each row block reads its segment's weight id from a
+  scalar-prefetched table, and with the N tile outermost a slice's tile
+  is fetched once however many row blocks its segment has.
 
 int8 expert banks: pass the raw quantized bank as ``w`` plus the
 per-(slice, out-channel) dequant scales ``w_scale`` [E, N] — the kernel
@@ -64,6 +67,9 @@ from jax.experimental.pallas import tpu as pltpu
 from ...core.device import pallas_interpret
 
 from .flash_attention import _sds
+
+# its name in a compiled program and a device trace
+GROUPED_MATMUL_BLOCKS_KERNEL = "grouped_matmul_blocks"
 
 
 def align_rows(n, block_rows: int):
@@ -110,15 +116,109 @@ def _gmm_kernel(*refs, block_rows: int, has_scale: bool):
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
+# a [K, N] slice up to this rides whole in one block (the dropless-MoE
+# path's 2048 x 1408 in bf16 is 5.8 MB, double-buffered by the pipeline);
+# a larger one is tiled over N, a tile of at most _TILE_BYTES
+_WHOLE_SLICE_BYTES = 6 * 1024 * 1024
+_TILE_BYTES = 4 * 1024 * 1024
+
+
+def _tile_n(K: int, N: int, itemsize: int) -> int:
+    """N itself where the slice rides whole; else the widest multiple of
+    128 that divides N with a [K, tile] block of at most ``_TILE_BYTES``."""
+    if K * N * itemsize <= _WHOLE_SLICE_BYTES or N % 128:
+        return N
+    best = 128
+    for t in range(128, N + 1, 128):
+        if N % t == 0 and K * t * itemsize <= _TILE_BYTES:
+            best = t
+    return best
+
+
+def _gmm_blocks_kernel(*refs, has_scale: bool):
+    wid_ref, used_ref = refs[:2]
+    if has_scale:
+        x_ref, w_ref, scale_ref, o_ref = refs[2:]
+    else:
+        x_ref, w_ref, o_ref = refs[2:]
+        scale_ref = None
+
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _():
+        xb = x_ref[...]                     # [bm, K]
+        wb = w_ref[0]                       # [K, tn]
+        if wb.dtype == jnp.int8:
+            wb = wb.astype(xb.dtype)
+        acc = jax.lax.dot_general(
+            xb, wb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if scale_ref is not None:
+            acc = acc * scale_ref[0][None, :]
+        o_ref[...] = acc.astype(o_ref.dtype)
+
+
+def _grouped_matmul_blocks(x, w, starts, lens, wids, bm: int, tn: int,
+                           w_scale, interpret):
+    """Block-major grouped matmul (module docstring).  Segments tile
+    ``[0, sum(align(len)))`` densely, so row block ``b`` belongs to the
+    segment whose aligned window holds it; blocks past the last
+    segment's end park on the pad block and skip their work."""
+    R, K = x.shape
+    N = w.shape[2]
+    nblocks = R // bm
+    ends = (starts + align_rows(lens, bm)) // bm          # in blocks
+    blk = jnp.arange(nblocks, dtype=jnp.int32)
+    seg_of = jnp.minimum(jnp.searchsorted(ends, blk, side="right"),
+                         starts.shape[0] - 1)
+    blk_wid = wids[seg_of].astype(jnp.int32)
+    used = jnp.max(ends).astype(jnp.int32).reshape(1)
+    xp = jnp.concatenate([x, jnp.zeros((bm, K), x.dtype)], axis=0)
+
+    def live(b, used_ref):
+        # the last used block again (DMA elided) once past the end
+        return jnp.minimum(b, jnp.maximum(used_ref[0] - 1, 0))
+
+    def x_map(n, b, wid_ref, used_ref):
+        return (live(b, used_ref), 0)
+
+    def w_map(n, b, wid_ref, used_ref):
+        return (wid_ref[live(b, used_ref)], 0, n)
+
+    def o_map(n, b, wid_ref, used_ref):
+        return (jnp.where(b < used_ref[0], b, nblocks), n)
+
+    in_specs = [pl.BlockSpec((bm, K), x_map), pl.BlockSpec((1, K, tn), w_map)]
+    operands = [xp, w]
+    if w_scale is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, tn), lambda n, b, wid_ref, used_ref:
+            (wid_ref[live(b, used_ref)], n)))
+        operands.append(w_scale.astype(jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_gmm_blocks_kernel, has_scale=w_scale is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(N // tn, nblocks),
+            in_specs=in_specs, out_specs=pl.BlockSpec((bm, tn), o_map)),
+        out_shape=_sds((R + bm, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name=GROUPED_MATMUL_BLOCKS_KERNEL,
+        interpret=interpret,
+    )(blk_wid, used, *operands)
+    return out[:R]
+
+
 def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
                        block_rows: int = 128, w_scale=None,
-                       interpret=None):
+                       interpret=None, tile_n=None):
     """Ragged grouped matmul: ``y[start_s:start_s+len_s] =
     x[start_s:start_s+len_s] @ w[wid_s]`` for every segment ``s`` in one
     launch.  x [R, K] (R % block_rows == 0, see module contract);
     w [E, K, N]; seg_starts/seg_lens/seg_wids [S] int32; optional
     w_scale [E, N] dequant scales for an int8 ``w``.  Returns y [R, N]
-    in x's dtype (rows outside valid segments unspecified)."""
+    in x's dtype (rows outside valid segments unspecified).  ``tile_n``
+    forces the block-major form with that N tile (tests); left None the
+    slice's size decides."""
     R, K = x.shape
     E, Kw, N = w.shape
     if Kw != K:
@@ -131,6 +231,11 @@ def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
         interpret = pallas_interpret()
     if R == 0 or S == 0:
         return jnp.zeros((R, N), x.dtype)
+    tn = _tile_n(K, N, w.dtype.itemsize) if tile_n is None else int(tile_n)
+    if tn != N:
+        return _grouped_matmul_blocks(
+            x, w, seg_starts.astype(jnp.int32), seg_lens.astype(jnp.int32),
+            seg_wids.astype(jnp.int32), bm, tn, w_scale, interpret)
     pad_blk = R // bm                       # the appended safe block
     nbmax = R // bm                         # worst case: one segment owns all
 

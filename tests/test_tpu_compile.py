@@ -276,6 +276,114 @@ def test_grouped_matmul_compiles(one_chip):
         ((_MOE_SEGS, _MOE_K, _MOE_N), jnp.bfloat16), seg, seg, seg)
 
 
+# the DeepSeek-V3.2 serving cell (benchmarks/configs/
+# deepseek-v3.2-serve-l5-ep16.json): 16 held experts of 7168 x 2048, a
+# 528-row step's 4224 token copies in 16-row blocks
+@pytest.mark.parametrize("k, n", [(7168, 2048), (2048, 7168)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_block_major_compiles(one_chip, k, n):
+    """A 29 MB slice cannot ride in one block: tiled over N, a row block
+    reading its segment's weight id."""
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul_raw
+
+    seg = ((16,), jnp.int32)
+    _compile(lambda x, w, s, l, i: grouped_matmul_raw(
+        x, w, s, l, i, block_rows=16, interpret=False), one_chip,
+        ((4496, k), jnp.bfloat16), ((16, k, n), jnp.bfloat16), seg, seg, seg,
+        kernels=["grouped_matmul_blocks"])
+
+
+_DS_ROWS, _DS_PAGES, _DS_PAGE, _DS_SLOTS, _DS_SEQ = 528, 3072, 128, 16, 24704
+
+
+@pytest.mark.parametrize("pages_per_step", [4, 16])
+def test_lightning_index_scores_compiles(one_chip, pages_per_step):
+    from paddle_tpu.ops.pallas.sparse_mla import lightning_index_scores_raw
+
+    _compile(lambda q, w, pool, lens, slot, tables:
+             lightning_index_scores_raw(q, w, pool, lens, slot, tables,
+                                        pages_per_step=pages_per_step,
+                                        interpret=False), one_chip,
+             ((_DS_ROWS, 64, 128), jnp.bfloat16), ((_DS_ROWS, 64), jnp.float32),
+             ((_DS_PAGES, _DS_PAGE, 128), jnp.bfloat16),
+             ((_DS_ROWS,), jnp.int32), ((_DS_ROWS,), jnp.int32),
+             ((_DS_SLOTS, 193), jnp.int32), kernels=["lightning_index_scores"])
+
+
+@pytest.mark.parametrize("pages_per_step", [4, 16])
+def test_sparse_mla_attention_compiles(one_chip, pages_per_step):
+    from paddle_tpu.ops.pallas.sparse_mla import sparse_mla_attention_raw
+
+    width = -(-193 // pages_per_step) * pages_per_step * _DS_PAGE
+    _compile(lambda q, pool, scores, sel, lens, slot, tables:
+             sparse_mla_attention_raw(q, pool, scores, sel, lens, slot, tables,
+                                      dv=512, pages_per_step=pages_per_step,
+                                      interpret=False), one_chip,
+             ((_DS_ROWS, 128, 640), jnp.bfloat16),
+             ((_DS_PAGES, _DS_PAGE, 640), jnp.bfloat16),
+             ((_DS_ROWS, width), jnp.float32), ((_DS_ROWS, 2), jnp.float32),
+             ((_DS_ROWS,), jnp.int32), ((_DS_ROWS,), jnp.int32),
+             ((_DS_SLOTS, 193), jnp.int32), kernels=["sparse_mla_attention"])
+
+
+def test_deepseek_step_writes_latents_and_index_keys_in_place(one_chip,
+                                                              monkeypatch):
+    """PR 25's test for the new pools: the DeepSeek unified step (one
+    dense and one expert layer at the published widths, the cell's pool
+    geometry) copies or transposes no whole pool, and every pool is
+    updated in the buffer it came in."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models.deepseek_v32 import DeepseekV32Config
+    from paddle_tpu.ops.pallas import grouped_matmul, sparse_mla
+
+    for mod in (sparse_mla, grouped_matmul):
+        monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
+    cfg = DeepseekV32Config(num_hidden_layers=2, first_k_dense_replace=1,
+                            experts_held=(0, 16), vocab_size=16160)
+    params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+              for k, s in cfg.leaf_shapes().items()}
+    small = 24
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=_DS_SLOTS, num_pages=small,
+        page_size=_DS_PAGE, max_seq_len=_DS_SEQ, prefill_token_budget=512,
+        enable_prefix_cache=True)
+    assert eng.pages_per_step == 16
+    fn, args, kwargs, _ = eng.analysis_entry()
+    assert args[3].shape == (_DS_ROWS, 5)
+    pools = {(_DS_PAGES, _DS_PAGE, 640): 0, (_DS_PAGES, _DS_PAGE, 128): 0}
+
+    def described(x):
+        shape = (_DS_PAGES, *x.shape[1:]) if x.shape[:2] == (small, _DS_PAGE) \
+            else x.shape
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=one_chip)
+
+    static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step")}
+    text = fn.lower(*jax.tree.map(described, args), **static,
+                    **jax.tree.map(described, kwargs)).compile().as_text()
+    names = {m.group(1) for m in re.finditer(
+        r"%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call", text)}
+    assert {"lightning_index_scores", "sparse_mla_attention",
+            "grouped_matmul_blocks"} <= names, names
+    moved = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"(copy|transpose)\(", ln)
+        if m and any(np.prod([int(x) for x in m.group(1).split(",")])
+                     == np.prod(p) for p in pools):
+            moved.append(ln.strip()[:160])
+    assert not moved, "\n".join(moved)
+    entry = text[text.index("\nENTRY "):]
+    header = next(ln for ln in text.splitlines() if "HloModule" in ln)
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    for pool in pools:
+        dims = ",".join(map(str, pool))
+        found = {int(n) for n in re.findall(
+            rf"= \w+\[{dims}\]\S* parameter\((\d+)\)", entry)}
+        assert len(found) == cfg.num_hidden_layers and found <= aliased, \
+            (pool, found, aliased)
+
+
 def test_grouped_outer_compiles(one_chip):
     """The dW half of the grouped-matmul backward: (K, N) is tiled in
     the grid — held whole in fp32 it asked for 33 MB of 16 MB VMEM."""
