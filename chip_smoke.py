@@ -65,6 +65,7 @@ class KernelShapes:
     pages_per_seq: int = 4
     slots: int = 4
     prefill_rows: int = 24
+    chunk_rows: int = 40
 
 
 @dataclasses.dataclass(frozen=True)
@@ -321,13 +322,20 @@ def _kernels_for_head_dim(d: int, ks: KernelShapes, target: Target) -> None:
         jnp.int32)
     cap = page * pps
     seq_lens = jnp.asarray(rng.integers(1, cap + 1, slots), jnp.int32)
-    # ragged rows: one decode row per slot, a prefill run on slot 0 and
-    # padding rows (slot -1) the kernel must zero
+    # ragged rows: one decode row per slot, a prefill run on slot 0, a
+    # chunk of slot 1 that starts mid-page in its second page (with the
+    # rows before it, longer than one query tile of the kernel: a tile
+    # of one slot's rows walks that slot's pages once) and padding rows
+    # (slot -1) the kernel must zero
     pre = min(ks.prefill_rows, cap)
+    at = page + page // 2 - 1
+    chunk = min(ks.chunk_rows, cap - at)
     row_slot = np.concatenate([np.arange(slots), np.zeros(pre, np.int64),
+                               np.ones(chunk, np.int64),
                                -np.ones(4, np.int64)])
-    row_lens = np.concatenate([np.asarray(seq_lens),
-                               np.arange(1, pre + 1), np.zeros(4, np.int64)])
+    row_lens = np.concatenate([np.asarray(seq_lens), np.arange(1, pre + 1),
+                               at + np.arange(1, chunk + 1),
+                               np.zeros(4, np.int64)])
     row_slot = jnp.asarray(row_slot, jnp.int32)
     row_lens = jnp.asarray(row_lens, jnp.int32)
     live = (row_slot >= 0)[:, None, None]

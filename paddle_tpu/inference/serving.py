@@ -38,7 +38,10 @@ floor):
   XLA relaid the whole pool around the kernel (PERF.md section 6, PR 25);
 - the paged kernel iterates ``pages_per_step`` physical pages per grid
   step (tune_pages_per_step), recovering the dense decode kernel's
-  ~512-token window instead of paying one grid trip per page;
+  ~512-token window instead of paying one grid trip per page; the
+  unified step's ragged kernel takes the same pages a TURN of a walk
+  inside the kernel, so its cost follows the live rows (PERF.md section
+  6, PR 27);
 - the host scheduler runs ONE CHUNK AHEAD: ``step()`` launches the next
   decode chunk against the device-resident token carry BEFORE reading
   back the previous chunk's tokens, so admission/eviction bookkeeping
@@ -237,9 +240,23 @@ class PagedLayout:
         return tuple((num_pages, page_size, *r) for r in self.rows)
 
 
+def ragged_kv_tokens_read(row_slot, row_lens, tile_rows: int, page: int,
+                          max_pages: int) -> int:
+    """K/V positions the ragged kernel's walk fetches for these packed
+    rows in one layer: whole pages, each unit's slot as far as the
+    unit's reach."""
+    from ..ops.pallas.decode_attention import ragged_units
+
+    _, reach = ragged_units(np.asarray(row_slot), np.asarray(row_lens),
+                            tile_rows, np)
+    pages = np.minimum(-(-reach // page), max_pages)
+    return int(pages.sum()) * page
+
+
 def kv_layout(cfg) -> PagedLayout:
     row = (cfg.num_key_value_heads, cfg.head_dim)
-    return PagedLayout(name="kv", rows=(row, row))
+    return PagedLayout(name="kv", rows=(row, row),
+                       count_names=("kv_ctx_tokens", "attn_kv_tokens_read"))
 
 
 class PageAllocator:
@@ -682,7 +699,8 @@ class ContinuousBatchingEngine:
                  prefill_only: bool = False,
                  host_tier_pages: int = 0):
         from ..models.generation import _CFGS, register_config
-        from ..ops.pallas.decode_attention import tune_pages_per_step
+        from ..ops.pallas.decode_attention import (ragged_tile_rows,
+                                                   tune_pages_per_step)
 
         self.cfg = cfg
         self.params = params
@@ -847,6 +865,11 @@ class ContinuousBatchingEngine:
         # row per slot (k+1 under speculation) + the prefill chunk
         self.rows_cap = self.max_slots * (1 + self.spec_k) \
             + self.prefill_budget
+        # rows of a query tile of the ragged kernel: the packing counts
+        # the K/V its walk reads by the kernel's own units of work
+        self.attn_tile_rows = ragged_tile_rows(
+            cfg.num_attention_heads, cfg.num_key_value_heads,
+            cfg.head_dim) if kv else 0
         # static capacity of the CONSUMED-row gather (round-13): every
         # verify-window row + at most one chunk-final row per slot —
         # the head matmul, fp32 logits buffer and host transfer are
@@ -1986,6 +2009,13 @@ class ContinuousBatchingEngine:
             # the K/V the step has to read at least: its bytes
             "kv_ctx_tokens": kv_ctx,
         }
+        if self.attn_tile_rows:
+            # what the ragged kernel's walk fetches in one layer (whole
+            # pages, a slot once for each of its units of work): over
+            # kv_ctx_tokens, the re-read factor
+            counts["attn_kv_tokens_read"] = ragged_kv_tokens_read(
+                rows[:r, 4], rows[:r, 3], self.attn_tile_rows,
+                self.page_size, self.pages_per_seq)
         if self.layout.row_counts is not None:
             counts.update(self.layout.row_counts(rows[:r], kv_ctx))
         return rows, gather, gathered, metas, enc, counts

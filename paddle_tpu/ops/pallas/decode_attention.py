@@ -27,6 +27,20 @@ step.  The TPU design mirrors that:
 
 Shapes: q [b, h, d]; k_cache/v_cache [b, kvh, t_max, d]; seq_lens [b]
 int32 = number of valid cache rows (attend positions < seq_lens).
+
+Three kernels share that online softmax.  ``flash_decode_raw`` (dense
+cache) and ``paged_decode_raw`` (paged cache, one query row a sequence:
+the legacy chunked serving path) have the grid described above, the
+page indirection of the second in its index maps.
+``ragged_paged_decode_raw`` (the unified serving step: decode rows,
+verify windows and prefill chunks of many sequences packed into one
+launch) has NOT: a grid of (rows, page blocks) costs a grid trip for
+every block a row COULD have, whatever the step carries, and re-reads a
+sequence's pages for every row of its chunk.  Its grid is the query
+tiles alone; inside a tile each run of one sequence's rows walks that
+sequence's pages once, in a loop of dynamic length with manual
+double-buffered copies, so its cost follows the live rows and the pages
+they can see (PERF.md section 6, PR 27).
 """
 
 from __future__ import annotations
@@ -183,8 +197,7 @@ def flash_decode_raw(q, k_cache, v_cache, seq_lens, scale=None,
     return out[:, :, :rep].reshape(b, h, d)
 
 
-def _paged_decode_kernel(*refs, page: int, pp: int, scale: float,
-                         nsp: int = 2):
+def _paged_decode_kernel(*refs, page: int, pp: int, scale: float):
     """Paged online-softmax decode body iterating ``pp`` physical pages
     per grid step.  The per-page k/v refs were DMA'd independently by
     ``pp`` scalar-prefetch index maps (ragged page iteration fused into
@@ -193,17 +206,12 @@ def _paged_decode_kernel(*refs, page: int, pp: int, scale: float,
     blocks are tiny, so per-grid-step overhead dominates — folding pp
     pages into one step recovers the dense kernel's ~512-token window
     (measured r4/r5: 64-128 token pages paid ~3x the dense kernel's
-    grid overhead).
-
-    ``nsp`` is the number of scalar-prefetch operands ahead of q: 2 for
-    the per-sequence layout (seq_lens, tables), 3 for the ragged
-    per-row layout (row_lens, row_slot, tables) — the body itself only
-    ever reads refs[0] (the per-grid-row visibility length), so both
-    layouts share it."""
+    grid overhead).  Two scalar-prefetch operands (seq_lens, tables)
+    ride ahead of q; the body reads only the first."""
     seq_ref = refs[0]
-    q_ref = refs[nsp]
-    k_refs = refs[nsp + 1:nsp + 1 + pp]
-    v_refs = refs[nsp + 1 + pp:nsp + 1 + 2 * pp]
+    q_ref = refs[2]
+    k_refs = refs[3:3 + pp]
+    v_refs = refs[3 + pp:3 + 2 * pp]
     o_ref, m_scr, l_scr, acc_scr = refs[-4:]
     bi = pl.program_id(0)
     gi = pl.program_id(1)
@@ -313,7 +321,8 @@ def tune_pages_per_step(b, kvh, page, d, max_pages, dtype=jnp.bfloat16):
 
 
 def paged_decode_raw(q, key_cache, value_cache, seq_lens, block_tables,
-                     scale=None, interpret=None, pages_per_step="auto"):
+                     scale=None, interpret=None, pages_per_step="auto",
+                     name=PAGED_DECODE_KERNEL):
     """Paged (vLLM-layout) flash decode: q [b, h, d]; key/value_cache
     [n_blocks, kvh, page, d]; seq_lens [b] (valid tokens, INCLUDING the
     current one — the caller writes the new token's K/V into its page
@@ -331,7 +340,8 @@ def paged_decode_raw(q, key_cache, value_cache, seq_lens, block_tables,
 
     ``pages_per_step``: physical pages per grid step ("auto" targets a
     ~512-token window per step — the dense kernel's block size — under
-    a VMEM budget; serving pre-tunes it via tune_pages_per_step)."""
+    a VMEM budget; serving pre-tunes it via tune_pages_per_step).
+    ``name`` is the kernel's name in the compiled program."""
     b, h, d = q.shape
     kvh, page = key_cache.shape[1], key_cache.shape[2]
     if h % kvh != 0:
@@ -389,19 +399,255 @@ def paged_decode_raw(q, key_cache, value_cache, seq_lens, block_tables,
         out_shape=_sds((b, kvh, rp, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        name=PAGED_DECODE_KERNEL,
+        name=name,
         interpret=interpret,
     )(seq, tables, qg, *([key_cache] * pp), *([value_cache] * pp))
     return out[:, :, :rep].reshape(b, h, d)
 
 
+# sublanes of a query tile: the MXU's rows.  A tile of ``tile_rows``
+# packed rows stacks ``tile_rows * rep`` (row, query head) pairs of one
+# KV head on the sublane axis
+_RAGGED_TILE_SUBLANES = 128
+# the narrow window a short run (a decode row, a verify window) computes
+# on instead of the whole tile, so that a step of decode rows from many
+# slots is bound by their K/V bytes and not by a tile's softmax each
+_RAGGED_SHORT_SUBLANES = 32
+# sublane alignment of a window's start: bf16's (16, 128) tile
+_RAGGED_ALIGN = 16
+# VMEM the tile's own blocks may take (q, out, softmax state) beside the
+# double-buffered K/V pages (_PAGED_VMEM_BUDGET)
+_RAGGED_TILE_VMEM = 4 * 1024 * 1024
+
+
+def _padded_rep(rep: int) -> int:
+    """Query heads a KV head, padded so that a row's heads never
+    straddle a window's alignment: a power of two up to the alignment,
+    whole alignments above."""
+    if rep >= _RAGGED_ALIGN:
+        return -(-rep // _RAGGED_ALIGN) * _RAGGED_ALIGN
+    return 1 << (rep - 1).bit_length()
+
+
+def ragged_tile_rows(h: int, kvh: int, d: int) -> int:
+    """Packed rows a query tile of the ragged kernel: as many as fill
+    the MXU's 128 rows with the ``rep`` query heads of a KV head,
+    halved while the tile's q and output (double-buffered, reckoned at
+    four bytes) and fp32 softmax state (all KV heads ride in the tile)
+    pass their VMEM budget."""
+    rp = _padded_rep(h // kvh)
+    per_sublane = kvh * (2 * 128 * 4 + d * 4 + 2 * 2 * d * 4)
+    m = _RAGGED_TILE_SUBLANES
+    while m > max(rp, _RAGGED_ALIGN) and m * per_sublane > _RAGGED_TILE_VMEM:
+        m //= 2
+    return max(1, m // rp)
+
+
+def ragged_units(row_slot, row_lens, tile_rows: int, xp):
+    """The ragged kernel's units of work, from the packed rows alone.
+
+    A unit is a maximal run of consecutive live rows (slot >= 0) of ONE
+    slot inside ONE query tile of ``tile_rows`` rows: the kernel walks
+    that slot's pages once for all of the unit's rows, as far as the
+    largest visibility among them.  Returns ``(count, reach)``, int32
+    ``[T]`` each: at a unit's FIRST row the unit's row count and that
+    largest visibility, 0 at every other row.  ``xp`` is ``numpy`` (the
+    engine's packing counts what a step's walk reads) or ``jax.numpy``
+    (the kernel's wrapper): one definition for both."""
+    T = row_slot.shape[0]
+    idx = xp.arange(T)
+    live = row_slot >= 0
+    prev = xp.concatenate([xp.full((1,), -1, row_slot.dtype), row_slot[:-1]])
+    first = live & ((row_slot != prev) | (idx % tile_rows == 0))
+    uid = xp.where(live, xp.cumsum(first), 0)     # 1.. a unit, 0 not live
+    lens = xp.where(live, row_lens, 0)
+    tail = xp.zeros((tile_rows - 1,), uid.dtype)
+    # a unit never leaves its tile, so the tile_rows rows from its first
+    # hold all of it
+    win = xp.arange(tile_rows)[:, None] + idx[None, :]
+    same = xp.concatenate([uid, tail])[win] == uid[None]
+    reach = xp.where(same, xp.concatenate([lens, tail.astype(lens.dtype)]
+                                          )[win], 0).max(0)
+    count = same.sum(0)
+    return (xp.where(first, count, 0).astype(xp.int32),
+            xp.where(first, reach, 0).astype(xp.int32))
+
+
+def _ragged_paged_kernel(slot_ref, cnt_ref, reach_ref, tab_ref, live_ref,
+                         q_ref, vis_ref, k_hbm, v_hbm, o_ref,
+                         kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
+                         page: int, pp: int, rp: int, tile_rows: int,
+                         short: int, scale: float):
+    """One query tile of the ragged kernel: every unit of work of the
+    tile (``ragged_units``) walks its slot's pages in a loop of dynamic
+    length, ``pp`` pages a turn, copying the next turn's pages (or the
+    next unit's first) while it computes on this turn's.  The online
+    softmax of a (row, page) is ``_paged_decode_kernel``'s."""
+    M = tile_rows * rp
+    row0 = pl.program_id(0) * tile_rows
+    # one past the launch's last live row: tiles past it have no work
+    end = jnp.minimum(row0 + tile_rows, live_ref[0])
+    n_rows = cnt_ref.shape[0]
+
+    def copies(slot, reach, blk, half):
+        """The turn's page copies, for ``start`` and ``wait`` alike;
+        only the pages that hold a position under the unit's reach."""
+        out = []
+        for j in range(pp):
+            idx = blk * pp + j
+            need = idx * page < reach
+            phys = jnp.maximum(tab_ref[slot, idx], 0)
+            out.append((need, [
+                pltpu.make_async_copy(k_hbm.at[phys], kbuf.at[half, j],
+                                      sem.at[half, 0, j]),
+                pltpu.make_async_copy(v_hbm.at[phys], vbuf.at[half, j],
+                                      sem.at[half, 1, j])]))
+        return out
+
+    def start(slot, reach, blk, half):
+        for need, pair in copies(slot, reach, blk, half):
+            @pl.when(need)
+            def _(pair=pair):
+                for c in pair:
+                    c.start()
+
+    def wait(slot, reach, blk, half):
+        for need, pair in copies(slot, reach, blk, half):
+            @pl.when(need)
+            def _(pair=pair):
+                for c in pair:
+                    c.wait()
+
+    def walk(lo, n, slot, reach, nblk, nxt, has_next, half, w0, W):
+        """All turns of one unit on the window of ``W`` sublanes at
+        ``w0``; returns the buffer half the next unit starts in."""
+        win = pl.ds(w0, W)
+        q = q_ref[:, win, :]                              # [kvh, W, d]
+        # a row of the window attends below its own visibility; rows of
+        # other units in the window attend nothing here
+        r = row0 + (w0 + jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)) // rp
+        vis = jnp.where((r >= lo) & (r < lo + n), vis_ref[win, :], 0)[None]
+
+        def turn(blk, half):
+            wait(slot, reach, blk, half)
+
+            @pl.when(blk + 1 < nblk)
+            def _():
+                start(slot, reach, blk + 1, 1 - half)
+
+            @pl.when((blk + 1 == nblk) & has_next)
+            def _():
+                start(slot_ref[nxt], reach_ref[nxt], 0, 1 - half)
+
+            for j in range(pp):
+                first = (blk * pp + j) * page
+
+                def compute(j=j, first=first):
+                    k = kbuf[half, j]                     # [kvh, page, d]
+                    if k.dtype == jnp.int8:
+                        # int8 KV: half the HBM stream; dequant scales
+                        # are folded into q / the output by the callers
+                        k = k.astype(q.dtype)
+                    s = jax.lax.dot_general(
+                        q, k, (((2,), (2,)), ((0,), (0,))),
+                        preferred_element_type=jnp.float32) * scale
+                    kpos = first + jax.lax.broadcasted_iota(
+                        jnp.int32, (1, 1, page), 2)
+                    seen = kpos < vis                     # [1, W, page]
+                    s = jnp.where(seen, s, NEG_INF)
+                    m_prev = m_scr[:, win, :1]
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(s, axis=-1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    # a row that sees nothing of this page (another
+                    # unit's, or an earlier row of a chunk) may still be
+                    # at NEG_INF, where exp(s - m) is 1: mask p as well
+                    p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+                    l_new = (l_scr[:, win, :1] * alpha
+                             + jnp.sum(p, axis=-1, keepdims=True))
+                    v = vbuf[half, j]
+                    if v.dtype == jnp.int8:
+                        v = v.astype(q.dtype)
+                    # positions past the reach carry whatever the pool
+                    # holds (p there is 0, but 0 * inf/nan would poison
+                    # acc): zero them
+                    rpos = first + jax.lax.broadcasted_iota(
+                        jnp.int32, v.shape, 1)
+                    v = jnp.where(rpos < reach, v, jnp.zeros_like(v))
+                    acc_scr[:, win, :] = (
+                        acc_scr[:, win, :] * alpha + jax.lax.dot_general(
+                            p.astype(v.dtype), v,
+                            (((2,), (1,)), ((0,), (0,))),
+                            preferred_element_type=jnp.float32))
+                    m_scr[:, win, :] = jnp.broadcast_to(
+                        m_new, (m_new.shape[0], W, m_scr.shape[2]))
+                    l_scr[:, win, :] = jnp.broadcast_to(
+                        l_new, (l_new.shape[0], W, l_scr.shape[2]))
+
+                # pages wholly past the reach were not copied
+                pl.when(first < reach)(compute)
+            return 1 - half
+
+        return jax.lax.fori_loop(0, nblk, turn, half)
+
+    def unit(lo, n, half, started):
+        slot, reach = slot_ref[lo], reach_ref[lo]
+        nblk = (reach + pp * page - 1) // (pp * page)
+        nxt = jnp.minimum(lo + n, n_rows - 1)
+        has_next = (lo + n < end) & (cnt_ref[nxt] > 0)
+
+        @pl.when(started == 0)
+        def _():
+            start(slot, reach, 0, half)
+
+        args = (lo, n, slot, reach, nblk, nxt, has_next, half)
+        if short:
+            # a short run computes on a narrow aligned window
+            w0 = ((lo - row0) * rp // _RAGGED_ALIGN) * _RAGGED_ALIGN
+            w0 = jnp.minimum(w0, M - short)
+            fits = (lo - row0 + n) * rp - w0 <= short
+            half = jax.lax.cond(
+                fits,
+                lambda: walk(*args, pl.multiple_of(w0, _RAGGED_ALIGN),
+                             short),
+                lambda: walk(*args, 0, M))
+        else:
+            half = walk(*args, 0, M)
+        return half, (has_next & (nblk > 0)).astype(jnp.int32)
+
+    @pl.when(row0 >= end)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(row0 < end)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+        def body(c):
+            lo, half, started = c
+            n = cnt_ref[lo]
+            half, started = jax.lax.cond(
+                n > 0, lambda: unit(lo, n, half, started),
+                lambda: (half, jnp.int32(0)))       # a padding row
+            return lo + jnp.maximum(n, 1), half, started
+
+        jax.lax.while_loop(lambda c: c[0] < end, body,
+                           (row0, jnp.int32(0), jnp.int32(0)))
+        l = l_scr[:, :, :1]
+        l = jnp.where(l == 0.0, 1.0, l)
+        valid = m_scr[:, :, :1] > NEG_INF * 0.5
+        o_ref[...] = jnp.where(valid, acc_scr[...] / l, 0.0
+                               ).astype(o_ref.dtype)
+
+
 def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
                             block_tables, scale=None, interpret=None,
-                            pages_per_step="auto"):
+                            pages_per_step="auto", tile_rows=None):
     """Ragged paged flash attention: the serving plane's unified
     prefill+decode step (the Ragged Paged Attention kernel shape,
-    PAPERS.md 2604.15464), built as a per-ROW generalization of
-    ``paged_decode_raw``'s scalar-prefetch index maps.
+    PAPERS.md 2604.15464).
 
     q [T, h, d] is a PACKED array of query tokens from MANY sequences in
     one launch: decode slots contribute one row each (q_len=1), prefill
@@ -415,20 +661,27 @@ def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
       position p this is p+1, so a prefill chunk's rows each see the
       shared prefix plus the chunk tokens at or before themselves —
       their K/V must already be scattered into the pages, exactly like
-      the decode contract);
+      the decode contract); positions past the table's width do not
+      exist (lookahead scheduling may run a slot past capacity);
     - ``block_tables`` [slots, max_pages] int32 physical page ids.
 
-    The page indirection happens in the index maps: grid step (r, g)
-    DMAs ``pages_per_step`` physical pages of row r's sequence via
-    ``tab_ref[row_slot[r], ...]`` — the same clamp-to-last-valid-page
-    trick bounds both HBM traffic and compute by each ROW's visibility,
-    so a decode row costs one tiny step regardless of how many prefill
-    rows share the launch (the property that makes mixing chunked
-    prefill into the decode batch latency-safe).  Per-row grid steps
-    keep the decode rows' cost identical to ``paged_decode_raw``;
-    prefill rows pay one grid trip per row (the RPA paper's fused
-    multi-row q tiles are the TPU follow-on once chunk shapes are
-    pinned)."""
+    The cost follows the live rows.  The grid is the query TILES alone
+    (``ragged_tile_rows`` packed rows each, the ``rep`` query heads of a
+    KV head stacked on the sublanes: 32 rows x 4 heads are the MXU's 128
+    rows).  Inside a tile every UNIT of work (``ragged_units``: a run of
+    consecutive rows of one slot) walks that slot's pages ONCE for all
+    of its rows, in a loop of dynamic length as far as the unit's
+    largest visibility, ``pages_per_step`` pages a turn with manual
+    double-buffered copies (``sparse_mla._page_walk``'s idea, for two
+    pools); each row masks by its own visibility.  So a prefill chunk
+    of 256 rows reads its context 8 times and not 256, a tile with no
+    live row returns at once without a copy, and the engine's packing
+    (live rows first, a slot's rows contiguous) puts every padding row
+    in such tiles.  A short run (a decode row, a verify window) computes
+    on a narrow window of the tile, so a step of decode rows from many
+    slots is bound by their bytes.  Rows may come in any order and with
+    any visibilities: the units are found from the ``slot`` column on
+    the device, once a step (every layer's launch shares them)."""
     T, h, d = q.shape
     kvh, page = key_cache.shape[1], key_cache.shape[2]
     if h % kvh != 0:
@@ -437,64 +690,116 @@ def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = pallas_interpret()
-    rep = h // kvh
-    rp = -(-rep // 8) * 8
+    if not interpret and d % 128:
+        # Mosaic copies by hand only whole lanes of the minor dimension
+        # (a pool of head dim 64 is padded to 128 lanes in HBM and a
+        # slice of 64 is refused), so such pools cannot be walked inside
+        # the kernel: a row goes as a sequence of its own through the
+        # grid kernel, pages by its index maps.  Interpret mode takes
+        # the walk at every head dim
+        live = row_slot >= 0
+        return paged_decode_raw(
+            q, key_cache, value_cache, jnp.where(live, row_lens, 0),
+            block_tables[jnp.maximum(row_slot, 0)], scale=scale,
+            interpret=interpret, pages_per_step=pages_per_step,
+            name=RAGGED_PAGED_KERNEL)
     max_pages = block_tables.shape[1]
     if pages_per_step == "auto":
         pages_per_step = default_pages_per_step(
             page, kvh, d, max_pages, jnp.dtype(key_cache.dtype).itemsize)
     pp = max(1, min(int(pages_per_step), max_pages))
-    ng = -(-max_pages // pp)
+    if tile_rows is None:
+        tile_rows = ragged_tile_rows(h, kvh, d)
+    # a launch smaller than a tile is one tile of its own size (whole
+    # alignments of sublanes): the same units of work, less padding
+    whole = max(1, _RAGGED_ALIGN // _padded_rep(h // kvh))
+    tile_rows = min(int(tile_rows), -(-T // whole) * whole)
+    return _ragged_walk(q, key_cache, value_cache, row_lens, row_slot,
+                        block_tables, scale=float(scale),
+                        interpret=bool(interpret), pp=pp, tq=int(tile_rows))
 
-    qg = q.reshape(T, kvh, rep, d)
-    if rp != rep:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rp - rep), (0, 0)))
-    lens = row_lens.astype(jnp.int32)
-    # padding rows (slot < 0) clamp to table row 0 with visibility 0:
-    # their DMA still lands somewhere valid, their output is forced to 0
-    lens = jnp.where(row_slot < 0, 0, lens)
-    slots = jnp.maximum(row_slot.astype(jnp.int32), 0)
-    tables = block_tables.astype(jnp.int32)
 
-    def kv_map(j):
-        def _map(ri, gi, lens_ref, slot_ref, tab_ref):
-            # clamp to the row's last VISIBLE page: grid steps past it
-            # revisit the same page and Mosaic elides the DMA, so a
-            # decode row never streams a prefill row's page span
-            last = jnp.maximum((lens_ref[ri] + page - 1) // page - 1, 0)
-            last = jnp.minimum(last, max_pages - 1)
-            phys = tab_ref[slot_ref[ri], jnp.minimum(gi * pp + j, last)]
-            return (jnp.maximum(phys, 0), 0, 0, 0)
-        return _map
+# jitted on its own: a step's launches (one a layer) are then ONE traced
+# and lowered function called sixteen times, not sixteen kernels traced
+# and lowered one by one (5.7 s of a 16-layer step's lowering otherwise)
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "pp", "tq"))
+def _ragged_walk(q, key_cache, value_cache, row_lens, row_slot, block_tables,
+                 *, scale, interpret, pp, tq):
+    T, h, d = q.shape
+    kvh, page = key_cache.shape[1], key_cache.shape[2]
+    rep = h // kvh
+    rp = _padded_rep(rep)
+    itemsize = jnp.dtype(key_cache.dtype).itemsize
+    max_pages = block_tables.shape[1]
+    M = tq * rp
+    short = _RAGGED_SHORT_SUBLANES \
+        if rp <= _RAGGED_ALIGN and M >= 2 * _RAGGED_SHORT_SUBLANES else 0
+    n_tiles = -(-T // tq)
+    Tp = n_tiles * tq
+
+    slots = jnp.pad(row_slot.astype(jnp.int32), (0, Tp - T),
+                    constant_values=-1)
+    # a padding row sees nothing; a position past the table's width is
+    # none
+    lens = jnp.minimum(jnp.pad(row_lens.astype(jnp.int32), (0, Tp - T)),
+                       max_pages * page)
+    lens = jnp.where(slots < 0, 0, lens)
+    count, reach = ragged_units(slots, lens, tq, jnp)
+    live_end = jnp.max(jnp.where(slots >= 0, jnp.arange(Tp) + 1, 0),
+                       keepdims=True).astype(jnp.int32)
+    # the table's width padded to whole turns, so that a turn's page
+    # index never leaves it (the padding is never copied: past the reach)
+    tables = jnp.pad(block_tables.astype(jnp.int32),
+                     ((0, 0), (0, -max_pages % pp)))
+    # [kvh, rows x heads, d]: a tile's rows of one KV head are one
+    # matmul operand
+    qg = jnp.pad(q.reshape(T, kvh, rep, d),
+                 ((0, Tp - T), (0, 0), (0, rp - rep), (0, 0)))
+    qg = qg.transpose(1, 0, 2, 3).reshape(kvh, Tp * rp, d)
+    vis = jnp.repeat(lens, rp)[:, None]                   # [Tp * rp, 1]
+
+    def tile(i, *_):
+        return (0, i, 0)
+
+    # K/V pages of two turns, the tile's q/out (double-buffered by the
+    # pipeline) and softmax state, a page's fp32 scores and weights
+    resident = (2 * 2 * pp * kvh * page * d * itemsize
+                + kvh * M * (2 * 128 * 4 + d * 4
+                             + 2 * 2 * d * jnp.dtype(q.dtype).itemsize)
+                + 3 * kvh * M * page * 4)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(T, ng),
-        in_specs=(
-            [pl.BlockSpec((1, kvh, rp, d),
-                          lambda ri, gi, l, s, t: (ri, 0, 0, 0))]
-            + [pl.BlockSpec((1, kvh, page, d), kv_map(j)) for j in range(pp)]
-            + [pl.BlockSpec((1, kvh, page, d), kv_map(j)) for j in range(pp)]
-        ),
-        out_specs=pl.BlockSpec((1, kvh, rp, d),
-                               lambda ri, gi, l, s, t: (ri, 0, 0, 0)),
+        num_scalar_prefetch=5,
+        grid=(n_tiles,),
+        in_specs=[
+            pl.BlockSpec((kvh, M, d), tile),
+            pl.BlockSpec((M, 1), lambda i, *_: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+        ],
+        out_specs=pl.BlockSpec((kvh, M, d), tile),
         scratch_shapes=[
-            pltpu.VMEM((kvh, rp, 128), jnp.float32),
-            pltpu.VMEM((kvh, rp, 128), jnp.float32),
-            pltpu.VMEM((kvh, rp, d), jnp.float32),
+            pltpu.VMEM((2, pp, kvh, page, d), key_cache.dtype),
+            pltpu.VMEM((2, pp, kvh, page, d), value_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, pp)),
+            pltpu.VMEM((kvh, M, 128), jnp.float32),   # m (lane-replicated)
+            pltpu.VMEM((kvh, M, 128), jnp.float32),   # l
+            pltpu.VMEM((kvh, M, d), jnp.float32),     # acc
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, page=page, pp=pp,
-                          scale=float(scale), nsp=3),
+        functools.partial(_ragged_paged_kernel, page=page, pp=pp, rp=rp,
+                          tile_rows=tq, short=short, scale=scale),
         grid_spec=grid_spec,
-        out_shape=_sds((T, kvh, rp, d), q.dtype),
+        out_shape=_sds((kvh, Tp * rp, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(16 << 20, 2 * resident)),
         name=RAGGED_PAGED_KERNEL,
         interpret=interpret,
-    )(lens, slots, tables, qg, *([key_cache] * pp), *([value_cache] * pp))
-    return out[:, :, :rep].reshape(T, h, d)
+    )(slots, count, reach, tables, live_end, qg, vis, key_cache, value_cache)
+    out = out.reshape(kvh, Tp, rp, d)[:, :T, :rep]
+    return out.transpose(1, 0, 2, 3).reshape(T, h, d)
 
 
 # framework op registration (forward-only inference ops)

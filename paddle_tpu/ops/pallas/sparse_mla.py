@@ -19,16 +19,16 @@ serving engine's unified ragged step.
   selected rows (PERF.md section 6, PR 26 has the microbenchmark
   against the gather).
 
-Both kernels share one shape, which differs from
-``decode_attention.ragged_paged_decode_raw``'s: the grid is the packed
-ROWS alone and each row walks its own pages in a loop of DYNAMIC length
-with double-buffered manual copies, ``pages_per_step`` pages a turn.  A
-grid of (rows, page blocks) costs a grid step for every block a row
-COULD have (528 rows x 49 blocks of a 24k context is 9 ms of empty
-steps a kernel a layer); here a padding row costs one step and a decode
-row at position 300 one turn of the loop.  The heads are the matmul's
-rows (64 or 128 of them against one page of keys), which is what makes
-the per-row walk efficient for latent attention.
+Both kernels share one shape: the grid is the packed ROWS alone and each
+row walks its own pages in a loop of DYNAMIC length with double-buffered
+manual copies, ``pages_per_step`` pages a turn.  A grid of (rows, page
+blocks) costs a grid step for every block a row COULD have (528 rows x
+49 blocks of a 24k context is 9 ms of empty steps a kernel a layer);
+here a padding row costs one step and a decode row at position 300 one
+turn.  The heads are the matmul's rows (64 or 128 against one page of
+keys), which makes the walk a row efficient for latent attention;
+``decode_attention.ragged_paged_decode_raw`` (4 query heads a KV head)
+walks the same way since PR 27, a TILE of one slot's rows a walk.
 
 Layouts: index keys ``[pages, page, di]``, latents ``[pages, page,
 dl]`` with ``dl`` a multiple of 128 (the 512 latent + 64 rotary numbers

@@ -25,8 +25,8 @@ MARKERS = ("serving.admit_request", "serving.first_token",
            "serving.step_counts")
 STEP_COUNTS = ("step", "admitted", "queued", "free_pages", "rows", "rows_cap",
                "decode_rows", "prefill_rows", "slots", "prefill_backlog",
-               "attn_row_ctx", "kv_ctx_tokens", "gathered", "produced",
-               "finished")
+               "attn_row_ctx", "kv_ctx_tokens", "attn_kv_tokens_read",
+               "gathered", "produced", "finished")
 PROMPT_LENS = (20, 9, 13, 30)
 NEW_TOKENS = 5
 
@@ -150,7 +150,7 @@ def test_step_numbers_run_on_and_each_step_ends_with_its_counts(traced):
 
 
 @pytest.mark.parametrize("key", ["rows", "slots", "gathered", "context",
-                                 "backlog", "produced", "empty"])
+                                 "walk", "backlog", "produced", "empty"])
 def test_per_step_counts_hold_together(traced, key):
     counts = [c for *_, c in _named(traced, "serving.step_counts")]
     last = counts[-1]               # the step with nothing left to do
@@ -171,6 +171,14 @@ def test_per_step_counts_hold_together(traced, key):
         assert all(c["kv_ctx_tokens"] <= c["attn_row_ctx"]
                    <= c["kv_ctx_tokens"] * max(c["rows"], 1) for c in counts)
         assert all(c["kv_ctx_tokens"] >= c["rows"] for c in counts)
+    elif key == "walk":
+        # the kernel's walk fetches whole pages of 16, every scheduled
+        # slot's at least once and (one tile holds this engine's rows,
+        # a slot's rows are one run) here exactly once
+        for c in counts:
+            assert c["kv_ctx_tokens"] <= c["attn_kv_tokens_read"] \
+                < c["kv_ctx_tokens"] + 16 * max(c["slots"], 1)
+            assert c["attn_kv_tokens_read"] % 16 == 0
     elif key == "backlog":
         # prompt tokens admitted and not yet prefilled: ends at nothing
         assert counts[0]["prefill_backlog"] > 0
@@ -186,7 +194,8 @@ def test_per_step_counts_hold_together(traced, key):
 
 
 @pytest.mark.parametrize("key", ["steps", "rows", "rows_cap", "decode_rows",
-                                 "prefill_rows", "admitted", "queue_wait_s",
+                                 "prefill_rows", "admitted", "kv_ctx_tokens",
+                                 "attn_kv_tokens_read", "queue_wait_s",
                                  "prefill_s"])
 def test_the_spans_arguments_add_up_to_serving_stats(traced, key):
     kept = traced["stats"]["steps"][key]
@@ -201,6 +210,53 @@ def test_the_spans_arguments_add_up_to_serving_stats(traced, key):
         assert kept == {"sum": sum(us) / 1e6, "max": max(us) / 1e6}
     else:
         assert kept == sum(c[key] for c in counts)
+
+
+def test_the_walk_reads_what_the_packing_counts(tiny):
+    """2 decode rows and one 20-row chunk over 3 pages of 8: the K/V
+    positions the ragged kernel's walk fetches in a layer, by hand, and
+    the same launch through the kernel's own units of work."""
+    from paddle_tpu.ops.pallas.decode_attention import (ragged_tile_rows,
+                                                        ragged_units)
+
+    cfg, params = tiny
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=3, num_pages=33, page_size=8, max_seq_len=64,
+        prefill_token_budget=20)
+    rng = np.random.default_rng(1)
+    for n in (5, 9):
+        eng.add_request(rng.integers(1, 64, n).astype(np.int32),
+                        max_new_tokens=4)
+    eng.step()                      # both prompts prefilled: 5 + 9 rows
+    before = dict(eng.serving_stats()["steps"])
+    eng.add_request(rng.integers(1, 64, 20).astype(np.int32),
+                    max_new_tokens=4)
+    packed = {}
+    pack = eng._pack_unified
+
+    def spy(*a):
+        out = pack(*a)
+        packed["rows"] = out[0]
+        return out
+
+    eng._pack_unified = spy
+    eng.step()
+    after = eng.serving_stats()["steps"]
+    got = {k: after[k] - before[k] for k in
+           ("rows", "decode_rows", "kv_ctx_tokens", "attn_kv_tokens_read")}
+    # the decode rows see 6 and 10 positions (1 and 2 pages), the chunk's
+    # last row 20 (3 pages): 8 + 16 + 24 fetched for 6 + 10 + 20 attended
+    assert got == {"rows": 22, "decode_rows": 2, "kv_ctx_tokens": 36,
+                   "attn_kv_tokens_read": 48}
+    rows = packed["rows"]
+    tile = ragged_tile_rows(cfg.num_attention_heads,
+                            cfg.num_key_value_heads, cfg.head_dim)
+    assert eng.attn_tile_rows == tile
+    count, reach = ragged_units(rows[:, 4], rows[:, 3], tile, np)
+    assert count[count > 0].tolist() == [1, 1, 20]
+    assert reach[count > 0].tolist() == [6, 10, 20]
+    eng.run()
+    eng.shutdown()
 
 
 def test_each_request_is_stamped_once_at_admission_and_first_token(traced):

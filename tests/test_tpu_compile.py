@@ -153,6 +153,72 @@ _CELL_PAGES, _CELL_PAGE, _CELL_SLOTS, _CELL_SEQ, _CELL_BUDGET = (
     705, 128, 32, 2816, 256)
 
 
+def test_ragged_paged_decode_compiles_at_the_cells_geometry(one_chip):
+    """``mistral7b-serve-l16.chat``'s launch: 288 packed rows (32 slots +
+    a 256-token chunk), a 32 x 22 page table, 705 bf16 pages of 128."""
+    from paddle_tpu.ops.pallas.decode_attention import \
+        ragged_paged_decode_raw
+
+    rows = _CELL_SLOTS + _CELL_BUDGET
+    cache = ((_CELL_PAGES, KV_HEADS, _CELL_PAGE, 128), jnp.bfloat16)
+    _compile(lambda q, kc, vc, lens, slot, tables: ragged_paged_decode_raw(
+        q, kc, vc, lens, slot, tables, interpret=False), one_chip,
+        ((rows, HEADS, 128), jnp.bfloat16), cache, cache,
+        ((rows,), jnp.int32), ((rows,), jnp.int32),
+        ((_CELL_SLOTS, -(-_CELL_SEQ // _CELL_PAGE)), jnp.int32),
+        kernels=["ragged_paged_attention"])
+
+
+def _ragged_calls(max_seq_len, layers=2):
+    """The ``pallas_call``s named ``ragged_paged_attention`` in the
+    jaxpr of a small engine's unified step (nothing is compiled)."""
+    import functools
+
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    cfg = LlamaConfig.debug(vocab=64, hidden=32, layers=layers, heads=4,
+                            kv_heads=2, inter=64, max_pos=max_seq_len)
+    params = {k: jnp.asarray(v) for k, v in
+              LlamaForCausalLM(cfg).functional_state().items()}
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=8, num_pages=24, page_size=8,
+        max_seq_len=max_seq_len, prefill_token_budget=120, pages_per_step=4)
+    fn, args, kwargs, _ = eng.analysis_entry()
+    static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step")}
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call" \
+                    and eqn.params["name"] == "ragged_paged_attention":
+                found.append(eqn)
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jax.make_jaxpr(functools.partial(fn, **static))(*args, **kwargs
+                                                         ).jaxpr)
+    return eng, found
+
+
+def test_ragged_kernels_grid_is_the_query_tiles_alone():
+    """One launch a layer, over the step's query tiles: the page walk is
+    inside the kernel, so a longer page table (``pages_per_seq`` 8 and
+    32 here, turns of 4 pages) adds no grid step.  The parent's grid was
+    rows x page groups: 288 x 6 in the serving cell."""
+    grids = []
+    for seq in (64, 256):
+        eng, calls = _ragged_calls(seq)
+        assert eng.pages_per_seq == seq // 8
+        assert len(calls) == eng.cfg.num_hidden_layers == 2
+        (grid,) = {c.params["grid_mapping"].grid for c in calls}
+        grids.append(grid)
+    tiles = -(-eng.rows_cap // eng.attn_tile_rows)
+    assert grids == [(tiles,), (tiles,)] and tiles == 2
+
+
 def _serving_step_text(one_chip, monkeypatch, cache_dtype, unified):
     """Optimized HLO of the engine's step program (the unified ragged
     step, or the legacy decode chunk) for the described chip, 2 layers
