@@ -506,7 +506,7 @@ def _serving_bench(params, cfg):
     def drive():
         eng = ContinuousBatchingEngine(
             cfg, params, max_slots=8, num_pages=8 * 16 + 1, page_size=128,
-            max_seq_len=2048, decode_chunk_steps=16)
+            max_seq_len=2048, prefill_token_budget=256)
         t0 = time.perf_counter()
         produced = it = qi = 0
         while qi < len(prompts) or eng.queue or eng.active.any():
@@ -526,7 +526,7 @@ def _serving_bench(params, cfg):
     return {
         "requests": len(prompts),
         "total_new_tokens": int(sum(budgets)),
-        "wall_tokens_per_sec_chunk16": round(produced / dt, 1),
+        "wall_tokens_per_sec": round(produced / dt, 1),
         "admission": "3 requests / 2 iterations (mid-decode joins)",
         "pages_per_step": pps,
         "method": "whole trace wall time on a warm engine",
@@ -1834,7 +1834,7 @@ def doctor():
     """bench.py --doctor — run the Graph Doctor (paddle_tpu.analysis)
     over the benched steps: every seeded-bug fixture must trigger exactly
     its finding code, the flagship entry points (build_train_step in
-    both accum regimes, llama fwd/bwd, the serving decode chunk) must
+    both accum regimes, llama fwd/bwd, the serving step) must
     report zero findings, and every tracked exemption must still match a
     live suppressed finding.  Round-14: DOCTOR.json additionally carries
     the ``sharding`` block (per-stack reshard audits + the cross-stack
@@ -2241,8 +2241,8 @@ def smoke(fast: bool = False):
     prompts = [rng.integers(1, cfg.vocab_size, (n,)).astype(np.int32)
                for n in (5, 11)]
 
-    # 1. pipelined continuous-batching engine: greedy parity vs the
-    #    one-shot generate path (the whole scheduler + paged kernel)
+    # 1. continuous-batching engine: greedy parity vs the one-shot
+    #    generate path (the whole scheduler + ragged paged kernel)
     try:
         if fast:
             raise _FastSkip("tests/test_serving.py (one-shot parity + "
@@ -2250,7 +2250,7 @@ def smoke(fast: bool = False):
         eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
                                        num_pages=17, page_size=16,
                                        max_seq_len=64,
-                                       decode_chunk_steps=3)
+                                       prefill_token_budget=8)
         for p in prompts:
             eng.add_request(p, max_new_tokens=5)
         done = eng.run()
@@ -2453,7 +2453,7 @@ def smoke(fast: bool = False):
         eng = ContinuousBatchingEngine(cfg, qp, max_slots=1,
                                        num_pages=9, page_size=16,
                                        max_seq_len=64,
-                                       decode_chunk_steps=3,
+                                       prefill_token_budget=8,
                                        cache_dtype=jnp.int8)
         eng.add_request(prompts[0], max_new_tokens=4)
         done = eng.run()

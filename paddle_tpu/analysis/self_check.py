@@ -4,7 +4,7 @@ Three layers, all required green:
 1. every seeded-bug fixture (fixtures.py) triggers EXACTLY its intended
    finding code — true-positive coverage per pass;
 2. the clean flagship entry points (build_train_step unmasked-bf16 in
-   both accum regimes, llama fwd/bwd, the serving decode chunk) report
+   both accum regimes, llama fwd/bwd, the serving step) report
    ZERO findings — false-positive coverage;
 3. every standing exemption entry still matches a live suppressed
    finding — stale exemptions rot loudly (the masked grad-accum fp32
@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 # jaxpr/lowering-level passes (no XLA compile) — used for the fast clean
-# sweeps; the accum train step and the serving decode chunk also run the
+# sweeps; the accum train step and the serving step also run the
 # compiled HLO checks.
 FAST_PASSES = ("collective_order", "dtype_promotion", "donation")
 ALL_PASSES = None
@@ -301,22 +301,13 @@ def _clean_targets():
                               "min_bytes": DONATION_MIN_BYTES}},
         declared_dtype=jnp.bfloat16, target="llama_fwd_bwd[bf16]")
 
-    # 4. serving decode chunk (paged pipelined engine) — full suite
-    from paddle_tpu.inference.serving import ContinuousBatchingEngine
-
-    eng = ContinuousBatchingEngine(cfg, params, max_slots=2, num_pages=9,
-                                   page_size=16, max_seq_len=64,
-                                   decode_chunk_steps=2)
-    fn, args, kwargs, options = eng.analysis_entry()
-    yield "serving_decode_chunk", check(
-        fn, *args, kwargs=kwargs, options=options, passes=ALL_PASSES,
-        target="serving_decode_chunk")
-
-    # 4a. round-11 unified serving step (chunked prefill + speculative
+    # 4. the serving engine's step (chunked prefill + speculative
     # verify rows mixed into the decode launch) — gated like the
     # training flagship: ZERO collectives on the single-chip serving
     # path (COMM001) and the pinned peak-HBM contract (MEM001), plus
     # the full pass suite over the ragged program
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+
     ueng = ContinuousBatchingEngine(cfg, params, max_slots=2,
                                     num_pages=9, page_size=16,
                                     max_seq_len=64,

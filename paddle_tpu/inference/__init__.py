@@ -334,10 +334,10 @@ def create_predictor(config_or_layer, layer=None):
     return Predictor(config_or_layer, layer)
 
 
-# continuous-batching serving engine (round-5; reference capability:
-# the serving loop around block_multihead_attention).  Round-11 adds
-# the unified serving plane: radix prefix cache + chunked prefill mixed
-# into the decode step + speculative decoding.
+# continuous-batching serving engine (reference capability: the serving
+# loop around block_multihead_attention): ONE ragged step a tick with
+# chunked prefill, decode rows and speculative verify windows in it,
+# over refcounted pages and a radix prefix cache.
 from .serving import (ContinuousBatchingEngine, PageAllocator,  # noqa: E402
                       PrefixCache)
 # round-13 serving resilience plane: replica fleet manager + SLO-aware

@@ -130,11 +130,6 @@ class Replica:
         step, then report SERVING."""
         self.state = WARMING
         self.engine = self._factory(params)
-        if not getattr(self.engine, "unified", False):
-            raise ValueError(
-                "fleet replicas require the unified engine "
-                "(prefill_token_budget > 0): migration replays and the "
-                "shed ladder ride the ragged step's runtime knobs")
         self._warmup()
         self.state = SERVING
 

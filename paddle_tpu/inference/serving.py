@@ -1,94 +1,64 @@
-"""Continuous-batching LLM serving engine over the paged KV cache.
+"""Continuous-batching LLM serving engine over a paged cache.
 
 The capability the reference's block_multihead_attention signature exists
 for (paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu;
 Python entry python/paddle/incubate/nn/functional/
 block_multihead_attention.py): a scheduler that ADMITS new prompts into a
-RUNNING decode batch, grows sequences page by page, EVICTS finished ones
-and reuses their pages — the reference models the mixed prefill/decode
-step with its ``seq_lens_encoder`` / ``seq_lens_decoder`` /
-``seq_lens_this_time`` triplet, which this engine's step report mirrors.
+RUNNING batch, grows sequences page by page, EVICTS finished ones and
+reuses their pages.  The reference models the mixed prefill/decode step
+with its ``seq_lens_encoder`` / ``seq_lens_decoder`` /
+``seq_lens_this_time`` triplet, which ``last_report`` mirrors.
 
-TPU-first shape: the host owns the (cheap, branchy) scheduling — slot
-and page bookkeeping, admission, eviction; the device runs two compiled
-programs with STATIC shapes:
+The host owns what is cheap and branchy: slots, page tables, the
+refcounted allocator, the radix prefix cache, admission, sampling,
+speculative accept/reject, eviction.  The device runs ONE program of
+static shape a step, the model's ``PagedLayout.step`` (the Llama
+family's is ``_unified_step_jit``, DeepSeek-V3.2's its own under the
+same signature): a packed batch of ``rows_cap`` token rows from many
+sequences through one forward, with attention served by a ragged paged
+kernel whose cost follows the live rows.  A row is a decode slot's
+token, one of the k+1 tokens of a speculative verify window, or one
+prompt token of a prefill chunk; at most ``prefill_token_budget`` prompt
+tokens ride a step, so a decode slot emits a token EVERY step whatever
+prompt is prefilled beside it.  Padding rows are the price of the static
+shape: they compute garbage that is never read and write it to the
+TRASH page, the last physical page, which no slot owns.
 
-- ``prefill``: full causal forward of one prompt (padded to a power-of-2
-  bucket so retraces stay logarithmic), whose per-layer K/V are scattered
-  into the slot's pages;
-- ``decode_chunk``: ``decode_chunk_steps`` single-token steps for ALL
-  slots in one jit (a ``lax.scan``), each step routing attention through
-  the Pallas paged flash-decoding kernel (ops/pallas/
-  decode_attention.py: page indirection in the DMA index maps, HBM
-  traffic bounded by live lengths, several physical pages fused into one
-  grid step).  Inactive slots compute masked garbage that is never read
-  — the price of static shapes, paid once per slot instead of per-
-  retrace.
+``engine.step()`` is: admit (prefix-cache hits map shared full pages
+copy-on-write and skip their prefill), propose (draft model only), pack,
+launch, fetch, commit.
 
-Step-time design (round 6 — closing the gap to the weight-streaming
-floor):
-
-- the page pools are PER-LAYER arrays carried through the scan, so each
-  step's cache update is one direct scatter into the layer's pool.  The
-  previous [L, pages, ...] slab forced a slice + whole-layer
-  dynamic-update per layer per step, which XLA materialised as layer-pool
-  copies (~2x the pool's HBM bytes per step on top of the weight
-  stream).  The write (``_write_kv_rows``) indexes (page, head, offset),
+- Everything the host tells the device rides in ONE int32 upload a
+  step: ``rows`` ``[rows_cap, 5]`` = (input token, physical page its
+  K/V is written to, in-page offset, causal visibility, slot), beside
+  the page tables.
+- The page pools are PER-LAYER arrays, donated through the step, so a
+  layer's cache update is one scatter into its own pool; a fused
+  ``[L, pages, ...]`` slab cost a slice and a whole-layer update a
+  layer.  The write (``_write_kv_rows``) indexes (page, head, offset),
   so it scatters rows of ``d`` in place; with the head left as a slice
-  XLA relaid the whole pool around the kernel (PERF.md section 6, PR 25);
-- the paged kernel iterates ``pages_per_step`` physical pages per grid
-  step (tune_pages_per_step), recovering the dense decode kernel's
-  ~512-token window instead of paying one grid trip per page; the
-  unified step's ragged kernel takes the same pages a TURN of a walk
-  inside the kernel, so its cost follows the live rows (PERF.md section
-  6, PR 27);
-- the host scheduler runs ONE CHUNK AHEAD: ``step()`` launches the next
-  decode chunk against the device-resident token carry BEFORE reading
-  back the previous chunk's tokens, so admission/eviction bookkeeping
-  overlaps device execution and the device queue is never drained by
-  host logic.  Eviction therefore lands one chunk late; the lookahead
-  chunk's tokens for a finished slot are discarded at harvest (its
-  writes land in its own reserved pages or the trash page, and the
-  pages are only freed AFTER the stale chunk was already dispatched —
-  single-stream device ordering makes the overlap safe);
-- all host->device scheduling state rides in ONE packed int32 array
-  (page tables + seq lens + active/dirty masks + restart tokens) — one
-  transfer per chunk, applied on-device.
+  XLA relaid the whole pool around the kernel (PERF.md section 6,
+  PR 25).
+- The CONSUMED rows alone (every verify-window row and each prefill
+  chunk's final row) are gathered on the device before the final norm
+  and the vocabulary projection: the head matmul, the fp32 logits and
+  the copy back to the host are sized to ``gather_cap``, not
+  ``rows_cap``.  Sampling is on the host, from those logits.
 
-Chunked decode amortizes the host's per-launch latency AND is the
-admission granularity: new requests wait
-at most ``decode_chunk_steps`` tokens — the same knob vLLM-style servers
-expose.
+Weight-only int8 params (models/generation.quantize_params_int8) run
+through the same program: dequant fuses into the consumer dots.  An
+int8 K/V cache takes its scales from ONE calibration pass over the
+first submitted prompt (``_calibrate_int8_unified``: absmax per (layer,
+kv head), 2x headroom, frozen); the step quantizes every row it
+scatters with them.
 
-Page size is autotunable: ``page_size="auto"`` measures the paged kernel
-across candidate sizes for this model's shape (ops/autotune.py cache) —
-round-4 measured 64-token pages paying ~3x the dense kernel's grid
-overhead; bigger pages amortize it at the cost of allocation granularity
-(and round-6's multi-page grid steps take the residual overhead out).
+``cancel(rid)`` withdraws a request with no ``Finished`` record (the
+fleet router's migration and retry primitive, inference/fleet.py);
+``throttle()`` sheds work at run time (``speculative_k``,
+``prefill_token_budget``) under the constructor's static shapes.
 
-Weight-only int8: params produced by models/generation.
-quantize_params_int8 (int8 matrices + per-out-channel scales) run
-through the same compiled programs — dequant fuses into the consumer
-dots, so an 8B-shaped model's weight stream halves (the bench.py
-llama-8B serving leg).
-
-Round 13 (the serving resilience plane, inference/fleet.py):
-
-- int8 KV cache on the UNIFIED path — the first admission runs the
-  legacy chunked path's calibration pass (absmax per (layer, kv head),
-  2x headroom, frozen) and the ragged step quantizes every scattered
-  K/V row with those scales;
-- device-side gather of the CONSUMED logit rows (every verify-window
-  row + each prefill chunk's final row) before the final norm/head:
-  the vocab projection, fp32 logits buffer and device->host transfer
-  are sized to ``gather_cap``, not ``rows_cap``;
-- ``cancel(rid)`` withdraws a request with no Finished record (the
-  router's migration/retry primitive) and ``throttle()`` exposes the
-  runtime shed knobs (speculative_k, prefill_token_budget) under the
-  constructor's static compiled shapes.
-
-Measurement inside the unified step: every phase of ``_step_unified``
-is a ``profiler.RecordEvent`` (``serving.step`` > ``serving.admit``,
+Measurement: every phase of ``_step_unified`` is a
+``profiler.RecordEvent`` (``serving.step`` > ``serving.admit``,
 ``serving.propose``, ``serving.pack``, ``serving.launch``,
 ``serving.fetch_logits``, ``serving.commit``), so a profiler trace that
 runs, whoever started it, holds them on the device's clock; one marker
@@ -111,9 +81,7 @@ from typing import Any, Deque, Dict, List, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax import lax
 
-from ..core.device import is_tpu as _is_tpu
 from ..profiler import RecordEvent
 
 
@@ -137,36 +105,6 @@ class Finished:
     rid: int
     tokens: np.ndarray                  # generated tokens (incl. first)
     prompt_len: int
-
-
-def tune_page_size(b, kvh, d, capacity, dtype=jnp.bfloat16,
-                   candidates=(64, 128, 256, 512)):
-    """Measure paged_decode_raw across page sizes for this serving shape
-    (cached per signature).  Falls back to 128 when autotune is off or
-    under interpret/CPU."""
-    from ..ops import autotune as _at
-    from ..ops.pallas.decode_attention import paged_decode_raw
-
-    key = ("paged_page_size", b, kvh, d, capacity, str(dtype))
-    cached = _at.AutoTuneCache.instance().lookup(key)
-    if cached is not None:
-        return cached
-    if not _at.enabled() or not _is_tpu():
-        return 128
-
-    def measure(page):
-        npages_seq = capacity // page
-        npages = b * npages_seq
-        kc = jnp.zeros((npages, kvh, page, d), dtype)
-        vc = jnp.zeros((npages, kvh, page, d), dtype)
-        tables = jnp.arange(npages, dtype=jnp.int32).reshape(b, npages_seq)
-        q = jnp.ones((b, kvh, d), dtype)
-        lens = jnp.full((b,), capacity // 2, jnp.int32)
-        return _at.time_fn(lambda: jax.block_until_ready(
-            paged_decode_raw(q, kc, vc, lens, tables)))
-
-    return _at.AutoTuneCache.instance().tune(
-        key, [p for p in candidates if capacity % p == 0], measure)
 
 
 def _softmax_np(logits: np.ndarray, temperature: float) -> np.ndarray:
@@ -213,17 +151,20 @@ class PagedLayout:
     out head-major as the paged kernels read them (``[pages, kvh, page,
     d]``); a model with ``paged_layout()`` on its config brings its own
     (DeepSeek-V3.2: a latent row ``[640]`` and an index key ``[128]``,
-    ``[pages, page, n]``) and ``step``, a jitted function under
-    ``_unified_step_jit``'s signature whose third result is ``(logits,
-    device counts)``.  ``row_counts(rows, ctx_tokens)`` gives the
-    step's counts the packed rows determine, ``device_counts`` names
-    those the step returns; both ride on ``serving.step_counts`` and
-    are summed in ``serving_stats()["steps"]``.
+    ``[pages, page, n]``).  ``step`` is the model's part of the engine
+    step, a jitted function under ``_unified_step_jit``'s signature; if
+    ``device_counts`` names counts it takes on the device, its third
+    result is ``(logits, those counts)``.  ``row_counts(rows,
+    ctx_tokens, page_size, pages_per_seq)`` gives the step's counts the
+    packed rows determine.  Both kinds ride on ``serving.step_counts``
+    and are summed in ``serving_stats()["steps"]`` under
+    ``count_names``.  ``pages_per_step(page_size, pages_per_seq,
+    itemsize)`` is how many pages the step's kernels take a turn of
+    their page walk, where the constructor is given no number.
 
-    Pools other than K and V are served by the unified step alone;
-    what else the engine can do with K/V pages (the legacy chunked
-    path, a draft model's mirror, an int8 cache, the host tier, the
-    prefill-only handoff) refuses them at construction."""
+    What the engine can do with K/V pages alone (a draft model's
+    mirror, an int8 cache, the host tier, the prefill-only handoff)
+    refuses other pools at construction."""
     name: str
     rows: tuple
     head_major: bool = True
@@ -231,7 +172,7 @@ class PagedLayout:
     row_counts: Any = None
     device_counts: tuple = ()
     count_names: tuple = ()             # row_counts' keys + device_counts
-    pages_per_step: Any = None          # page size -> pages a kernel turn
+    pages_per_step: Any = None
 
     def pool_shapes(self, num_pages: int, page_size: int):
         if self.head_major:
@@ -254,9 +195,33 @@ def ragged_kv_tokens_read(row_slot, row_lens, tile_rows: int, page: int,
 
 
 def kv_layout(cfg) -> PagedLayout:
-    row = (cfg.num_key_value_heads, cfg.head_dim)
-    return PagedLayout(name="kv", rows=(row, row),
-                       count_names=("kv_ctx_tokens", "attn_kv_tokens_read"))
+    """The Llama family's layout: K rows and V rows, ``_unified_step_jit``
+    and what its ragged kernel's walk reads."""
+    from ..ops.pallas.decode_attention import (default_pages_per_step,
+                                               ragged_tile_rows)
+
+    kvh, d = cfg.num_key_value_heads, cfg.head_dim
+    # rows of a query tile of the ragged kernel: the K/V its walk reads
+    # are counted by the kernel's own units of work
+    tile_rows = ragged_tile_rows(cfg.num_attention_heads, kvh, d)
+
+    def row_counts(rows, ctx_tokens, page_size, pages_per_seq):
+        # what the walk fetches in one layer (whole pages, a slot once
+        # for each of its units of work): over kv_ctx_tokens, the
+        # re-read factor
+        return {"attn_kv_tokens_read": ragged_kv_tokens_read(
+            rows[:, 4], rows[:, 3], tile_rows, page_size, pages_per_seq)}
+
+    def pages_per_step(page_size, pages_per_seq, itemsize):
+        return default_pages_per_step(page_size, kvh, d, pages_per_seq,
+                                      itemsize)
+
+    return PagedLayout(
+        name="kv", rows=((kvh, d), (kvh, d)),
+        step=ContinuousBatchingEngine._unified_step_jit,
+        row_counts=row_counts,
+        count_names=("kv_ctx_tokens", "attn_kv_tokens_read"),
+        pages_per_step=pages_per_step)
 
 
 class PageAllocator:
@@ -680,27 +645,27 @@ class PrefixCache:
 
 
 class ContinuousBatchingEngine:
-    """Greedy-decode continuous batching over a paged cache.
+    """Continuous batching over a paged cache: the one ragged step.
 
-    params/cfg: the flagship Llama functional state (models/generation.py
-    weight naming; weight-only int8 dicts from quantize_params_int8 work
-    unchanged).  ``max_slots`` bounds the in-flight batch;
-    ``num_pages`` x ``page_size`` is the shared KV pool per layer."""
+    params/cfg: a model's functional state (models/generation.py weight
+    naming; weight-only int8 dicts from quantize_params_int8 work
+    unchanged).  ``max_slots`` bounds the in-flight batch; ``num_pages``
+    x ``page_size`` is the shared pool of every layer;
+    ``prefill_token_budget`` is the most prompt tokens a step carries
+    (256: what the ledger's Mistral cell is timed at).  ``pages_per_step``
+    left unset is the layout's rule (``PagedLayout.pages_per_step``)."""
 
     def __init__(self, cfg, params, max_slots: int = 8,
-                 num_pages: int = 64, page_size="auto",
-                 max_seq_len: Optional[int] = None,
-                 decode_chunk_steps: int = 8, eos_id: int = -1,
-                 cache_dtype=None, pages_per_step="auto",
-                 prefill_token_budget: Optional[int] = None,
+                 num_pages: int = 64, page_size: int = 128,
+                 max_seq_len: Optional[int] = None, eos_id: int = -1,
+                 cache_dtype=None, pages_per_step: Optional[int] = None,
+                 prefill_token_budget: int = 256,
                  enable_prefix_cache: bool = False,
                  draft_params=None, draft_cfg=None,
                  speculative_k: int = 0,
                  prefill_only: bool = False,
                  host_tier_pages: int = 0):
         from ..models.generation import _CFGS, register_config
-        from ..ops.pallas.decode_attention import (ragged_tile_rows,
-                                                   tune_pages_per_step)
 
         self.cfg = cfg
         self.params = params
@@ -708,22 +673,21 @@ class ContinuousBatchingEngine:
         _, self.cos_tab, self.sin_tab = _CFGS[self.cfg_id]
         self.layout = (cfg.paged_layout() if hasattr(cfg, "paged_layout")
                        else kv_layout(cfg))
-        kv = self.layout.name == "kv"
         self.max_slots = int(max_slots)
         self.max_seq_len = int(max_seq_len or cfg.max_position_embeddings)
-        if page_size == "auto":
-            page_size = tune_page_size(
-                self.max_slots, cfg.num_key_value_heads, cfg.head_dim,
-                self.max_seq_len) if kv else 128
         self.page_size = int(page_size)
         self.num_pages = int(num_pages)
-        # the LAST physical page is a reserved scribble target: masked
-        # (inactive/overrun) slots in the static-shape decode program
-        # write their garbage K/V there instead of corrupting a live page
+        # the LAST physical page is a reserved scribble target: the
+        # static step's padding rows write their garbage there instead
+        # of corrupting a live page
         self.trash_page = self.num_pages - 1
         self.pages_per_seq = -(-self.max_seq_len // self.page_size)
-        self.chunk = int(decode_chunk_steps)
         self.eos_id = int(eos_id)
+        if prefill_token_budget is None or int(prefill_token_budget) < 1:
+            raise ValueError(
+                f"prefill_token_budget {prefill_token_budget!r}: the most "
+                f"prompt tokens a step carries is an int >= 1")
+        self.prefill_budget = int(prefill_token_budget)
 
         L = cfg.num_hidden_layers
         dt = next(iter(v for k, v in params.items()
@@ -733,18 +697,13 @@ class ContinuousBatchingEngine:
         if cache_dtype is not None:
             dt = jnp.dtype(cache_dtype)
         self.cache_dtype = dt
-        if pages_per_step == "auto":
-            pages_per_step = tune_pages_per_step(
-                self.max_slots, cfg.num_key_value_heads, self.page_size,
-                cfg.head_dim, self.pages_per_seq, dt) if kv \
-                else self.layout.pages_per_step(self.page_size)
+        if pages_per_step is None:
+            pages_per_step = self.layout.pages_per_step(
+                self.page_size, self.pages_per_seq, jnp.dtype(dt).itemsize)
         self.pages_per_step = int(pages_per_step)
-        if not kv:
-            # the unified step is all that knows these pools (PagedLayout)
+        if self.layout.name != "kv":
+            # the step is all that knows these pools (PagedLayout)
             for what, asked in (
-                    ("the legacy chunked path (prefill_token_budget unset): "
-                     "its prefill and decode-chunk programs are Llama's",
-                     not prefill_token_budget),
                     ("a draft model: its mirror launches assume the "
                      "target's K/V geometry", draft_params is not None
                      or speculative_k),
@@ -763,9 +722,9 @@ class ContinuousBatchingEngine:
         # reference's static cachekv_quant mode; see incubate/nn/
         # decode_attention.py for the dynamic per-sequence contract)
         self.kv_scales = None
-        # PER-LAYER pools: each decode-step cache write is one direct
-        # scatter into its layer's pool (a fused [L, ...] slab would cost
-        # a slice + whole-layer dynamic-update per layer per step)
+        # PER-LAYER pools: a layer's cache write is one direct scatter
+        # into its own pool (a fused [L, ...] slab would cost a slice +
+        # whole-layer dynamic-update per layer per step)
         # (whatever the layout's two pools hold, they go by k_pages and
         # v_pages here: for "latent", latent rows and index keys)
         ka, vb = self.layout.pool_shapes(self.num_pages, self.page_size)
@@ -786,28 +745,16 @@ class ContinuousBatchingEngine:
         self.queue: deque[Request] = deque()
         self._next_rid = 0
         self.finished: List[Finished] = []
-        # pipelined-launch state: chunks in flight (launched, not yet
-        # harvested), the device-resident token carry from the newest
-        # launch, per-slot dirty mask (host rewrote the slot since the
-        # last launch) and pending (launched-but-unharvested) steps
-        self._inflight: deque = deque()
-        self._dev_tok = None
-        self._dirty = np.ones(self.max_slots, bool)
-        self._pending = np.zeros(self.max_slots, np.int32)
         # step report (reference seq_lens_encoder/decoder/this_time
         # semantics: encoder = prompt tokens prefilled this step,
         # decoder = cached tokens of decoding slots, this_time = tokens
         # processed this step)
         self.last_report: Dict[str, np.ndarray] = {}
-        # unified step's returned logits, for checks against a reference:
+        # the step's returned logits, for checks against a reference:
         # ([(rid, absolute position of the input token), ...], fp32
         # [len(rows), vocab]) of the newest launch
         self.last_logits: Optional[tuple] = None
 
-        # ---- round-11 unified serving plane (ragged prefill+decode) ----
-        self.prefill_budget = (0 if prefill_token_budget is None
-                               else int(prefill_token_budget))
-        self.unified = self.prefill_budget > 0
         self.spec_k = int(speculative_k)
         if self.spec_k and draft_params is None:
             raise ValueError("speculative_k > 0 needs draft_params "
@@ -816,26 +763,11 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 "draft_params without speculative_k >= 1: the draft "
                 "would mirror every step without ever proposing")
-        if (self.spec_k or draft_params is not None) and not self.unified:
-            raise ValueError(
-                "speculative decoding requires the unified engine "
-                "(prefill_token_budget > 0): the verify step IS a "
-                "q_len=k+1 ragged chunk of the unified step")
-        if enable_prefix_cache and not self.unified:
-            raise ValueError(
-                "the prefix cache requires the unified engine "
-                "(prefill_token_budget > 0): cache hits enter decode "
-                "mid-prompt, which only the ragged step can serve")
         # ---- round-16 disaggregated serving (inference/disagg.py) ----
         # prefill_only: prompt-only ragged steps — a completed prompt
         # parks in ``handoff_ready`` (KV pages + first sampled token)
         # for the fleet's KV handoff instead of entering decode.
         self.prefill_only = bool(prefill_only)
-        if self.prefill_only and not self.unified:
-            raise ValueError(
-                "prefill_only requires the unified engine "
-                "(prefill_token_budget > 0): the prompt-only step IS "
-                "the ragged prefill chunk")
         if self.prefill_only and self.spec_k:
             raise ValueError(
                 "prefill_only excludes speculative decoding: a prefill "
@@ -861,15 +793,10 @@ class ContinuousBatchingEngine:
             promote_fn=(self._promote_page if self.host_tier_pages
                         else None))
             if enable_prefix_cache else None)
-        # static packed-row capacity of one unified launch: one decode
-        # row per slot (k+1 under speculation) + the prefill chunk
+        # static packed-row capacity of one launch: one decode row per
+        # slot (k+1 under speculation) + the prefill chunk
         self.rows_cap = self.max_slots * (1 + self.spec_k) \
             + self.prefill_budget
-        # rows of a query tile of the ragged kernel: the packing counts
-        # the K/V its walk reads by the kernel's own units of work
-        self.attn_tile_rows = ragged_tile_rows(
-            cfg.num_attention_heads, cfg.num_key_value_heads,
-            cfg.head_dim) if kv else 0
         # static capacity of the CONSUMED-row gather (round-13): every
         # verify-window row + at most one chunk-final row per slot —
         # the head matmul, fp32 logits buffer and host transfer are
@@ -888,7 +815,7 @@ class ContinuousBatchingEngine:
         # requests must show prefilled == prompt_len - cached; run-scoped
         # by design — bench/tests sum it over the whole trace)
         self.prefill_stats: Dict[int, Dict[str, int]] = {}
-        # what the unified steps did, summed over the engine's life
+        # what the steps did, summed over the engine's life
         # (serving_stats()["steps"]); the waits in whole microseconds,
         # as the spans' arguments carry them
         self.step_totals: Dict[str, int] = dict.fromkeys(
@@ -907,183 +834,47 @@ class ContinuousBatchingEngine:
                             if not k.endswith("._scale"))).dtype
             if not jnp.issubdtype(ddt, jnp.floating):
                 ddt = jnp.bfloat16
-            dkvh, dd = dcfg.num_key_value_heads, dcfg.head_dim
             dL = dcfg.num_hidden_layers
             # draft pools mirror the target's page GEOMETRY (same ids,
             # same tables) so the one page table serves both models;
             # shared prefix pages are therefore shared for the draft
             # too (the donor's draft prefill wrote them)
+            dlayout = kv_layout(dcfg)
+            dka, dvb = dlayout.pool_shapes(self.num_pages, self.page_size)
             self.draft = {
                 "cfg": dcfg, "params": draft_params, "cfg_id": did,
-                "cos_tab": dcos, "sin_tab": dsin,
-                "k_pages": tuple(jnp.zeros(
-                    (self.num_pages, dkvh, self.page_size, dd), ddt)
-                    for _ in range(dL)),
-                "v_pages": tuple(jnp.zeros(
-                    (self.num_pages, dkvh, self.page_size, dd), ddt)
-                    for _ in range(dL)),
+                "cos_tab": dcos, "sin_tab": dsin, "step": dlayout.step,
+                "k_pages": tuple(jnp.zeros(dka, ddt) for _ in range(dL)),
+                "v_pages": tuple(jnp.zeros(dvb, ddt) for _ in range(dL)),
             }
 
     # ---------------- device programs ----------------
 
-    @partial(jax.jit, static_argnames=("self_cfg_id", "chunk",
-                                       "pages_per_step"),
-             donate_argnums=(1, 2))
-    def _decode_chunk_jit(params, k_pages, v_pages, sched, dev_tok,
-                          cos_tab, sin_tab, self_cfg_id, chunk,
-                          pages_per_step, kv_scales=None):
-        """``chunk`` decode steps for all slots.  ``sched`` is the packed
-        host scheduling state, ONE int32 [slots, P+4] upload per chunk:
-        columns [0:P) page tables, P seq lens, P+1 active, P+2 dirty,
-        P+3 restart token.  ``dev_tok`` is the previous chunk's token
-        carry (still on device — the lookahead pipeline never reads it
-        back); slots the host rewrote since that launch (admissions,
-        evictions) take their restart token from the sched upload
-        instead."""
-        from ..models.generation import _CFGS, _Weights, _ffn
-
-        cfg, _, _ = _CFGS[self_cfg_id]
-        w = _Weights(cfg, params)
-        L = cfg.num_hidden_layers
-        h, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                     cfg.head_dim)
-        page = k_pages[0].shape[2]
-        P = sched.shape[1] - 4
-        tables = sched[:, :P]
-        seq0 = sched[:, P]
-        active = sched[:, P + 1] > 0
-        dirty = sched[:, P + 2] > 0
-        tok0 = jnp.where(dirty, sched[:, P + 3], dev_tok)
-        nslots = sched.shape[0]
-        trash = k_pages[0].shape[0] - 1
-        from ..ops.pallas.decode_attention import paged_decode_raw
-
-        def one_step(carry, _):
-            k_pages, v_pages, seq_lens, tok, done = carry
-            x = w.embed(tok[:, None])
-            cos = jnp.take(cos_tab, seq_lens, axis=0)[:, None, None, :]
-            sin = jnp.take(sin_tab, seq_lens, axis=0)[:, None, None, :]
-            cos = cos.astype(x.dtype)
-            sin = sin.astype(x.dtype)
-            from ..models.generation import (_apply_rope, _rms_norm)
-
-            blk = seq_lens // page
-            slot = seq_lens % page
-            bidx = jnp.arange(nslots)
-            phys = tables[bidx, jnp.minimum(blk, P - 1)]   # [nslots]
-            # masked slots (inactive/finished) and overrun slots (the
-            # lookahead chunk of an already-finished sequence) scribble
-            # into the reserved trash page
-            phys = jnp.where(done | (phys < 0) | (blk >= P), trash, phys)
-            new_k, new_v = [], []
-            for i in range(L):
-                xin = _rms_norm(x, w.layer(i, "input_layernorm.weight"),
-                                cfg.rms_norm_eps)
-                q = (xin @ w.layer(i, "self_attn.q_proj.weight")
-                     ).reshape(nslots, 1, h, d)
-                k = (xin @ w.layer(i, "self_attn.k_proj.weight")
-                     ).reshape(nslots, 1, kvh, d)
-                v = (xin @ w.layer(i, "self_attn.v_proj.weight")
-                     ).reshape(nslots, 1, kvh, d)
-                q, k = _apply_rope(q, k, cos, sin)
-                kw_, vw_ = k[:, 0], v[:, 0]
-                qd = q.reshape(nslots, h, d)
-                rep_ = h // kvh
-                if k_pages[i].dtype == jnp.int8:
-                    # quantize the new token; fold k-dequant into q and
-                    # v-dequant into the context (exact per-head linear
-                    # folds — see incubate/nn/decode_attention.py)
-                    kw_ = _round_int8(kw_.astype(jnp.float32)
-                                      * kv_scales["kq"][i][None, :, None])
-                    vw_ = _round_int8(vw_.astype(jnp.float32)
-                                      * kv_scales["vq"][i][None, :, None])
-                    kdq = jnp.repeat(kv_scales["kdq"][i], rep_)
-                    qd = (qd.astype(jnp.float32)
-                          * kdq[None, :, None]).astype(q.dtype)
-                # ONE in-place row scatter into this layer's pool
-                # (per-layer pools: no [L, ...] slab slice/update on
-                # the hot path)
-                kp = _write_kv_rows(k_pages[i], phys, slot, kw_)
-                vp = _write_kv_rows(v_pages[i], phys, slot, vw_)
-                new_k.append(kp)
-                new_v.append(vp)
-                ctx = paged_decode_raw(qd, kp, vp,
-                                       seq_lens + 1, tables,
-                                       scale=d ** -0.5,
-                                       pages_per_step=pages_per_step)
-                if kp.dtype == jnp.int8:
-                    vdq = jnp.repeat(kv_scales["vdq"][i], rep_)
-                    ctx = ctx.astype(jnp.float32) * vdq[None, :, None]
-                x = x + (ctx.reshape(nslots, 1, h * d).astype(x.dtype)
-                         @ w.layer(i, "self_attn.o_proj.weight"))
-                xm = _rms_norm(x, w.layer(i, "post_attention_layernorm"
-                                             ".weight"), cfg.rms_norm_eps)
-                x = x + _ffn(w, i, xm)
-            x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
-            logits = w.head(x[:, 0]).astype(jnp.float32)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt = jnp.where(done, tok, nxt)
-            seq_lens = jnp.where(active & ~done, seq_lens + 1, seq_lens)
-            return (tuple(new_k), tuple(new_v), seq_lens, nxt, done), nxt
-
-        done0 = ~active
-        (k_pages, v_pages, _, tok, _), toks = lax.scan(
-            one_step, (k_pages, v_pages, seq0, tok0, done0), None,
-            length=chunk)
-        return k_pages, v_pages, tok, jnp.moveaxis(toks, 0, 1)
-
     @partial(jax.jit, static_argnames=("self_cfg_id", "bucket"))
-    def _prefill_jit(params, ids, length, cos_tab, sin_tab, self_cfg_id,
-                     bucket):
-        """Causal prefill of ONE prompt padded to ``bucket``; returns
-        (first sampled token, per-layer K/V [L, bucket, kvh, d])."""
-        from ..models.generation import _CFGS, _Weights, _block, _rms_norm
+    def _calibration_prefill_jit(params, ids, cos_tab, sin_tab, self_cfg_id,
+                                 bucket):
+        """Dense causal forward of ONE prompt padded to ``bucket``, for
+        the int8 cache's scale calibration alone
+        (``_calibrate_int8_unified``): returns the per-layer K and V
+        ``[L, bucket, kvh, d]`` as the model computes them, unquantized.
+        Nothing is written to the pools."""
+        from ..models.generation import _CFGS, _Weights, _block
 
         cfg, _, _ = _CFGS[self_cfg_id]
         w = _Weights(cfg, params)
-        L = cfg.num_hidden_layers
         x = w.embed(ids[None])
         pos = jnp.arange(bucket)
         cos = jnp.take(cos_tab, pos, axis=0)[None, :, None, :].astype(x.dtype)
         sin = jnp.take(sin_tab, pos, axis=0)[None, :, None, :].astype(x.dtype)
-        # causal AND padding-masked (padded rows attend real prefix only;
-        # their outputs are discarded)
+        # causal, so the padding behind the prompt changes no real row
         causal = jnp.where(jnp.tril(jnp.ones((bucket, bucket), bool)),
                            0.0, -jnp.inf)
         ks, vs = [], []
-        for i in range(L):
+        for i in range(cfg.num_hidden_layers):
             x, k, v = _block(w, i, x, cos, sin, causal)
             ks.append(k[0])
             vs.append(v[0])
-        x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
-        last = jnp.take(x[0], length - 1, axis=0)
-        logits = w.head(last[None]).astype(jnp.float32)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
-        return tok, jnp.stack(ks), jnp.stack(vs)
-
-    @partial(jax.jit, static_argnames=("npages", "page_size"),
-             donate_argnums=(0, 1))
-    def _write_pages_jit(k_pages, v_pages, ks, vs, pg, npages, page_size):
-        """Write a prompt's per-layer K/V ([L, bucket, kvh, d]) into its
-        physical pages — one compiled dispatch per admission, one
-        batched scatter per layer pool.  Pages beyond the prompt's real
-        length land in the trash page."""
-        L = ks.shape[0]
-        kt = jnp.moveaxis(ks, 1, 2)                  # [L, kvh, B, d]
-        vt = jnp.moveaxis(vs, 1, 2)
-        pad = npages * page_size - kt.shape[2]
-        if pad > 0:      # bucket smaller than the page span: zero-pad
-            kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad), (0, 0)))
-            vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        kvh, d = kt.shape[1], kt.shape[3]
-        # [L, kvh, npages, page, d] -> [L, npages, kvh, page, d]
-        kt = kt.reshape(L, kvh, npages, page_size, d).transpose(0, 2, 1, 3, 4)
-        vt = vt.reshape(L, kvh, npages, page_size, d).transpose(0, 2, 1, 3, 4)
-        new_k = tuple(k_pages[i].at[pg].set(kt[i].astype(k_pages[i].dtype))
-                      for i in range(L))
-        new_v = tuple(v_pages[i].at[pg].set(vt[i].astype(v_pages[i].dtype))
-                      for i in range(L))
-        return new_k, new_v
+        return jnp.stack(ks), jnp.stack(vs)
 
     @partial(jax.jit, donate_argnums=(0, 1))
     def _set_page_jit(k_pages, v_pages, k, v, page):
@@ -1149,12 +940,6 @@ class ContinuousBatchingEngine:
             self.k_pages, self.v_pages, place_on_device(k),
             place_on_device(v), jnp.asarray(p, jnp.int32))
         return p
-
-    @staticmethod
-    def _quant(x, scale):
-        """x [L, tokens, kvh, d] x per-(L, kvh) scale -> int8."""
-        return _round_int8(x.astype(jnp.float32)
-                           * scale[:, None, :, None])
 
     @partial(jax.jit, static_argnames=("self_cfg_id", "pages_per_step",
                                        "with_head"),
@@ -1289,13 +1074,7 @@ class ContinuousBatchingEngine:
                 f"request needs {self._pages_needed(reserve)} pages "
                 f"but the pool only has {self.alloc.total} — it could "
                 f"never be admitted (head-of-line livelock)")
-        if temperature > 0 and not self.unified:
-            raise ValueError("temperature sampling requires the unified "
-                             "engine (host-side sampling from returned "
-                             "logits); the legacy chunked path is "
-                             "greedy-only")
-        if (self.unified and self.cache_dtype == jnp.int8
-                and self.kv_scales is None):
+        if self.cache_dtype == jnp.int8 and self.kv_scales is None:
             # calibrate on the FIRST real prompt at SUBMISSION time —
             # outside any caller's step/heartbeat window, so the
             # calibration prefill's jit compile can never be mistaken
@@ -1312,66 +1091,6 @@ class ContinuousBatchingEngine:
     def _pages_needed(self, tokens: int) -> int:
         return -(-tokens // self.page_size)
 
-    def _admit(self) -> List[int]:
-        """Admit queued prompts into free slots while pages last.  Full
-        prompt + generation budget is reserved up front (no mid-flight
-        OOM — the reference serving stack reserves block budgets the
-        same way)."""
-        admitted = []
-        free_slots = np.nonzero(~self.active)[0]
-        si = 0
-        while self.queue and si < len(free_slots):
-            req = self.queue[0]
-            need = self._pages_needed(len(req.prompt) + req.max_new_tokens)
-            if need > self.alloc.available:
-                break                      # head-of-line waits for pages
-            self.queue.popleft()
-            slot = int(free_slots[si])
-            si += 1
-            pages = [self.alloc.alloc() for _ in range(need)]
-            self.slot_pages[slot] = pages
-            self.tables[slot] = -1
-            self.tables[slot, :need] = pages
-            s = len(req.prompt)
-            bucket = max(16, 1 << (s - 1).bit_length())
-            ids = np.zeros(bucket, np.int32)
-            ids[:s] = req.prompt
-            tok, ks, vs = ContinuousBatchingEngine._prefill_jit(
-                self.params, jnp.asarray(ids), jnp.asarray(s, jnp.int32),
-                self.cos_tab, self.sin_tab, self_cfg_id=self.cfg_id,
-                bucket=bucket)
-            if self.cache_dtype == jnp.int8 and self.kv_scales is None:
-                # calibrate once: absmax per (layer, kv head) over the
-                # first prompt's real tokens, 2x headroom
-                self.kv_scales = self._kv_calibration_scales(ks, vs, s)
-            if self.cache_dtype == jnp.int8:
-                ks = self._quant(ks, self.kv_scales["kq"])
-                vs = self._quant(vs, self.kv_scales["vq"])
-            # scatter the prompt K/V into this slot's pages in ONE
-            # dispatch (per-page eager .at[].set would rewrite the whole
-            # pool per page)
-            npg = self._pages_needed(bucket)
-            pg = np.full(npg, self.trash_page, np.int32)
-            pg[:self._pages_needed(s)] = pages[:self._pages_needed(s)]
-            self.k_pages, self.v_pages = \
-                ContinuousBatchingEngine._write_pages_jit(
-                    self.k_pages, self.v_pages, ks, vs,
-                    jnp.asarray(pg), npages=npg,
-                    page_size=self.page_size)
-            self.active[slot] = True
-            self.seq_lens[slot] = s
-            self.cur_tok[slot] = int(tok)
-            self.budget[slot] = req.max_new_tokens - 1
-            self.slot_rid[slot] = req.rid
-            self._dirty[slot] = True
-            self._pending[slot] = 0
-            self.out_tokens[req.rid] = [int(tok)]
-            self.prompt_lens[req.rid] = s
-            admitted.append((slot, s))
-            if int(tok) == self.eos_id or req.max_new_tokens <= 1:
-                self._finish(slot)
-        return admitted
-
     def _release_slot(self, slot: int):
         """Return a slot's pages and clear its host state — the shared
         tail of normal completion (``_finish``) and withdrawal
@@ -1381,9 +1100,6 @@ class ContinuousBatchingEngine:
         self.tables[slot] = -1
         self.seq_lens[slot] = 0
         self.slot_rid[slot] = -1
-        self._dirty[slot] = True
-        self._pending[slot] = 0
-        # unified-plane slot state (no-ops on the legacy path)
         self.pending_prompt.pop(slot, None)
         if slot in self.prefill_order:
             self.prefill_order.remove(slot)
@@ -1404,10 +1120,8 @@ class ContinuousBatchingEngine:
         elsewhere from its committed prefix, so completing it here would
         double-count it).  Queued requests leave the queue; an active
         request's slot releases its pages (prefix-cache refs on shared
-        pages are the trie's own and survive).  On the legacy pipelined
-        path a canceled slot's stale in-flight chunk is dropped at
-        harvest by the existing rid match.  Returns True when the rid
-        was found."""
+        pages are the trie's own and survive).  Returns True when the
+        rid was found."""
         for i, req in enumerate(self.queue):
             if req.rid == rid:
                 del self.queue[i]
@@ -1493,9 +1207,7 @@ class ContinuousBatchingEngine:
         classic (non-tiered) cache — interior trie pages free only as
         their chains drain — so ``adopt_request`` keeps its own None
         return as the authoritative answer."""
-        if not self.unified or self.prefill_only:
-            return False
-        if self.active.all():
+        if self.prefill_only or self.active.all():
             return False
         if int(seq_len) + int(max_new_tokens) > self.max_seq_len:
             return False
@@ -1518,9 +1230,9 @@ class ContinuousBatchingEngine:
         the fleet's quant/dequant pair single-sourced.  Returns the
         engine rid, or None when no slot/pages are free (the router's
         backpressure signal — retry next tick)."""
-        if not self.unified or self.prefill_only:
+        if self.prefill_only:
             raise ValueError("adopt_request needs a decode-capable "
-                             "unified engine")
+                             "engine")
         if self.layout.name != "kv":
             raise ValueError(f"{self.layout.name} pools do not support the "
                              f"KV handoff: the wire format is K and V pages")
@@ -1603,35 +1315,28 @@ class ContinuousBatchingEngine:
             self._finish(slot)
         return rid
 
-    @staticmethod
-    def _kv_calibration_scales(ks, vs, s: int):
-        """THE int8 K/V scale rule (one home for legacy + unified):
-        absmax per (layer, kv head) over the first ``s`` real tokens,
-        2x headroom, frozen quant/dequant pairs."""
-        kabs = jnp.max(jnp.abs(ks[:, :s].astype(jnp.float32)),
-                       axis=(1, 3)) * 2.0 + 1e-6          # [L, kvh]
-        vabs = jnp.max(jnp.abs(vs[:, :s].astype(jnp.float32)),
-                       axis=(1, 3)) * 2.0 + 1e-6
-        return {"kq": 127.0 / kabs, "kdq": kabs / 127.0,
-                "vq": 127.0 / vabs, "vdq": vabs / 127.0}
-
     def _calibrate_int8_unified(self, prompt) -> None:
-        """One-shot K/V scale calibration for the unified plane: run the
-        legacy full prefill over the FIRST admitted prompt, apply the
-        shared scale rule, then DISCARD that prefill's K/V: the unified
-        step re-prefills the prompt through its own quantized ragged
-        scatter, so the cache holds one self-consistent int8 stream."""
+        """One-shot K/V scale calibration: a dense prefill of the FIRST
+        submitted prompt, absmax per (layer, kv head) over its real
+        tokens with 2x headroom, frozen as quant/dequant pairs.  That
+        prefill's K/V are DISCARDED: the step prefills the prompt again
+        through its own quantized scatter, so the cache holds one
+        self-consistent int8 stream."""
         s = len(prompt)
         bucket = max(16, 1 << (s - 1).bit_length())
         ids = np.zeros(bucket, np.int32)
         ids[:s] = prompt
-        _, ks, vs = ContinuousBatchingEngine._prefill_jit(
-            self.params, jnp.asarray(ids), jnp.asarray(s, jnp.int32),
-            self.cos_tab, self.sin_tab, self_cfg_id=self.cfg_id,
-            bucket=bucket)
-        self.kv_scales = self._kv_calibration_scales(ks, vs, s)
+        ks, vs = ContinuousBatchingEngine._calibration_prefill_jit(
+            self.params, jnp.asarray(ids), self.cos_tab, self.sin_tab,
+            self_cfg_id=self.cfg_id, bucket=bucket)
+        kabs = jnp.max(jnp.abs(ks[:, :s].astype(jnp.float32)),
+                       axis=(1, 3)) * 2.0 + 1e-6          # [L, kvh]
+        vabs = jnp.max(jnp.abs(vs[:, :s].astype(jnp.float32)),
+                       axis=(1, 3)) * 2.0 + 1e-6
+        self.kv_scales = {"kq": 127.0 / kabs, "kdq": kabs / 127.0,
+                          "vq": 127.0 / vabs, "vdq": vabs / 127.0}
 
-    # ---------------- unified serving plane (round 11) ----------------
+    # ---------------- the step ----------------
     #
     # One ragged launch per engine step serves THREE request phases at
     # once: decode slots (one row each), prompt-prefill chunks (up to
@@ -1653,13 +1358,14 @@ class ContinuousBatchingEngine:
         return page
 
     def _admit_unified(self) -> List[tuple]:
-        """Admit queued prompts into free slots.  Unlike the legacy
-        path, NO prefill runs here — the prompt enters the pending
-        queue and is consumed ``prefill_token_budget`` tokens per step
-        by the unified launch, so a long prompt never stalls in-flight
-        decode slots.  Prefix-cache hits map the shared full pages into
-        the new table (copy-on-write: the request only ever writes at
-        or past its private suffix) and skip their prefill entirely."""
+        """Admit queued prompts into free slots.  NO prefill runs here:
+        the prompt enters the pending queue and is consumed
+        ``prefill_token_budget`` tokens per step by the launch, so a
+        long prompt never stalls in-flight decode slots.  Full prompt +
+        generation budget is reserved up front (no mid-flight OOM).
+        Prefix-cache hits map the shared full pages into the new table
+        (copy-on-write: the request only ever writes at or past its
+        private suffix) and skip their prefill entirely."""
         admitted = []
         if (self.cache_dtype == jnp.int8 and self.kv_scales is None
                 and self.queue):
@@ -1725,8 +1431,7 @@ class ContinuousBatchingEngine:
 
     def _sample_row(self, logits_row: np.ndarray, req: Request) -> int:
         """Sample the next token from one returned logits row: greedy
-        argmax (bit-compatible with the device argmax the legacy path
-        used — same fp32 values, same first-max tie-break) or host-side
+        argmax (first maximum, as a device argmax breaks ties) or
         temperature sampling from the request's seeded stream."""
         if req.temperature <= 0:
             return int(np.argmax(logits_row))
@@ -1741,13 +1446,11 @@ class ContinuousBatchingEngine:
         (no vocab projection, no logits buffer) and skips the
         device-to-host copy — the mirror only needs the K/V scatter."""
         d = self.draft
-        d["k_pages"], d["v_pages"], logits = \
-            ContinuousBatchingEngine._unified_step_jit(
-                d["params"], d["k_pages"], d["v_pages"],
-                jnp.asarray(rows_np), jnp.asarray(self.tables),
-                d["cos_tab"], d["sin_tab"], self_cfg_id=d["cfg_id"],
-                pages_per_step=self.pages_per_step,
-                with_head=need_logits)
+        d["k_pages"], d["v_pages"], logits = d["step"](
+            d["params"], d["k_pages"], d["v_pages"],
+            jnp.asarray(rows_np), jnp.asarray(self.tables),
+            d["cos_tab"], d["sin_tab"], self_cfg_id=d["cfg_id"],
+            pages_per_step=self.pages_per_step, with_head=need_logits)
         return np.asarray(logits) if need_logits else None
 
     def _propose(self, decoding: List[int]) -> Dict[int, tuple]:
@@ -1892,10 +1595,8 @@ class ContinuousBatchingEngine:
                     # Python call stack into every operation's location,
                     # and one more frame under the first call cost 0.9 s
                     # of lowering at 16 layers (PERF.md, PR 24)
-                    step_jit = self.layout.step \
-                        or ContinuousBatchingEngine._unified_step_jit
                     self.k_pages, self.v_pages, logits = \
-                        step_jit(
+                        self.layout.step(
                             self.params, self.k_pages, self.v_pages,
                             jnp.asarray(rows), jnp.asarray(self.tables),
                             self.cos_tab, self.sin_tab,
@@ -2009,15 +1710,8 @@ class ContinuousBatchingEngine:
             # the K/V the step has to read at least: its bytes
             "kv_ctx_tokens": kv_ctx,
         }
-        if self.attn_tile_rows:
-            # what the ragged kernel's walk fetches in one layer (whole
-            # pages, a slot once for each of its units of work): over
-            # kv_ctx_tokens, the re-read factor
-            counts["attn_kv_tokens_read"] = ragged_kv_tokens_read(
-                rows[:r, 4], rows[:r, 3], self.attn_tile_rows,
-                self.page_size, self.pages_per_seq)
-        if self.layout.row_counts is not None:
-            counts.update(self.layout.row_counts(rows[:r], kv_ctx))
+        counts.update(self.layout.row_counts(
+            rows[:r], kv_ctx, self.page_size, self.pages_per_seq))
         return rows, gather, gathered, metas, enc, counts
 
     def _commit_unified(self, metas, logits: np.ndarray, props,
@@ -2107,20 +1801,17 @@ class ContinuousBatchingEngine:
     def serving_stats(self) -> Dict[str, Any]:
         """Serving-plane telemetry: prefix-cache counters, per-request
         prefill accounting (the FLOPs-skip contract), speculative
-        accepted-length distribution and, for a unified engine,
-        ``"steps"``: what its steps did since it was built, for an
-        operator who never traces (how full the steps are: ``rows`` over
-        ``rows_cap``; how long requests queue and prefill, sum and max
-        in seconds).  The same numbers, per step and per request, ride
+        accepted-length distribution and ``"steps"``: what the engine's
+        steps did since it was built, for an operator who never traces
+        (how full the steps are: ``rows`` over ``rows_cap``; how long
+        requests queue and prefill, sum and max in seconds).  The same numbers, per step and per request, ride
         on the ``serving.step_counts``, ``serving.admit_request`` and
         ``serving.first_token`` markers of a profiler trace."""
+        t = self.step_totals
         out: Dict[str, Any] = {
             "prefill": dict(self.prefill_stats),
             "accepted_lengths": list(self.accepted_lengths),
-        }
-        if self.unified:
-            t = self.step_totals
-            out["steps"] = {
+            "steps": {
                 **{k: t[k] for k in ("steps", "rows", "rows_cap",
                                      "decode_rows", "prefill_rows",
                                      "admitted", *self.layout.count_names)},
@@ -2128,7 +1819,8 @@ class ContinuousBatchingEngine:
                                  "max": t["queue_wait_us_max"] / 1e6},
                 "prefill_s": {"sum": t["prefill_us"] / 1e6,
                               "max": t["prefill_us_max"] / 1e6},
-            }
+            },
+        }
         if self.accepted_lengths:
             out["mean_accepted_len"] = float(
                 np.mean(self.accepted_lengths))
@@ -2136,118 +1828,20 @@ class ContinuousBatchingEngine:
             out["prefix_cache"] = self.prefix_cache.stats()
         return out
 
-    def _pack_sched(self) -> np.ndarray:
-        P = self.pages_per_seq
-        sched = np.empty((self.max_slots, P + 4), np.int32)
-        sched[:, :P] = self.tables
-        sched[:, P] = self.seq_lens
-        sched[:, P + 1] = self.active
-        sched[:, P + 2] = self._dirty
-        sched[:, P + 3] = self.cur_tok
-        return sched
-
-    def _launch(self) -> bool:
-        """Dispatch the next decode chunk (async) against the current
-        host schedule and the device-resident token carry.  Returns
-        False when no active slot could still produce a consumable token
-        (all remaining budget is already covered by in-flight chunks)."""
-        if not self.active.any():
-            return False
-        remaining = self.budget - self._pending
-        if not (self.active & (remaining > 0)).any():
-            return False
-        dev_tok = (self._dev_tok if self._dev_tok is not None
-                   else jnp.zeros((self.max_slots,), jnp.int32))
-        out = ContinuousBatchingEngine._decode_chunk_jit(
-            self.params, self.k_pages, self.v_pages,
-            jnp.asarray(self._pack_sched()), dev_tok,
-            self.cos_tab, self.sin_tab, self_cfg_id=self.cfg_id,
-            chunk=self.chunk, pages_per_step=self.pages_per_step,
-            kv_scales=self.kv_scales)
-        self.k_pages, self.v_pages, self._dev_tok, toks = out
-        self._inflight.append({
-            "toks": toks,
-            "steps": self.chunk,
-            "rids": self.slot_rid.copy(),
-            "launched_active": self.active.copy(),
-        })
-        # the host mirror advances deterministically (the scan adds one
-        # token per step per active slot) — no readback needed
-        self.seq_lens = np.where(self.active,
-                                 self.seq_lens + self.chunk,
-                                 self.seq_lens).astype(np.int32)
-        self._pending = np.where(self.active,
-                                 self._pending + self.chunk,
-                                 self._pending).astype(np.int32)
-        self._dirty[:] = False
-        return True
-
-    def _harvest(self, force: bool = False):
-        """Consume the oldest in-flight chunk's tokens (the only
-        host<->device sync on the serving path).  With the one-chunk
-        lookahead, this normally runs while the NEXT chunk executes on
-        device; ``force`` drains the pipeline when nothing new was
-        launched this step."""
-        this_time = np.zeros(self.max_slots, np.int32)
-        if not self._inflight or (len(self._inflight) < 2 and not force):
-            return 0, this_time
-        inf = self._inflight.popleft()
-        toks = np.asarray(inf["toks"])                # [slots, steps]
-        produced = 0
-        for s in np.nonzero(inf["launched_active"])[0]:
-            s = int(s)
-            rid = int(inf["rids"][s])
-            if (rid < 0 or not self.active[s]
-                    or int(self.slot_rid[s]) != rid):
-                continue            # evicted (or slot reused) since launch
-            take = int(min(inf["steps"], self.budget[s]))
-            hit_eos = False
-            for t in toks[s, :take]:
-                self.out_tokens[rid].append(int(t))
-                produced += 1
-                this_time[s] += 1
-                if int(t) == self.eos_id:
-                    hit_eos = True
-                    break
-            self.budget[s] -= take
-            self._pending[s] = max(0, int(self._pending[s]) - inf["steps"])
-            if self.budget[s] <= 0 or hit_eos:
-                self._finish(s)
-        return produced, this_time
-
     def step(self):
-        """One scheduler iteration.  Unified engines run the ragged
-        admit/propose/launch/commit step; legacy engines admit, launch
-        the next decode chunk and harvest the previous one.  Returns
-        the number of tokens consumed this iteration (legacy: 0 while
-        the pipeline fills)."""
-        if self.unified:
-            return self._step_unified()
-        admitted = self._admit()
-        enc = np.zeros(self.max_slots, np.int32)
-        for s, plen in admitted:
-            enc[s] = plen
-        launched = self._launch()
-        # decoder lens snapshot BEFORE this harvest's evictions (the
-        # reference reports the lens the step ran with)
-        dec = np.where(self.active, self.seq_lens, 0).astype(np.int32)
-        produced, this_dec = self._harvest(force=not launched)
-        self.last_report = {
-            "seq_lens_encoder": enc,
-            "seq_lens_decoder": dec,
-            "seq_lens_this_time": enc + this_dec,
-        }
-        return produced
+        """One scheduler iteration; returns the number of tokens
+        produced.  It stays a call of ``_step_unified``: the jitted step
+        is lowered from that frame, no deeper (PERF.md, PR 24)."""
+        return self._step_unified()
 
     def run(self, max_iters: int = 10_000):
-        """Drive until queue + slots + in-flight chunks drain.  Returns
-        finished requests sorted by rid."""
+        """Drive until queue and slots drain.  Returns finished requests
+        sorted by rid."""
         it = 0
-        while ((self.queue or self.active.any() or self._inflight)
-               and it < max_iters):
+        while (self.queue or self.active.any()) and it < max_iters:
             self.step()
             it += 1
-        if self.queue or self.active.any() or self._inflight:
+        if self.queue or self.active.any():
             raise RuntimeError("serving loop did not drain")
         return sorted(self.finished, key=lambda f: f.rid)
 
@@ -2255,48 +1849,20 @@ class ContinuousBatchingEngine:
 
     def analysis_entry(self):
         """(fn, args, kwargs, options) for ``paddle_tpu.analysis.check``
-        over the compiled decode-chunk program — the serving hot path as
-        the doctor sees it (same static config, current pool/schedule
-        shapes).  ``options`` declares the donation contract: params and
-        the rope tables persist across chunks BY DESIGN (the weight
-        stream re-reads them every chunk; donating would force a
-        re-upload), while the page pools are donated through the program
-        (donate_argnums=(1, 2)) and the doctor verifies that stays true.
+        over the step program: the SAME jit the scheduler launches
+        (``layout.step``), at its static row capacity (decode rows +
+        spec windows + a full prefill chunk).  ``options`` declares the
+        donation contract: params and the rope tables persist across
+        steps BY DESIGN (every step re-reads them; donating would force
+        a re-upload), while the page pools are donated through the
+        program (donate_argnums=(1, 2)) and the doctor verifies that
+        stays true; the packed row schedule and page table are per-step
+        uploads (small int32, below the donation floor by construction).
 
             fn, args, kwargs, options = engine.analysis_entry()
             report = paddle_tpu.analysis.check(
                 fn, *args, kwargs=kwargs, options=options)
         """
-        if self.unified:
-            return self._unified_analysis_entry()
-        dev_tok = (self._dev_tok if self._dev_tok is not None
-                   else jnp.zeros((self.max_slots,), jnp.int32))
-        fn = ContinuousBatchingEngine._decode_chunk_jit
-        args = (self.params, self.k_pages, self.v_pages,
-                jnp.asarray(self._pack_sched()), dev_tok,
-                self.cos_tab, self.sin_tab)
-        kwargs = dict(self_cfg_id=self.cfg_id, chunk=self.chunk,
-                      pages_per_step=self.pages_per_step,
-                      kv_scales=self.kv_scales)
-        # min_bytes sized to the page pools, not the 1MB production
-        # default: tiny test/debug engines must still FAIL the doctor if
-        # the pools stop being donated (a vacuous gate passes when the
-        # contract breaks)
-        pool_bytes = min(int(np.prod(k.shape)) * k.dtype.itemsize
-                         for k in self.k_pages)
-        options = {"donation": {"persistent": (0, 5, 6),
-                                "min_bytes": min(1 << 20,
-                                                 max(1, pool_bytes // 2))}}
-        return fn, args, kwargs, options
-
-    def _unified_analysis_entry(self):
-        """Doctor entry for the unified ragged step: the SAME jit the
-        scheduler launches, at its static row capacity (decode rows +
-        spec windows + a full prefill chunk) — the serving hot path of
-        the round-11 plane.  Argument indices match the legacy entry:
-        params/rope tables persistent, page pools donated; the packed
-        row schedule and page table are per-step uploads (small int32,
-        below the donation floor by construction)."""
         rows = np.zeros((self.rows_cap, 5), np.int32)
         rows[:, 1] = self.trash_page
         rows[:, 4] = -1
@@ -2309,7 +1875,6 @@ class ContinuousBatchingEngine:
                              self.cfg.num_key_value_heads), jnp.float32)
             kv_scales = {"kq": ones, "kdq": ones,
                          "vq": ones, "vdq": ones}
-        fn = self.layout.step or ContinuousBatchingEngine._unified_step_jit
         args = (self.params, self.k_pages, self.v_pages,
                 jnp.asarray(rows), jnp.asarray(self.tables),
                 self.cos_tab, self.sin_tab)
@@ -2317,6 +1882,10 @@ class ContinuousBatchingEngine:
                       pages_per_step=self.pages_per_step,
                       kv_scales=kv_scales,
                       gather=jnp.zeros(self.gather_cap, jnp.int32))
+        # min_bytes sized to the page pools, not the 1MB production
+        # default: tiny test/debug engines must still FAIL the doctor if
+        # the pools stop being donated (a vacuous gate passes when the
+        # contract breaks)
         pool_bytes = min(int(np.prod(k.shape)) * k.dtype.itemsize
                          for k in self.k_pages)
         options = {"donation": {"persistent": (0, 5, 6),
@@ -2327,7 +1896,7 @@ class ContinuousBatchingEngine:
                    # a GSPMD-inserted all-to-all/permute/gather here
                    # means a spec leaked into the unified step
                    "sharding_consistency": {"audit_resharding": True}}
-        return fn, args, kwargs, options
+        return self.layout.step, args, kwargs, options
 
     def param_layout(self):
         """Canonical SpecLayout of the engine's committed params (the

@@ -188,7 +188,8 @@ class DeepseekV32Config:
             count_names=(*ROW_COUNTS, *DEVICE_COUNTS),
             # 2048 keys a turn of the kernels' page walk: the best of 512,
             # 1024 and 2048 on the chip (PERF.md section 6, PR 26)
-            pages_per_step=lambda page: max(1, 2048 // page))
+            pages_per_step=lambda page, pages_per_seq, itemsize:
+            max(1, 2048 // page))
 
     def leaf_shapes(self) -> Dict[str, tuple]:
         """Every leaf of the functional state this model reads, by name
@@ -250,10 +251,12 @@ DEVICE_COUNTS = ("moe_rows_held", "moe_rows_routed", "moe_expert_rows_max")
 ROW_COUNTS = ("index_row_ctx", "sel_row_tokens", "latent_ctx_tokens")
 
 
-def _row_counts(topk: int, rows: np.ndarray, ctx_tokens: int) -> Dict[str, int]:
+def _row_counts(topk: int, rows: np.ndarray, ctx_tokens: int,
+                page_size: int, pages_per_seq: int) -> Dict[str, int]:
     """A step's counts that the packed rows give (host side):
     positions the indexer scores, positions attended after selection,
-    and the context each scheduled slot holds, once each."""
+    and the context each scheduled slot holds, once each (none of them
+    in whole pages, so the page geometry goes unread)."""
     vis = rows[:, 3]
     return dict(zip(ROW_COUNTS, (int(vis.sum()),
                                  int(np.minimum(vis, topk).sum()),

@@ -289,39 +289,8 @@ def default_pages_per_step(page: int, kvh: int, d: int, max_pages: int,
     return max(1, pp)
 
 
-def tune_pages_per_step(b, kvh, page, d, max_pages, dtype=jnp.bfloat16):
-    """Measure paged_decode_raw across pages-per-step candidates for this
-    serving shape (cached per signature; ops/autotune.py pattern).
-    Returns the heuristic default when autotune is off or on CPU."""
-    from .. import autotune as _at
-
-    default = default_pages_per_step(page, kvh, d, max_pages,
-                                     jnp.dtype(dtype).itemsize)
-    key = ("paged_pages_per_step", b, kvh, page, d, max_pages, str(dtype))
-    cached = _at.AutoTuneCache.instance().lookup(key)
-    if cached is not None:
-        return cached
-    if not _at.enabled() or pallas_interpret():
-        return default
-
-    npages = b * max_pages
-    kc = jnp.zeros((npages, kvh, page, d), dtype)
-    vc = jnp.zeros((npages, kvh, page, d), dtype)
-    tables = jnp.arange(npages, dtype=jnp.int32).reshape(b, max_pages)
-    qx = jnp.ones((b, kvh, d), dtype)
-    lens = jnp.full((b,), (max_pages * page) // 2, jnp.int32)
-
-    def measure(pp):
-        return _at.time_fn(lambda: jax.block_until_ready(
-            paged_decode_raw(qx, kc, vc, lens, tables, pages_per_step=pp)))
-
-    cands = sorted({p for p in (1, 2, 4, 8)
-                    if p <= max_pages} | {default})
-    return _at.AutoTuneCache.instance().tune(key, cands, measure)
-
-
 def paged_decode_raw(q, key_cache, value_cache, seq_lens, block_tables,
-                     scale=None, interpret=None, pages_per_step="auto",
+                     scale=None, interpret=None, pages_per_step=None,
                      name=PAGED_DECODE_KERNEL):
     """Paged (vLLM-layout) flash decode: q [b, h, d]; key/value_cache
     [n_blocks, kvh, page, d]; seq_lens [b] (valid tokens, INCLUDING the
@@ -338,9 +307,9 @@ def paged_decode_raw(q, key_cache, value_cache, seq_lens, block_tables,
     (DMA elided) and their compute is skipped, so both HBM traffic AND
     grid-step count are bounded by the live lengths, not capacity.
 
-    ``pages_per_step``: physical pages per grid step ("auto" targets a
-    ~512-token window per step — the dense kernel's block size — under
-    a VMEM budget; serving pre-tunes it via tune_pages_per_step).
+    ``pages_per_step``: physical pages per grid step (left unset,
+    ``default_pages_per_step``: a ~512-token window per step, the dense
+    kernel's block size, under a VMEM budget).
     ``name`` is the kernel's name in the compiled program."""
     b, h, d = q.shape
     kvh, page = key_cache.shape[1], key_cache.shape[2]
@@ -353,7 +322,7 @@ def paged_decode_raw(q, key_cache, value_cache, seq_lens, block_tables,
     rep = h // kvh
     rp = -(-rep // 8) * 8
     max_pages = block_tables.shape[1]
-    if pages_per_step == "auto":
+    if pages_per_step is None:
         pages_per_step = default_pages_per_step(
             page, kvh, d, max_pages, jnp.dtype(key_cache.dtype).itemsize)
     pp = max(1, min(int(pages_per_step), max_pages))
@@ -644,7 +613,7 @@ def _ragged_paged_kernel(slot_ref, cnt_ref, reach_ref, tab_ref, live_ref,
 
 def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
                             block_tables, scale=None, interpret=None,
-                            pages_per_step="auto", tile_rows=None):
+                            pages_per_step=None, tile_rows=None):
     """Ragged paged flash attention: the serving plane's unified
     prefill+decode step (the Ragged Paged Attention kernel shape,
     PAPERS.md 2604.15464).
@@ -704,7 +673,7 @@ def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
             interpret=interpret, pages_per_step=pages_per_step,
             name=RAGGED_PAGED_KERNEL)
     max_pages = block_tables.shape[1]
-    if pages_per_step == "auto":
+    if pages_per_step is None:
         pages_per_step = default_pages_per_step(
             page, kvh, d, max_pages, jnp.dtype(key_cache.dtype).itemsize)
     pp = max(1, min(int(pages_per_step), max_pages))
@@ -814,7 +783,7 @@ def flash_decoding_op(q, k_cache, v_cache, seq_lens, scale=None):
 @register("paged_flash_decoding", amp="white")
 def paged_flash_decoding_op(q, key_cache, value_cache, seq_lens,
                             block_tables, scale=None,
-                            pages_per_step="auto"):
+                            pages_per_step=None):
     return paged_decode_raw(q, key_cache, value_cache, seq_lens,
                             block_tables, scale=scale,
                             pages_per_step=pages_per_step)
@@ -823,7 +792,7 @@ def paged_flash_decoding_op(q, key_cache, value_cache, seq_lens,
 @register("ragged_paged_flash_decoding", amp="white")
 def ragged_paged_flash_decoding_op(q, key_cache, value_cache, row_lens,
                                    row_slot, block_tables, scale=None,
-                                   pages_per_step="auto"):
+                                   pages_per_step=None):
     return ragged_paged_decode_raw(q, key_cache, value_cache, row_lens,
                                    row_slot, block_tables, scale=scale,
                                    pages_per_step=pages_per_step)

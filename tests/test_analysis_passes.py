@@ -185,7 +185,7 @@ def test_serving_donation_gate_is_live():
     cfg, model, opt, params, ids, labels = _flagship()
     eng = ContinuousBatchingEngine(cfg, params, max_slots=2, num_pages=9,
                                    page_size=16, max_seq_len=64,
-                                   decode_chunk_steps=2)
+                                   prefill_token_budget=8)
     fn, args, kwargs, options = eng.analysis_entry()
 
     @jax.jit

@@ -261,8 +261,6 @@ class TestRouting:
 
 
 @pytest.mark.parametrize("option, kw", [
-    ("legacy chunked path", dict(prefill_token_budget=None,
-                                 enable_prefix_cache=False)),
     ("draft model", dict(speculative_k=2, draft_params={"x": jnp.zeros(1)})),
     ("int8 cache", dict(cache_dtype=jnp.int8)),
     ("host tier", dict(host_tier_pages=4)),
@@ -273,6 +271,24 @@ def test_what_the_latent_layout_cannot_do_raises_at_construction(model, option,
     with pytest.raises(ValueError, match=f"latent pools do not support.*"
                                          f"{option.split()[0]}"):
         engine(model, **kw)
+
+
+def test_the_engine_reaches_this_model_through_its_layout(model):
+    """The same questions as of a Llama config: the program the engine
+    launches, and hands the doctor, is the layout's ``step``; pages a
+    turn left unset are the layout's rule (2048 keys a turn); and there
+    is no engine without a prefill budget."""
+    from paddle_tpu.models.deepseek_v32 import unified_step_jit
+
+    cfg, _ = model
+    eng = engine(model)
+    fn, *_ = eng.analysis_entry()
+    assert fn is eng.layout.step is unified_step_jit
+    assert eng.pages_per_step == 2048 // PAGE
+    assert engine(model, pages_per_step=4).pages_per_step == 4
+    for budget in (0, None):
+        with pytest.raises(ValueError, match="prefill_token_budget"):
+            engine(model, prefill_token_budget=budget)
 
 
 def test_a_latent_engine_adopts_no_handoff(model):

@@ -493,30 +493,6 @@ def test_serving_sparse_int8_greedy_parity():
 
 
 @pytest.mark.slow
-def test_serving_sparse_legacy_path_parity():
-    """Tier-2 breadth (tier-1 home: test_serving_sparse_greedy_parity —
-    the unified step is the production path; the legacy chunked decode
-    shares generation._ffn with it): the paged pipelined scheduler also
-    serves the sparse checkpoint bit-identically."""
-    from paddle_tpu.inference.serving import ContinuousBatchingEngine
-    from paddle_tpu.models.generation import (_generate_jit,
-                                              register_config)
-
-    cfg, params = toy_sparse_llama(seed=3)
-    cfg_id = register_config(cfg)
-    prompt = np.array([3, 17, 9, 42, 7], np.int32)
-    key = jax.random.PRNGKey(0)
-    ref = np.asarray(_generate_jit(params, prompt[None], key, cfg_id,
-                                   8, False, 1.0, 0, 1.0, -1))[0]
-    eng = ContinuousBatchingEngine(cfg, params, max_slots=2,
-                                   num_pages=17, page_size=16,
-                                   max_seq_len=64, decode_chunk_steps=3)
-    eng.add_request(prompt, max_new_tokens=8)
-    done = eng.run()
-    assert list(done[0].tokens) == list(ref[:len(done[0].tokens)])
-
-
-@pytest.mark.slow
 def test_ep_forward_dp2_sharding1_variant():
     """Tier-2 breadth (tier-1 home: test_ep_forward_matches_dense_no_
     drops on the dp1 x sharding2 x ep4 mesh — same code path, different

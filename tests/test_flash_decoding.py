@@ -203,7 +203,8 @@ def test_flash_decode_tensor_parallel_shard_map():
                                rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("pp", [1, 2, 3, 4, "auto"])
+@pytest.mark.parametrize("pp", [1, 2, 3, 4, None],
+                         ids=["1", "2", "3", "4", "default"])
 def test_paged_decode_multi_page_grid_steps(pp):
     """Round-6 ragged page iteration: pages_per_step physical pages DMA'd
     per grid step must be bit-for-the-same-math as one-page-per-step

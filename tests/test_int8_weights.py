@@ -75,36 +75,3 @@ def test_int8_generate_mostly_matches_fp(tiny):
     assert np.isfinite(q8.astype(np.float64)).all()
     # int8 weights flip only rare near-ties on a greedy stream
     assert (fp == q8).mean() > 0.6, (fp, q8)
-
-
-@pytest.mark.slow
-def test_int8_weights_through_serving_engine(tiny):
-    # tier-2 (round-16 re-tier): duplicate of the int8_weight_serving
-    # smoke leg (same property, same engine path)
-    """int8 weights AND int8 KV cache composed in the serving engine —
-    the exact configuration of the bench 8B leg, at toy scale, with
-    greedy parity against int8-weight generate()."""
-    from paddle_tpu.inference.serving import ContinuousBatchingEngine
-
-    cfg, params = tiny
-    qp = quantize_params_int8(params)
-    cid = register_config(cfg)
-    rng = np.random.default_rng(1)
-    prompt = rng.integers(1, cfg.vocab_size, (6,)).astype(np.int32)
-
-    eng = ContinuousBatchingEngine(cfg, qp, max_slots=2,
-                                   num_pages=17, page_size=16,
-                                   max_seq_len=64, decode_chunk_steps=3,
-                                   cache_dtype=jnp.int8)
-    eng.add_request(prompt, max_new_tokens=6)
-    done = eng.run()
-    assert len(done) == 1 and len(done[0].tokens) == 6
-    # bf16/int8-cache engines already tested elsewhere; here assert the
-    # int8-weight stream against the int8-weight one-shot path (fp32
-    # cache there vs int8 cache here: near-ties may flip rarely)
-    # _generate_jit returns only the generated tokens [b, max_new]
-    ref = np.asarray(_generate_jit(
-        qp, jnp.asarray(prompt[None]), jax.random.PRNGKey(0), cfg_id=cid,
-        max_new_tokens=6, do_sample=False, temperature=1.0, top_k=0,
-        top_p=1.0, eos_id=-1))[0]
-    assert (done[0].tokens == ref).mean() > 0.6

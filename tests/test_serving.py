@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 from paddle_tpu.inference.serving import (ContinuousBatchingEngine,
-                                          PageAllocator, PrefixCache)
+                                          PageAllocator, PrefixCache,
+                                          kv_layout, ragged_kv_tokens_read)
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.generation import generate, self_draft_params
 
@@ -20,7 +21,7 @@ def tiny_model():
     # instantiate before the function-scoped autouse ``_seed`` fixture,
     # so without this the params depended on whatever RNG state the
     # previous test left behind — the root cause of the suite-order
-    # flake in test_serving_int8_cache_close_to_bf16 (VERDICT r5 Weak
+    # flake in test_unified_int8_kv_cache_close_to_bf16 (VERDICT r5 Weak
     # #4: near-tie greedy tokens flipped with different random params).
     import paddle_tpu as paddle
 
@@ -34,128 +35,6 @@ def tiny_model():
     return cfg, model, params
 
 
-def _engine(cfg, params, **kw):
-    kw.setdefault("max_slots", 3)
-    kw.setdefault("num_pages", 33)
-    kw.setdefault("page_size", 16)
-    kw.setdefault("max_seq_len", 128)
-    kw.setdefault("decode_chunk_steps", 4)
-    return ContinuousBatchingEngine(cfg, params, **kw)
-
-
-
-@pytest.mark.slow
-def test_serving_matches_oneshot_generate(tiny_model):
-    """Tier-2 (round-16 re-tier: legacy chunked-path parity; tier-1 home: the serving_pipeline_parity smoke leg + test_unified_matches_oneshot_generate).
-
-    Every request's greedy tokens == the plain generate() output for
-    that prompt alone — continuous batching must not change results."""
-    cfg, model, params = tiny_model
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(1, cfg.vocab_size, (n,)).astype(np.int32)
-               for n in (5, 9, 17)]
-    new = 6
-
-    eng = _engine(cfg, params)
-    for p in prompts:
-        eng.add_request(p, max_new_tokens=new)
-    done = eng.run()
-    assert len(done) == len(prompts)
-
-    for i, p in enumerate(prompts):
-        ref = generate(model, p[None], max_new_tokens=new, do_sample=False)
-        ref_new = np.asarray(ref._value if hasattr(ref, "_value") else ref
-                             )[0, len(p):]
-        got = done[i].tokens
-        np.testing.assert_array_equal(
-            got, ref_new[:len(got)],
-            err_msg=f"request {i} diverged from one-shot generate")
-        assert len(got) == new
-
-
-@pytest.mark.slow  # round-20 tier policy: tier-1 home = the backpressure
-# family's test_unified_throttle_sheds_and_restores + the page-leak
-# shutdown assertion every kept serving leg exercises
-def test_serving_admission_waits_for_pages(tiny_model):
-    """With pages for only ~one sequence, requests are admitted one at a
-    time; eviction frees pages and the next request proceeds."""
-    cfg, model, params = tiny_model
-    rng = np.random.default_rng(1)
-    # each request needs ceil((8+8)/16)=1 page; give the pool 2 usable
-    # pages so at most 2 requests fit concurrently
-    eng = _engine(cfg, params, num_pages=3, max_slots=3)
-    prompts = [rng.integers(1, cfg.vocab_size, (8,)).astype(np.int32)
-               for _ in range(4)]
-    for p in prompts:
-        eng.add_request(p, max_new_tokens=8)
-    eng.step()
-    assert eng.active.sum() <= 2       # third waits for pages
-    assert len(eng.queue) >= 2
-    done = eng.run()
-    assert len(done) == 4
-    # all pages returned
-    assert eng.alloc.available == 2
-    assert not eng.active.any()
-
-
-
-@pytest.mark.slow
-def test_serving_page_reuse_and_growth(tiny_model):
-    """Tier-2 (round-16 re-tier: legacy-path page growth; tier-1 home: the smoke leg drives the same allocator/scheduler path).
-
-    Sequences spanning multiple pages get them up front; released page
-    ids are reused by later requests (LIFO)."""
-    cfg, model, params = tiny_model
-    rng = np.random.default_rng(2)
-    eng = _engine(cfg, params, num_pages=9, page_size=16)
-    p1 = rng.integers(1, cfg.vocab_size, (30,)).astype(np.int32)
-    eng.add_request(p1, max_new_tokens=12)  # 42 tokens -> 3 pages
-    eng.step()                              # chunk=4 < 12: still active
-    used_first = set(range(8)) - set(eng.alloc.free)
-    assert len(used_first) == 3
-    done = eng.run()
-    assert len(done) == 1 and len(done[0].tokens) == 12
-    assert eng.alloc.available == 8
-    # next request reuses freed ids
-    eng.add_request(rng.integers(1, cfg.vocab_size, (10,)).astype(np.int32),
-                    max_new_tokens=4)
-    eng.step()
-    used_second = set(range(8)) - set(eng.alloc.free)
-    assert used_second <= used_first
-    eng.run()
-
-
-@pytest.mark.slow
-def test_serving_mixed_arrivals_report(tiny_model):
-    # tier-2 (round-16 re-tier): legacy-path report breadth; tier-1
-    # home: the unified report semantics + the smoke pipeline leg
-    """Requests arriving mid-decode join the running batch; the step
-    report carries the reference's seq_lens_encoder/decoder/this_time
-    semantics."""
-    cfg, model, params = tiny_model
-    rng = np.random.default_rng(3)
-    eng = _engine(cfg, params, decode_chunk_steps=2)
-    r0 = eng.add_request(rng.integers(1, cfg.vocab_size, (6,)).astype(
-        np.int32), max_new_tokens=10)
-    eng.step()
-    rep = eng.last_report
-    assert rep["seq_lens_encoder"].sum() == 6          # prefilled 6
-    assert eng.active.sum() == 1
-    # second request arrives while r0 decodes
-    r1 = eng.add_request(rng.integers(1, cfg.vocab_size, (4,)).astype(
-        np.int32), max_new_tokens=6)
-    eng.step()
-    rep = eng.last_report
-    assert rep["seq_lens_encoder"].sum() == 4          # r1's prefill
-    assert (rep["seq_lens_decoder"] > 0).sum() == 2    # both decoding
-    done = eng.run()
-    assert sorted(f.rid for f in done) == [r0, r1]
-    # each produced its budget
-    by_rid = {f.rid: f for f in done}
-    assert len(by_rid[r0].tokens) == 10
-    assert len(by_rid[r1].tokens) == 6
-
-
 def test_page_allocator_lifo():
     a = PageAllocator(4)
     got = [a.alloc() for _ in range(3)]
@@ -167,92 +46,10 @@ def test_page_allocator_lifo():
 
 def test_serving_rejects_oversized_prompt(tiny_model):
     cfg, model, params = tiny_model
-    eng = _engine(cfg, params, max_seq_len=32)
+    eng = _unified(cfg, params, max_seq_len=32)
     with pytest.raises(ValueError):
         eng.add_request(np.zeros(30, np.int32), max_new_tokens=8)
 
-
-
-@pytest.mark.slow
-def test_serving_int8_cache_close_to_bf16(tiny_model):
-    """Tier-2 (round-16 re-tier: legacy int8-KV tolerance leg; tier-1 home: the EXACT int8 gates (disagg int8 bit-parity + warmup-no-calibrate)).
-
-    cache_dtype=int8: frozen auto-calibrated per-(layer, head) scales;
-    the greedy token streams should match the fp32-cache engine for most
-    steps (quantization may flip rare near-ties, but the run must
-    complete and mostly agree) — the serving-side composition of the
-    int8 KV-cache capability."""
-    cfg, model, params = tiny_model
-    rng = np.random.default_rng(5)
-    prompts = [rng.integers(1, cfg.vocab_size, (n,)).astype(np.int32)
-               for n in (6, 11)]
-
-    outs = {}
-    for dt in (None, jnp.int8):
-        eng = _engine(cfg, params, cache_dtype=dt)
-        for p in prompts:
-            eng.add_request(p, max_new_tokens=8)
-        done = eng.run()
-        # keyed by rid (run() sorts by rid) — order-independent pairing
-        outs[dt] = {f.rid: f.tokens for f in done}
-        if dt == jnp.int8:
-            assert all(kp.dtype == jnp.int8 for kp in eng.k_pages)
-            assert eng.kv_scales is not None
-
-    assert sorted(outs[None]) == sorted(outs[jnp.int8])
-    total_matching_tokens = sum(
-        (np.asarray(a[:len(b)]) == np.asarray(b[:len(a)])).mean()
-        for a, b in ((outs[None][r], outs[jnp.int8][r])
-                     for r in sorted(outs[None]))) / len(prompts)
-    assert total_matching_tokens > 0.7, (outs, total_matching_tokens)
-
-
-
-@pytest.mark.slow
-def test_serving_slot_reuse_under_lookahead(tiny_model):
-    """Tier-2 (round-16 re-tier: legacy pipelined-lookahead breadth; tier-1 home: the smoke leg's pipelined run + allocator leak checks).
-
-    Round-6 pipelined scheduler: with ONE slot, requests run strictly
-    one after another through slot 0 — the stale lookahead chunk of a
-    finished request must never leak tokens into (or corrupt the pages
-    of) the request that reuses its slot.  Greedy parity with one-shot
-    generate() proves both."""
-    cfg, model, params = tiny_model
-    rng = np.random.default_rng(7)
-    prompts = [rng.integers(1, cfg.vocab_size, (n,)).astype(np.int32)
-               for n in (6, 9, 5)]
-    eng = _engine(cfg, params, max_slots=1, num_pages=5,
-                  decode_chunk_steps=3)
-    for p in prompts:
-        eng.add_request(p, max_new_tokens=7)
-    done = eng.run()
-    assert len(done) == len(prompts)
-    for i, p in enumerate(prompts):
-        ref = generate(model, p[None], max_new_tokens=7, do_sample=False)
-        ref_new = np.asarray(ref._value if hasattr(ref, "_value") else ref
-                             )[0, len(p):]
-        np.testing.assert_array_equal(
-            done[i].tokens, ref_new[:len(done[i].tokens)],
-            err_msg=f"request {i} corrupted by slot reuse")
-        assert len(done[i].tokens) == 7
-    assert eng.alloc.available == 4 and not eng._inflight
-
-
-def test_serving_pipeline_overlaps_chunks(tiny_model):
-    """The scheduler keeps one chunk in flight: after a step that
-    launched, the previous chunk (if any) was harvested and the new one
-    is pending; run() drains the pipeline completely."""
-    cfg, model, params = tiny_model
-    rng = np.random.default_rng(8)
-    eng = _engine(cfg, params)
-    eng.add_request(rng.integers(1, cfg.vocab_size, (5,)).astype(np.int32),
-                    max_new_tokens=12)
-    produced0 = eng.step()          # admit + launch; nothing to harvest
-    assert produced0 == 0 and len(eng._inflight) == 1
-    produced1 = eng.step()          # launch #2, harvest #1
-    assert produced1 == 4 and len(eng._inflight) == 1
-    eng.run()
-    assert not eng._inflight and not eng.active.any()
 
 
 # =====================================================================
@@ -311,6 +108,117 @@ def test_unified_matches_oneshot_generate(tiny_model):
             done[i].tokens, ref_new[:len(done[i].tokens)],
             err_msg=f"request {i} diverged under the unified step")
     eng.shutdown()                     # allocator leak check
+
+
+def test_unified_page_and_slot_reuse(tiny_model):
+    """ONE slot and 4 usable pages: three requests pass through slot 0
+    one after another.  The second grows across a page boundary while it
+    decodes (14 + 7 tokens), the third while it prefills (30 tokens in
+    chunks of 8, then 7 more: 3 pages).  A request that takes a slot
+    and pages another has left must see none of its K/V: greedy parity
+    with one-shot generate() proves it.  Released page ids are handed
+    out again, and after the drain nothing is held."""
+    cfg, model, params = tiny_model
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in (6, 14, 30)]
+    eng = _unified(cfg, params, max_slots=1, num_pages=5,
+                   prefill_token_budget=8)
+    rids = [eng.add_request(p, max_new_tokens=7) for p in prompts]
+    held = {}
+    while eng.queue or eng.active.any():
+        eng.step()
+        if eng.active[0]:
+            assert eng.active.sum() == 1
+            held[int(eng.slot_rid[0])] = list(eng.slot_pages[0])
+    done = sorted(eng.finished, key=lambda f: f.rid)
+    assert [len(held[r]) for r in rids] == [1, 2, 3]
+    # LIFO: the page a finished request gave back is the next one taken
+    assert held[rids[0]][0] in held[rids[1]] \
+        and set(held[rids[1]]) <= set(held[rids[2]])
+    for f, p in zip(done, prompts):
+        ref = generate(model, p[None], max_new_tokens=7, do_sample=False)
+        ref_new = np.asarray(ref._value if hasattr(ref, "_value") else ref
+                             )[0, len(p):]
+        np.testing.assert_array_equal(
+            f.tokens, ref_new, err_msg=f"request {f.rid} corrupted by "
+                                       f"slot or page reuse")
+    assert eng.alloc.available == eng.alloc.total == 4
+    assert (eng.tables == -1).all() and not eng.slot_pages
+    eng.alloc.assert_balanced()
+    eng.shutdown()
+
+
+def test_engine_has_one_step_path(tiny_model):
+    """No option selects between engines: no ``decode_chunk_steps``, no
+    ``"auto"``, a budget that is a number; and the program the engine
+    launches is its layout's ``step``, which is what the doctor is
+    handed."""
+    import inspect
+
+    cfg, model, params = tiny_model
+    sig = inspect.signature(ContinuousBatchingEngine.__init__).parameters
+    assert len(sig) == 3 + 14 and "decode_chunk_steps" not in sig
+    assert "auto" not in [p.default for p in sig.values()]
+    assert sig["prefill_token_budget"].default == 256 \
+        and sig["page_size"].default == 128
+    eng = _unified(cfg, params)
+    assert not hasattr(eng, "unified")
+    fn, *_ = eng.analysis_entry()
+    assert fn is eng.layout.step is kv_layout(cfg).step \
+        is ContinuousBatchingEngine._unified_step_jit
+    # left unset, the pages a turn are the layout's rule; an int overrides
+    assert eng.pages_per_step == eng.layout.pages_per_step(
+        eng.page_size, eng.pages_per_seq, 4) == 8
+    assert _unified(cfg, params, pages_per_step=2).pages_per_step == 2
+
+
+@pytest.mark.parametrize("chunk, want", [(0, 8 + 16), (70, 8 + 16 + 64 + 72)],
+                         ids=["decode_rows", "chunk_crossing_a_tile"])
+def test_kv_layout_row_counts(tiny_model, chunk, want):
+    """What the K/V layout counts for a packed schedule, by hand (pages
+    of 8, the tiny shapes' query tile of 64 rows): decode rows that see
+    6 and 10 positions fetch 1 and 2 pages; behind them a 70-row chunk
+    is two units of work, 62 rows in the first tile reaching 62
+    positions (8 pages) and 8 rows in the second reaching 70 (9 pages).
+    The same rows in a served step put the same number on
+    ``serving.step_counts``."""
+    cfg, model, params = tiny_model
+    eng = _unified(cfg, params, max_slots=3, num_pages=33, page_size=8,
+                   max_seq_len=96, prefill_token_budget=72)
+    rng = np.random.default_rng(1)
+    for n in (5, 9):
+        eng.add_request(rng.integers(1, 64, n).astype(np.int32),
+                        max_new_tokens=4)
+    eng.step()                      # both prompts prefilled: 5 + 9 rows
+    if chunk:
+        eng.add_request(rng.integers(1, 64, chunk).astype(np.int32),
+                        max_new_tokens=4)
+    seen = {}
+    pack = eng._pack_unified
+
+    def spy(*a):
+        out = pack(*a)
+        seen["rows"], seen["counts"] = out[0], out[-1]
+        return out
+
+    eng._pack_unified = spy
+    eng.step()
+    r = 2 + chunk
+    assert seen["counts"]["rows"] == r
+    # the hand-built schedule: (token, page, offset, visibility, slot)
+    vis = [6, 10] + list(range(1, chunk + 1))
+    slot = [0, 1] + [2] * chunk
+    rows = np.zeros((r, 5), np.int32)
+    rows[:, 3], rows[:, 4] = vis, slot
+    assert (seen["rows"][:r, 3:] == rows[:, 3:]).all()
+    got = kv_layout(cfg).row_counts(rows, sum(vis[:2]) + chunk, 8, 12)
+    assert got == {"attn_kv_tokens_read": want}
+    assert got["attn_kv_tokens_read"] \
+        == ragged_kv_tokens_read(rows[:, 4], rows[:, 3], 64, 8, 12) \
+        == seen["counts"]["attn_kv_tokens_read"]
+    eng.run()
+    eng.shutdown()
 
 
 def test_prefix_cache_hit_bit_identical_greedy(tiny_model):
@@ -561,21 +469,17 @@ def test_speculative_temperature_runs_and_drains(tiny_model):
 
 
 def test_unified_guard_rails(tiny_model):
-    """Config invariants: spec/prefix-cache/temperature need the unified
-    engine; speculative_k needs draft params; draft depth is bounded."""
+    """Config invariants: the prefill budget is an int >= 1 (there is
+    no engine without one); speculative_k needs draft params; draft
+    depth is bounded."""
     cfg, model, params = tiny_model
-    with pytest.raises(ValueError, match="unified"):
-        _engine(cfg, params, enable_prefix_cache=True)
-    with pytest.raises(ValueError, match="unified"):
-        _engine(cfg, params, draft_params=params, speculative_k=2)
+    for budget in (0, None):
+        with pytest.raises(ValueError, match="prefill_token_budget"):
+            _unified(cfg, params, prefill_token_budget=budget)
     with pytest.raises(ValueError, match="draft_params"):
         _unified(cfg, params, speculative_k=2)
     with pytest.raises(ValueError, match="speculative_k"):
         _unified(cfg, params, draft_params=params)  # a draft that never proposes
-    eng = _engine(cfg, params)
-    with pytest.raises(ValueError, match="temperature"):
-        eng.add_request(np.arange(1, 5, dtype=np.int32),
-                        max_new_tokens=4, temperature=0.5)
     with pytest.raises(ValueError):
         self_draft_params(cfg, params, cfg.num_hidden_layers + 1)
 
@@ -631,9 +535,8 @@ def test_unified_teardown_catches_leaks(tiny_model):
 def test_unified_int8_kv_cache_close_to_bf16(tiny_model):
     """Tier-2 (round-16 re-tier: unified int8-KV tolerance leg; tier-1 home: the EXACT int8 parity gates in tests/test_serving_disagg.py).
 
-    int8 KV cache on the UNIFIED plane (the PR-6 follow-up): the
-    first admission runs the calibration pass the legacy chunked path
-    already had (absmax per (layer, kv head), 2x headroom, frozen), the
+    int8 KV cache: the first submission runs the calibration pass
+    (absmax per (layer, kv head), 2x headroom, frozen), the
     ragged step quantizes every scattered K/V row with those scales,
     and the greedy streams must mostly agree with the fp-cache engine
     (parity under tolerance — int8 may flip rare near-ties)."""

@@ -396,14 +396,15 @@ def test_multi_prefill_int8_shares_one_calibration(tiny_model):
 
 
 def test_prefill_only_engine_guards(tiny_model):
-    """Constructor/adopt contracts: prefill_only needs the unified
-    engine and excludes speculation; the host tier needs the prefix
-    cache; adopt refuses prefill-only engines and mismatched pools."""
+    """Constructor/adopt contracts: prefill_only excludes speculation;
+    the host tier needs the prefix cache; adopt refuses prefill-only
+    engines and mismatched pools."""
     cfg, model, params = tiny_model
     jparams = {k: jnp.asarray(v) for k, v in params.items()}
     kw = dict(max_slots=2, num_pages=17, page_size=16, max_seq_len=64)
     with pytest.raises(ValueError, match="prefill_only"):
-        ContinuousBatchingEngine(cfg, jparams, prefill_only=True, **kw)
+        ContinuousBatchingEngine(cfg, jparams, prefill_only=True,
+                                 speculative_k=2, draft_params=jparams, **kw)
     with pytest.raises(ValueError, match="host_tier"):
         ContinuousBatchingEngine(cfg, jparams, prefill_token_budget=16,
                                  host_tier_pages=2, **kw)

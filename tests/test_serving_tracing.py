@@ -251,7 +251,6 @@ def test_the_walk_reads_what_the_packing_counts(tiny):
     rows = packed["rows"]
     tile = ragged_tile_rows(cfg.num_attention_heads,
                             cfg.num_key_value_heads, cfg.head_dim)
-    assert eng.attn_tile_rows == tile
     count, reach = ragged_units(rows[:, 4], rows[:, 3], tile, np)
     assert count[count > 0].tolist() == [1, 1, 20]
     assert reach[count > 0].tolist() == [6, 10, 20]
@@ -294,10 +293,6 @@ def test_no_span_is_recorded_without_a_trace_and_stats_are_always_on(tiny):
     assert steps["steps"] > 0 and steps["admitted"] == len(PROMPT_LENS)
     assert 0 < steps["rows"] < steps["rows_cap"]
     assert steps["queue_wait_s"]["max"] <= steps["queue_wait_s"]["sum"]
-    # the legacy chunked engine has no unified step to count
-    legacy = ContinuousBatchingEngine(*tiny, max_slots=2, num_pages=33,
-                                      page_size=16, max_seq_len=128)
-    assert "steps" not in legacy.serving_stats()
 
 
 def test_profiler_records_the_engines_spans_with_their_nesting(tiny, tmp_path):

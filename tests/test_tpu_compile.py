@@ -134,7 +134,9 @@ def test_ragged_paged_decode_compiles(one_chip, d, cache_dtype):
 
 @pytest.mark.parametrize("d", HEAD_DIMS)
 def test_paged_decode_compiles(one_chip, d):
-    """The legacy chunked serving path's kernel (one query row a slot)."""
+    """One query row a sequence: the registered op's and the incubate
+    API's kernel, and the ragged wrapper's path for head dims that are
+    not whole lanes."""
     from paddle_tpu.ops.pallas.decode_attention import paged_decode_raw
 
     slots, pages, page = 8, 129, 128
@@ -208,6 +210,8 @@ def test_ragged_kernels_grid_is_the_query_tiles_alone():
     inside the kernel, so a longer page table (``pages_per_seq`` 8 and
     32 here, turns of 4 pages) adds no grid step.  The parent's grid was
     rows x page groups: 288 x 6 in the serving cell."""
+    from paddle_tpu.ops.pallas.decode_attention import ragged_tile_rows
+
     grids = []
     for seq in (64, 256):
         eng, calls = _ragged_calls(seq)
@@ -215,14 +219,15 @@ def test_ragged_kernels_grid_is_the_query_tiles_alone():
         assert len(calls) == eng.cfg.num_hidden_layers == 2
         (grid,) = {c.params["grid_mapping"].grid for c in calls}
         grids.append(grid)
-    tiles = -(-eng.rows_cap // eng.attn_tile_rows)
+    cfg = eng.cfg
+    tiles = -(-eng.rows_cap // ragged_tile_rows(
+        cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim))
     assert grids == [(tiles,), (tiles,)] and tiles == 2
 
 
-def _serving_step_text(one_chip, monkeypatch, cache_dtype, unified):
+def _serving_step_text(one_chip, monkeypatch, cache_dtype):
     """Optimized HLO of the engine's step program (the unified ragged
-    step, or the legacy decode chunk) for the described chip, 2 layers
-    at the cell's KV geometry.  The engine is built small (24 pages, on
+    step) for the described chip, 2 layers at the cell's KV geometry.  The engine is built small (24 pages, on
     the CPU) and gives the call through ``analysis_entry()``; the shapes
     it is lowered with carry the cell's 705 pages."""
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
@@ -243,23 +248,16 @@ def _serving_step_text(one_chip, monkeypatch, cache_dtype, unified):
     eng = ContinuousBatchingEngine(
         cfg, params, max_slots=_CELL_SLOTS, num_pages=small,
         page_size=_CELL_PAGE, max_seq_len=_CELL_SEQ, pages_per_step=4,
-        prefill_token_budget=_CELL_BUDGET if unified else 0,
-        cache_dtype=cache_dtype)
-    if cache_dtype == jnp.int8 and not unified:
-        # the legacy path calibrates at its first prefill
-        ones = jnp.ones((layers, KV_HEADS), jnp.float32)
-        eng.kv_scales = {"kq": ones, "kdq": ones, "vq": ones, "vdq": ones}
+        prefill_token_budget=_CELL_BUDGET, cache_dtype=cache_dtype)
     fn, args, kwargs, _ = eng.analysis_entry()
-    if unified:
-        assert args[3].shape == (_CELL_SLOTS + _CELL_BUDGET, 5)
+    assert args[3].shape == (_CELL_SLOTS + _CELL_BUDGET, 5)
     pool = (_CELL_PAGES, KV_HEADS, _CELL_PAGE, 128)
 
     def described(x):
         shape = pool if x.shape == (small,) + pool[1:] else x.shape
         return jax.ShapeDtypeStruct(shape, x.dtype, sharding=one_chip)
 
-    static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step",
-                                         "chunk") if k in kwargs}
+    static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step")}
     text = fn.lower(*jax.tree.map(described, args), **static,
                     **jax.tree.map(described, kwargs)).compile().as_text()
     assert "tpu_custom_call" in text
@@ -268,10 +266,7 @@ def _serving_step_text(one_chip, monkeypatch, cache_dtype, unified):
 
 @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
-@pytest.mark.parametrize("unified", [True, False],
-                         ids=["unified_step", "decode_chunk"])
-def test_serving_step_writes_kv_in_place(one_chip, monkeypatch, unified,
-                                         cache_dtype):
+def test_serving_step_writes_kv_in_place(one_chip, monkeypatch, cache_dtype):
     """The step's K/V write touches the rows it writes: no instruction
     of the compiled step copies or transposes a whole pool (the old
     write, ``pool.at[phys, :, off, :].set``, had XLA move each pool into
@@ -279,7 +274,7 @@ def test_serving_step_writes_kv_in_place(one_chip, monkeypatch, unified,
     a step in the serving cell, PERF.md section 6, PR 25), and every
     pool is updated in the buffer it came in."""
     text, pool, npools = _serving_step_text(one_chip, monkeypatch,
-                                            cache_dtype, unified)
+                                            cache_dtype)
     count = int(np.prod(pool))
     moved = []
     for ln in text.splitlines():
