@@ -10,28 +10,50 @@ with its ``seq_lens_encoder`` / ``seq_lens_decoder`` /
 ``seq_lens_this_time`` triplet, which ``last_report`` mirrors.
 
 The host owns what is cheap and branchy: slots, page tables, the
-refcounted allocator, the radix prefix cache, admission, sampling,
-speculative accept/reject, eviction.  The device runs ONE program of
-static shape a step, the model's ``PagedLayout.step`` (the Llama
-family's is ``_unified_step_jit``, DeepSeek-V3.2's its own under the
-same signature): a packed batch of ``rows_cap`` token rows from many
+refcounted allocator, the radix prefix cache, admission, temperature
+sampling, speculative accept/reject, eviction.  The device runs ONE
+program of static shape a step, the model's ``PagedLayout.step`` (the
+Llama family's is ``_unified_step_jit``, DeepSeek-V3.2's its own under
+the same signature): a packed batch of ``rows_cap`` token rows from many
 sequences through one forward, with attention served by a ragged paged
-kernel whose cost follows the live rows.  A row is a decode slot's
-token, one of the k+1 tokens of a speculative verify window, or one
-prompt token of a prefill chunk; at most ``prefill_token_budget`` prompt
-tokens ride a step, so a decode slot emits a token EVERY step whatever
-prompt is prefilled beside it.  Padding rows are the price of the static
-shape: they compute garbage that is never read and write it to the
-TRASH page, the last physical page, which no slot owns.
+kernel whose cost follows the live rows, and the greedy token of every
+consumed row sampled at its end.  A row is a decode slot's token, one
+of the k+1 tokens of a speculative verify window, or one prompt token
+of a prefill chunk; at most ``prefill_token_budget`` prompt tokens ride
+a step, so a decode slot emits a token EVERY step whatever prompt is
+prefilled beside it.  Padding rows are the price of the static shape:
+they compute garbage that is never read and write it to the TRASH page,
+the last physical page, which no slot owns.
 
-``engine.step()`` is: admit (prefix-cache hits map shared full pages
-copy-on-write and skip their prefill), propose (draft model only), pack,
-launch, fetch, commit.
+``engine.step()`` runs ONE STEP AHEAD of what it has read
+(``_step_unified``).  With launch n enqueued by the call before, a call
+is: admit (prefix-cache hits map shared full pages copy-on-write and
+skip their prefill), pack launch n+1 from the SCHEDULED state
+(``_schedule``: launch n counted as done), launch it, and only then
+fetch launch n's tokens and commit them.  The one thing launch n+1
+needs from launch n that the host does not know when it packs is one
+token a decode row: that input is a REFERENCE into the tokens launch n
+sampled, which never leave the device on their way
+(``resolve_row_tokens``).  The device so always has a launch queued,
+and a token reaches the host when its own device step ends.  What is
+scheduled (a slot's position and budget as the packing sees them)
+advances at launch; what is committed (``out_tokens``, ``cur_tok``,
+``finished``, pages and slots freed, the prefix cache's insert, the
+handoff record) changes at commit.  A slot that ends on ``eos_id``, or
+is canceled, with a row enqueued runs that row STALE: it writes inside
+the slot's own pages, which are freed after it was enqueued and so
+cannot be reused under it (a device runs its launches in order), and
+its token is dropped.  Where the host has to see a step before it can
+pack the next (a request with a temperature draws from its own seeded
+numpy stream; a draft model's window is accepted or rejected on the
+host: ``_host_samples``) the call reads and commits the launch it just
+made: the engine decides that from what it is serving, a step at a
+time, and nothing selects it from outside.
 
 - Everything the host tells the device rides in ONE int32 upload a
-  step: ``rows`` ``[rows_cap, 5]`` = (input token, physical page its
-  K/V is written to, in-page offset, causal visibility, slot), beside
-  the page tables.
+  step: ``rows`` ``[rows_cap, 5]`` = (input token or reference, physical
+  page its K/V is written to, in-page offset, causal visibility, slot),
+  beside the page tables.
 - The page pools are PER-LAYER arrays, donated through the step, so a
   layer's cache update is one scatter into its own pool; a fused
   ``[L, pages, ...]`` slab cost a slice and a whole-layer update a
@@ -41,9 +63,11 @@ launch, fetch, commit.
   PR 25).
 - The CONSUMED rows alone (every verify-window row and each prefill
   chunk's final row) are gathered on the device before the final norm
-  and the vocabulary projection: the head matmul, the fp32 logits and
-  the copy back to the host are sized to ``gather_cap``, not
-  ``rows_cap``.  Sampling is on the host, from those logits.
+  and the vocabulary projection: the head matmul and the fp32 logits
+  are sized to ``gather_cap``, not ``rows_cap``.  Their first maxima
+  (``sample_greedy``) are what a step copies back, 4 bytes a row; the
+  logits themselves cross only for a request with a temperature, or
+  when ``last_logits`` is read.
 
 Weight-only int8 params (models/generation.quantize_params_int8) run
 through the same program: dequant fuses into the consumer dots.  An
@@ -61,10 +85,13 @@ Measurement: every phase of ``_step_unified`` is a
 ``profiler.RecordEvent`` (``serving.step`` > ``serving.admit``,
 ``serving.propose``, ``serving.pack``, ``serving.launch``,
 ``serving.fetch_logits``, ``serving.commit``), so a profiler trace that
-runs, whoever started it, holds them on the device's clock; one marker
-a step (``serving.step_counts``) and one per request at admission and
-at its first token (``serving.admit_request``, ``serving.first_token``)
-carry the counts.  The same counts are summed in
+runs, whoever started it, holds them on the device's clock
+(``serving.fetch_logits`` keeps its name: it is where the host waits for
+the device and copies back what it needs, the tokens); one marker a
+call (``serving.step_counts``: the counts of the launch the call
+COMMITS, with ``ahead`` and ``stale_rows``) and one per request at
+admission and at its first token (``serving.admit_request``,
+``serving.first_token``) carry the counts.  The same counts are summed in
 ``serving_stats()["steps"]`` whether or not anything traces.
 """
 
@@ -107,6 +134,23 @@ class Finished:
     prompt_len: int
 
 
+@dataclasses.dataclass
+class _Launch:
+    """One enqueued step, as the host needs it to commit the step when
+    its tokens come back: ``metas`` ``(kind, slot, first gathered row,
+    rows)`` a scheduled slot, ``gathered`` ``(rid, position)`` a
+    gathered row, the step's ``counts`` for ``serving.step_counts``,
+    ``enc`` / ``dec`` for ``last_report``, the draft's ``props`` and
+    ``out``, the program's third result, still on the device."""
+    metas: List[tuple]
+    gathered: List[tuple]
+    counts: Dict[str, int]
+    enc: np.ndarray
+    dec: np.ndarray
+    props: Dict[int, tuple]
+    out: Any = None
+
+
 def _softmax_np(logits: np.ndarray, temperature: float) -> np.ndarray:
     """Host-side fp64 softmax over one row of returned logits —
     deterministic (no device reduction-order variance), so a warm
@@ -139,6 +183,25 @@ def _write_kv_rows(pool, phys, off, x):
         x.astype(pool.dtype))
 
 
+def resolve_row_tokens(tok, prev_tokens):
+    """The packed rows' input tokens, references resolved.  ``tok >= 0``
+    is a token the host knew when it packed the step; ``tok < 0`` names
+    entry ``-1 - tok`` of ``prev_tokens``, what the launch before this
+    one sampled (``sample_greedy`` of its gathered rows): the host
+    enqueued this launch before it had read that token.  Every model's
+    step calls this before its embedding."""
+    ref = jnp.take(prev_tokens, jnp.maximum(-1 - tok, 0), mode="clip")
+    return jnp.where(tok < 0, ref, tok)
+
+
+def sample_greedy(logits):
+    """int32 ``[G]``: the first maximum of each row of fp32 logits
+    ``[G, vocab]``, which is what ``np.argmax`` of the same row gives
+    the host.  It stays on the device for the next launch's
+    ``resolve_row_tokens`` and is all a greedy step copies back."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 @dataclasses.dataclass(frozen=True)
 class PagedLayout:
     """What a model tells the engine of its paged state and of its part
@@ -152,9 +215,10 @@ class PagedLayout:
     d]``); a model with ``paged_layout()`` on its config brings its own
     (DeepSeek-V3.2: a latent row ``[640]`` and an index key ``[128]``,
     ``[pages, page, n]``).  ``step`` is the model's part of the engine
-    step, a jitted function under ``_unified_step_jit``'s signature; if
-    ``device_counts`` names counts it takes on the device, its third
-    result is ``(logits, those counts)``.  ``row_counts(rows,
+    step, a jitted function under ``_unified_step_jit``'s signature
+    whose third result is ``(logits, tokens)``; if ``device_counts``
+    names counts it takes on the device, ``(logits, tokens, those
+    counts)``.  ``row_counts(rows,
     ctx_tokens, page_size, pages_per_seq)`` gives the step's counts the
     packed rows determine.  Both kinds ride on ``serving.step_counts``
     and are summed in ``serving_stats()["steps"]`` under
@@ -750,10 +814,11 @@ class ContinuousBatchingEngine:
         # decoder = cached tokens of decoding slots, this_time = tokens
         # processed this step)
         self.last_report: Dict[str, np.ndarray] = {}
-        # the step's returned logits, for checks against a reference:
-        # ([(rid, absolute position of the input token), ...], fp32
-        # [len(rows), vocab]) of the newest launch
-        self.last_logits: Optional[tuple] = None
+        # the launch that is enqueued and not yet read (``_step_unified``
+        # runs one step ahead), and the newest COMMITTED launch's
+        # gathered rows and logits, still on the device (``last_logits``)
+        self._flight: Optional[_Launch] = None
+        self._last_logits: Optional[tuple] = None
 
         self.spec_k = int(speculative_k)
         if self.spec_k and draft_params is None:
@@ -804,6 +869,10 @@ class ContinuousBatchingEngine:
         # intermediate rows never reach the host)
         self.gather_cap = self.max_slots * (1 + self.spec_k) \
             + self.max_slots
+        # what the first launch resolves token references against (it
+        # holds none): the same shape as a launch's sampled tokens, so
+        # the step is one compiled program
+        self._no_tokens = jnp.zeros(self.gather_cap, jnp.int32)
         # runtime degradation floors: throttle() may shed work but
         # never grow past the constructor's static shapes
         self._init_spec_k = self.spec_k
@@ -820,8 +889,9 @@ class ContinuousBatchingEngine:
         # as the spans' arguments carry them
         self.step_totals: Dict[str, int] = dict.fromkeys(
             ("steps", "rows", "rows_cap", "decode_rows", "prefill_rows",
-             "admitted", "queue_wait_us", "queue_wait_us_max",
-             "prefill_us", "prefill_us_max", *self.layout.count_names), 0)
+             "ahead", "stale_rows", "admitted", "queue_wait_us",
+             "queue_wait_us_max", "prefill_us", "prefill_us_max",
+             *self.layout.count_names), 0)
         # spec telemetry: one entry per verify window, bounded so a
         # long-running server doesn't grow it without limit
         self.accepted_lengths: Deque[int] = deque(maxlen=65536)
@@ -946,7 +1016,8 @@ class ContinuousBatchingEngine:
              donate_argnums=(1, 2))
     def _unified_step_jit(params, k_pages, v_pages, rows, tables,
                           cos_tab, sin_tab, self_cfg_id, pages_per_step,
-                          kv_scales=None, with_head=True, gather=None):
+                          kv_scales=None, with_head=True, gather=None,
+                          prev_tokens=None):
         """ONE ragged engine step: a packed batch of tokens from many
         sequences — decode slots (one row each), prefill chunks (one row
         per prompt token) and speculative verify windows (k+1 rows) —
@@ -962,10 +1033,16 @@ class ContinuousBatchingEngine:
         position + 1, page-table row / slot).  Padding rows carry
         slot -1 / visibility 0 and scatter into the trash page.
         ``tables`` [slots, pages_per_seq] feeds the kernel's
-        scalar-prefetch index maps.  Returns the updated (donated) page
-        pools and fp32 logits for EVERY row — sampling is host-side
-        (greedy argmax, temperature, and speculative accept/reject all
-        read the same array)."""
+        scalar-prefetch index maps.  An input token below zero is a
+        reference into ``prev_tokens``, the int32 ``[gather_cap]`` that
+        the launch before this one sampled (``resolve_row_tokens``): the
+        engine enqueues a greedy step before it has read the step
+        before.  Returns the updated (donated) page pools and
+        ``(logits, tokens)``: fp32 logits of the gathered rows and
+        their first maxima (``sample_greedy``).  The tokens are all a
+        greedy step copies back; the logits stay on the device unless
+        the host has to draw from them (a request with a temperature)
+        or something asks for ``last_logits``."""
         from ..models.generation import (_CFGS, _Weights, _apply_rope,
                                          _ffn, _rms_norm)
         from ..ops.pallas.decode_attention import ragged_paged_decode_raw
@@ -977,6 +1054,8 @@ class ContinuousBatchingEngine:
                      cfg.head_dim)
         T = rows.shape[0]
         tok = rows[:, 0]
+        if prev_tokens is not None:
+            tok = resolve_row_tokens(tok, prev_tokens)
         phys = rows[:, 1]
         off = rows[:, 2]
         lens = rows[:, 3]
@@ -1054,7 +1133,7 @@ class ContinuousBatchingEngine:
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
             logits = w.head(x).astype(jnp.float32)    # [G, vocab]
-        return tuple(new_k), tuple(new_v), logits
+        return tuple(new_k), tuple(new_v), (logits, sample_greedy(logits))
 
     # ---------------- host scheduler ----------------
 
@@ -1096,6 +1175,16 @@ class ContinuousBatchingEngine:
         tail of normal completion (``_finish``) and withdrawal
         (``cancel``)."""
         self.alloc.release(self.slot_pages.pop(slot))
+        if self._flight is not None:
+            # rows already enqueued for this slot are STALE: they run
+            # (the device executes launches in order, so they write this
+            # slot's pages before any later launch can reuse them) and
+            # what they sample is never committed
+            for m in self._flight.metas:
+                if m[1] == slot:        # a slot has one entry a launch
+                    self._flight.counts["stale_rows"] += m[3]
+                    self._flight.metas.remove(m)
+                    break
         self.active[slot] = False
         self.tables[slot] = -1
         self.seq_lens[slot] = 0
@@ -1430,11 +1519,10 @@ class ContinuousBatchingEngine:
         return admitted
 
     def _sample_row(self, logits_row: np.ndarray, req: Request) -> int:
-        """Sample the next token from one returned logits row: greedy
-        argmax (first maximum, as a device argmax breaks ties) or
-        temperature sampling from the request's seeded stream."""
-        if req.temperature <= 0:
-            return int(np.argmax(logits_row))
+        """Draw the next token of a request with a temperature from one
+        returned logits row and the request's seeded stream.  (A greedy
+        row's token is the device's ``sample_greedy``: the host never
+        sees its logits.)"""
         p = _softmax_np(logits_row, req.temperature)
         return int(req.rng.choice(len(p), p=p))
 
@@ -1446,12 +1534,12 @@ class ContinuousBatchingEngine:
         (no vocab projection, no logits buffer) and skips the
         device-to-host copy — the mirror only needs the K/V scatter."""
         d = self.draft
-        d["k_pages"], d["v_pages"], logits = d["step"](
+        d["k_pages"], d["v_pages"], out = d["step"](
             d["params"], d["k_pages"], d["v_pages"],
             jnp.asarray(rows_np), jnp.asarray(self.tables),
             d["cos_tab"], d["sin_tab"], self_cfg_id=d["cfg_id"],
             pages_per_step=self.pages_per_step, with_head=need_logits)
-        return np.asarray(logits) if need_logits else None
+        return np.asarray(out[0]) if need_logits else None
 
     def _propose(self, decoding: List[int]) -> Dict[int, tuple]:
         """Draft-model proposals: up to ``spec_k`` tokens per decoding
@@ -1497,12 +1585,14 @@ class ContinuousBatchingEngine:
         return props
 
     def _commit_window(self, slot: int, start: int, n: int,
-                       logits: np.ndarray, prop) -> List[int]:
+                       tokens: np.ndarray, logits: Optional[np.ndarray],
+                       prop) -> List[int]:
         """Accept/reject one slot's verify window (rows ``start`` ..
         ``start+n-1``; window inputs were [cur_tok, d_1..d_{n-1}]) and
         commit the emitted tokens.  Greedy targets use exact
-        prefix-match acceptance; temperature>0 uses standard rejection
-        sampling (accept d with prob min(1, p(d)/q(d)), resample the
+        prefix-match acceptance against the device's ``tokens``;
+        temperature>0 uses standard rejection sampling over the
+        ``logits`` (accept d with prob min(1, p(d)/q(d)), resample the
         first rejection from max(p-q, 0)).  n == 1 (no draft tokens)
         degenerates to plain decode.  Returns the emitted tokens."""
         req = self.req_info[slot]
@@ -1512,12 +1602,12 @@ class ContinuousBatchingEngine:
         emitted: List[int] = []
         if req.temperature <= 0:
             for j in range(n - 1):
-                t = int(np.argmax(logits[start + j]))
+                t = int(tokens[start + j])
                 emitted.append(t)
                 if drafts[j] != t:
                     break
             else:
-                emitted.append(int(np.argmax(logits[start + n - 1])))
+                emitted.append(int(tokens[start + n - 1]))
         else:
             rng = req.rng
             for j in range(n - 1):
@@ -1556,54 +1646,124 @@ class ContinuousBatchingEngine:
             self._finish(slot)
         return take
 
-    def _step_unified(self) -> int:
-        """One unified engine step: admit, propose (draft), pack ONE
-        ragged row schedule — a decode/verify window per decoding slot
-        plus up to ``prefill_token_budget`` prompt tokens — launch the
-        target once, sample host-side, commit.  Decode slots emit at
-        least one token EVERY step regardless of any co-scheduled
-        prompt's length: that is the latency contract chunked prefill
-        exists for.
+    def _host_samples(self) -> bool:
+        """Whether the host has to see the next launch's results before
+        it can pack the one after it: a draft model's verify windows
+        (how many tokens a window emits is decided by accept/reject on
+        the host) or a scheduled request with a temperature (it draws
+        from its own seeded numpy stream, which migrates with a
+        handoff).  Such a step is read and committed in the call that
+        launches it; every other step runs one step ahead."""
+        return self.draft is not None or any(
+            req.temperature > 0 for s, req in self.req_info.items()
+            if s not in self.handoff_ready)
 
-        Each phase is a span under ``serving.step``; a step's counts
-        are known only when its work is done, so they ride on ONE
-        marker at its end (an annotation takes its arguments when it
-        opens) and the phase spans carry none."""
+    def _schedule(self):
+        """What the next launch may carry, with the launch in flight (if
+        there is one) counted as done, because the device runs launches
+        in order: ``(decode, prefill)``.  ``decode`` holds ``(slot,
+        position, input token)`` a decoding slot; the token is
+        ``cur_tok`` where the host has read it, and else ``-1 - g``, a
+        reference to gathered row ``g`` of the launch in flight (a
+        decode row there, or the final row of the slot's prompt).  A
+        slot whose budget the launch in flight exhausts is left out;
+        one that will end on ``eos_id`` is not known yet and rides one
+        stale row.  ``prefill`` holds ``(slot, position, prompt tokens
+        not launched yet)`` in admission order."""
+        flying = {}
+        if self._flight is not None:
+            for kind, s, g, n in self._flight.metas:
+                last = kind == "verify" or n == len(self.pending_prompt[s])
+                flying[s] = (n, g if last else None)
+        decode, prefill = [], []
+        for s in range(self.max_slots):
+            if not self.active[s] or s in self.handoff_ready:
+                continue
+            n, ref = flying.get(s, (0, None))
+            if s in self.pending_prompt and (ref is None
+                                             or self.prefill_only):
+                continue        # prefilling still, or parks at commit
+            if self.budget[s] - (ref is not None) <= 0:
+                continue        # the launch in flight emits its last
+            decode.append((s, int(self.seq_lens[s]) + n,
+                           int(self.cur_tok[s]) if ref is None
+                           else -1 - ref))
+        for s in self.prefill_order:
+            n, ref = flying.get(s, (0, None))
+            if ref is None:
+                prefill.append((s, int(self.seq_lens[s]) + n,
+                                self.pending_prompt[s][n:]))
+        return decode, prefill
+
+    def _step_unified(self) -> int:
+        """One engine step, one step AHEAD of the device's results.
+        With launch n enqueued by the call before: admit, pack launch
+        n+1 from the SCHEDULED state (``_schedule``), launch it, and
+        only then fetch launch n's sampled tokens (the host blocks while
+        the device runs n, with n+1 queued behind it) and commit n.  A
+        token so reaches the host as soon as its own device step ends,
+        the device never waits for the host between two steps, and a
+        request sent between two calls is admitted by the next one.
+        Slots, pages, ``out_tokens``, ``finished`` and the prefix
+        cache change at COMMIT; a slot's position and budget as the
+        packing sees them advance at LAUNCH.
+
+        With nothing in flight (the first call, after an idle spell)
+        the call enqueues two launches and commits the first.  Where
+        the host must see a launch's results before the next can be
+        packed (``_host_samples``) it enqueues one at most and commits
+        it in the same call: the order of work before PR 29.  Decode
+        slots emit at least one token EVERY call regardless of any
+        co-scheduled prompt's length: that is the latency contract
+        chunked prefill exists for.
+
+        Each phase is a span under ``serving.step``; the call's ONE
+        ``serving.step_counts`` marker carries the counts of the launch
+        the call COMMITS, whose device work ran while the host was
+        inside this call (an annotation takes its arguments when it
+        opens, so the phase spans carry none)."""
         tot = self.step_totals
         tot["steps"] += 1
         with RecordEvent("serving.step", step=tot["steps"]):
             with RecordEvent("serving.admit"):
                 admitted = self._admit_unified()
-            decoding = [s for s in range(self.max_slots)
-                        if self.active[s] and s not in self.pending_prompt
-                        and s not in self.handoff_ready]
-            props = {}
-            if self.draft is not None and self.spec_k > 0 and decoding:
-                with RecordEvent("serving.propose"):
-                    props = self._propose(decoding)
-            with RecordEvent("serving.pack"):
-                rows, gather, gathered, metas, enc, counts = \
-                    self._pack_unified(decoding, props)
-            this_dec = np.zeros(self.max_slots, np.int32)
-            dec = np.zeros(self.max_slots, np.int32)
-            n_finished = len(self.finished)
-            produced = 0
-            if counts["rows"]:
-                dec = np.where(self.active, self.seq_lens, 0).astype(np.int32)
+            deep = 1 if self._host_samples() else 2
+            queued = [] if self._flight is None else [self._flight]
+            idle = None
+            while len(queued) < deep:
+                self._flight = queued[-1] if queued else None
+                decode, prefill = self._schedule()
+                props = {}
+                if self.draft is not None and self.spec_k > 0 and decode:
+                    with RecordEvent("serving.propose"):
+                        props = self._propose([s for s, _, _ in decode])
+                with RecordEvent("serving.pack"):
+                    rows, gather, new = self._pack_unified(decode, prefill,
+                                                           props)
+                if not new.counts["rows"]:
+                    idle = new
+                    break
+                # 1: enqueued before the launch before it was read
+                new.counts["ahead"] = len(queued)
                 with RecordEvent("serving.launch"):
                     # called from HERE, not from a helper: JAX writes the
                     # Python call stack into every operation's location,
                     # and one more frame under the first call cost 0.9 s
-                    # of lowering at 16 layers (PERF.md, PR 24)
-                    self.k_pages, self.v_pages, logits = \
+                    # of lowering at 16 layers (PERF.md, PR 24).  The
+                    # tables are COPIED: a commit changes them while
+                    # this launch may not have taken them yet
+                    self.k_pages, self.v_pages, new.out = \
                         self.layout.step(
                             self.params, self.k_pages, self.v_pages,
-                            jnp.asarray(rows), jnp.asarray(self.tables),
+                            jnp.asarray(rows),
+                            jnp.asarray(self.tables.copy()),
                             self.cos_tab, self.sin_tab,
                             self_cfg_id=self.cfg_id,
                             pages_per_step=self.pages_per_step,
                             kv_scales=self.kv_scales,
-                            gather=jnp.asarray(gather))
+                            gather=jnp.asarray(gather),
+                            prev_tokens=(queued[-1].out[1] if queued
+                                         else self._no_tokens))
                     if self.draft is not None:
                         # mirror the SAME rows through the draft: its
                         # paged cache tracks the target's committed
@@ -1612,24 +1772,41 @@ class ContinuousBatchingEngine:
                         # positions land above the rolled-back length,
                         # exactly like the target's own window writes
                         self._draft_launch(rows, need_logits=False)
+                queued.append(new)
+            cur = queued[0] if queued else None
+            self._flight = queued[1] if len(queued) > 1 else None
+            this_dec = np.zeros(self.max_slots, np.int32)
+            n_finished = len(self.finished)
+            produced = 0
+            if cur is not None:
                 with RecordEvent("serving.fetch_logits"):
-                    # the host blocks here until the device has run the
-                    # step, then copies the gathered rows back
+                    # the host blocks here until the device has run
+                    # launch ``cur``, then copies its tokens back (the
+                    # span keeps its name: the benchmark reads it); the
+                    # logits only if a slot of it draws from them
+                    tokens = np.asarray(cur.out[1])
                     if self.layout.device_counts:
-                        logits, dev = logits
-                        counts.update(zip(self.layout.device_counts,
-                                          (int(v) for v in np.asarray(dev))))
-                    logits = np.asarray(logits)
-                self.last_logits = (gathered, logits[:len(gathered)])
+                        cur.counts.update(zip(
+                            self.layout.device_counts,
+                            (int(v) for v in np.asarray(cur.out[2]))))
+                    logits = None
+                    if any(self.req_info[m[1]].temperature > 0
+                           for m in cur.metas):
+                        logits = np.asarray(cur.out[0])
+                self._last_logits = (cur.gathered, cur.out[0])
                 with RecordEvent("serving.commit"):
-                    produced = self._commit_unified(metas, logits, props,
+                    produced = self._commit_unified(cur, tokens, logits,
                                                     this_dec)
+            else:
+                cur = idle
             self.last_report = {
-                "seq_lens_encoder": enc,
-                "seq_lens_decoder": dec,
-                "seq_lens_this_time": enc + this_dec,
+                "seq_lens_encoder": cur.enc,
+                "seq_lens_decoder": cur.dec,
+                "seq_lens_this_time": cur.enc + this_dec,
             }
-            for k in ("rows", "rows_cap", "decode_rows", "prefill_rows"):
+            counts = cur.counts
+            for k in ("rows", "rows_cap", "decode_rows", "prefill_rows",
+                      "ahead", "stale_rows"):
                 tot[k] += counts[k]
             for k in self.layout.count_names:
                 if k.endswith("_max"):
@@ -1647,27 +1824,27 @@ class ContinuousBatchingEngine:
                 pass
         return produced
 
-    def _pack_unified(self, decoding: List[int], props: Dict[int, tuple]):
-        """The step's packed row schedule (``_unified_step_jit``'s
-        ``rows`` and ``gather``), what each gathered row is, the
-        commit loop's ``metas``, the prompt tokens scheduled by slot,
-        and the step's counts for ``serving.step_counts``."""
+    def _pack_unified(self, decode, prefill, props: Dict[int, tuple]):
+        """The packed row schedule of one launch (``_unified_step_jit``'s
+        ``rows`` and ``gather``) for what ``_schedule`` found, and the
+        ``_Launch`` that commits it: what each gathered row is, the
+        commit loop's ``metas``, the prompt tokens scheduled by slot and
+        the step's counts for ``serving.step_counts``."""
         enc = np.zeros(self.max_slots, np.int32)
+        dec = np.zeros(self.max_slots, np.int32)
         rows = np.zeros((self.rows_cap, 5), np.int32)
         rows[:, 1] = self.trash_page
         rows[:, 4] = -1
         # consumed-row gather schedule: metas carry GATHERED offsets, so
-        # the commit loop indexes the gathered logits directly
+        # the commit loop indexes the gathered tokens directly
         gather = np.zeros(self.gather_cap, np.int32)
         gathered = []                 # (rid, position) per gathered row
         g = 0
         r = 0
         kv_ctx = 0      # context each scheduled slot attends to, once each
         metas = []
-        for s in decoding:
-            base = int(self.seq_lens[s])
-            window = [int(self.cur_tok[s])] \
-                + list(props.get(s, ([], []))[0])
+        for s, base, tok in decode:
+            window = [tok] + list(props.get(s, ([], []))[0])
             gstart = g
             for j, t in enumerate(window):
                 p = base + j
@@ -1677,16 +1854,15 @@ class ContinuousBatchingEngine:
                 gathered.append((int(self.slot_rid[s]), p))
                 g += 1
                 r += 1
+            dec[s] = base
             kv_ctx += base + len(window)
             metas.append(("verify", s, gstart, len(window)))
         decode_rows = r
         left = self.prefill_budget
-        for s in list(self.prefill_order):
+        for s, base, pend in prefill:
             if left <= 0:
                 break
-            pend = self.pending_prompt[s]
             chunk = min(len(pend), left)
-            base = int(self.seq_lens[s])
             for j in range(chunk):
                 p = base + j
                 rows[r] = (int(pend[j]), self._phys(s, p),
@@ -1694,6 +1870,7 @@ class ContinuousBatchingEngine:
                 r += 1
             left -= chunk
             enc[s] = chunk
+            dec[s] = base
             kv_ctx += base + chunk
             # only the chunk's FINAL row can seed generation — it is
             # the one prefill row the gather hands to the host
@@ -1705,6 +1882,9 @@ class ContinuousBatchingEngine:
             "rows": r, "rows_cap": self.rows_cap,
             "decode_rows": decode_rows, "prefill_rows": r - decode_rows,
             "slots": len(metas), "gathered": g,
+            # whether the launch was enqueued before the one before it
+            # was read, and its rows whose slot had ended by then
+            "ahead": 0, "stale_rows": 0,
             # sum of the rows' visibilities: the attention's arithmetic
             "attn_row_ctx": int(rows[:r, 3].sum()),
             # the K/V the step has to read at least: its bytes
@@ -1712,19 +1892,22 @@ class ContinuousBatchingEngine:
         }
         counts.update(self.layout.row_counts(
             rows[:r], kv_ctx, self.page_size, self.pages_per_seq))
-        return rows, gather, gathered, metas, enc, counts
+        return rows, gather, _Launch(metas, gathered, counts, enc, dec,
+                                     props)
 
-    def _commit_unified(self, metas, logits: np.ndarray, props,
+    def _commit_unified(self, launch: _Launch, tokens: np.ndarray,
+                        logits: Optional[np.ndarray],
                         this_dec: np.ndarray) -> int:
-        """Sample and commit every scheduled slot from the gathered
-        logits; returns the tokens produced and fills ``this_dec`` (the
-        tokens each slot emitted)."""
+        """Commit every slot of ``launch`` that is still scheduled from
+        the tokens the device sampled (``logits`` too where a request
+        draws from them); returns the tokens produced and fills
+        ``this_dec`` (the tokens each slot emitted)."""
         produced = 0
-        for kind, s, gstart, n in metas:
+        for kind, s, gstart, n in launch.metas:
             rid = int(self.slot_rid[s])
             if kind == "verify":
-                take = self._commit_window(s, gstart, n, logits,
-                                           props.get(s))
+                take = self._commit_window(s, gstart, n, tokens, logits,
+                                           launch.props.get(s))
                 this_dec[s] = len(take)
                 produced += len(take)
                 continue
@@ -1738,13 +1921,14 @@ class ContinuousBatchingEngine:
                 self.pending_prompt[s] = pend[n:]
                 continue
             # prompt complete: the chunk's final row (gathered at
-            # ``gstart``) carries the first-token logits; commit full
-            # pages to the prefix cache
+            # ``gstart``) carries the first token; commit full pages to
+            # the prefix cache
             del self.pending_prompt[s]
             self.prefill_order.remove(s)
             if self.prefix_cache is not None:
                 self.prefix_cache.insert(req.prompt, self.slot_pages[s])
-            tok = self._sample_row(logits[gstart], req)
+            tok = (int(tokens[gstart]) if req.temperature <= 0
+                   else self._sample_row(logits[gstart], req))
             prefill_us = int((time.perf_counter() - req.admitted) * 1e6)
             tot = self.step_totals
             tot["prefill_us"] += prefill_us
@@ -1789,6 +1973,11 @@ class ContinuousBatchingEngine:
         if self.active.any() or self.queue:
             raise AssertionError(
                 "shutdown with live requests — drain via run() first")
+        if self._flight is not None:
+            # only stale rows can be left in flight (their slots ended
+            # or were canceled): see them through, commit nothing
+            jax.block_until_ready(self._flight.out)
+            self._flight = None
         if self.prefix_cache is not None:
             self.prefix_cache.assert_consistent()
             self.prefix_cache.clear()
@@ -1803,8 +1992,11 @@ class ContinuousBatchingEngine:
         prefill accounting (the FLOPs-skip contract), speculative
         accepted-length distribution and ``"steps"``: what the engine's
         steps did since it was built, for an operator who never traces
-        (how full the steps are: ``rows`` over ``rows_cap``; how long
-        requests queue and prefill, sum and max in seconds).  The same numbers, per step and per request, ride
+        (how full the steps are: ``rows`` over ``rows_cap``; how many
+        launches were enqueued before the one before them was read,
+        ``ahead``, and how many rows ran for a slot that had ended,
+        ``stale_rows``; how long requests queue and prefill, sum and max
+        in seconds).  The same numbers, per step and per request, ride
         on the ``serving.step_counts``, ``serving.admit_request`` and
         ``serving.first_token`` markers of a profiler trace."""
         t = self.step_totals
@@ -1814,7 +2006,8 @@ class ContinuousBatchingEngine:
             "steps": {
                 **{k: t[k] for k in ("steps", "rows", "rows_cap",
                                      "decode_rows", "prefill_rows",
-                                     "admitted", *self.layout.count_names)},
+                                     "ahead", "stale_rows", "admitted",
+                                     *self.layout.count_names)},
                 "queue_wait_s": {"sum": t["queue_wait_us"] / 1e6,
                                  "max": t["queue_wait_us_max"] / 1e6},
                 "prefill_s": {"sum": t["prefill_us"] / 1e6,
@@ -1827,6 +2020,24 @@ class ContinuousBatchingEngine:
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()
         return out
+
+    @property
+    def last_logits(self) -> Optional[tuple]:
+        """The newest COMMITTED launch's gathered rows, for checks
+        against a reference: ``([(rid, absolute position of the input
+        token), ...], fp32 [len(rows), vocab])``.  The logits are copied
+        from the device when this is read; a greedy step never copies
+        them.  Setting it to ``None`` forgets them."""
+        if self._last_logits is None:
+            return None
+        gathered, logits = self._last_logits
+        return gathered, np.asarray(logits)[:len(gathered)]
+
+    @last_logits.setter
+    def last_logits(self, value) -> None:
+        if value is not None:
+            raise ValueError("last_logits can only be cleared")
+        self._last_logits = None
 
     def step(self):
         """One scheduler iteration; returns the number of tokens
@@ -1881,7 +2092,8 @@ class ContinuousBatchingEngine:
         kwargs = dict(self_cfg_id=self.cfg_id,
                       pages_per_step=self.pages_per_step,
                       kv_scales=kv_scales,
-                      gather=jnp.zeros(self.gather_cap, jnp.int32))
+                      gather=jnp.zeros(self.gather_cap, jnp.int32),
+                      prev_tokens=self._no_tokens)
         # min_bytes sized to the page pools, not the 1MB production
         # default: tiny test/debug engines must still FAIL the doctor if
         # the pools stop being donated (a vacuous gate passes when the

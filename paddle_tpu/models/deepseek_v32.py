@@ -350,23 +350,30 @@ def attention_part(cfg, w, i, x, lat_pool, idx_pool, phys, off, lens, slot,
          donate_argnums=(1, 2))
 def unified_step_jit(params, lat_pages, idx_pages, rows, tables, cos_tab,
                      sin_tab, self_cfg_id, pages_per_step, kv_scales=None,
-                     with_head=True, gather=None, debug_select=False):
+                     with_head=True, gather=None, prev_tokens=None,
+                     debug_select=False):
     """This model's part of the engine's ONE ragged step, under
     ``ContinuousBatchingEngine._unified_step_jit``'s signature and row
     schedule (its docstring): ``lat_pages`` / ``idx_pages`` are the
     per-layer latent and index-key pools ``[pages, page, numbers]`` in
     the engine's ``k_pages`` / ``v_pages`` places, donated and written
-    in place.  The third result is ``(logits [G, vocab] fp32, counts)``,
-    ``counts`` the int32 ``DEVICE_COUNTS`` of this step's expert layers
-    (sums over layers; the fullest expert's rows, the maximum).  With
-    ``debug_select`` (tests) it is ``(logits, counts, [the selection as
-    a boolean [T, W], a layer])``."""
+    in place.  Input tokens below zero are references into
+    ``prev_tokens`` and the gathered rows are sampled on the device, as
+    there (``serving.resolve_row_tokens``, ``serving.sample_greedy``).
+    The third result is ``(logits [G, vocab] fp32, tokens [G] int32,
+    counts)``, ``counts`` the int32 ``DEVICE_COUNTS`` of this step's
+    expert layers (sums over layers; the fullest expert's rows, the
+    maximum).  With ``debug_select`` (tests) it is ``(logits, tokens,
+    counts, [the selection as a boolean [T, W], a layer])``."""
+    from ..inference.serving import resolve_row_tokens, sample_greedy
     from ..ops.pallas.sparse_mla import selected_mask
     from .generation import _CFGS, _Weights, _ffn, _rms_norm
 
     cfg, _, _ = _CFGS[self_cfg_id]
     w = _Weights(cfg, params)
     tok, phys, off, lens, slot = (rows[:, c] for c in range(5))
+    if prev_tokens is not None:
+        tok = resolve_row_tokens(tok, prev_tokens)
     lens = jnp.where(slot < 0, 0, lens)
     x = w.embed(tok)
     pos = jnp.maximum(lens - 1, 0)
@@ -400,5 +407,7 @@ def unified_step_jit(params, lat_pages, idx_pages, rows, tables, cos_tab,
         sum(stats["moe_rows_held"], zero), sum(stats["moe_rows_routed"], zero),
         jnp.max(jnp.stack(stats["moe_expert_rows_max"] or [zero]))]
     ).astype(jnp.int32)
-    out = (logits, counts, masks) if debug_select else (logits, counts)
+    out = (logits, sample_greedy(logits), counts)
+    if debug_select:
+        out = (*out, masks)
     return tuple(new_lat), tuple(new_idx), out

@@ -1,0 +1,28 @@
+"""What ``benchmarks/tests/test_benchmarks.py::
+test_serve_is_not_correct_when_a_token_is_altered`` meant, held here
+since PR 29: that test alters a token in ``_sample_row``, where the host
+sampled it; greedy tokens are sampled on the device now and the engine
+commits them in ``_commit_unified``, so the alteration is made there.
+Only a ``benchmark`` PR may re-point the test itself (PERF.md section
+7)."""
+
+import numpy as np
+
+from benchmarks.tests.test_benchmarks import _run, tiny_root  # noqa: F401
+
+
+def test_serve_is_not_correct_when_a_committed_token_is_altered(
+        tiny_root, monkeypatch):  # noqa: F811
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine as E
+
+    real = E._commit_unified
+
+    def altered(self, launch, tokens, logits, this_dec):
+        bad = (np.asarray(tokens) + 1) % self.cfg.vocab_size
+        return real(self, launch, bad, logits, this_dec)
+
+    monkeypatch.setattr(E, "_commit_unified", altered)
+    line = _run(tiny_root, "tiny-serve.chat")
+    assert line["correct"] is False
+    monkeypatch.undo()
+    assert _run(tiny_root, "tiny-serve.chat")["correct"] is True

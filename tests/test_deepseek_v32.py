@@ -163,13 +163,54 @@ def test_selected_sets_equal_the_reference(model):
     for p, t in enumerate(prompt):
         rows[p] = (t, tables[0, p // PAGE], p % PAGE, p + 1, 0)
     args = (*args[:3], jnp.asarray(rows), jnp.asarray(tables), *args[5:])
-    _, _, (_, _, masks) = fn(*args, **kwargs, debug_select=True)
+    _, _, (_, _, _, masks) = fn(*args, **kwargs, debug_select=True)
     _, want = ref.forward(params, jnp.asarray(prompt), ref_cfg(cfg, HELD))
     assert len(masks) == cfg.num_hidden_layers
     for got, sel in zip(masks, want):
         got = np.asarray(got)[:21, :21]
         assert np.array_equal(got, np.asarray(sel))
         assert (got.sum(1) == np.minimum(np.arange(21) + 1, 8)).all()
+
+
+def test_the_step_takes_and_returns_the_token_column(model, served):
+    """The latent layout's step under the engine's run-ahead: beside the
+    logits it returns their first maxima as int32 ``[gather_cap]``, and
+    an input token below zero is entry ``-1 - tok`` of the launch
+    before's tokens.  A prompt fed as tokens and the same prompt fed as
+    references into a shuffled column give the same logits; and the
+    fixture's engine, which ran ahead, served what the reference picks
+    (``test_engine_logits_match_the_reference``)."""
+    rng = np.random.default_rng(3)
+    prompt = rng.integers(0, VOCAB, 5).astype(np.int32)
+    outs = []
+    for by_reference in (False, True):
+        eng = engine(model, max_slots=1)
+        fn, args, kwargs, _ = eng.analysis_entry()
+        rows = np.zeros((eng.rows_cap, 5), np.int32)
+        rows[:, 1], rows[:, 4] = eng.trash_page, -1
+        tables = np.full_like(eng.tables, -1)
+        tables[0, 0] = 3
+        prev = np.zeros(eng.gather_cap, np.int32)
+        for p, t in enumerate(prompt):
+            tok = t
+            if by_reference and p < eng.gather_cap:
+                where = eng.gather_cap - 1 - p
+                prev[where], tok = t, -1 - where
+            rows[p] = (tok, 3, p, p + 1, 0)
+        gather = np.zeros(eng.gather_cap, np.int32)
+        gather[:2] = (4, 2)
+        kwargs = dict(kwargs, gather=jnp.asarray(gather),
+                      prev_tokens=jnp.asarray(prev))
+        args = (*args[:3], jnp.asarray(rows), jnp.asarray(tables), *args[5:])
+        _, _, (logits, tokens, counts) = fn(*args, **kwargs)
+        assert tokens.dtype == jnp.int32 \
+            and tokens.shape == (eng.gather_cap,)
+        assert counts.shape == (len(DEVICE_COUNTS),)
+        assert np.array_equal(tokens, np.asarray(logits).argmax(-1))
+        outs.append(np.asarray(logits)[:2])
+    np.testing.assert_array_equal(outs[0], outs[1])
+    steps = served["stats"]["steps"]
+    assert steps["ahead"] > steps["steps"] // 2 and steps["stale_rows"] == 0
 
 
 def test_the_shares_add_up_to_the_uncut_layer(model):

@@ -178,7 +178,9 @@ def test_engine_has_one_step_path(tiny_model):
 def test_kv_layout_row_counts(tiny_model, chunk, want):
     """What the K/V layout counts for a packed schedule, by hand (pages
     of 8, the tiny shapes' query tile of 64 rows): decode rows that see
-    6 and 10 positions fetch 1 and 2 pages; behind them a 70-row chunk
+    7 and 11 positions (the engine runs one step ahead: the second
+    call packs each slot's SECOND decode row, the first is in flight)
+    fetch 1 and 2 pages; behind them a 70-row chunk
     is two units of work, 62 rows in the first tile reaching 62
     positions (8 pages) and 8 rows in the second reaching 70 (9 pages).
     The same rows in a served step put the same number on
@@ -199,7 +201,7 @@ def test_kv_layout_row_counts(tiny_model, chunk, want):
 
     def spy(*a):
         out = pack(*a)
-        seen["rows"], seen["counts"] = out[0], out[-1]
+        seen["rows"], seen["counts"] = out[0], out[-1].counts
         return out
 
     eng._pack_unified = spy
@@ -207,7 +209,7 @@ def test_kv_layout_row_counts(tiny_model, chunk, want):
     r = 2 + chunk
     assert seen["counts"]["rows"] == r
     # the hand-built schedule: (token, page, offset, visibility, slot)
-    vis = [6, 10] + list(range(1, chunk + 1))
+    vis = [7, 11] + list(range(1, chunk + 1))
     slot = [0, 1] + [2] * chunk
     rows = np.zeros((r, 5), np.int32)
     rows[:, 3], rows[:, 4] = vis, slot
@@ -651,6 +653,271 @@ def test_unified_throttle_sheds_and_restores(tiny_model):
         eng.throttle(prefill_token_budget=0)     # below the floor
     with pytest.raises(ValueError):
         eng.throttle(prefill_token_budget=32)    # above the static cap
+    eng.shutdown()
+
+
+# ---------------------------------------------------------------------
+# PR 29: the engine runs one step AHEAD.  Launch n+1 is enqueued before
+# launch n's tokens are read; a decode row's input token is then a
+# reference into the tokens launch n sampled on the device.
+# ---------------------------------------------------------------------
+
+
+def _greedy(model, prompt, n):
+    ref = generate(model, prompt[None], max_new_tokens=n, do_sample=False)
+    return np.asarray(ref._value if hasattr(ref, "_value") else ref
+                      )[0, len(prompt):]
+
+
+def _spy_launches(eng):
+    """Every launch the engine packs from now on: ``[(rows, launch)]``."""
+    seen, pack = [], eng._pack_unified
+
+    def spy(*a):
+        out = pack(*a)
+        if out[-1].counts["rows"]:
+            seen.append((out[0].copy(), out[-1]))
+        return out
+
+    eng._pack_unified = spy
+    return seen
+
+
+def test_run_ahead_decodes_over_a_page_boundary(tiny_model):
+    """Pages of 8, a prompt of 6 and 13 new tokens: the decode rows
+    cross two page boundaries while every one of them but the first is
+    launched before the token it continues has been read.  Token for
+    token what ``generate()`` gives."""
+    cfg, model, params = tiny_model
+    p = np.random.default_rng(31).integers(1, 64, 6).astype(np.int32)
+    eng = _unified(cfg, params, max_slots=1, num_pages=9, page_size=8,
+                   max_seq_len=32)
+    seen = _spy_launches(eng)
+    compiled = eng.layout.step._cache_size()
+    eng.add_request(p, max_new_tokens=13)
+    (done,) = eng.run()
+    np.testing.assert_array_equal(done.tokens, _greedy(model, p, 13))
+    # the first launch (no tokens before it) and every later one (the
+    # launch before's tokens, still on the device) are ONE program
+    assert eng.layout.step._cache_size() == compiled + 1
+    # the prompt's chunk, then 12 decode rows, each a reference
+    assert [int(r[0, 0] < 0) for r, _ in seen] == [0] + [1] * 12
+    # each writes the next position: pages 0, 1 and 2 in turn
+    assert [(int(r[0, 1]), int(r[0, 2])) for r, _ in seen[1:]] \
+        == [((6 + i) // 8, (6 + i) % 8) for i in range(12)]
+    steps = eng.serving_stats()["steps"]
+    assert steps["ahead"] == 12 and steps["stale_rows"] == 0
+    eng.alloc.assert_balanced()
+    eng.shutdown()
+
+
+def test_run_ahead_first_decode_row_refers_to_the_final_chunk(tiny_model):
+    """A prompt of 20 in chunks of 8: the final chunk (4 rows) and the
+    first decode row sit in consecutive launches, so that row's input
+    token is a reference to the chunk's gathered row, resolved on the
+    device; the chunks before it produce no token anybody reads."""
+    cfg, model, params = tiny_model
+    p = np.random.default_rng(32).integers(1, 64, 20).astype(np.int32)
+    eng = _unified(cfg, params, max_slots=1, prefill_token_budget=8)
+    seen = _spy_launches(eng)
+    eng.add_request(p, max_new_tokens=3)
+    (done,) = eng.run()
+    np.testing.assert_array_equal(done.tokens, _greedy(model, p, 3))
+    kinds = [[m[0] for m in l.metas] for _, l in seen]
+    assert kinds == [["prefill"]] * 3 + [["verify"]] * 2
+    assert [l.counts["rows"] for _, l in seen] == [8, 8, 4, 1, 1]
+    final, first = seen[2], seen[3]
+    g = final[1].metas[0][2]                 # the final row's gathered place
+    assert int(first[0][0, 0]) == -1 - g and int(first[0][0, 3]) == 21
+    assert (np.concatenate([r[:l.counts["rows"], 0]
+                            for r, l in seen[:3]]) == p).all()
+    eng.alloc.assert_balanced()
+    eng.shutdown()
+
+
+def test_run_ahead_a_slot_that_ends_by_budget_is_not_packed_again(tiny_model):
+    """Two slots, three requests: the first ends by its budget while the
+    second keeps decoding, and the queued third takes its slot and its
+    pages in the very next call.  No row is launched for a slot whose
+    budget the launch in flight exhausts (``stale_rows`` 0)."""
+    cfg, model, params = tiny_model
+    rng = np.random.default_rng(33)
+    ps = [rng.integers(1, 64, n).astype(np.int32) for n in (7, 9, 11)]
+    new = (3, 12, 5)
+    eng = _unified(cfg, params, max_slots=2, num_pages=5, page_size=16,
+                   max_seq_len=32)
+    seen = _spy_launches(eng)
+    rids = [eng.add_request(p, max_new_tokens=n) for p, n in zip(ps, new)]
+    took = {}
+    while eng.queue or eng.active.any():
+        eng.step()
+        for s in range(2):
+            if eng.active[s]:
+                took.setdefault(int(eng.slot_rid[s]),
+                                (s, tuple(eng.slot_pages[s])))
+    done = {f.rid: f.tokens for f in eng.finished}
+    for rid, p, n in zip(rids, ps, new):
+        np.testing.assert_array_equal(done[rid], _greedy(model, p, n))
+    # the third request sat in the first one's slot, on its pages
+    assert took[rids[2]] == took[rids[0]]
+    steps = eng.serving_stats()["steps"]
+    assert steps["stale_rows"] == 0
+    # slot 0's rows by launch: 7 prompt rows and 2 decode rows for 3
+    # tokens; the launch packed while the third token is in flight has
+    # none (the slot is freed when that token is committed); the next
+    # call admits the third request: 11 prompt rows, 4 decode rows
+    s0 = [sum(m[3] for m in l.metas if m[1] == 0) for _, l in seen]
+    assert s0[:9] == [7, 1, 1, 0, 11, 1, 1, 1, 1] and not any(s0[9:])
+    eng.alloc.assert_balanced()
+    eng.shutdown()
+
+
+def test_run_ahead_a_slot_that_ends_on_eos_runs_one_stale_row(tiny_model):
+    """``eos_id`` is known one step late: the slot's next row is already
+    enqueued when the host reads the token that ends it.  Exactly one
+    stale row runs (inside the slot's own pages), its token is dropped,
+    and the next request reuses slot and pages with the right answer."""
+    cfg, model, params = tiny_model
+    rng = np.random.default_rng(44)
+    p0, p1 = (rng.integers(1, 64, n).astype(np.int32) for n in (9, 12))
+    want0, want1 = _greedy(model, p0, 8), _greedy(model, p1, 6)
+    stop = next(i for i in range(2, 7) if want0[i] not in want0[:i]
+                and want0[i] not in want1)
+    eng = _unified(cfg, params, max_slots=1, num_pages=3, page_size=16,
+                   max_seq_len=32, eos_id=int(want0[stop]))
+    r0 = eng.add_request(p0, max_new_tokens=8)
+    r1 = eng.add_request(p1, max_new_tokens=6)
+    pages = {}
+    while eng.queue or eng.active.any():
+        eng.step()
+        if eng.active[0]:
+            pages.setdefault(int(eng.slot_rid[0]), tuple(eng.slot_pages[0]))
+    done = {f.rid: f.tokens for f in eng.finished}
+    np.testing.assert_array_equal(done[r0], want0[:stop + 1])
+    np.testing.assert_array_equal(done[r1], want1)
+    assert pages[r0] == pages[r1]
+    assert eng.serving_stats()["steps"]["stale_rows"] == 1
+    eng.alloc.assert_balanced()
+    eng.shutdown()
+
+
+def test_run_ahead_cancel_of_a_slot_with_a_row_in_flight(tiny_model):
+    """``cancel(rid)`` between two calls, while a row of that slot is
+    enqueued: the row runs stale, nothing of it is committed, the
+    survivor's stream is untouched and the pages come back."""
+    cfg, model, params = tiny_model
+    rng = np.random.default_rng(35)
+    p0, p1 = (rng.integers(1, 64, n).astype(np.int32) for n in (7, 9))
+    eng = _unified(cfg, params, max_slots=2)
+    r0 = eng.add_request(p0, max_new_tokens=8)
+    r1 = eng.add_request(p1, max_new_tokens=8)
+    eng.step()
+    eng.step()
+    slot = int(np.nonzero(eng.slot_rid == r0)[0][0])
+    assert any(m[1] == slot for m in eng._flight.metas)
+    free = eng.alloc.available
+    assert eng.cancel(r0) is True
+    assert eng.alloc.available > free
+    assert not any(m[1] == slot for m in eng._flight.metas)
+    assert eng._flight.counts["stale_rows"] == 1
+    done = eng.run()
+    assert [f.rid for f in done] == [r1] and r0 not in eng.out_tokens
+    np.testing.assert_array_equal(done[0].tokens, _greedy(model, p1, 8))
+    assert eng.serving_stats()["steps"]["stale_rows"] == 1
+    eng.alloc.assert_balanced()
+    eng.shutdown()
+
+
+def test_run_ahead_run_and_shutdown_with_a_launch_in_flight(tiny_model):
+    """``run()`` picks up an engine that has a launch in flight and
+    drains it to the right tokens; ``shutdown()`` of an engine whose
+    last launch holds only stale rows sees that launch through."""
+    cfg, model, params = tiny_model
+    rng = np.random.default_rng(36)
+    p = rng.integers(1, 64, 10).astype(np.int32)
+    eng = _unified(cfg, params, max_slots=1)
+    eng.add_request(p, max_new_tokens=6)
+    eng.step()
+    eng.step()
+    assert eng._flight is not None and eng._flight.metas
+    (done,) = eng.run()
+    np.testing.assert_array_equal(done.tokens, _greedy(model, p, 6))
+    assert eng._flight is None
+    rid = eng.add_request(p, max_new_tokens=6)
+    eng.step()
+    assert eng._flight is not None
+    with pytest.raises(AssertionError, match="live requests"):
+        eng.shutdown()
+    eng.cancel(rid)
+    assert eng._flight is not None and not eng._flight.metas
+    eng.alloc.assert_balanced()
+    eng.shutdown()
+    assert eng._flight is None
+
+
+@pytest.mark.parametrize("who", ["temperature", "draft"])
+def test_the_host_waits_only_for_steps_it_has_to_see(tiny_model, who):
+    """Where the host has to see a step's results before it can pack the
+    next, the engine does not run ahead, and decides that from what it
+    is serving.  ``temperature``: a greedy request decodes; a request
+    with a temperature joins it for a while; the steps that carry it
+    are read before the next is packed (``ahead`` 0), every other step
+    but the first of a spell runs ahead (``ahead`` 1); the greedy
+    stream is ``generate()``'s and the sampled one is what the same
+    seed draws when the request is served alone, every step waited for
+    (the order before PR 29).  ``draft``: a verify window's length is
+    decided on the host, so no step runs ahead, and the stream is the
+    greedy one."""
+    cfg, model, params = tiny_model
+    rng = np.random.default_rng(37)
+    pg, pt = (rng.integers(1, 64, n).astype(np.int32) for n in (8, 11))
+    kw = {}
+    if who == "draft":
+        dcfg, dparams = self_draft_params(cfg, params, 1)
+        kw = dict(draft_cfg=dcfg, draft_params=dparams, speculative_k=2)
+    eng = _unified(cfg, params, max_slots=2, **kw)
+    log = []                             # (ahead, carries a temperature)
+    commit = eng._commit_unified
+
+    def spy(launch, *a):
+        log.append((launch.counts["ahead"],
+                    any(eng.req_info[m[1]].temperature > 0
+                        for m in launch.metas)))
+        return commit(launch, *a)
+
+    eng._commit_unified = spy
+    rg = eng.add_request(pg, max_new_tokens=16)
+    for _ in range(4):
+        eng.step()
+    rt = eng.add_request(pt, max_new_tokens=4, temperature=0.8, seed=5)
+    done = {f.rid: f.tokens for f in eng.run()}
+    np.testing.assert_array_equal(done[rg], _greedy(model, pg, 16))
+    alone = _unified(cfg, params, max_slots=2, **kw)
+    alone.add_request(pt, max_new_tokens=4, temperature=0.8, seed=5)
+    (want,) = alone.run()
+    np.testing.assert_array_equal(done[rt], want.tokens)
+    # what the commit before PR 29 served for this model, these prompts
+    # and this seed (run there once, by hand)
+    assert done[rt].tolist() == {"temperature": [51, 53, 49, 17],
+                                 "draft": [51, 49, 32, 25]}[who]
+    assert alone.serving_stats()["steps"]["ahead"] == 0
+    alone.shutdown()
+    steps = eng.serving_stats()["steps"]
+    assert steps["ahead"] == sum(a for a, _ in log)
+    if who == "draft":
+        assert steps["ahead"] == 0 and eng.accepted_lengths
+    else:
+        with_t = [a for a, t in log if t]
+        assert with_t and not any(with_t)
+        first, last = log.index((0, True)), \
+            len(log) - 1 - log[::-1].index((0, True))
+        # before it came: one launch to fill, then every one ahead;
+        # after it left: the same
+        assert [a for a, _ in log[:first]] == [0] + [1] * (first - 1)
+        assert [a for a, _ in log[last + 1:]] \
+            == [0] + [1] * (len(log) - last - 2)
+        assert first >= 3 and len(log) - last > 3
+    eng.alloc.assert_balanced()
     eng.shutdown()
 
 

@@ -26,7 +26,7 @@ MARKERS = ("serving.admit_request", "serving.first_token",
 STEP_COUNTS = ("step", "admitted", "queued", "free_pages", "rows", "rows_cap",
                "decode_rows", "prefill_rows", "slots", "prefill_backlog",
                "attn_row_ctx", "kv_ctx_tokens", "attn_kv_tokens_read",
-               "gathered", "produced", "finished")
+               "gathered", "produced", "finished", "ahead", "stale_rows")
 PROMPT_LENS = (20, 9, 13, 30)
 NEW_TOKENS = 5
 
@@ -149,6 +149,71 @@ def test_step_numbers_run_on_and_each_step_ends_with_its_counts(traced):
         assert max(inside, key=lambda sp: sp[1])[0] == "serving.step_counts"
 
 
+def test_every_call_that_commits_a_launch_writes_every_phase(traced):
+    """The engine runs one step ahead and the readers still find what
+    they read, in every call: the call that commits a launch (its
+    marker has rows) has ``serving.admit``, ``serving.fetch_logits``
+    and ``serving.commit``; it packs unless the host had to see the
+    launch before (a draft model) and it was in flight already, which
+    never happens here; and there is one ``serving.launch`` a launch
+    (two in the call that fills the pipeline, none in the call that
+    finds nothing left to launch)."""
+    counts = {c["step"]: c for *_, c in _named(traced, "serving.step_counts")}
+    launches = 0
+    for _, s, e, arg in _named(traced, "serving.step"):
+        inside = [sp[0] for sp in traced["spans"] if s <= sp[1] and sp[2] <= e]
+        launches += inside.count("serving.launch")
+        if counts[arg["step"]]["rows"]:
+            for name in ("serving.admit", "serving.pack",
+                         "serving.fetch_logits", "serving.commit"):
+                assert name in inside, (arg["step"], name)
+            assert inside.count("serving.fetch_logits") \
+                == inside.count("serving.commit") == 1
+    assert launches == sum(1 for c in counts.values() if c["rows"])
+    ahead = [c["ahead"] for c in counts.values() if c["rows"]]
+    assert not any(ahead) if traced["draft"] else sum(ahead) >= len(ahead) - 4
+
+
+def test_a_calls_marker_carries_the_launch_it_commits(tiny, tmp_path):
+    """Three calls by hand: one request, a prompt of 5 and 3 new tokens.
+    Call 1 enqueues the prompt's launch (5 rows) AND the first decode
+    row's, and commits the first; call 2 enqueues the second decode
+    row and commits the first decode row; call 3 finds nothing to
+    launch (the budget is spent by what is in flight) and commits the
+    second.  Each call's marker, joined to its ``serving.step`` by
+    ``step``, counts the launch the device ran while the host was
+    inside that call."""
+    cfg, params = tiny
+    eng = ContinuousBatchingEngine(cfg, params, max_slots=1, num_pages=9,
+                                   page_size=16, max_seq_len=32,
+                                   prefill_token_budget=8)
+    eng.add_request(np.arange(1, 6, dtype=np.int32), max_new_tokens=3)
+    with _trace(tmp_path):
+        produced = [eng.step() for _ in range(3)]
+    assert produced == [1, 1, 1] and not eng.active.any()
+    spans = _program_spans(tmp_path)
+    steps = [a["step"] for n, *_, a in spans if n == "serving.step"]
+    marks = [a for n, *_, a in spans if n == "serving.step_counts"]
+    assert [m["step"] for m in marks] == steps == [1, 2, 3]
+    want = [dict(rows=5, prefill_rows=5, decode_rows=0, ahead=0, admitted=1,
+                 attn_row_ctx=15, kv_ctx_tokens=5, gathered=1, finished=0),
+            dict(rows=1, prefill_rows=0, decode_rows=1, ahead=1, admitted=0,
+                 attn_row_ctx=6, kv_ctx_tokens=6, gathered=1, finished=0),
+            dict(rows=1, prefill_rows=0, decode_rows=1, ahead=1, admitted=0,
+                 attn_row_ctx=7, kv_ctx_tokens=7, gathered=1, finished=1)]
+    for mark, w in zip(marks, want):
+        assert {k: mark[k] for k in w} == w
+        assert mark["produced"] == 1 and mark["stale_rows"] == 0
+    launches = [[n for n, a, b, _ in spans if n in ("serving.pack",
+                                                    "serving.launch")
+                 and s <= a and b <= e]
+                for name, s, e, _ in spans if name == "serving.step"]
+    assert launches == [["serving.pack", "serving.launch"] * 2,
+                        ["serving.pack", "serving.launch"],
+                        ["serving.pack"]]
+    eng.shutdown()
+
+
 @pytest.mark.parametrize("key", ["rows", "slots", "gathered", "context",
                                  "walk", "backlog", "produced", "empty"])
 def test_per_step_counts_hold_together(traced, key):
@@ -196,7 +261,7 @@ def test_per_step_counts_hold_together(traced, key):
 @pytest.mark.parametrize("key", ["steps", "rows", "rows_cap", "decode_rows",
                                  "prefill_rows", "admitted", "kv_ctx_tokens",
                                  "attn_kv_tokens_read", "queue_wait_s",
-                                 "prefill_s"])
+                                 "prefill_s", "ahead", "stale_rows"])
 def test_the_spans_arguments_add_up_to_serving_stats(traced, key):
     kept = traced["stats"]["steps"][key]
     counts = [c for *_, c in _named(traced, "serving.step_counts")]
@@ -215,7 +280,10 @@ def test_the_spans_arguments_add_up_to_serving_stats(traced, key):
 def test_the_walk_reads_what_the_packing_counts(tiny):
     """2 decode rows and one 20-row chunk over 3 pages of 8: the K/V
     positions the ragged kernel's walk fetches in a layer, by hand, and
-    the same launch through the kernel's own units of work."""
+    the same launch through the kernel's own units of work.  The engine
+    runs one step ahead: the second call PACKS that launch (each slot's
+    second decode row) and the third call COMMITS it, which is when its
+    counts reach ``serving_stats()``."""
     from paddle_tpu.ops.pallas.decode_attention import (ragged_tile_rows,
                                                         ragged_units)
 
@@ -228,7 +296,6 @@ def test_the_walk_reads_what_the_packing_counts(tiny):
         eng.add_request(rng.integers(1, 64, n).astype(np.int32),
                         max_new_tokens=4)
     eng.step()                      # both prompts prefilled: 5 + 9 rows
-    before = dict(eng.serving_stats()["steps"])
     eng.add_request(rng.integers(1, 64, 20).astype(np.int32),
                     max_new_tokens=4)
     packed = {}
@@ -236,24 +303,26 @@ def test_the_walk_reads_what_the_packing_counts(tiny):
 
     def spy(*a):
         out = pack(*a)
-        packed["rows"] = out[0]
+        packed.setdefault("rows", out[0])
         return out
 
     eng._pack_unified = spy
     eng.step()
+    before = dict(eng.serving_stats()["steps"])
+    eng.step()
     after = eng.serving_stats()["steps"]
     got = {k: after[k] - before[k] for k in
            ("rows", "decode_rows", "kv_ctx_tokens", "attn_kv_tokens_read")}
-    # the decode rows see 6 and 10 positions (1 and 2 pages), the chunk's
-    # last row 20 (3 pages): 8 + 16 + 24 fetched for 6 + 10 + 20 attended
-    assert got == {"rows": 22, "decode_rows": 2, "kv_ctx_tokens": 36,
+    # the decode rows see 7 and 11 positions (1 and 2 pages), the chunk's
+    # last row 20 (3 pages): 8 + 16 + 24 fetched for 7 + 11 + 20 attended
+    assert got == {"rows": 22, "decode_rows": 2, "kv_ctx_tokens": 38,
                    "attn_kv_tokens_read": 48}
     rows = packed["rows"]
     tile = ragged_tile_rows(cfg.num_attention_heads,
                             cfg.num_key_value_heads, cfg.head_dim)
     count, reach = ragged_units(rows[:, 4], rows[:, 3], tile, np)
     assert count[count > 0].tolist() == [1, 1, 20]
-    assert reach[count > 0].tolist() == [6, 10, 20]
+    assert reach[count > 0].tolist() == [7, 11, 20]
     eng.run()
     eng.shutdown()
 

@@ -225,7 +225,7 @@ def test_ragged_kernels_grid_is_the_query_tiles_alone():
     assert grids == [(tiles,), (tiles,)] and tiles == 2
 
 
-def _serving_step_text(one_chip, monkeypatch, cache_dtype):
+def _serving_step_text(one_chip, monkeypatch, cache_dtype, vocab=256):
     """Optimized HLO of the engine's step program (the unified ragged
     step) for the described chip, 2 layers at the cell's KV geometry.  The engine is built small (24 pages, on
     the CPU) and gives the call through ``analysis_entry()``; the shapes
@@ -239,7 +239,7 @@ def _serving_step_text(one_chip, monkeypatch, cache_dtype):
     monkeypatch.setattr(decode_attention, "pallas_interpret",
                         lambda: False)
     layers = 2
-    cfg = LlamaConfig.debug(vocab=256, hidden=KV_HEADS * 128, layers=layers,
+    cfg = LlamaConfig.debug(vocab=vocab, hidden=KV_HEADS * 128, layers=layers,
                             heads=KV_HEADS, kv_heads=KV_HEADS, inter=256,
                             max_pos=_CELL_SEQ)
     params = {k: jnp.asarray(v, jnp.bfloat16) for k, v in
@@ -291,6 +291,27 @@ def test_serving_step_writes_kv_in_place(one_chip, monkeypatch, cache_dtype):
     aliased = {int(n) for n in re.findall(
         r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
     assert len(pools) == npools and pools <= aliased, (pools, aliased)
+
+
+def test_serving_step_samples_on_the_device(one_chip, monkeypatch):
+    """The engine's own call (``analysis_entry()``: the previous
+    launch's tokens among its arguments) is ONE program for the
+    described chip whose results hold the sampled tokens, int32
+    ``[gather_cap]``, beside the gathered logits; the token column it
+    takes has the same shape, so the launch after it is the same
+    program.  The head runs over the gathered rows alone: nothing of
+    ``[rows_cap, vocab]`` exists in it."""
+    vocab = 384                     # no other dimension of the tiny model
+    text, pool, _ = _serving_step_text(one_chip, monkeypatch, jnp.bfloat16,
+                                       vocab=vocab)
+    assert text.count("\nENTRY ") == 1
+    rows_cap = _CELL_SLOTS + _CELL_BUDGET
+    gather_cap = 2 * _CELL_SLOTS
+    entry = text[text.index("\nENTRY "):]
+    root = next(ln for ln in entry.splitlines() if "ROOT " in ln)
+    assert f"s32[{gather_cap}]" in root and f"f32[{gather_cap},{vocab}]" in root
+    assert re.search(rf"= s32\[{gather_cap}\]\S* parameter\(", entry)
+    assert not re.search(rf"\[{rows_cap},{vocab}\]", text)
 
 
 @pytest.mark.parametrize("d", HEAD_DIMS)
