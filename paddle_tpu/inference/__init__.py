@@ -337,9 +337,12 @@ def create_predictor(config_or_layer, layer=None):
 # continuous-batching serving engine (reference capability: the serving
 # loop around block_multihead_attention): ONE ragged step a tick with
 # chunked prefill, decode rows and speculative verify windows in it,
-# over refcounted pages and a radix prefix cache.
+# over refcounted pages and a radix prefix cache.  A model tells the
+# engine of its pools and its kinds of page through a PagedLayout (window
+# and full attention layers mixed: a pool, an allocator, a table and a
+# budget a PageKind).
 from .serving import (ContinuousBatchingEngine, PageAllocator,  # noqa: E402
-                      PrefixCache)
+                      PagedLayout, PageKind, PrefixCache)
 # round-13 serving resilience plane: replica fleet manager + SLO-aware
 # router + request-level fault tolerance
 from .fleet import (FleetConfig, FleetRouter, OverloadRejected,  # noqa: E402

@@ -54,6 +54,17 @@ time, and nothing selects it from outside.
   step: ``rows`` ``[rows_cap, 5]`` = (input token or reference, physical
   page its K/V is written to, in-page offset, causal visibility, slot),
   beside the page tables.
+- A model may have several KINDS of page (``PagedLayout.kinds``: window
+  and full attention layers mixed).  The engine then holds, for each
+  kind, a pool size, an allocator, a table ``[slots, pages_per_seq]``
+  and a budget (``_KindPages``), ``rows`` carries one more column a
+  further kind (the page the row writes there) and the step takes a
+  table a kind.  A kind that retains a window maps a block when a
+  launch first writes into it and gives it back when the launch that
+  last read it is committed, so a slot holds at most ``window_bound``
+  of its pages whatever its context; the prefix cache holds a page of
+  each kind a block and serves a hit as far as every kind is whole.  A
+  layout of one kind runs the same code with a tuple of length one.
 - The page pools are PER-LAYER arrays, donated through the step, so a
   layer's cache update is one scatter into its own pool; a fused
   ``[L, pages, ...]`` slab cost a slice and a whole-layer update a
@@ -203,11 +214,33 @@ def sample_greedy(logits):
 
 
 @dataclasses.dataclass(frozen=True)
+class PageKind:
+    """One KIND of page of a model: the layers whose pools hold it and
+    what those layers retain of a context, every position (``window``
+    None) or the last ``window`` positions.  A kind has a pool size, an
+    allocator and a page table ``[slots, pages_per_seq]`` of its own in
+    the engine (``_KindPages``)."""
+    name: str
+    layers: tuple
+    window: Optional[int] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class PagedLayout:
     """What a model tells the engine of its paged state and of its part
     of the unified step.  Every layer has TWO pools, and one page id
-    names a page in each pool of every layer, so slots, tables, the
-    allocator and the prefix cache never know what a page holds.
+    names a page in each pool of every layer OF ONE KIND, so slots,
+    tables, the allocators and the prefix cache never know what a page
+    holds.
+
+    ``kinds``: the kinds of page the model has (``PageKind``), empty for
+    ONE kind that every layer shares.  The first kind retains every
+    position; each further kind retains a window, and the engine then
+    holds at most a bounded number of its pages a slot, whatever the
+    context (``ContinuousBatchingEngine.window_bound``).  A packed row
+    carries the physical page it writes in each kind (``rows`` column 1
+    for the first, columns 5.. for the others) and the step takes a
+    table a kind.
 
     ``rows``: the shape of one token's row in each pool.  A Llama-shaped
     decoder's (``kv_layout``) are its K and V rows ``[kvh, d]``, laid
@@ -226,9 +259,10 @@ class PagedLayout:
     itemsize)`` is how many pages the step's kernels take a turn of
     their page walk, where the constructor is given no number.
 
-    What the engine can do with K/V pages alone (a draft model's
-    mirror, an int8 cache, the host tier, the prefill-only handoff)
-    refuses other pools at construction."""
+    What the engine can do with K/V pages of one kind alone (a draft
+    model's mirror, an int8 cache, the host tier, the prefill-only
+    handoff) refuses other pools, and more kinds than one, at
+    construction."""
     name: str
     rows: tuple
     head_major: bool = True
@@ -237,6 +271,7 @@ class PagedLayout:
     device_counts: tuple = ()
     count_names: tuple = ()             # row_counts' keys + device_counts
     pages_per_step: Any = None
+    kinds: tuple = ()
 
     def pool_shapes(self, num_pages: int, page_size: int):
         if self.head_major:
@@ -258,9 +293,43 @@ def ragged_kv_tokens_read(row_slot, row_lens, tile_rows: int, page: int,
     return int(pages.sum()) * page
 
 
+#: what the Llama family's step counts on the device where a layer has
+#: experts, in the order it returns them: token copies routed and those
+#: of an expert in the bank, the fullest expert's rows, the experts that
+#: got at least one row (how much of the bank a step streams) and the
+#: experts there are, both summed over the expert layers
+MOE_DEVICE_COUNTS = ("moe_rows_routed", "moe_rows_held",
+                     "moe_expert_rows_max", "moe_experts_hit",
+                     "moe_experts_total")
+#: what the packed rows give where a kind retains a window
+WINDOW_ROW_COUNTS = ("attn_row_ctx_window", "kv_ctx_tokens_window")
+#: what the engine counts of a window kind's pages at a commit
+WINDOW_PAGE_COUNTS = ("window_pages_live", "window_pages_recycled")
+
+
+def page_kinds(cfg) -> tuple:
+    """The kinds of page of a Llama-shaped config, from its
+    ``layer_types``: the ``full_attention`` layers first, then the
+    ``sliding_attention`` layers with ``cfg.sliding_window``.  Empty
+    where every layer retains its whole context (no ``layer_types``, or
+    none of them sliding): ONE kind, as ever."""
+    types = tuple(getattr(cfg, "layer_types", None) or ())
+    sliding = tuple(i for i, t in enumerate(types)
+                    if t == "sliding_attention")
+    if not sliding:
+        return ()
+    full = tuple(i for i, t in enumerate(types) if t != "sliding_attention")
+    if not full:
+        raise ValueError("every layer slides: the first kind of page "
+                         "retains every position (PagedLayout)")
+    return (PageKind("full", full),
+            PageKind("window", sliding, int(cfg.sliding_window)))
+
+
 def kv_layout(cfg) -> PagedLayout:
     """The Llama family's layout: K rows and V rows, ``_unified_step_jit``
-    and what its ragged kernel's walk reads."""
+    and what its ragged kernel's walk reads; two kinds of page where
+    ``cfg.layer_types`` mixes window and full layers (``page_kinds``)."""
     from ..ops.pallas.decode_attention import (default_pages_per_step,
                                                ragged_tile_rows)
 
@@ -268,24 +337,48 @@ def kv_layout(cfg) -> PagedLayout:
     # rows of a query tile of the ragged kernel: the K/V its walk reads
     # are counted by the kernel's own units of work
     tile_rows = ragged_tile_rows(cfg.num_attention_heads, kvh, d)
+    kinds = page_kinds(cfg)
+    windows = [k.window for k in kinds if k.window is not None]
 
     def row_counts(rows, ctx_tokens, page_size, pages_per_seq):
         # what the walk fetches in one layer (whole pages, a slot once
         # for each of its units of work): over kv_ctx_tokens, the
         # re-read factor
-        return {"attn_kv_tokens_read": ragged_kv_tokens_read(
+        out = {"attn_kv_tokens_read": ragged_kv_tokens_read(
             rows[:, 4], rows[:, 3], tile_rows, page_size, pages_per_seq)}
+        if windows:
+            # a window layer's least work: a row's arithmetic over
+            # min(visibility, W) keys, a slot's bytes over min(context,
+            # W) positions (a slot's context is its rows' largest
+            # visibility)
+            w, vis = windows[0], rows[:, 3]
+            ctx = np.zeros(int(rows[:, 4].max(initial=-1)) + 1, np.int64)
+            np.maximum.at(ctx, rows[:, 4], vis)
+            out.update(zip(WINDOW_ROW_COUNTS,
+                           (int(np.minimum(vis, w).sum()),
+                            int(np.minimum(ctx, w).sum()))))
+        return out
 
     def pages_per_step(page_size, pages_per_seq, itemsize):
         return default_pages_per_step(page_size, kvh, d, pages_per_seq,
                                       itemsize)
 
+    device_counts = MOE_DEVICE_COUNTS if _counts_experts(cfg) else ()
     return PagedLayout(
         name="kv", rows=((kvh, d), (kvh, d)),
         step=ContinuousBatchingEngine._unified_step_jit,
-        row_counts=row_counts,
-        count_names=("kv_ctx_tokens", "attn_kv_tokens_read"),
-        pages_per_step=pages_per_step)
+        row_counts=row_counts, device_counts=device_counts,
+        count_names=("kv_ctx_tokens", "attn_kv_tokens_read",
+                     *((*WINDOW_ROW_COUNTS, *WINDOW_PAGE_COUNTS)
+                       if windows else ()), *device_counts),
+        pages_per_step=pages_per_step, kinds=kinds)
+
+
+def _counts_experts(cfg) -> bool:
+    """Whether the Llama family's step takes the expert layers' counts
+    on the device (``MOE_DEVICE_COUNTS``): a config that states its
+    experts.  ``kv_layout`` and the step ask the same question."""
+    return int(getattr(cfg, "num_experts", 0) or 0) > 0
 
 
 class PageAllocator:
@@ -392,17 +485,24 @@ class _TrieNode:
     ``device`` (``page`` is a live pool page id, the trie holds one
     allocator ref on it) or ``host`` (``page`` is None and ``host_kv``
     carries the page's per-layer K/V stacked [L, kvh, page, d] pair,
-    placed in the pinned-host memory space)."""
+    placed in the pinned-host memory space).
 
-    __slots__ = ("children", "key", "page", "parent", "tick", "host_kv")
+    Where the model has further kinds of page (``PagedLayout.kinds``),
+    ``more`` holds the block's page of each: a window kind's page, or
+    None once it was evicted alone (or the prefill that committed the
+    block had already given it back)."""
 
-    def __init__(self, key=None, page=None, parent=None):
+    __slots__ = ("children", "key", "page", "parent", "tick", "host_kv",
+                 "more")
+
+    def __init__(self, key=None, page=None, parent=None, kinds: int = 0):
         self.children: Dict[tuple, "_TrieNode"] = {}
         self.key = key
         self.page = page
         self.parent = parent
         self.tick = 0
         self.host_kv = None
+        self.more: List[Optional[int]] = [None] * kinds
 
     @property
     def tier(self) -> str:
@@ -440,13 +540,26 @@ class PrefixCache:
     no re-quantization).  Demotion needs no leaf-ness (the trie
     structure is untouched), so interior pages demote too; only when
     the host tier itself overflows its cap are LRU host-tier LEAVES
-    truly dropped, bottom-up like classic eviction."""
+    truly dropped, bottom-up like classic eviction.
+
+    KINDS of page (``windows``: one ``(allocator, window)`` a further
+    kind of the layout): a cached block holds a page of each kind.  The
+    rows that continue a hit of ``P`` tokens read the first kind's pages
+    of ``[0, P)`` and a window kind's pages of the positions from ``P +
+    1 - window`` on, so a hit is served as far as BOTH are whole
+    (``lookup_all``) and shrinks to the longest prefix of which that
+    holds, never to something wrong.  A window kind's page may be
+    evicted ALONE, from any block (``evict_window``): first those no
+    possible hit can need (the run of blocks a hit would read is broken
+    already), then the least recently used."""
 
     def __init__(self, page_size: int, alloc: PageAllocator, *,
                  host_tier_pages: int = 0, demote_fn=None,
-                 promote_fn=None):
+                 promote_fn=None, windows=()):
         self.page_size = int(page_size)
         self.alloc = alloc
+        self.windows = tuple(windows)       # (allocator, window) a kind
+        self.evicted_window_pages = 0
         self.root = _TrieNode()
         self._tick = 0
         self.hits = 0
@@ -485,11 +598,30 @@ class PrefixCache:
         engine once the request is actually admitted — a lookup whose
         admission aborts on pool pressure releases its refs and must
         not count as a served hit."""
+        if self.windows:
+            raise ValueError("a cache over several kinds of page is "
+                             "asked through lookup_all")
+        pages, matched = self.lookup_all(prompt)
+        return pages[0], matched
+
+    def _first_read(self, blocks: int, window: int) -> int:
+        """The first block whose window-kind page the rows continuing a
+        hit of ``blocks`` blocks read: the one holding position ``P + 1
+        - window``."""
+        return max(0, blocks * self.page_size + 1 - window) // self.page_size
+
+    def lookup_all(self, prompt):
+        """``lookup`` for every kind of page: ``(pages, matched_tokens)``
+        with ``pages[0]`` the first kind's pages of ``[0, matched)`` and
+        ``pages[k]`` the k-th kind's pages of the blocks from
+        ``_first_read`` to the hit's last, a ref acquired on each.  The
+        hit is the longest prefix of the walk whose window-kind pages
+        are all there."""
         self.lookups += 1
         self._tick += 1
         limit = max(0, (len(prompt) - 1) // self.page_size)
         node = self.root
-        pages: List[int] = []
+        path: List[_TrieNode] = []
         for key in self._chunks(prompt, limit):
             child = node.children.get(key)
             if child is None:
@@ -511,9 +643,24 @@ class PrefixCache:
                 self.promoted_pages += 1
                 self.host_hits += 1
             self.alloc.acquire(child.page)
-            pages.append(child.page)
+            path.append(child)
             node = child
-        return pages, len(pages) * self.page_size
+        # the longest prefix whose window-kind pages are whole: run[k]
+        # counts the blocks ending at the current one that hold kind k's
+        blocks, run = 0, [0] * len(self.windows)
+        for j, n in enumerate(path, 1):
+            run = [r + 1 if n.more[k] is not None else 0
+                   for k, r in enumerate(run)]
+            if all(r >= j - self._first_read(j, w)
+                   for r, (_, w) in zip(run, self.windows)):
+                blocks = j
+        # what lies past a hit that shrank is handed back
+        self.alloc.release([n.page for n in path[blocks:]])
+        pages = [[n.page for n in path[:blocks]]]
+        for k, (alloc, w) in enumerate(self.windows):
+            pages.append([alloc.acquire(n.more[k]) for n in
+                          path[self._first_read(blocks, w):blocks]])
+        return pages, blocks * self.page_size
 
     def probe(self, prompt) -> int:
         """Matched FULL-PAGE tokens for ``prompt`` across BOTH tiers,
@@ -536,12 +683,15 @@ class PrefixCache:
             self.hits += 1
             self.hit_tokens += matched_tokens
 
-    def insert(self, prompt, pages) -> int:
+    def insert(self, prompt, pages, more=()) -> int:
         """Commit a completed prefill's FULL prompt pages.  New nodes
         acquire a trie reference on their page; existing nodes are left
         untouched (a concurrent prefill of the same prefix keeps its
         private copy, which simply frees when that request finishes).
-        Returns the number of newly committed pages."""
+        ``more[k]`` is ``(first block, pages)``: the k-th further kind's
+        pages the slot still holds, from that block on; a block that
+        lacks its page of that kind, new or not, takes it.  Returns the
+        number of newly committed pages."""
         self._tick += 1
         n = min(len(prompt) // self.page_size, len(pages))
         node = self.root
@@ -550,10 +700,14 @@ class PrefixCache:
             child = node.children.get(key)
             if child is None:
                 child = _TrieNode(key, self.alloc.acquire(int(pages[i])),
-                                  node)
+                                  node, len(self.windows))
                 node.children[key] = child
                 self.inserted_pages += 1
                 added += 1
+            for k, (first, held) in enumerate(more):
+                if child.more[k] is None and 0 <= i - first < len(held):
+                    child.more[k] = self.windows[k][0].acquire(
+                        int(held[i - first]))
             child.tick = self._tick
             node = child
         return added
@@ -595,6 +749,7 @@ class PrefixCache:
             parent = victim.parent
             del parent.children[victim.key]
             self.alloc.release([victim.page])
+            self._drop_more(victim)
             self.evicted_pages += 1
             freed += 1
             if (parent is not self.root and not parent.children
@@ -602,6 +757,57 @@ class PrefixCache:
                 heap_entry = (parent.tick, seq, parent)
                 seq += 1
                 heapq.heappush(heap, heap_entry)
+        return freed
+
+    def _drop_more(self, node: _TrieNode) -> None:
+        """Give back a block's pages of the further kinds (the block
+        itself is going)."""
+        for k, (alloc, _) in enumerate(self.windows):
+            if node.more[k] is not None:
+                alloc.release([node.more[k]])
+                node.more[k] = None
+
+    def evict_window(self, k: int, pages_needed: int) -> int:
+        """Evict up to ``pages_needed`` pages of the k-th further kind
+        that only the trie holds, from ANY block (the block stays, with
+        its first-kind page).  First those no possible hit can need: a
+        hit that ends at block j reads the pages of the blocks from
+        ``_first_read(j)`` to j, so a page is of use only while some
+        block at or below it, as far as a window reaches, still has its
+        whole run; then the least recently used.  Returns pages freed."""
+        alloc, w = self.windows[k]
+        # (useful, tick, n, node) of every candidate; a node's run is the
+        # count of blocks ending at it that hold the kind's page
+        found = []
+        stack = [(c, 1, 0) for c in self.root.children.values()]
+        order = []
+        while stack:
+            n, depth, run = stack.pop()
+            run = run + 1 if n.more[k] is not None else 0
+            whole = run >= depth - self._first_read(depth, w)
+            order.append((n, depth, whole))
+            stack.extend((c, depth + 1, run) for c in n.children.values())
+        # reach[n]: blocks down to the nearest block at or below n whose
+        # run is whole (children before parents: the walk's reverse)
+        reach: Dict[int, int] = {}
+        far = 1 << 30
+        for n, depth, whole in reversed(order):
+            r = 0 if whole else min(
+                (reach[id(c)] + 1 for c in n.children.values()), default=far)
+            reach[id(n)] = r
+            if n.more[k] is not None and alloc.refs[n.more[k]] == 1:
+                # of use to the hit that ends r blocks further down, if
+                # that hit's run reaches back as far as this block
+                useful = r < far and \
+                    depth > self._first_read(depth + r, w)
+                found.append((useful, n.tick, len(found), n))
+        found.sort(key=lambda f: f[:3])
+        freed = 0
+        for _, _, _, n in found[:pages_needed]:
+            alloc.release([n.more[k]])
+            n.more[k] = None
+            self.evicted_window_pages += 1
+            freed += 1
         return freed
 
     def _demote_lru(self, pages_needed: int) -> int:
@@ -656,6 +862,7 @@ class PrefixCache:
         for n in list(self._nodes()):
             if n.host_kv is None:
                 self.alloc.release([n.page])
+            self._drop_more(n)
         self.root = _TrieNode()
         self.host_pages = 0
 
@@ -691,6 +898,16 @@ class PrefixCache:
             raise AssertionError(
                 f"host-tier counter drift: counter={self.host_pages} "
                 f"actual={host_nodes}")
+        for k, (alloc, _) in enumerate(self.windows):
+            held = [n.more[k] for n in self._nodes()
+                    if n.more[k] is not None]
+            if len(set(held)) != len(held):
+                raise AssertionError(
+                    f"a page of kind {k + 1} held by two trie nodes")
+            dead = [p for p in held if alloc.refs[p] <= 0]
+            if dead:
+                raise AssertionError(
+                    f"trie nodes hold dead pages {dead} of kind {k + 1}")
 
     @property
     def cached_pages(self) -> int:
@@ -702,10 +919,54 @@ class PrefixCache:
                 "cached_pages": self.cached_pages,
                 "inserted_pages": self.inserted_pages,
                 "evicted_pages": self.evicted_pages,
+                "evicted_window_pages": self.evicted_window_pages,
                 "host_pages": self.host_pages,
                 "host_hits": self.host_hits,
                 "demoted_pages": self.demoted_pages,
                 "promoted_pages": self.promoted_pages}
+
+
+class _KindPages:
+    """One KIND of page (``PageKind``) as the host holds it: the pool's
+    size and its trash page (the last), the allocator, the table
+    ``[slots, pages_per_seq]`` and the pages each slot holds a reference
+    on, ``held[slot]``, in the order of the blocks they stand for from
+    block ``lo[slot]`` on.
+
+    A kind that retains every position (``window`` None) reserves a
+    slot's whole context at admission: ``lo`` stays 0.  A kind that
+    retains a window maps a block when a launch first writes into it
+    (``ContinuousBatchingEngine._map_pages``) and gives a block back
+    once no row to come can read it (``_recycle``); what it reserves at
+    admission is a CLAIM of at most ``bound`` pages, and the sum of the
+    live slots' claims never passes the pool.  Then a slot in need of a
+    page finds one: the pages no slot holds are at least the claims not
+    yet taken up, and each is free or held by the prefix cache alone,
+    which gives a window kind's page up on demand."""
+
+    def __init__(self, kind: PageKind, num_pages: int, max_slots: int,
+                 pages_per_seq: int, bound: Optional[int]):
+        self.kind = kind
+        self.window = kind.window
+        self.num_pages = int(num_pages)
+        self.trash = self.num_pages - 1
+        self.alloc = PageAllocator(self.num_pages - 1)
+        self.tables = np.full((max_slots, pages_per_seq), -1, np.int32)
+        self.held: Dict[int, List[int]] = {}
+        self.lo: Dict[int, int] = {}
+        self.bound = bound
+        self.claim: Dict[int, int] = {}
+        self.recycled = 0       # pages given back since the last marker
+
+    def claim_of(self, need: int) -> int:
+        """Pages a request of ``need`` blocks reserves of this kind."""
+        return need if self.bound is None else min(need, self.bound)
+
+    def release(self, slot: int) -> None:
+        self.alloc.release(self.held.pop(slot))
+        self.lo.pop(slot, None)
+        self.claim.pop(slot, None)
+        self.tables[slot] = -1
 
 
 class ContinuousBatchingEngine:
@@ -714,7 +975,9 @@ class ContinuousBatchingEngine:
     params/cfg: a model's functional state (models/generation.py weight
     naming; weight-only int8 dicts from quantize_params_int8 work
     unchanged).  ``max_slots`` bounds the in-flight batch; ``num_pages``
-    x ``page_size`` is the shared pool of every layer;
+    x ``page_size`` is the shared pool of every layer, or, for a layout
+    of several kinds of page (``PagedLayout.kinds``), a mapping from a
+    kind's name to its pool's pages (an int gives every kind that many);
     ``prefill_token_budget`` is the most prompt tokens a step carries
     (256: what the ledger's Mistral cell is timed at).  ``pages_per_step``
     left unset is the layout's rule (``PagedLayout.pages_per_step``)."""
@@ -740,11 +1003,6 @@ class ContinuousBatchingEngine:
         self.max_slots = int(max_slots)
         self.max_seq_len = int(max_seq_len or cfg.max_position_embeddings)
         self.page_size = int(page_size)
-        self.num_pages = int(num_pages)
-        # the LAST physical page is a reserved scribble target: the
-        # static step's padding rows write their garbage there instead
-        # of corrupting a live page
-        self.trash_page = self.num_pages - 1
         self.pages_per_seq = -(-self.max_seq_len // self.page_size)
         self.eos_id = int(eos_id)
         if prefill_token_budget is None or int(prefill_token_budget) < 1:
@@ -752,6 +1010,7 @@ class ContinuousBatchingEngine:
                 f"prefill_token_budget {prefill_token_budget!r}: the most "
                 f"prompt tokens a step carries is an int >= 1")
         self.prefill_budget = int(prefill_token_budget)
+        self._init_prefill_budget = self.prefill_budget
 
         L = cfg.num_hidden_layers
         dt = next(iter(v for k, v in params.items()
@@ -765,8 +1024,16 @@ class ContinuousBatchingEngine:
             pages_per_step = self.layout.pages_per_step(
                 self.page_size, self.pages_per_seq, jnp.dtype(dt).itemsize)
         self.pages_per_step = int(pages_per_step)
-        if self.layout.name != "kv":
-            # the step is all that knows these pools (PagedLayout)
+        kinds = self.layout.kinds or (PageKind("pages", tuple(range(L))),)
+        if kinds[0].window is not None or any(k.window is None
+                                              for k in kinds[1:]):
+            raise ValueError("a layout's first kind of page retains every "
+                             "position and each further kind a window")
+        if self.layout.name != "kv" or len(kinds) > 1:
+            # the step is all that knows these pools (PagedLayout); what
+            # follows moves, mirrors or calibrates K/V pages of ONE kind
+            pools = (f"{self.layout.name} pools" if len(kinds) == 1 else
+                     f"{self.layout.name} pools of {len(kinds)} kinds of page")
             for what, asked in (
                     ("a draft model: its mirror launches assume the "
                      "target's K/V geometry", draft_params is not None
@@ -778,34 +1045,52 @@ class ContinuousBatchingEngine:
                     ("prefill_only and the KV handoff: the wire format is "
                      "K and V pages", prefill_only)):
                 if asked:
-                    raise ValueError(f"{self.layout.name} pools do not "
-                                     f"support {what}")
+                    raise ValueError(f"{pools} do not support {what}")
         # int8 cache: frozen per-(layer, kv-head) scales, auto-calibrated
         # from the FIRST prefill's K/V absmax (2x headroom) — a single
         # self-consistent quant/dequant pair for the whole run (the
         # reference's static cachekv_quant mode; see incubate/nn/
         # decode_attention.py for the dynamic per-sequence contract)
         self.kv_scales = None
+        # one _KindPages a kind of page: pool size, allocator, table.
+        # The LAST physical page of a kind's pool is a reserved scribble
+        # target: the static step's padding rows write their garbage
+        # there instead of corrupting a live page
+        sizes = (dict(num_pages) if isinstance(num_pages, dict)
+                 else {k.name: num_pages for k in kinds})
+        if set(sizes) != {k.name for k in kinds}:
+            raise ValueError(f"num_pages {sorted(sizes)}: the layout's kinds "
+                             f"of page are {[k.name for k in kinds]}")
+        self.pages = tuple(
+            _KindPages(k, sizes[k.name], self.max_slots, self.pages_per_seq,
+                       None if k.window is None
+                       else self.window_bound(k.window))
+            for k in kinds)
+        self.more_pages = self.pages[1:]    # the window kinds, if any
+        # the FIRST kind's, under the names they have always had (a
+        # layout of one kind has no other)
+        first = self.pages[0]
+        self.num_pages, self.trash_page = first.num_pages, first.trash
+        self.alloc, self.tables = first.alloc, first.tables
+        self.slot_pages = first.held
         # PER-LAYER pools: a layer's cache write is one direct scatter
         # into its own pool (a fused [L, ...] slab would cost a slice +
         # whole-layer dynamic-update per layer per step)
         # (whatever the layout's two pools hold, they go by k_pages and
         # v_pages here: for "latent", latent rows and index keys)
-        ka, vb = self.layout.pool_shapes(self.num_pages, self.page_size)
-        self.k_pages = tuple(jnp.zeros(ka, dt) for _ in range(L))
-        self.v_pages = tuple(jnp.zeros(vb, dt) for _ in range(L))
+        kind_of = {i: kp for kp in self.pages for i in kp.kind.layers}
+        shapes = [self.layout.pool_shapes(kind_of[i].num_pages,
+                                          self.page_size) for i in range(L)]
+        self.k_pages = tuple(jnp.zeros(ka, dt) for ka, _ in shapes)
+        self.v_pages = tuple(jnp.zeros(vb, dt) for _, vb in shapes)
         # host-side slot state
-        self.tables = np.full((self.max_slots, self.pages_per_seq), -1,
-                              np.int32)
         self.seq_lens = np.zeros(self.max_slots, np.int32)
         self.active = np.zeros(self.max_slots, bool)
         self.cur_tok = np.zeros(self.max_slots, np.int32)
         self.budget = np.zeros(self.max_slots, np.int32)
         self.slot_rid = np.full(self.max_slots, -1, np.int64)
-        self.slot_pages: Dict[int, List[int]] = {}
         self.out_tokens: Dict[int, List[int]] = {}
         self.prompt_lens: Dict[int, int] = {}
-        self.alloc = PageAllocator(self.num_pages - 1)
         self.queue: deque[Request] = deque()
         self._next_rid = 0
         self.finished: List[Finished] = []
@@ -856,7 +1141,8 @@ class ContinuousBatchingEngine:
             demote_fn=(self._demote_page if self.host_tier_pages
                        else None),
             promote_fn=(self._promote_page if self.host_tier_pages
-                        else None))
+                        else None),
+            windows=tuple((kp.alloc, kp.window) for kp in self.pages[1:]))
             if enable_prefix_cache else None)
         # static packed-row capacity of one launch: one decode row per
         # slot (k+1 under speculation) + the prefill chunk
@@ -876,7 +1162,6 @@ class ContinuousBatchingEngine:
         # runtime degradation floors: throttle() may shed work but
         # never grow past the constructor's static shapes
         self._init_spec_k = self.spec_k
-        self._init_prefill_budget = self.prefill_budget
         self.pending_prompt: Dict[int, np.ndarray] = {}
         self.prefill_order: List[int] = []       # FIFO over mid-prefill slots
         self.req_info: Dict[int, Request] = {}   # slot -> live request
@@ -1032,8 +1317,14 @@ class ContinuousBatchingEngine:
         this token's K/V, in-page offset, causal visibility = absolute
         position + 1, page-table row / slot).  Padding rows carry
         slot -1 / visibility 0 and scatter into the trash page.
-        ``tables`` [slots, pages_per_seq] feeds the kernel's
-        scalar-prefetch index maps.  An input token below zero is a
+        ``tables`` is a tuple of [slots, pages_per_seq] tables, one a
+        kind of page (``page_kinds``; most configs have one kind), that
+        feed the kernel's scalar-prefetch index maps.  Where window and
+        full layers mix, ``rows`` has a column more for each
+        further kind (the page the row writes there), a layer takes its
+        kind's, a window layer's kernel attends the last ``window``
+        positions, and ``cos_tab`` / ``sin_tab`` are dicts by
+        ``layer_types`` entry from which a layer takes its own.  An input token below zero is a
         reference into ``prev_tokens``, the int32 ``[gather_cap]`` that
         the launch before this one sampled (``resolve_row_tokens``): the
         engine enqueues a greedy step before it has read the step
@@ -1060,16 +1351,35 @@ class ContinuousBatchingEngine:
         off = rows[:, 2]
         lens = rows[:, 3]
         slot = rows[:, 4]
+        # a layer's kind of page: its table, the column of the page a
+        # row writes, its window (one kind: the table, column 1, none)
+        kinds = page_kinds(cfg)
+        kind_of = {i: k for k, kind in enumerate(kinds) for i in kind.layers}
+        phys_of = [phys] + [rows[:, 4 + k] for k in range(1, len(tables))]
         x = w.embed(tok)                              # [T, hidden]
         pos = jnp.maximum(lens - 1, 0)
-        cos = jnp.take(cos_tab, pos, axis=0)[:, None, :].astype(x.dtype)
-        sin = jnp.take(sin_tab, pos, axis=0)[:, None, :].astype(x.dtype)
+
+        def rope_rows(tab):
+            return jnp.take(tab, pos, axis=0)[:, None, :].astype(x.dtype)
+
+        # rope tables by kind of layer where the config has them
+        cos, sin = jax.tree.map(rope_rows, (cos_tab, sin_tab))
         new_k, new_v = list(k_pages), list(v_pages)
         rep_ = h // kvh
+        stats = None
+        if _counts_experts(cfg):
+            stats = {"valid": slot >= 0,
+                     **{c: [] for c in MOE_DEVICE_COUNTS[:4]}}
         # the SAME scope names in every layer, so that a trace viewer
         # adds a layer's parts up across layers; scopes are metadata and
         # change nothing that is compiled
         for i in range(L):
+            ki = kind_of.get(i, 0)
+            phys = phys_of[ki]
+            lcos, lsin = cos, sin
+            if isinstance(cos, dict):
+                lcos, lsin = (cos[cfg.layer_types[i]],
+                              sin[cfg.layer_types[i]])
             with jax.named_scope("attn_qkv"):
                 xin = _rms_norm(x, w.layer(i, "input_layernorm.weight"),
                                 cfg.rms_norm_eps)
@@ -1079,7 +1389,7 @@ class ContinuousBatchingEngine:
                      ).reshape(T, kvh, d)
                 v = (xin @ w.layer(i, "self_attn.v_proj.weight")
                      ).reshape(T, kvh, d)
-                q, k = _apply_rope(q, k, cos, sin)
+                q, k = _apply_rope(q, k, lcos, lsin)
             with jax.named_scope("kv_scatter"):
                 kw_, vw_, qd = k, v, q
                 if new_k[i].dtype == jnp.int8:
@@ -1099,8 +1409,9 @@ class ContinuousBatchingEngine:
                 new_k[i], new_v[i] = kp, vp
             with jax.named_scope("paged_attn"):
                 ctx = ragged_paged_decode_raw(
-                    qd, kp, vp, lens, slot, tables, scale=d ** -0.5,
-                    pages_per_step=pages_per_step)
+                    qd, kp, vp, lens, slot, tables[ki], scale=d ** -0.5,
+                    pages_per_step=pages_per_step,
+                    window=kinds[ki].window if kinds else None)
                 if kp.dtype == jnp.int8:
                     vdq = jnp.repeat(kv_scales["vdq"][i], rep_)
                     ctx = ctx.astype(jnp.float32) * vdq[None, :, None]
@@ -1115,7 +1426,7 @@ class ContinuousBatchingEngine:
                 # (the int8 _Weights expert view), dense layers through
                 # SwiGLU — the unified ragged step serves sparse
                 # checkpoints unchanged
-                x = x + _ffn(w, i, xm)
+                x = x + _ffn(w, i, xm, stats)
         if not with_head:
             # draft cache-mirror launches only need the K/V scatter side
             # effect: skip the [T, hidden] x [hidden, vocab] head matmul
@@ -1133,7 +1444,18 @@ class ContinuousBatchingEngine:
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
             logits = w.head(x).astype(jnp.float32)    # [G, vocab]
-        return tuple(new_k), tuple(new_v), (logits, sample_greedy(logits))
+        out = (logits, sample_greedy(logits))
+        if stats is not None:
+            # MOE_DEVICE_COUNTS, over this step's expert layers
+            zero = jnp.zeros((), jnp.int32)
+            hit = stats["moe_experts_hit"]
+            out = (*out, jnp.stack([
+                sum(stats["moe_rows_routed"], zero),
+                sum(stats["moe_rows_held"], zero),
+                jnp.max(jnp.stack(stats["moe_expert_rows_max"] or [zero])),
+                sum(hit, zero),
+                zero + len(hit) * int(cfg.num_experts)]).astype(jnp.int32))
+        return tuple(new_k), tuple(new_v), out
 
     # ---------------- host scheduler ----------------
 
@@ -1148,11 +1470,13 @@ class ContinuousBatchingEngine:
         # budget pages belong to the replica the KV hands off to
         reserve = len(prompt) + (0 if self.prefill_only
                                  else max_new_tokens)
-        if self._pages_needed(reserve) > self.alloc.total:
-            raise ValueError(
-                f"request needs {self._pages_needed(reserve)} pages "
-                f"but the pool only has {self.alloc.total} — it could "
-                f"never be admitted (head-of-line livelock)")
+        for kp in self.pages:
+            claim = kp.claim_of(self._pages_needed(reserve))
+            if claim > kp.alloc.total:
+                raise ValueError(
+                    f"request needs {claim} pages but the pool only has "
+                    f"{kp.alloc.total} — it could never be admitted "
+                    f"(head-of-line livelock)")
         if self.cache_dtype == jnp.int8 and self.kv_scales is None:
             # calibrate on the FIRST real prompt at SUBMISSION time —
             # outside any caller's step/heartbeat window, so the
@@ -1170,11 +1494,28 @@ class ContinuousBatchingEngine:
     def _pages_needed(self, tokens: int) -> int:
         return -(-tokens // self.page_size)
 
+    def window_bound(self, window: int) -> int:
+        """The most pages of a kind that retains ``window`` positions a
+        slot holds at once, whatever its context.  With c positions
+        committed, nothing under position ``c + 1 - window`` is read
+        again (``_recycle`` gives those blocks back when the launch that
+        last read them is committed), the launch in flight wrote a <=
+        chunk positions from c on and the one being packed writes b <=
+        chunk more (``_step_unified`` runs one launch ahead), chunk the
+        constructor's ``prefill_token_budget``: the blocks held span
+        positions ``c + 1 - window .. c + a + b - 1``, and a span of n
+        positions touches at most ``(n - 2) // page + 2`` pages, which
+        ``pages(window + 2 chunk) + 1`` never falls short of (17 at
+        window 1024, chunk 512, pages of 128)."""
+        return self._pages_needed(int(window)
+                                  + 2 * self._init_prefill_budget) + 1
+
     def _release_slot(self, slot: int):
         """Return a slot's pages and clear its host state — the shared
         tail of normal completion (``_finish``) and withdrawal
         (``cancel``)."""
-        self.alloc.release(self.slot_pages.pop(slot))
+        for kp in self.pages:
+            kp.release(slot)
         if self._flight is not None:
             # rows already enqueued for this slot are STALE: they run
             # (the device executes launches in order, so they write this
@@ -1186,7 +1527,6 @@ class ContinuousBatchingEngine:
                     self._flight.metas.remove(m)
                     break
         self.active[slot] = False
-        self.tables[slot] = -1
         self.seq_lens[slot] = 0
         self.slot_rid[slot] = -1
         self.pending_prompt.pop(slot, None)
@@ -1322,9 +1662,12 @@ class ContinuousBatchingEngine:
         if self.prefill_only:
             raise ValueError("adopt_request needs a decode-capable "
                              "engine")
-        if self.layout.name != "kv":
-            raise ValueError(f"{self.layout.name} pools do not support the "
-                             f"KV handoff: the wire format is K and V pages")
+        if self.layout.name != "kv" or len(self.pages) > 1:
+            raise ValueError(f"{self.layout.name} pools"
+                             + (f" of {len(self.pages)} kinds of page"
+                                if len(self.pages) > 1 else "")
+                             + " do not support the KV handoff: the wire "
+                             "format is K and V pages of one kind")
         plen = int(meta["seq_len"])
         first = int(meta["first_token"])
         if int(meta["page_size"]) != self.page_size:
@@ -1469,26 +1812,35 @@ class ContinuousBatchingEngine:
             plen = len(req.prompt)
             need = self._pages_needed(
                 plen if self.prefill_only else plen + req.max_new_tokens)
-            shared: List[int] = []
+            shared: List[List[int]] = [[] for _ in self.pages]
             matched = 0
             if self.prefix_cache is not None:
-                shared, matched = self.prefix_cache.lookup(req.prompt)
-            need_new = need - len(shared)
-            if need_new > self.alloc.available \
-                    and self.prefix_cache is not None:
-                self.prefix_cache.evict(need_new - self.alloc.available)
-            if need_new > self.alloc.available:
-                if shared:          # aborted hit: hand the refs back
-                    self.alloc.release(shared)
+                shared, matched = self.prefix_cache.lookup_all(req.prompt)
+            if not self._reserve(need, shared):
+                for kp, pages in zip(self.pages, shared):
+                    kp.alloc.release(pages)   # aborted hit: refs back
                 break               # head-of-line waits for pages
             self.queue.popleft()
             slot = free_slots[si]
             si += 1
-            pages = list(shared) \
-                + [self.alloc.alloc() for _ in range(need_new)]
-            self.slot_pages[slot] = pages
-            self.tables[slot] = -1
-            self.tables[slot, :need] = pages
+            for kp, pages in zip(self.pages, shared):
+                kp.claim[slot] = kp.claim_of(need)
+                kp.tables[slot] = -1
+                if kp.window is None:
+                    # every position is retained: the whole context's
+                    # pages now (no mid-flight OOM)
+                    kp.lo[slot] = 0
+                    kp.held[slot] = list(pages) + [
+                        kp.alloc.alloc() for _ in range(need - len(pages))]
+                else:
+                    # the hit's pages of the blocks its rows will read;
+                    # every later block is mapped when a launch first
+                    # writes into it (``_map_pages``)
+                    kp.lo[slot] = matched // self.page_size - len(pages)
+                    kp.held[slot] = list(pages)
+                    kp.tables[slot, :kp.lo[slot]] = kp.trash
+                lo = kp.lo[slot]
+                kp.tables[slot, lo:lo + len(kp.held[slot])] = kp.held[slot]
             self.active[slot] = True
             self.seq_lens[slot] = matched
             self.cur_tok[slot] = 0
@@ -1518,6 +1870,69 @@ class ContinuousBatchingEngine:
                 pass
         return admitted
 
+    def _reserve(self, need: int, shared) -> bool:
+        """Whether every kind of page can take a request of ``need``
+        blocks of which the prefix cache gave ``shared[k]``, evicting
+        from the cache where that helps.  A kind that retains every
+        position needs the blocks it does not share FREE now; a window
+        kind needs room for the request's claim beside the live slots'
+        claims (``_KindPages``)."""
+        for kp, pages in zip(self.pages, shared):
+            if kp.window is not None:
+                if sum(kp.claim.values()) + kp.claim_of(need) \
+                        > kp.alloc.total:
+                    return False
+                continue
+            short = need - len(pages) - kp.alloc.available
+            if short > 0 and self.prefix_cache is not None:
+                self.prefix_cache.evict(short)
+            if need - len(pages) > kp.alloc.available:
+                return False
+        return True
+
+    def _map_pages(self, slot: int, end: int) -> None:
+        """Give ``slot`` a page of every window kind for each block up
+        to the one holding position ``end - 1`` (a launch is about to
+        write there).  A page is there to be had: see ``_KindPages``."""
+        for k, kp in enumerate(self.more_pages):
+            held = kp.held[slot]
+            while (kp.lo[slot] + len(held)) * self.page_size < end:
+                page = kp.alloc.alloc()
+                if page is None and self.prefix_cache is not None:
+                    # the cache gives up pages of this kind alone; a few
+                    # dozen a time, so that its walk is rare
+                    self.prefix_cache.evict_window(k, 4 * self.max_slots)
+                    page = kp.alloc.alloc()
+                if page is None:
+                    raise AssertionError(
+                        f"no page of kind {kp.kind.name!r} for slot {slot}: "
+                        f"the live slots' claims passed the pool")
+                kp.tables[slot, kp.lo[slot] + len(held)] = page
+                held.append(page)
+                if len(held) > kp.claim[slot]:
+                    raise AssertionError(
+                        f"slot {slot} holds {len(held)} pages of kind "
+                        f"{kp.kind.name!r}, its claim is {kp.claim[slot]}")
+
+    def _recycle(self, slot: int) -> None:
+        """Give back ``slot``'s blocks of every window kind that no row
+        to come can read: with ``seq_lens[slot]`` positions committed a
+        row's visibility is at least one more, so nothing under
+        ``seq_lens + 1 - window`` is read again, by the launch in flight
+        (packed from at least this length) or by any later one.  Called
+        when a launch is COMMITTED, so the launch that last read a block
+        has run; the table entry becomes the trash page."""
+        for kp in self.more_pages:
+            keep = max(0, int(self.seq_lens[slot]) + 1 - kp.window) \
+                // self.page_size
+            n = min(keep - kp.lo[slot], len(kp.held[slot]))
+            if n > 0:
+                kp.alloc.release(kp.held[slot][:n])
+                del kp.held[slot][:n]
+                kp.tables[slot, kp.lo[slot]:kp.lo[slot] + n] = kp.trash
+                kp.lo[slot] += n
+                kp.recycled += n
+
     def _sample_row(self, logits_row: np.ndarray, req: Request) -> int:
         """Draw the next token of a request with a temperature from one
         returned logits row and the request's seeded stream.  (A greedy
@@ -1536,7 +1951,7 @@ class ContinuousBatchingEngine:
         d = self.draft
         d["k_pages"], d["v_pages"], out = d["step"](
             d["params"], d["k_pages"], d["v_pages"],
-            jnp.asarray(rows_np), jnp.asarray(self.tables),
+            jnp.asarray(rows_np), (jnp.asarray(self.tables),),
             d["cos_tab"], d["sin_tab"], self_cfg_id=d["cfg_id"],
             pages_per_step=self.pages_per_step, with_head=need_logits)
         return np.asarray(out[0]) if need_logits else None
@@ -1756,7 +2171,8 @@ class ContinuousBatchingEngine:
                         self.layout.step(
                             self.params, self.k_pages, self.v_pages,
                             jnp.asarray(rows),
-                            jnp.asarray(self.tables.copy()),
+                            tuple(jnp.asarray(kp.tables.copy())
+                                  for kp in self.pages),
                             self.cos_tab, self.sin_tab,
                             self_cfg_id=self.cfg_id,
                             pages_per_step=self.pages_per_step,
@@ -1805,6 +2221,15 @@ class ContinuousBatchingEngine:
                 "seq_lens_this_time": cur.enc + this_dec,
             }
             counts = cur.counts
+            windows = self.more_pages
+            if windows:
+                # a window kind's pages the slots hold now, and those
+                # this call's commit gave back
+                counts.update(zip(WINDOW_PAGE_COUNTS, (
+                    sum(len(h) for kp in windows for h in kp.held.values()),
+                    sum(kp.recycled for kp in windows))))
+                for kp in windows:
+                    kp.recycled = 0
             for k in ("rows", "rows_cap", "decode_rows", "prefill_rows",
                       "ahead", "stale_rows"):
                 tot[k] += counts[k]
@@ -1817,6 +2242,8 @@ class ContinuousBatchingEngine:
                     "serving.step_counts", step=tot["steps"],
                     admitted=len(admitted), queued=len(self.queue),
                     free_pages=self.alloc.available,
+                    **{f"free_pages_{kp.kind.name}": kp.alloc.available
+                       for kp in windows},
                     prefill_backlog=sum(
                         len(p) for p in self.pending_prompt.values()),
                     produced=produced,
@@ -1832,7 +2259,7 @@ class ContinuousBatchingEngine:
         the step's counts for ``serving.step_counts``."""
         enc = np.zeros(self.max_slots, np.int32)
         dec = np.zeros(self.max_slots, np.int32)
-        rows = np.zeros((self.rows_cap, 5), np.int32)
+        rows = np.zeros((self.rows_cap, 4 + len(self.pages)), np.int32)
         rows[:, 1] = self.trash_page
         rows[:, 4] = -1
         # consumed-row gather schedule: metas carry GATHERED offsets, so
@@ -1846,10 +2273,12 @@ class ContinuousBatchingEngine:
         for s, base, tok in decode:
             window = [tok] + list(props.get(s, ([], []))[0])
             gstart = g
+            if self.more_pages:
+                self._map_pages(s, base + len(window))
             for j, t in enumerate(window):
                 p = base + j
-                rows[r] = (t, self._phys(s, p), p % self.page_size,
-                           p + 1, s)
+                rows[r, :5] = (t, self._phys(s, p), p % self.page_size,
+                               p + 1, s)
                 gather[g] = r
                 gathered.append((int(self.slot_rid[s]), p))
                 g += 1
@@ -1863,10 +2292,12 @@ class ContinuousBatchingEngine:
             if left <= 0:
                 break
             chunk = min(len(pend), left)
+            if self.more_pages:
+                self._map_pages(s, base + chunk)
             for j in range(chunk):
                 p = base + j
-                rows[r] = (int(pend[j]), self._phys(s, p),
-                           p % self.page_size, p + 1, s)
+                rows[r, :5] = (int(pend[j]), self._phys(s, p),
+                               p % self.page_size, p + 1, s)
                 r += 1
             left -= chunk
             enc[s] = chunk
@@ -1892,6 +2323,12 @@ class ContinuousBatchingEngine:
         }
         counts.update(self.layout.row_counts(
             rows[:r], kv_ctx, self.page_size, self.pages_per_seq))
+        # the page a row writes in each further kind of page, from that
+        # kind's table (columns 5..; a padding row's is the kind's trash)
+        for k, kp in enumerate(self.more_pages, 5):
+            rows[:, k] = kp.trash
+            rows[:r, k] = kp.tables[rows[:r, 4],
+                                    (rows[:r, 3] - 1) // self.page_size]
         return rows, gather, _Launch(metas, gathered, counts, enc, dec,
                                      props)
 
@@ -1926,7 +2363,9 @@ class ContinuousBatchingEngine:
             del self.pending_prompt[s]
             self.prefill_order.remove(s)
             if self.prefix_cache is not None:
-                self.prefix_cache.insert(req.prompt, self.slot_pages[s])
+                self.prefix_cache.insert(
+                    req.prompt, self.slot_pages[s],
+                    [(kp.lo[s], kp.held[s]) for kp in self.pages[1:]])
             tok = (int(tokens[gstart]) if req.temperature <= 0
                    else self._sample_row(logits[gstart], req))
             prefill_us = int((time.perf_counter() - req.admitted) * 1e6)
@@ -1963,6 +2402,10 @@ class ContinuousBatchingEngine:
             produced += 1
             if tok == self.eos_id or self.budget[s] <= 0:
                 self._finish(s)
+        if self.more_pages:
+            for _, s, _, _ in launch.metas:
+                if self.active[s]:
+                    self._recycle(s)
         return produced
 
     def shutdown(self) -> None:
@@ -1981,11 +2424,13 @@ class ContinuousBatchingEngine:
         if self.prefix_cache is not None:
             self.prefix_cache.assert_consistent()
             self.prefix_cache.clear()
-        self.alloc.assert_consistent()
-        if self.alloc.available != self.alloc.total:
-            raise AssertionError(
-                f"page leak at teardown: {self.alloc.total - self.alloc.available} "
-                f"pages still referenced")
+        for kp in self.pages:
+            kp.alloc.assert_consistent()
+            if kp.alloc.available != kp.alloc.total:
+                raise AssertionError(
+                    f"page leak at teardown: "
+                    f"{kp.alloc.total - kp.alloc.available} pages still "
+                    f"referenced")
 
     def serving_stats(self) -> Dict[str, Any]:
         """Serving-plane telemetry: prefix-cache counters, per-request
@@ -2074,9 +2519,10 @@ class ContinuousBatchingEngine:
             report = paddle_tpu.analysis.check(
                 fn, *args, kwargs=kwargs, options=options)
         """
-        rows = np.zeros((self.rows_cap, 5), np.int32)
-        rows[:, 1] = self.trash_page
+        rows = np.zeros((self.rows_cap, 4 + len(self.pages)), np.int32)
         rows[:, 4] = -1
+        for k, kp in zip((1, *range(5, rows.shape[1])), self.pages):
+            rows[:, k] = kp.trash
         kv_scales = self.kv_scales
         if kv_scales is None and self.cache_dtype == jnp.int8:
             # doctor sweep BEFORE the first admission calibrated: unit
@@ -2087,7 +2533,8 @@ class ContinuousBatchingEngine:
             kv_scales = {"kq": ones, "kdq": ones,
                          "vq": ones, "vdq": ones}
         args = (self.params, self.k_pages, self.v_pages,
-                jnp.asarray(rows), jnp.asarray(self.tables),
+                jnp.asarray(rows),
+                tuple(jnp.asarray(kp.tables) for kp in self.pages),
                 self.cos_tab, self.sin_tab)
         kwargs = dict(self_cfg_id=self.cfg_id,
                       pages_per_step=self.pages_per_step,

@@ -158,24 +158,15 @@ class DeepseekV32Config:
         """YaRN's cos/sin ``[max_position_embeddings, qk_rope_head_dim]``
         (halves layout).  With ``mscale == mscale_all_dim`` the tables
         carry no magnitude correction; it is in ``softmax_scale``."""
-        rs, d = dict(self.rope_scaling), self.qk_rope_head_dim
-        j = np.arange(d // 2, dtype=np.float64)
-        freq = self.rope_theta ** (-2.0 * j / d)
+        from .llama import yarn_rope_tables
 
-        def bound(beta):
-            return d * math.log(rs["original_max_position_embeddings"]
-                                / (beta * 2 * math.pi)) \
-                / (2 * math.log(self.rope_theta))
-
-        lo = max(math.floor(bound(rs["beta_fast"])), 0)
-        hi = min(math.ceil(bound(rs["beta_slow"])), d - 1)
-        keep = 1.0 - np.clip((j - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
-        freq = freq / rs["factor"] * (1.0 - keep) + freq * keep
-        ang = np.outer(np.arange(self.max_position_embeddings,
-                                 dtype=np.float64), freq)
-        ang = np.concatenate([ang, ang], axis=-1)
-        return (jnp.asarray(np.cos(ang), jnp.float32),
-                jnp.asarray(np.sin(ang), jnp.float32))
+        rs = dict(self.rope_scaling)
+        return yarn_rope_tables(
+            self.qk_rope_head_dim, self.max_position_embeddings,
+            self.rope_theta, factor=rs["factor"],
+            original_max_position_embeddings=rs[
+                "original_max_position_embeddings"],
+            beta_fast=rs["beta_fast"], beta_slow=rs["beta_slow"])
 
     def paged_layout(self):
         from ..inference.serving import PagedLayout
@@ -372,6 +363,7 @@ def unified_step_jit(params, lat_pages, idx_pages, rows, tables, cos_tab,
     cfg, _, _ = _CFGS[self_cfg_id]
     w = _Weights(cfg, params)
     tok, phys, off, lens, slot = (rows[:, c] for c in range(5))
+    (tables,) = tables                  # a table a kind of page: it has one
     if prev_tokens is not None:
         tok = resolve_row_tokens(tok, prev_tokens)
     lens = jnp.where(slot < 0, 0, lens)

@@ -280,6 +280,8 @@ def _moe_experts(w: _Weights, i, x2, top_ids, top_p, lo, hi, e_all, stats):
         stats["moe_rows_routed"].append(jnp.sum(stats["valid"]) * k)
         stats["moe_rows_held"].append(jnp.sum(held))
         stats["moe_expert_rows_max"].append(jnp.max(counts))
+        if "moe_experts_hit" in stats:      # experts with a row at least
+            stats["moe_experts_hit"].append(jnp.sum(counts > 0))
 
     def bank(proj):
         name = pre + f"experts.{proj}.weight"
@@ -293,8 +295,10 @@ def _moe_experts(w: _Weights, i, x2, top_ids, top_p, lo, hi, e_all, stats):
 
     def gmm(xin, proj):
         wq, sc = bank(proj)
+        # the sorted dispatch's segments tile the rows densely: the row
+        # blocks alone are the grid, however many experts the bank has
         return grouped_matmul_raw(xin, wq, seg_st, counts, wids,
-                                  block_rows=bm, w_scale=sc)
+                                  block_rows=bm, w_scale=sc, dense=True)
 
     def experts_of(n):
         """The layer's routed part from the first ``n`` sorted copies
@@ -710,6 +714,11 @@ def generate(model, input_ids, max_new_tokens: int = 32,
         else jnp.asarray(input_ids)
     ids = ids.astype(jnp.int32)
     cfg = model.cfg if hasattr(model, "cfg") else model.model.cfg
+    if "sliding_attention" in (getattr(cfg, "layer_types", None) or ()):
+        raise NotImplementedError(
+            "generate() attends every layer's whole context and takes one "
+            "rope table a config: a model that mixes window and full "
+            "layers is served by inference.ContinuousBatchingEngine")
     max_new_tokens = int(max_new_tokens)
     if max_new_tokens <= 0:
         return Tensor(ids)

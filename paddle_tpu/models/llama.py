@@ -111,6 +111,35 @@ def _rope_tables(head_dim: int, max_pos: int, theta: float):
             jnp.asarray(np.sin(emb), dtype=jnp.float32))
 
 
+def yarn_rope_tables(dim: int, max_pos: int, theta: float, *, factor: float,
+                     original_max_position_embeddings: int,
+                     beta_fast: float = 32, beta_slow: float = 1,
+                     attention_factor: float = 1.0):
+    """YaRN's cos/sin ``[max_pos, dim]`` (halves layout), as transformers
+    computes them: each of theta's frequencies ``f_j`` becomes ``f_j /
+    factor * (1 - m_j) + f_j * m_j`` with ``m_j = 1 - clip((j - low) /
+    (high - low), 0, 1)``, ``low`` and ``high`` the floor and ceiling of
+    ``dim ln(original / (beta 2 pi)) / (2 ln theta)`` for ``beta_fast``
+    and ``beta_slow``; cos and sin are multiplied by
+    ``attention_factor``.  The ONE spelling: DeepSeek-V3.2's rotary
+    dimensions and Mellum2's full-attention layers both take it."""
+    j = np.arange(dim // 2, dtype=np.float64)
+    freq = theta ** (-2.0 * j / dim)
+
+    def bound(beta):
+        return dim * math.log(original_max_position_embeddings
+                              / (beta * 2 * math.pi)) / (2 * math.log(theta))
+
+    lo = max(math.floor(bound(beta_fast)), 0)
+    hi = min(math.ceil(bound(beta_slow)), dim - 1)
+    keep = 1.0 - np.clip((j - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    freq = freq / factor * (1.0 - keep) + freq * keep
+    ang = np.outer(np.arange(max_pos, dtype=np.float64), freq)
+    ang = np.concatenate([ang, ang], axis=-1)
+    return (jnp.asarray(np.cos(ang) * attention_factor, jnp.float32),
+            jnp.asarray(np.sin(ang) * attention_factor, jnp.float32))
+
+
 class LlamaAttention(Layer):
     """GQA attention. Layout [b, s, h, d] throughout (flash kernel layout)."""
 

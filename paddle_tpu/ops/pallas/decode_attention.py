@@ -62,6 +62,8 @@ from .flash_attention import NEG_INF, _sds
 FLASH_DECODE_KERNEL = "flash_decode_attention"
 PAGED_DECODE_KERNEL = "paged_decode_attention"
 RAGGED_PAGED_KERNEL = "ragged_paged_attention"
+# the same kernel over a layer that attends the last ``window`` positions
+RAGGED_PAGED_WINDOW_KERNEL = "ragged_paged_attention_window"
 
 
 def _decode_kernel(*refs, block_k: int, scale: float):
@@ -412,7 +414,7 @@ def ragged_tile_rows(h: int, kvh: int, d: int) -> int:
     return max(1, m // rp)
 
 
-def ragged_units(row_slot, row_lens, tile_rows: int, xp):
+def ragged_units(row_slot, row_lens, tile_rows: int, xp, low: bool = False):
     """The ragged kernel's units of work, from the packed rows alone.
 
     A unit is a maximal run of consecutive live rows (slot >= 0) of ONE
@@ -420,9 +422,11 @@ def ragged_units(row_slot, row_lens, tile_rows: int, xp):
     that slot's pages once for all of the unit's rows, as far as the
     largest visibility among them.  Returns ``(count, reach)``, int32
     ``[T]`` each: at a unit's FIRST row the unit's row count and that
-    largest visibility, 0 at every other row.  ``xp`` is ``numpy`` (the
-    engine's packing counts what a step's walk reads) or ``jax.numpy``
-    (the kernel's wrapper): one definition for both."""
+    largest visibility, 0 at every other row; with ``low`` also the
+    unit's SMALLEST visibility (a window layer's walk starts at the page
+    that holds the lowest position any of its rows attends).  ``xp`` is
+    ``numpy`` (the engine's packing counts what a step's walk reads) or
+    ``jax.numpy`` (the kernel's wrapper): one definition for both."""
     T = row_slot.shape[0]
     idx = xp.arange(T)
     live = row_slot >= 0
@@ -438,32 +442,57 @@ def ragged_units(row_slot, row_lens, tile_rows: int, xp):
     reach = xp.where(same, xp.concatenate([lens, tail.astype(lens.dtype)]
                                           )[win], 0).max(0)
     count = same.sum(0)
-    return (xp.where(first, count, 0).astype(xp.int32),
-            xp.where(first, reach, 0).astype(xp.int32))
+    out = (xp.where(first, count, 0).astype(xp.int32),
+           xp.where(first, reach, 0).astype(xp.int32))
+    if not low:
+        return out
+    lens_win = xp.concatenate([lens, tail.astype(lens.dtype)])[win]
+    lowest = xp.where(same, lens_win, xp.iinfo(xp.int32).max).min(0)
+    return (*out, xp.where(first, lowest, 0).astype(xp.int32))
 
 
-def _ragged_paged_kernel(slot_ref, cnt_ref, reach_ref, tab_ref, live_ref,
-                         q_ref, vis_ref, k_hbm, v_hbm, o_ref,
-                         kbuf, vbuf, sem, m_scr, l_scr, acc_scr, *,
-                         page: int, pp: int, rp: int, tile_rows: int,
-                         short: int, scale: float):
+def _ragged_paged_kernel(*refs, page: int, pp: int, rp: int, tile_rows: int,
+                         short: int, scale: float, window=None):
     """One query tile of the ragged kernel: every unit of work of the
     tile (``ragged_units``) walks its slot's pages in a loop of dynamic
     length, ``pp`` pages a turn, copying the next turn's pages (or the
     next unit's first) while it computes on this turn's.  The online
-    softmax of a (row, page) is ``_paged_decode_kernel``'s."""
+    softmax of a (row, page) is ``_paged_decode_kernel``'s.
+
+    With ``window`` (a layer that attends the last ``window`` positions)
+    one more prefetched scalar a unit, ``first_ref``, is the page its
+    walk STARTS at: the one holding the lowest position any of its rows
+    attends.  No table entry under it is read and no page under it
+    copied (the engine has given those pages back), and a row masks
+    below ``visibility - window``."""
+    if window is None:
+        (slot_ref, cnt_ref, reach_ref, tab_ref, live_ref, q_ref, vis_ref,
+         k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr) = refs
+        first_ref = None
+    else:
+        (slot_ref, cnt_ref, reach_ref, first_ref, tab_ref, live_ref, q_ref,
+         vis_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_scr, l_scr,
+         acc_scr) = refs
     M = tile_rows * rp
     row0 = pl.program_id(0) * tile_rows
     # one past the launch's last live row: tiles past it have no work
     end = jnp.minimum(row0 + tile_rows, live_ref[0])
     n_rows = cnt_ref.shape[0]
 
-    def copies(slot, reach, blk, half):
+    def page_at(p0, blk, j):
+        """Index into the slot's table of page ``j`` of turn ``blk`` of
+        a walk that starts at page ``p0`` (None: at the table's first)."""
+        return blk * pp + j if p0 is None else p0 + blk * pp + j
+
+    def first_of(row):
+        return None if first_ref is None else first_ref[row]
+
+    def copies(slot, reach, p0, blk, half):
         """The turn's page copies, for ``start`` and ``wait`` alike;
         only the pages that hold a position under the unit's reach."""
         out = []
         for j in range(pp):
-            idx = blk * pp + j
+            idx = page_at(p0, blk, j)
             need = idx * page < reach
             phys = jnp.maximum(tab_ref[slot, idx], 0)
             out.append((need, [
@@ -473,21 +502,21 @@ def _ragged_paged_kernel(slot_ref, cnt_ref, reach_ref, tab_ref, live_ref,
                                       sem.at[half, 1, j])]))
         return out
 
-    def start(slot, reach, blk, half):
-        for need, pair in copies(slot, reach, blk, half):
+    def start(slot, reach, p0, blk, half):
+        for need, pair in copies(slot, reach, p0, blk, half):
             @pl.when(need)
             def _(pair=pair):
                 for c in pair:
                     c.start()
 
-    def wait(slot, reach, blk, half):
-        for need, pair in copies(slot, reach, blk, half):
+    def wait(slot, reach, p0, blk, half):
+        for need, pair in copies(slot, reach, p0, blk, half):
             @pl.when(need)
             def _(pair=pair):
                 for c in pair:
                     c.wait()
 
-    def walk(lo, n, slot, reach, nblk, nxt, has_next, half, w0, W):
+    def walk(lo, n, slot, reach, p0, nblk, nxt, has_next, half, w0, W):
         """All turns of one unit on the window of ``W`` sublanes at
         ``w0``; returns the buffer half the next unit starts in."""
         win = pl.ds(w0, W)
@@ -498,18 +527,19 @@ def _ragged_paged_kernel(slot_ref, cnt_ref, reach_ref, tab_ref, live_ref,
         vis = jnp.where((r >= lo) & (r < lo + n), vis_ref[win, :], 0)[None]
 
         def turn(blk, half):
-            wait(slot, reach, blk, half)
+            wait(slot, reach, p0, blk, half)
 
             @pl.when(blk + 1 < nblk)
             def _():
-                start(slot, reach, blk + 1, 1 - half)
+                start(slot, reach, p0, blk + 1, 1 - half)
 
             @pl.when((blk + 1 == nblk) & has_next)
             def _():
-                start(slot_ref[nxt], reach_ref[nxt], 0, 1 - half)
+                start(slot_ref[nxt], reach_ref[nxt], first_of(nxt), 0,
+                      1 - half)
 
             for j in range(pp):
-                first = (blk * pp + j) * page
+                first = page_at(p0, blk, j) * page
 
                 def compute(j=j, first=first):
                     k = kbuf[half, j]                     # [kvh, page, d]
@@ -523,6 +553,8 @@ def _ragged_paged_kernel(slot_ref, cnt_ref, reach_ref, tab_ref, live_ref,
                     kpos = first + jax.lax.broadcasted_iota(
                         jnp.int32, (1, 1, page), 2)
                     seen = kpos < vis                     # [1, W, page]
+                    if window is not None:
+                        seen = seen & (kpos >= vis - window)
                     s = jnp.where(seen, s, NEG_INF)
                     m_prev = m_scr[:, win, :1]
                     m_new = jnp.maximum(
@@ -560,16 +592,19 @@ def _ragged_paged_kernel(slot_ref, cnt_ref, reach_ref, tab_ref, live_ref,
         return jax.lax.fori_loop(0, nblk, turn, half)
 
     def unit(lo, n, half, started):
-        slot, reach = slot_ref[lo], reach_ref[lo]
-        nblk = (reach + pp * page - 1) // (pp * page)
+        slot, reach, p0 = slot_ref[lo], reach_ref[lo], first_of(lo)
+        if p0 is None:
+            nblk = (reach + pp * page - 1) // (pp * page)
+        else:
+            nblk = (reach - p0 * page + pp * page - 1) // (pp * page)
         nxt = jnp.minimum(lo + n, n_rows - 1)
         has_next = (lo + n < end) & (cnt_ref[nxt] > 0)
 
         @pl.when(started == 0)
         def _():
-            start(slot, reach, 0, half)
+            start(slot, reach, p0, 0, half)
 
-        args = (lo, n, slot, reach, nblk, nxt, has_next, half)
+        args = (lo, n, slot, reach, p0, nblk, nxt, has_next, half)
         if short:
             # a short run computes on a narrow aligned window
             w0 = ((lo - row0) * rp // _RAGGED_ALIGN) * _RAGGED_ALIGN
@@ -613,7 +648,8 @@ def _ragged_paged_kernel(slot_ref, cnt_ref, reach_ref, tab_ref, live_ref,
 
 def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
                             block_tables, scale=None, interpret=None,
-                            pages_per_step=None, tile_rows=None):
+                            pages_per_step=None, tile_rows=None,
+                            window=None):
     """Ragged paged flash attention: the serving plane's unified
     prefill+decode step (the Ragged Paged Attention kernel shape,
     PAPERS.md 2604.15464).
@@ -650,7 +686,15 @@ def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
     on a narrow window of the tile, so a step of decode rows from many
     slots is bound by their bytes.  Rows may come in any order and with
     any visibilities: the units are found from the ``slot`` column on
-    the device, once a step (every layer's launch shares them)."""
+    the device, once a step (every layer's launch shares them).
+
+    ``window=W`` is a layer that attends the last ``W`` positions: row r
+    attends ``row_lens[r] - W <= position < row_lens[r]``.  A unit's
+    walk then starts at the page that holds the lowest position any of
+    its rows attends; no table entry under that page is read and no page
+    under it copied, so those entries may name pages that have been
+    given back.  The launch is named ``ragged_paged_attention_window``
+    in a compiled program and a device trace."""
     T, h, d = q.shape
     kvh, page = key_cache.shape[1], key_cache.shape[2]
     if h % kvh != 0:
@@ -666,6 +710,10 @@ def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
         # the kernel: a row goes as a sequence of its own through the
         # grid kernel, pages by its index maps.  Interpret mode takes
         # the walk at every head dim
+        if window is not None:
+            raise ValueError(
+                f"a window layer of head dim {d} cannot be walked on the "
+                f"chip: the walk copies whole 128-lane rows")
         live = row_slot >= 0
         return paged_decode_raw(
             q, key_cache, value_cache, jnp.where(live, row_lens, 0),
@@ -683,17 +731,22 @@ def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
     # alignments of sublanes): the same units of work, less padding
     whole = max(1, _RAGGED_ALIGN // _padded_rep(h // kvh))
     tile_rows = min(int(tile_rows), -(-T // whole) * whole)
+    if window is not None and int(window) < 1:
+        raise ValueError(f"window {window!r}: a layer attends the last "
+                         f"window >= 1 positions")
     return _ragged_walk(q, key_cache, value_cache, row_lens, row_slot,
                         block_tables, scale=float(scale),
-                        interpret=bool(interpret), pp=pp, tq=int(tile_rows))
+                        interpret=bool(interpret), pp=pp, tq=int(tile_rows),
+                        window=None if window is None else int(window))
 
 
 # jitted on its own: a step's launches (one a layer) are then ONE traced
 # and lowered function called sixteen times, not sixteen kernels traced
 # and lowered one by one (5.7 s of a 16-layer step's lowering otherwise)
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "pp", "tq"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "pp", "tq",
+                                             "window"))
 def _ragged_walk(q, key_cache, value_cache, row_lens, row_slot, block_tables,
-                 *, scale, interpret, pp, tq):
+                 *, scale, interpret, pp, tq, window=None):
     T, h, d = q.shape
     kvh, page = key_cache.shape[1], key_cache.shape[2]
     rep = h // kvh
@@ -713,13 +766,21 @@ def _ragged_walk(q, key_cache, value_cache, row_lens, row_slot, block_tables,
     lens = jnp.minimum(jnp.pad(row_lens.astype(jnp.int32), (0, Tp - T)),
                        max_pages * page)
     lens = jnp.where(slots < 0, 0, lens)
-    count, reach = ragged_units(slots, lens, tq, jnp)
+    if window is None:
+        count, reach = ragged_units(slots, lens, tq, jnp)
+        units = (slots, count, reach)
+        tab_pad = -max_pages % pp
+    else:
+        count, reach, lowest = ragged_units(slots, lens, tq, jnp, low=True)
+        # the page a unit's walk starts at; its turns may run up to pp - 1
+        # entries past the table's padded width (never copied: past reach)
+        units = (slots, count, reach, jnp.maximum(lowest - window, 0) // page)
+        tab_pad = -max_pages % pp + pp
     live_end = jnp.max(jnp.where(slots >= 0, jnp.arange(Tp) + 1, 0),
                        keepdims=True).astype(jnp.int32)
     # the table's width padded to whole turns, so that a turn's page
     # index never leaves it (the padding is never copied: past the reach)
-    tables = jnp.pad(block_tables.astype(jnp.int32),
-                     ((0, 0), (0, -max_pages % pp)))
+    tables = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, tab_pad)))
     # [kvh, rows x heads, d]: a tile's rows of one KV head are one
     # matmul operand
     qg = jnp.pad(q.reshape(T, kvh, rep, d),
@@ -738,7 +799,7 @@ def _ragged_walk(q, key_cache, value_cache, row_lens, row_slot, block_tables,
                 + 3 * kvh * M * page * 4)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=len(units) + 2,
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((kvh, M, d), tile),
@@ -758,15 +819,17 @@ def _ragged_walk(q, key_cache, value_cache, row_lens, row_slot, block_tables,
     )
     out = pl.pallas_call(
         functools.partial(_ragged_paged_kernel, page=page, pp=pp, rp=rp,
-                          tile_rows=tq, short=short, scale=scale),
+                          tile_rows=tq, short=short, scale=scale,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=_sds((kvh, Tp * rp, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=max(16 << 20, 2 * resident)),
-        name=RAGGED_PAGED_KERNEL,
+        name=(RAGGED_PAGED_KERNEL if window is None
+              else RAGGED_PAGED_WINDOW_KERNEL),
         interpret=interpret,
-    )(slots, count, reach, tables, live_end, qg, vis, key_cache, value_cache)
+    )(*units, tables, live_end, qg, vis, key_cache, value_cache)
     out = out.reshape(kvh, Tp, rp, d)[:, :T, :rep]
     return out.transpose(1, 0, 2, 3).reshape(T, h, d)
 
@@ -792,7 +855,8 @@ def paged_flash_decoding_op(q, key_cache, value_cache, seq_lens,
 @register("ragged_paged_flash_decoding", amp="white")
 def ragged_paged_flash_decoding_op(q, key_cache, value_cache, row_lens,
                                    row_slot, block_tables, scale=None,
-                                   pages_per_step=None):
+                                   pages_per_step=None, window=None):
     return ragged_paged_decode_raw(q, key_cache, value_cache, row_lens,
                                    row_slot, block_tables, scale=scale,
-                                   pages_per_step=pages_per_step)
+                                   pages_per_step=pages_per_step,
+                                   window=window)

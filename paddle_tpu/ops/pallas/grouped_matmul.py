@@ -45,7 +45,11 @@ Contract (callers: parallel/expert.py dropless body, models/generation.py
   29 MB) takes the BLOCK-MAJOR form instead: the grid is (N tiles, row
   blocks), each row block reads its segment's weight id from a
   scalar-prefetched table, and with the N tile outermost a slice's tile
-  is fetched once however many row blocks its segment has.
+  is fetched once however many row blocks its segment has.  A caller
+  whose segments tile the rows densely (``dense=True``: the serving
+  step's sorted dispatch) takes it whatever the slice's size: its grid
+  is the row blocks alone, where the ``(S, nbmax)`` grid walks ``nbmax``
+  steps for every segment, nearly all of them parked.
 
 int8 expert banks: pass the raw quantized bank as ``w`` plus the
 per-(slice, out-channel) dequant scales ``w_scale`` [E, N] — the kernel
@@ -210,7 +214,7 @@ def _grouped_matmul_blocks(x, w, starts, lens, wids, bm: int, tn: int,
 
 def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
                        block_rows: int = 128, w_scale=None,
-                       interpret=None, tile_n=None):
+                       interpret=None, tile_n=None, dense: bool = False):
     """Ragged grouped matmul: ``y[start_s:start_s+len_s] =
     x[start_s:start_s+len_s] @ w[wid_s]`` for every segment ``s`` in one
     launch.  x [R, K] (R % block_rows == 0, see module contract);
@@ -218,7 +222,9 @@ def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
     w_scale [E, N] dequant scales for an int8 ``w``.  Returns y [R, N]
     in x's dtype (rows outside valid segments unspecified).  ``tile_n``
     forces the block-major form with that N tile (tests); left None the
-    slice's size decides."""
+    slice's size decides.  ``dense=True`` says the segments tile
+    ``[0, sum(align(len)))`` densely, as the block-major form needs, and
+    takes it with the slice's own tile (the slice whole where it fits)."""
     R, K = x.shape
     E, Kw, N = w.shape
     if Kw != K:
@@ -232,7 +238,7 @@ def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
     if R == 0 or S == 0:
         return jnp.zeros((R, N), x.dtype)
     tn = _tile_n(K, N, w.dtype.itemsize) if tile_n is None else int(tile_n)
-    if tn != N:
+    if tn != N or dense:
         return _grouped_matmul_blocks(
             x, w, seg_starts.astype(jnp.int32), seg_lens.astype(jnp.int32),
             seg_wids.astype(jnp.int32), bm, tn, w_scale, interpret)

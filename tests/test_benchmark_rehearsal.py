@@ -26,3 +26,14 @@ def test_serve_is_not_correct_when_a_committed_token_is_altered(
     assert line["correct"] is False
     monkeypatch.undo()
     assert _run(tiny_root, "tiny-serve.chat")["correct"] is True
+
+
+# PR 30: the Mellum2 cell's rehearsal (runner ``serve_mellum2`` at a tiny
+# size with two kinds of page, its four controls, its files and its
+# reader) runs with the tier-1 tests too
+from benchmarks.tests.test_mellum2_cell import (  # noqa: E402,F401
+    mellum2_root, test_a_mellum2_control_comes_out_not_correct,
+    test_kind_roofline_reader_counts_least_work_by_kind,
+    test_mellum2_sound_run_is_correct_and_hits_beyond_the_window,
+    test_the_mellum2_cell_is_not_under_the_one_kind_roofline,
+    test_the_real_mellum2_cell_loads_with_its_readers)

@@ -162,7 +162,7 @@ def test_selected_sets_equal_the_reference(model):
     tables[0, :3] = [5, 2, 9]
     for p, t in enumerate(prompt):
         rows[p] = (t, tables[0, p // PAGE], p % PAGE, p + 1, 0)
-    args = (*args[:3], jnp.asarray(rows), jnp.asarray(tables), *args[5:])
+    args = (*args[:3], jnp.asarray(rows), (jnp.asarray(tables),), *args[5:])
     _, _, (_, _, _, masks) = fn(*args, **kwargs, debug_select=True)
     _, want = ref.forward(params, jnp.asarray(prompt), ref_cfg(cfg, HELD))
     assert len(masks) == cfg.num_hidden_layers
@@ -201,7 +201,7 @@ def test_the_step_takes_and_returns_the_token_column(model, served):
         gather[:2] = (4, 2)
         kwargs = dict(kwargs, gather=jnp.asarray(gather),
                       prev_tokens=jnp.asarray(prev))
-        args = (*args[:3], jnp.asarray(rows), jnp.asarray(tables), *args[5:])
+        args = (*args[:3], jnp.asarray(rows), (jnp.asarray(tables),), *args[5:])
         _, _, (logits, tokens, counts) = fn(*args, **kwargs)
         assert tokens.dtype == jnp.int32 \
             and tokens.shape == (eng.gather_cap,)

@@ -56,7 +56,10 @@ def _valid_mask(R, starts, lens):
     [1, 1, 1, 1, 1, 1],   # many tiny segments
 ])
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
-def test_grouped_matmul_matches_dense_loop(lens, dtype):
+# dense: the block-major form, the slice whole (the serving step's
+# sorted dispatch asks for it: its segments tile the rows densely)
+@pytest.mark.parametrize("dense", [False, True], ids=["segments", "blocks"])
+def test_grouped_matmul_matches_dense_loop(lens, dtype, dense):
     rng = np.random.default_rng(0)
     bm, K, N = 8, 16, 24
     S = len(lens)
@@ -68,7 +71,7 @@ def test_grouped_matmul_matches_dense_loop(lens, dtype):
     wj = jnp.asarray(w, dtype)
     y = np.asarray(grouped_matmul_raw(
         xj, wj, jnp.asarray(starts), jnp.asarray(lens, jnp.int32),
-        jnp.asarray(wids), block_rows=bm), np.float32)
+        jnp.asarray(wids), block_rows=bm, dense=dense), np.float32)
     ref = _dense_reference(np.asarray(xj, np.float32),
                            np.asarray(wj, np.float32), starts, lens, wids)
     m = _valid_mask(R, starts, lens)

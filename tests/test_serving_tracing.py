@@ -438,7 +438,7 @@ def _optimized_hlo(eng):
                  static_argnames=("self_cfg_id", "pages_per_step", "with_head"))
     text = fn.lower(
         eng.params, eng.k_pages, eng.v_pages,
-        jnp.zeros((eng.rows_cap, 5), jnp.int32), jnp.asarray(eng.tables),
+        jnp.zeros((eng.rows_cap, 5), jnp.int32), (jnp.asarray(eng.tables),),
         eng.cos_tab, eng.sin_tab, self_cfg_id=eng.cfg_id,
         pages_per_step=eng.pages_per_step,
         gather=jnp.zeros(eng.gather_cap, jnp.int32)).compile().as_text()
@@ -458,3 +458,70 @@ def test_named_scopes_change_nothing_that_is_compiled(tiny, monkeypatch):
     without, scoped = _optimized_hlo(eng)
     assert not scoped
     assert with_scopes == without
+
+
+# ---- a layout of two kinds of page (window and full layers mixed) -------
+
+@pytest.fixture(scope="module")
+def traced_kinds(tmp_path_factory):
+    """One traced run of a small Mellum2-shaped engine: two kinds of
+    page, an expert layer in every layer."""
+    from paddle_tpu.models.mellum2 import Mellum2Config
+
+    cfg = Mellum2Config.debug()
+    rng = np.random.default_rng(0)
+    params = {k: jnp.asarray(
+        1.0 + 0.1 * rng.normal(size=s) if len(s) == 1
+        else 0.2 * rng.normal(size=s), jnp.float32)
+        for k, s in cfg.leaf_shapes().items()}
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=2, num_pages={"full": 33, "window": 13},
+        page_size=4, max_seq_len=64, prefill_token_budget=6,
+        enable_prefix_cache=True)
+    trace_dir = tmp_path_factory.mktemp("trace-kinds")
+    with _trace(trace_dir):
+        for n in (5, 30, 17):
+            eng.add_request(rng.integers(1, 96, n).astype(np.int32),
+                            max_new_tokens=5)
+        eng.run()
+        eng.step()
+    stats = eng.serving_stats()
+    eng.shutdown()
+    return {"spans": _program_spans(trace_dir), "stats": stats}
+
+
+@pytest.mark.parametrize("key", [
+    "attn_row_ctx_window", "kv_ctx_tokens_window", "window_pages_live",
+    "window_pages_recycled", "moe_rows_routed", "moe_rows_held",
+    "moe_experts_hit", "moe_experts_total", "moe_expert_rows_max"])
+def test_two_kinds_counts_add_up_to_serving_stats(traced_kinds, key):
+    kept = traced_kinds["stats"]["steps"][key]
+    counts = [c for *_, c in _named(traced_kinds, "serving.step_counts")]
+    if key.endswith("_max"):
+        assert kept == max(c.get(key, 0) for c in counts) > 0
+    else:
+        assert kept == sum(c.get(key, 0) for c in counts) > 0
+
+
+def test_two_kinds_counts_hold_together(traced_kinds):
+    counts = [c for *_, c in _named(traced_kinds, "serving.step_counts")]
+    busy = [c for c in counts if c["rows"]]
+    for c in busy:
+        # a window layer's least work is capped at the window (8)
+        assert c["attn_row_ctx_window"] <= min(c["attn_row_ctx"],
+                                               8 * c["rows"])
+        assert c["kv_ctx_tokens_window"] <= min(c["kv_ctx_tokens"],
+                                                8 * c["slots"])
+        # 4 expert layers of 8 experts, 2 copies a row and layer
+        assert c["moe_experts_total"] == 32
+        assert c["moe_rows_routed"] == c["moe_rows_held"] == 8 * c["rows"]
+        assert 0 < c["moe_experts_hit"] <= min(32, c["moe_rows_routed"])
+        assert c["moe_expert_rows_max"] <= c["rows"]
+    # both kinds' free pages ride on the marker; at the end all are back
+    assert all("free_pages_window" in c and "free_pages" in c
+               for c in counts)
+    # (what is not free at the end the prefix cache holds)
+    assert 0 < counts[-1]["free_pages_window"] <= 12
+    assert counts[-1]["window_pages_live"] == 0
+    assert any(c["window_pages_recycled"] for c in counts)
+    assert any(c["attn_row_ctx_window"] < c["attn_row_ctx"] for c in busy)

@@ -466,6 +466,93 @@ def test_deepseek_step_writes_latents_and_index_keys_in_place(one_chip,
             (pool, found, aliased)
 
 
+# the Mellum2 serving cell's engine (benchmarks/configs/
+# mellum2-12b-a2.5b-serve-l8.json): 32 slots of 195 pages of 128, a
+# 512-token prefill chunk, 7168 pages of the full kind and 1024 of the
+# window kind
+_M2_SLOTS, _M2_SEQ, _M2_BUDGET, _M2_FULL, _M2_WINDOW = (32, 24960, 512,
+                                                        7168, 1024)
+
+
+def test_mellum2_step_compiles_with_both_kinds_written_in_place(
+        one_chip, monkeypatch):
+    """PR 25's test for two kinds of page: one period of layers (three
+    window, one full) at the published widths and the cell's geometry.
+    The step holds both attention kernels under their own names and the
+    experts' grouped matmuls, copies or transposes no whole pool of
+    either kind, and updates every pool in the buffer it came in."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models.mellum2 import Mellum2Config
+    from paddle_tpu.ops.pallas import decode_attention, grouped_matmul
+
+    for mod in (decode_attention, grouped_matmul):
+        monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
+    cfg = Mellum2Config(num_hidden_layers=4)
+    params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+              for k, s in cfg.leaf_shapes().items()}
+    small = {"full": 24, "window": 20}
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=_M2_SLOTS, num_pages=small, page_size=128,
+        max_seq_len=_M2_SEQ, prefill_token_budget=_M2_BUDGET,
+        enable_prefix_cache=True)
+    assert [kp.bound for kp in eng.pages] == [None, 17]
+    fn, args, kwargs, _ = eng.analysis_entry()
+    assert args[3].shape == (_M2_SLOTS + _M2_BUDGET, 6)
+    assert [t.shape for t in args[4]] == [(_M2_SLOTS, 195)] * 2
+    real = {small["full"]: _M2_FULL, small["window"]: _M2_WINDOW}
+    tail = (cfg.num_key_value_heads, 128, cfg.head_dim)
+
+    def described(x):
+        shape = (real[x.shape[0]], *tail) if x.shape[1:] == tail else x.shape
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=one_chip)
+
+    static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step")}
+    text = fn.lower(*jax.tree.map(described, args), **static,
+                    **jax.tree.map(described, kwargs)).compile().as_text()
+    names = [m.group(1) for m in re.finditer(
+        r"%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call", text)]
+    assert names.count("ragged_paged_attention") == 1
+    assert names.count("ragged_paged_attention_window") == 3
+    assert names.count("grouped_matmul_blocks") == 3 * 4
+    pools = {(_M2_FULL, *tail): 2, (_M2_WINDOW, *tail): 6}
+    moved = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"(copy|transpose)\(", ln)
+        if m and any(np.prod([int(x) for x in m.group(1).split(",")])
+                     == np.prod(p) for p in pools):
+            moved.append(ln.strip()[:160])
+    assert not moved, "\n".join(moved)
+    entry = text[text.index("\nENTRY "):]
+    header = next(ln for ln in text.splitlines() if "HloModule" in ln)
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    for pool, n in pools.items():
+        dims = ",".join(map(str, pool))
+        found = {int(i) for i in re.findall(
+            rf"= \w+\[{dims}\]\S* parameter\((\d+)\)", entry)}
+        assert len(found) == n and found <= aliased, (pool, found, aliased)
+
+
+def test_one_kind_of_page_lowers_the_step_it_lowered(one_chip, monkeypatch):
+    """A Llama config has one kind of page, and its step is the program
+    it was before layouts had kinds: one table, five columns a row, the
+    ragged kernel under its one name with its five prefetched scalars,
+    nothing of the window walk.  (The text itself was diffed against
+    the parent commit's once: PERF.md section 6, PR 30.)"""
+    _, calls = _ragged_calls(64)            # before the backend is steered
+    for c in calls:
+        assert c.params["grid_mapping"].num_index_operands == 5
+    text, _, _ = _serving_step_text(one_chip, monkeypatch, jnp.bfloat16)
+    assert "ragged_paged_attention_window" not in text
+    entry = text[text.index("\nENTRY "):]
+    tables = re.findall(rf"= s32\[{_CELL_SLOTS},{-(-_CELL_SEQ // _CELL_PAGE)}\]"
+                        r"\S* parameter\(", entry)
+    rows = re.findall(rf"= s32\[{_CELL_SLOTS + _CELL_BUDGET},(\d+)\]\S* "
+                      r"parameter\(", entry)
+    assert len(tables) == 1 and rows == ["5"]
+
+
 def test_grouped_outer_compiles(one_chip):
     """The dW half of the grouped-matmul backward: (K, N) is tiled in
     the grid — held whole in fp32 it asked for 33 MB of 16 MB VMEM."""
