@@ -174,13 +174,20 @@ class DeepseekV32Config:
         return PagedLayout(
             name="latent", rows=((self.latent_row,), (self.index_head_dim,)),
             head_major=False, step=unified_step_jit,
-            row_counts=partial(_row_counts, self.index_topk),
+            row_counts=partial(_row_counts, self),
             device_counts=DEVICE_COUNTS,
             count_names=(*ROW_COUNTS, *DEVICE_COUNTS),
-            # 2048 keys a turn of the kernels' page walk: the best of 512,
-            # 1024 and 2048 on the chip (PERF.md section 6, PR 26)
-            pages_per_step=lambda page, pages_per_seq, itemsize:
-            max(1, 2048 // page))
+            pages_per_step=_pages_per_step)
+
+    @property
+    def walk_tile_rows(self) -> int:
+        """Packed rows a tile of the layer's two kernels (the attention's
+        shapes bound it; the index kernel takes the same, so a step's
+        units of work are the same in both)."""
+        from ..ops.pallas.sparse_mla import sparse_tile_rows
+
+        return sparse_tile_rows(self.num_attention_heads, self.latent_row,
+                                self.kv_lora_rank)
 
     def leaf_shapes(self) -> Dict[str, tuple]:
         """Every leaf of the functional state this model reads, by name
@@ -239,19 +246,39 @@ class DeepseekV32Config:
 #: what the step counts on the device, in the order it returns them
 DEVICE_COUNTS = ("moe_rows_held", "moe_rows_routed", "moe_expert_rows_max")
 #: what the packed rows give (``_row_counts``)
-ROW_COUNTS = ("index_row_ctx", "sel_row_tokens", "latent_ctx_tokens")
+ROW_COUNTS = ("index_row_ctx", "sel_row_tokens", "latent_ctx_tokens",
+              "attn_kv_tokens_read")
 
 
-def _row_counts(topk: int, rows: np.ndarray, ctx_tokens: int,
-                page_size: int, pages_per_seq: int) -> Dict[str, int]:
+def _pages_per_step(page: int, pages_per_seq: int, itemsize: int) -> int:
+    """2048 keys a turn of the kernels' page walk: the best of 512, 1024
+    and 2048 on the chip (PERF.md section 6, PR 26)."""
+    return max(1, 2048 // page)
+
+
+def _row_counts(cfg, rows: np.ndarray, ctx_tokens: int, page_size: int,
+                pages_per_seq: int) -> Dict[str, int]:
     """A step's counts that the packed rows give (host side):
     positions the indexer scores, positions attended after selection,
-    and the context each scheduled slot holds, once each (none of them
-    in whole pages, so the page geometry goes unread)."""
+    the context each scheduled slot holds, once each, and the positions
+    the kernels' walk FETCHES in one layer (``attn_kv_tokens_read``,
+    under the name and meaning it has on the Llama family's engines:
+    whole turns, a slot once for each of its units of work; over
+    ``latent_ctx_tokens`` it is the re-read factor.  At the layout's own
+    pages a turn: an engine given another counts as if it had not).
+    One function counts for both families: a turn of the walk here is
+    what a page is to the ragged kernel's."""
+    from ..inference.serving import ragged_kv_tokens_read
+    from ..ops.pallas.sparse_mla import walk_geometry
+
     vis = rows[:, 3]
+    _, keys, turns = walk_geometry(
+        page_size, pages_per_seq, _pages_per_step(page_size, pages_per_seq, 0))
+    read = ragged_kv_tokens_read(rows[:, 4], vis, cfg.walk_tile_rows, keys,
+                                 turns)
     return dict(zip(ROW_COUNTS, (int(vis.sum()),
-                                 int(np.minimum(vis, topk).sum()),
-                                 int(ctx_tokens))))
+                                 int(np.minimum(vis, cfg.index_topk).sum()),
+                                 int(ctx_tokens), read)))
 
 
 def _rope(x, cos, sin):
@@ -289,6 +316,7 @@ def attention_part(cfg, w, i, x, lat_pool, idx_pool, phys, off, lens, slot,
                      cfg.qk_rope_head_dim, cfg.v_head_dim)
     dc, dl, eps = cfg.kv_lora_rank, cfg.latent_row, cfg.rms_norm_eps
     Hi, di = cfg.index_n_heads, cfg.index_head_dim
+    walk = dict(pages_per_step=pages_per_step, tile_rows=cfg.walk_tile_rows)
     at = "self_attn."
     with jax.named_scope("mla_qkv"):
         xin = _rms_norm(x, w.layer(i, "input_layernorm.weight"), eps)
@@ -317,8 +345,7 @@ def attention_part(cfg, w, i, x, lat_pool, idx_pool, phys, off, lens, slot,
               ).astype(jnp.float32) * (Hi ** -0.5 * di ** -0.5)
         idx_pool = idx_pool.at[phys, off].set(ki.astype(idx_pool.dtype))
         scores = lightning_index_scores_raw(
-            qi, wi, idx_pool, lens, slot, tables,
-            pages_per_step=pages_per_step)
+            qi, wi, idx_pool, lens, slot, tables, **walk)
         sel = select_top_k(scores, cfg.index_topk)
     with jax.named_scope("sparse_attn"):
         wkvb = w.layer(i, at + "kv_b_proj.weight").reshape(dc, H, dn + dv)
@@ -329,8 +356,7 @@ def attention_part(cfg, w, i, x, lat_pool, idx_pool, phys, off, lens, slot,
              jnp.zeros((T, H, dl - dc - dr), jnp.float32)], axis=-1)
         qf = (qf * cfg.softmax_scale).astype(x.dtype)
         o_lat = sparse_mla_attention_raw(
-            qf, lat_pool, scores, sel, lens, slot, tables, dv=dc,
-            pages_per_step=pages_per_step)
+            qf, lat_pool, scores, sel, lens, slot, tables, dv=dc, **walk)
         o = jnp.einsum("thc,chd->thd", o_lat.astype(x.dtype), wkvb[..., dn:])
         x = x + o.reshape(T, H * dv) @ w.layer(i, at + "o_proj.weight")
     return x, lat_pool, idx_pool, (scores, sel)

@@ -677,7 +677,7 @@ def ragged_paged_decode_raw(q, key_cache, value_cache, row_lens, row_slot,
     consecutive rows of one slot) walks that slot's pages ONCE for all
     of its rows, in a loop of dynamic length as far as the unit's
     largest visibility, ``pages_per_step`` pages a turn with manual
-    double-buffered copies (``sparse_mla._page_walk``'s idea, for two
+    double-buffered copies (``sparse_mla._walk_tile``'s idea, for two
     pools); each row masks by its own visibility.  So a prefill chunk
     of 256 rows reads its context 8 times and not 256, a tile with no
     live row returns at once without a copy, and the engine's packing

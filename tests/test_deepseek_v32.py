@@ -368,6 +368,64 @@ def test_the_counters_against_hand_counts(served):
     assert set(DEVICE_COUNTS) <= set(st)
 
 
+def test_the_walk_reads_what_the_packing_counts(model, monkeypatch):
+    """Two decode rows and a 12-row chunk in one launch, tiles of 8: the
+    chunk is cut at the tile boundary into units of 6 and 6, so its slot
+    is fetched twice where a walk a row fetched it 12 times.  The count
+    is the kernels' own units' (``ragged_units`` at the layer's tile,
+    whole turns of ``walk_geometry``), rides ``serving.step_counts`` and
+    is summed in ``serving_stats()["steps"]``.  The engine runs one step
+    ahead: the call after the one that PACKS a launch commits it."""
+    from paddle_tpu.inference import serving
+    from paddle_tpu.ops.pallas.decode_attention import ragged_units
+
+    cfg, _ = model
+    assert cfg.walk_tile_rows == 8
+    eng = engine(model, prefill_token_budget=12)
+    rng = np.random.default_rng(3)
+    for n in (5, 9):
+        eng.add_request(rng.integers(0, VOCAB, n).astype(np.int32),
+                        max_new_tokens=4)
+    eng.step()
+    eng.step()                      # both prompts prefilled, 5 + 9 rows
+    eng.add_request(rng.integers(0, VOCAB, 12).astype(np.int32),
+                    max_new_tokens=4)
+    packed, marks = {}, []
+    pack, event = eng._pack_unified, serving.RecordEvent
+
+    def spy(*a):
+        out = pack(*a)
+        packed.setdefault("rows", out[0])
+        return out
+
+    def record(name, **kw):
+        if name == "serving.step_counts":
+            marks.append(kw)
+        return event(name, **kw)
+
+    eng._pack_unified = spy
+    monkeypatch.setattr(serving, "RecordEvent", record)
+    eng.step()
+    before = dict(eng.serving_stats()["steps"])
+    eng.step()
+    after = eng.serving_stats()["steps"]
+    rows = packed["rows"]
+    live = rows[rows[:, 4] >= 0]
+    assert len(live) == 14 and (live[:, 4] == live[2, 4])[2:].all()
+    count, reach = ragged_units(rows[:, 4], rows[:, 3], 8, np)
+    assert count[count > 0].tolist() == [1, 1, 6, 6]
+    _, nk, _ = sparse_mla.walk_geometry(PAGE, eng.pages_per_seq,
+                                        eng.pages_per_step)
+    want = int((-(-reach[count > 0] // nk)).sum()) * nk
+    assert want == 4 * 64               # a turn holds the table's 8 pages
+    assert after["attn_kv_tokens_read"] - before["attn_kv_tokens_read"] \
+        == want == marks[-1]["attn_kv_tokens_read"]
+    assert after["latent_ctx_tokens"] - before["latent_ctx_tokens"] \
+        == int(reach[count > 0][[0, 1, 3]].sum())
+    eng.run()
+    eng.shutdown()
+
+
 def test_yarn_tables_are_the_references(model):
     cfg, _ = model
     cos, sin = cfg.rope_tables()
@@ -399,23 +457,58 @@ def _paged_case(seed=0):
         qq=(rng.normal(size=(7, 4, dl)) * 0.3).astype(np.float32))
 
 
+def _chunks_case(seed=1):
+    """A step as the engine packs them, 28 rows over pages of 8: a
+    10-row chunk of slot 0 at visibilities 15..24 (it crosses two tile
+    boundaries at tiles of 4, and the edge of a turn of 8, 16 or 24
+    keys inside a unit), a 12-row chunk of slot 1 from position 0
+    beside it (two slots' chunks in one tile; its first rows lie whole
+    turns under the unit's reach), then a decode row, a padding row and
+    another slot's decode row, and three padding rows at the end (tiles
+    with no live row, whose blocks are not copied in)."""
+    rng = np.random.default_rng(seed)
+    P, page, di, dl = 16, 8, 16, 48
+    tables = np.full((4, 5), -1, np.int32)
+    tables[0, :3], tables[1, :2] = [3, 1, 7], [2, 4]
+    tables[2, :5], tables[3, :1] = [5, 6, 8, 10, 11], [9]
+    lens = np.concatenate([np.arange(15, 25), np.arange(1, 13),
+                           [33, 0, 5, 0, 0, 0]])
+    slot = np.concatenate([np.zeros(10), np.ones(12), [2, -1, 3, -1, -1, -1]])
+    T = len(lens)
+    return dict(
+        kp=rng.normal(size=(P, page, di)).astype(np.float32),
+        lp=rng.normal(size=(P, page, dl)).astype(np.float32),
+        tables=tables, lens=lens.astype(np.int32), slot=slot.astype(np.int32),
+        q=rng.normal(size=(T, 2, di)).astype(np.float32),
+        w=rng.normal(size=(T, 2)).astype(np.float32),
+        qq=(rng.normal(size=(T, 4, dl)) * 0.3).astype(np.float32))
+
+
 def _context(pool, tables, slot, n):
     return np.concatenate([pool[max(p, 0)] for p in tables[slot]])[:n]
 
 
 @pytest.mark.parametrize("pp", [1, 2, 3])
-def test_index_scores_selection_and_attention_kernels(pp):
-    """Two index heads: a score is exactly 0 whenever both products are
-    negative, so the k-th largest is often a tie, cut at the lower
-    positions as ``lax.top_k`` cuts it."""
-    c = _paged_case()
+@pytest.mark.parametrize("case, tile_rows", [
+    (_paged_case, None), (_chunks_case, 1), (_chunks_case, 2),
+    (_chunks_case, 4), (_chunks_case, 64)],
+    ids=["mixed-derived", "chunks-1", "chunks-2", "chunks-4", "chunks-64"])
+def test_index_scores_selection_and_attention_kernels(case, tile_rows, pp):
+    """The two kernels against dense numpy, a row at a time, whatever
+    the tile: every unit of work walks its slot's pages once and each
+    row keeps its own visibility, scores and cut.  Two index heads: a
+    score is exactly 0 whenever both products are negative, so the k-th
+    largest is often a tie, cut at the lower positions as ``lax.top_k``
+    cuts it."""
+    c = case()
+    T = len(c["lens"])
     j = {k: jnp.asarray(v) for k, v in c.items()}
     sc = sparse_mla.lightning_index_scores_raw(
         j["q"], j["w"], j["kp"], j["lens"], j["slot"], j["tables"],
-        pages_per_step=pp)
+        pages_per_step=pp, tile_rows=tile_rows)
     lens = np.where(c["slot"] < 0, 0, c["lens"])
     want = np.full(sc.shape, -np.inf, np.float32)
-    for r in range(7):
+    for r in range(T):
         keys = _context(c["kp"], c["tables"], c["slot"][r], lens[r])
         s = np.maximum(c["q"][r] @ keys.T, 0) * c["w"][r][:, None]
         want[r, :lens[r]] = s.sum(0)
@@ -427,9 +520,9 @@ def test_index_scores_selection_and_attention_kernels(pp):
     mask = np.asarray(sparse_mla.selected_mask(sc, sel, jnp.asarray(lens)))
     out = sparse_mla.sparse_mla_attention_raw(
         j["qq"], j["lp"], sc, sel, j["lens"], j["slot"], j["tables"], dv=32,
-        pages_per_step=pp)
+        pages_per_step=pp, tile_rows=tile_rows)
     ties = 0
-    for r in range(7):
+    for r in range(T):
         n = lens[r]
         keep = np.zeros(n, bool)
         if n:
@@ -447,6 +540,35 @@ def test_index_scores_selection_and_attention_kernels(pp):
                                    (p / p.sum(1, keepdims=True)) @ lat[:, :32],
                                    atol=1e-5)
     assert ties                              # the case does exercise the cut
+
+
+def test_the_units_of_a_packed_step_and_what_their_walk_fetches():
+    """The chunks pack at tiles of 4: a chunk is cut at every tile
+    boundary and where the slot changes, a decode row is a unit of one,
+    a padding row none; a unit fetches its slot as far as its largest
+    visibility in whole turns, and the layout's ``attn_kv_tokens_read``
+    is their sum at the layer's tile (8) and the layout's turn."""
+    from paddle_tpu.inference.serving import ragged_kv_tokens_read
+    from paddle_tpu.ops.pallas.decode_attention import ragged_units
+
+    c = _chunks_case()
+    count, reach = ragged_units(c["slot"], c["lens"], 4, np)
+    assert count[count > 0].tolist() == [4, 4, 2, 2, 4, 4, 2, 1, 1]
+    assert reach[count > 0].tolist() == [18, 22, 24, 2, 6, 10, 12, 33, 5]
+    for pp, want in ((1, 3 + 3 + 3 + 1 + 1 + 2 + 2 + 5 + 1),
+                     (2, 2 + 2 + 2 + 1 + 1 + 1 + 1 + 3 + 1),
+                     (16, 9)):            # clamped to the table's 5 pages
+        _, nk, turns = sparse_mla.walk_geometry(8, 5, pp)
+        assert ragged_kv_tokens_read(c["slot"], c["lens"], 4, nk, turns) \
+            == want * nk
+    # the layout's own count: tiles of 8, a turn the whole 5-page table,
+    # so a unit fetches 40 positions: 2 + 2 units of the chunks' 22 rows
+    # (rows 0..7, 8..9 | 10..15, 16..21) and the two decode rows
+    cfg = DeepseekV32Config.debug()
+    rows = np.zeros((len(c["lens"]), 5), np.int64)
+    rows[:, 3], rows[:, 4] = c["lens"], c["slot"]
+    got = cfg.paged_layout().row_counts(rows[c["slot"] >= 0], 0, 8, 5)
+    assert got["attn_kv_tokens_read"] == 6 * 40
 
 
 def test_kth_largest_without_a_sort():

@@ -171,6 +171,21 @@ def test_ragged_paged_decode_compiles_at_the_cells_geometry(one_chip):
         kernels=["ragged_paged_attention"])
 
 
+def _pallas_calls(jaxpr, name):
+    """The ``pallas_call`` equations named ``name`` in a jaxpr, however
+    deep (a kernel jitted on its own is an equation of an inner one)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" \
+                and eqn.params["name"] == name:
+            found.append(eqn)
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                found += _pallas_calls(inner, name)
+    return found
+
+
 def _ragged_calls(max_seq_len, layers=2):
     """The ``pallas_call``s named ``ragged_paged_attention`` in the
     jaxpr of a small engine's unified step (nothing is compiled)."""
@@ -188,21 +203,10 @@ def _ragged_calls(max_seq_len, layers=2):
         max_seq_len=max_seq_len, prefill_token_budget=120, pages_per_step=4)
     fn, args, kwargs, _ = eng.analysis_entry()
     static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step")}
-    found = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call" \
-                    and eqn.params["name"] == "ragged_paged_attention":
-                found.append(eqn)
-            for v in eqn.params.values():
-                inner = getattr(v, "jaxpr", v)
-                if hasattr(inner, "eqns"):
-                    walk(inner)
-
-    walk(jax.make_jaxpr(functools.partial(fn, **static))(*args, **kwargs
-                                                         ).jaxpr)
-    return eng, found
+    return eng, _pallas_calls(
+        jax.make_jaxpr(functools.partial(fn, **static))(*args, **kwargs
+                                                        ).jaxpr,
+        "ragged_paged_attention")
 
 
 def test_ragged_kernels_grid_is_the_query_tiles_alone():
@@ -378,18 +382,38 @@ def test_grouped_matmul_block_major_compiles(one_chip, k, n):
 _DS_ROWS, _DS_PAGES, _DS_PAGE, _DS_SLOTS, _DS_SEQ = 528, 3072, 128, 16, 24704
 
 
+def _ds_tile():
+    """The tile the wrappers derive at the cell's widths: 8 rows, 66
+    tiles of the 528-row step."""
+    from paddle_tpu.ops.pallas.sparse_mla import sparse_tile_rows
+
+    tile = sparse_tile_rows(128, 640, 512)
+    assert tile == sparse_tile_rows(64, 128) == 8 and _DS_ROWS % tile == 0
+    return tile
+
+
+def _compile_tiled(fn, one_chip, *shapes, kernel):
+    """Compile at the cell's geometry; the kernel's grid is the TILES of
+    packed rows alone (the page walk is inside it)."""
+    _compile(fn, one_chip, *shapes, kernels=[kernel])
+    (call,) = _pallas_calls(jax.make_jaxpr(fn)(
+        *[jax.ShapeDtypeStruct(s, d) for s, d in shapes]).jaxpr, kernel)
+    assert call.params["grid_mapping"].grid == (_DS_ROWS // _ds_tile(),)
+
+
 @pytest.mark.parametrize("pages_per_step", [4, 16])
 def test_lightning_index_scores_compiles(one_chip, pages_per_step):
     from paddle_tpu.ops.pallas.sparse_mla import lightning_index_scores_raw
 
-    _compile(lambda q, w, pool, lens, slot, tables:
-             lightning_index_scores_raw(q, w, pool, lens, slot, tables,
-                                        pages_per_step=pages_per_step,
-                                        interpret=False), one_chip,
-             ((_DS_ROWS, 64, 128), jnp.bfloat16), ((_DS_ROWS, 64), jnp.float32),
-             ((_DS_PAGES, _DS_PAGE, 128), jnp.bfloat16),
-             ((_DS_ROWS,), jnp.int32), ((_DS_ROWS,), jnp.int32),
-             ((_DS_SLOTS, 193), jnp.int32), kernels=["lightning_index_scores"])
+    _compile_tiled(
+        lambda q, w, pool, lens, slot, tables:
+        lightning_index_scores_raw(q, w, pool, lens, slot, tables,
+                                   pages_per_step=pages_per_step,
+                                   interpret=False), one_chip,
+        ((_DS_ROWS, 64, 128), jnp.bfloat16), ((_DS_ROWS, 64), jnp.float32),
+        ((_DS_PAGES, _DS_PAGE, 128), jnp.bfloat16),
+        ((_DS_ROWS,), jnp.int32), ((_DS_ROWS,), jnp.int32),
+        ((_DS_SLOTS, 193), jnp.int32), kernel="lightning_index_scores")
 
 
 @pytest.mark.parametrize("pages_per_step", [4, 16])
@@ -397,15 +421,16 @@ def test_sparse_mla_attention_compiles(one_chip, pages_per_step):
     from paddle_tpu.ops.pallas.sparse_mla import sparse_mla_attention_raw
 
     width = -(-193 // pages_per_step) * pages_per_step * _DS_PAGE
-    _compile(lambda q, pool, scores, sel, lens, slot, tables:
-             sparse_mla_attention_raw(q, pool, scores, sel, lens, slot, tables,
-                                      dv=512, pages_per_step=pages_per_step,
-                                      interpret=False), one_chip,
-             ((_DS_ROWS, 128, 640), jnp.bfloat16),
-             ((_DS_PAGES, _DS_PAGE, 640), jnp.bfloat16),
-             ((_DS_ROWS, width), jnp.float32), ((_DS_ROWS, 2), jnp.float32),
-             ((_DS_ROWS,), jnp.int32), ((_DS_ROWS,), jnp.int32),
-             ((_DS_SLOTS, 193), jnp.int32), kernels=["sparse_mla_attention"])
+    _compile_tiled(
+        lambda q, pool, scores, sel, lens, slot, tables:
+        sparse_mla_attention_raw(q, pool, scores, sel, lens, slot, tables,
+                                 dv=512, pages_per_step=pages_per_step,
+                                 interpret=False), one_chip,
+        ((_DS_ROWS, 128, 640), jnp.bfloat16),
+        ((_DS_PAGES, _DS_PAGE, 640), jnp.bfloat16),
+        ((_DS_ROWS, width), jnp.float32), ((_DS_ROWS, 2), jnp.float32),
+        ((_DS_ROWS,), jnp.int32), ((_DS_ROWS,), jnp.int32),
+        ((_DS_SLOTS, 193), jnp.int32), kernel="sparse_mla_attention")
 
 
 def test_deepseek_step_writes_latents_and_index_keys_in_place(one_chip,
