@@ -65,6 +65,31 @@ time, and nothing selects it from outside.
   of its pages whatever its context; the prefix cache holds a page of
   each kind a block and serves a hit as far as every kind is whole.  A
   layout of one kind runs the same code with a tuple of length one.
+- A model may hold a SECOND SORT of state beside its pages
+  (``PagedLayout.state``: Mamba-2's per-sequence SSM state and conv
+  tail): overwritten every token, so not paged, not addressed by
+  position and not shareable by reference.  The engine holds ONE pool
+  of entries a state layer for each of its arrays, donated through the
+  step like the page pools: an entry a slot, then the snapshot entries,
+  then a trash entry for the padding rows.  A packed row carries three
+  columns more: the entry its slot STARTS from (its own; a snapshot's
+  for a slot just admitted on a prefix hit; below zero, zeros, for a
+  fresh one), the entry the state is LEFT in, and, on a slot's last
+  row, the entry a snapshot of that state is copied to.  So admission,
+  restore and recycling are numbers in the one upload: no host copy,
+  no launch of their own that the run-ahead would wait for, no second
+  program.  The prefix cache keeps a snapshot at a block beside its
+  pages (taken where a prefill chunk ends on a page boundary:
+  ``_state_chunk`` cuts chunks so that they do) and serves a hit as far
+  as the deepest block that has BOTH; what the pages matched beyond it
+  is prefilled again (``state_lost_tokens``).  Snapshots have their own
+  budget (``state_snapshots``) and LRU.  A stale row writes its slot's
+  own entry, which the slot's next tenant never reads: it starts from
+  zeros or a snapshot, in a later launch.  Such a layout serves only
+  layers with pages through ``k_pages`` (one pool a layer that has
+  pages) and refuses what cannot carry state: a draft model, the int8
+  cache, the host tier, ``prefill_only``, ``adopt_request`` and
+  ``export_handoff``.
 - The page pools are PER-LAYER arrays, donated through the step, so a
   layer's cache update is one scatter into its own pool; a fused
   ``[L, pages, ...]`` slab cost a slice and a whole-layer update a
@@ -151,8 +176,10 @@ class _Launch:
     its tokens come back: ``metas`` ``(kind, slot, first gathered row,
     rows)`` a scheduled slot, ``gathered`` ``(rid, position)`` a
     gathered row, the step's ``counts`` for ``serving.step_counts``,
-    ``enc`` / ``dec`` for ``last_report``, the draft's ``props`` and
-    ``out``, the program's third result, still on the device."""
+    ``enc`` / ``dec`` for ``last_report``, the draft's ``props``,
+    ``out``, the program's third result, still on the device, and
+    ``snaps``: by slot, the ``(blocks, entry)`` of the state snapshot
+    the launch takes at the end of that slot's chunk."""
     metas: List[tuple]
     gathered: List[tuple]
     counts: Dict[str, int]
@@ -160,6 +187,7 @@ class _Launch:
     dec: np.ndarray
     props: Dict[int, tuple]
     out: Any = None
+    snaps: Dict[int, tuple] = dataclasses.field(default_factory=dict)
 
 
 def _softmax_np(logits: np.ndarray, temperature: float) -> np.ndarray:
@@ -227,11 +255,16 @@ class PageKind:
 
 @dataclasses.dataclass(frozen=True)
 class PagedLayout:
-    """What a model tells the engine of its paged state and of its part
-    of the unified step.  Every layer has TWO pools, and one page id
-    names a page in each pool of every layer OF ONE KIND, so slots,
-    tables, the allocators and the prefix cache never know what a page
-    holds.
+    """What a model tells the engine of what a sequence holds on the
+    device and of its part of the unified step.  Every layer THAT HAS
+    PAGES (a layer of some kind: all of them where ``kinds`` is empty)
+    has TWO pools, and one page id names a page in each pool of every
+    layer OF ONE KIND, so slots, tables, the allocators and the prefix
+    cache never know what a page holds.  A model may hold a second SORT
+    of state beside its pages (``state``): a recurrent state of fixed
+    size a sequence, in ``state_layers`` layers, which is overwritten
+    every token and so is neither addressed by position nor shared by
+    reference.
 
     ``kinds``: the kinds of page the model has (``PageKind``), empty for
     ONE kind that every layer shares.  The first kind retains every
@@ -259,10 +292,23 @@ class PagedLayout:
     itemsize)`` is how many pages the step's kernels take a turn of
     their page walk, where the constructor is given no number.
 
+    ``state``: ``(shape, dtype)`` of each array a slot's recurrent
+    state has in ONE state layer (dtype None: the cache's).  The engine
+    then holds, for each, one pool ``[entries, *shape]`` a state layer
+    (``ContinuousBatchingEngine.state``: a slot's own entry is its
+    number, then the snapshot entries, the last the trash entry),
+    passes them to ``step`` as ``state=`` (donated; the step returns
+    them as a FOURTH result) and gives every packed row three columns
+    more, after those of the kinds of page: the entry the row's slot
+    starts from (below zero: zeros), the entry its state is left in,
+    and, on a slot's last row, the entry a snapshot of that state is
+    copied to (below zero: none; at most ``state_snapshots_a_step`` a
+    launch).
+
     What the engine can do with K/V pages of one kind alone (a draft
     model's mirror, an int8 cache, the host tier, the prefill-only
-    handoff) refuses other pools, and more kinds than one, at
-    construction."""
+    handoff) refuses other pools, more kinds than one, and a recurrent
+    state, at construction."""
     name: str
     rows: tuple
     head_major: bool = True
@@ -272,6 +318,9 @@ class PagedLayout:
     count_names: tuple = ()             # row_counts' keys + device_counts
     pages_per_step: Any = None
     kinds: tuple = ()
+    state: tuple = ()
+    state_layers: int = 0
+    state_snapshots_a_step: int = 0
 
     def pool_shapes(self, num_pages: int, page_size: int):
         if self.head_major:
@@ -305,6 +354,15 @@ MOE_DEVICE_COUNTS = ("moe_rows_routed", "moe_rows_held",
 WINDOW_ROW_COUNTS = ("attn_row_ctx_window", "kv_ctx_tokens_window")
 #: what the engine counts of a window kind's pages at a commit
 WINDOW_PAGE_COUNTS = ("window_pages_live", "window_pages_recycled")
+#: what the engine counts a call where sequences hold a recurrent state
+#: (``PagedLayout.state``): snapshots the prefix cache holds, those this
+#: call's commit gave it and those evicted since the last call; of the
+#: requests this call admitted, the prompt tokens a snapshot restored
+#: and those the cache's pages matched beyond the deepest snapshot,
+#: which are prefilled again
+STATE_COUNTS = ("state_snapshots_live", "state_snapshots_taken",
+                "state_snapshots_evicted", "state_restored_tokens",
+                "state_lost_tokens")
 
 
 def page_kinds(cfg) -> tuple:
@@ -490,10 +548,15 @@ class _TrieNode:
     Where the model has further kinds of page (``PagedLayout.kinds``),
     ``more`` holds the block's page of each: a window kind's page, or
     None once it was evicted alone (or the prefill that committed the
-    block had already given it back)."""
+    block had already given it back).
+
+    Where sequences hold a recurrent state (``PagedLayout.state``),
+    ``snap`` is the entry of the state pools that holds the state AT the
+    end of this block (a snapshot; None: none was kept) and
+    ``snap_tick`` when it was last restored from."""
 
     __slots__ = ("children", "key", "page", "parent", "tick", "host_kv",
-                 "more")
+                 "more", "snap", "snap_tick")
 
     def __init__(self, key=None, page=None, parent=None, kinds: int = 0):
         self.children: Dict[tuple, "_TrieNode"] = {}
@@ -503,6 +566,8 @@ class _TrieNode:
         self.tick = 0
         self.host_kv = None
         self.more: List[Optional[int]] = [None] * kinds
+        self.snap: Optional[int] = None
+        self.snap_tick = 0
 
     @property
     def tier(self) -> str:
@@ -551,14 +616,31 @@ class PrefixCache:
     holds, never to something wrong.  A window kind's page may be
     evicted ALONE, from any block (``evict_window``): first those no
     possible hit can need (the run of blocks a hit would read is broken
-    already), then the least recently used."""
+    already), then the least recently used.
+
+    SNAPSHOTS of a recurrent state (``snaps``: the allocator of the
+    state pools' snapshot entries): a block may hold, beside its pages,
+    the entry with the state at its end.  Pages say what the attention
+    layers saw of a prefix, a snapshot what the recurrent layers made
+    of it, and a sequence can only go on from a point where it has
+    BOTH: ``lookup_all`` serves a hit as far as the deepest block of
+    the walk that has a snapshot, and says how many matched tokens lay
+    beyond it (they are prefilled again).  Snapshots have a budget and
+    an LRU of their own (``evict_snapshots``, by when one was last
+    restored from; ``snap_nodes`` is the few blocks that hold one, by
+    entry, so neither eviction nor the live count walks the trie); a
+    block that goes takes its snapshot along."""
 
     def __init__(self, page_size: int, alloc: PageAllocator, *,
                  host_tier_pages: int = 0, demote_fn=None,
-                 promote_fn=None, windows=()):
+                 promote_fn=None, windows=(), snaps=None):
         self.page_size = int(page_size)
         self.alloc = alloc
         self.windows = tuple(windows)       # (allocator, window) a kind
+        self.snaps: Optional[PageAllocator] = snaps
+        self.snap_nodes: Dict[int, _TrieNode] = {}  # entry -> its block
+        self.snapshots_taken = 0
+        self.evicted_snapshots = 0
         self.evicted_window_pages = 0
         self.root = _TrieNode()
         self._tick = 0
@@ -601,7 +683,7 @@ class PrefixCache:
         if self.windows:
             raise ValueError("a cache over several kinds of page is "
                              "asked through lookup_all")
-        pages, matched = self.lookup_all(prompt)
+        pages, matched, _, _ = self.lookup_all(prompt)
         return pages[0], matched
 
     def _first_read(self, blocks: int, window: int) -> int:
@@ -611,12 +693,17 @@ class PrefixCache:
         return max(0, blocks * self.page_size + 1 - window) // self.page_size
 
     def lookup_all(self, prompt):
-        """``lookup`` for every kind of page: ``(pages, matched_tokens)``
-        with ``pages[0]`` the first kind's pages of ``[0, matched)`` and
-        ``pages[k]`` the k-th kind's pages of the blocks from
-        ``_first_read`` to the hit's last, a ref acquired on each.  The
-        hit is the longest prefix of the walk whose window-kind pages
-        are all there."""
+        """``lookup`` for every kind of page and the recurrent state:
+        ``(pages, matched_tokens, snapshot entry or None, tokens matched
+        beyond it)`` with ``pages[0]`` the first kind's pages of ``[0,
+        matched)`` and ``pages[k]`` the k-th kind's pages of the blocks
+        from ``_first_read`` to the hit's last, a ref acquired on each.
+        The hit is the longest prefix of the walk whose window-kind
+        pages are all there.  In a cache with ``snaps`` it ends at the
+        deepest block of that prefix that holds a snapshot, a ref
+        acquired on the entry too: the caller gives it back once the
+        launch that reads it is committed.  Without, the last two are
+        ``None, 0``."""
         self.lookups += 1
         self._tick += 1
         limit = max(0, (len(prompt) - 1) // self.page_size)
@@ -654,13 +741,22 @@ class PrefixCache:
             if all(r >= j - self._first_read(j, w)
                    for r, (_, w) in zip(run, self.windows)):
                 blocks = j
+        snap, lost = None, 0
+        if self.snaps is not None:
+            whole = blocks
+            while blocks and path[blocks - 1].snap is None:
+                blocks -= 1
+            lost = (whole - blocks) * self.page_size
+            if blocks:
+                path[blocks - 1].snap_tick = self._tick
+                snap = self.snaps.acquire(path[blocks - 1].snap)
         # what lies past a hit that shrank is handed back
         self.alloc.release([n.page for n in path[blocks:]])
         pages = [[n.page for n in path[:blocks]]]
         for k, (alloc, w) in enumerate(self.windows):
             pages.append([alloc.acquire(n.more[k]) for n in
                           path[self._first_read(blocks, w):blocks]])
-        return pages, blocks * self.page_size
+        return pages, blocks * self.page_size, snap, lost
 
     def probe(self, prompt) -> int:
         """Matched FULL-PAGE tokens for ``prompt`` across BOTH tiers,
@@ -683,19 +779,24 @@ class PrefixCache:
             self.hits += 1
             self.hit_tokens += matched_tokens
 
-    def insert(self, prompt, pages, more=()) -> int:
+    def insert(self, prompt, pages, more=(), snaps=()) -> int:
         """Commit a completed prefill's FULL prompt pages.  New nodes
         acquire a trie reference on their page; existing nodes are left
         untouched (a concurrent prefill of the same prefix keeps its
         private copy, which simply frees when that request finishes).
         ``more[k]`` is ``(first block, pages)``: the k-th further kind's
         pages the slot still holds, from that block on; a block that
-        lacks its page of that kind, new or not, takes it.  Returns the
-        number of newly committed pages."""
+        lacks its page of that kind, new or not, takes it.  ``snaps``
+        is ``(blocks, entry)`` a state snapshot the prefill took: the
+        block that ends there takes the entry over (the caller's
+        reference becomes the trie's) unless it has one, in which case,
+        or where no such block is committed, the entry is given back.
+        Returns the number of newly committed pages."""
         self._tick += 1
         n = min(len(prompt) // self.page_size, len(pages))
         node = self.root
         added = 0
+        at = {int(b): int(e) for b, e in snaps}
         for i, key in enumerate(self._chunks(prompt, n)):
             child = node.children.get(key)
             if child is None:
@@ -709,7 +810,13 @@ class PrefixCache:
                     child.more[k] = self.windows[k][0].acquire(
                         int(held[i - first]))
             child.tick = self._tick
+            if child.snap is None and i + 1 in at:
+                child.snap, child.snap_tick = at.pop(i + 1), self._tick
+                self.snap_nodes[child.snap] = child
+                self.snapshots_taken += 1
             node = child
+        if at:
+            self.snaps.release(at.values())
         return added
 
     def _nodes(self):
@@ -760,12 +867,32 @@ class PrefixCache:
         return freed
 
     def _drop_more(self, node: _TrieNode) -> None:
-        """Give back a block's pages of the further kinds (the block
-        itself is going)."""
+        """Give back a block's pages of the further kinds and its state
+        snapshot (the block itself is going)."""
         for k, (alloc, _) in enumerate(self.windows):
             if node.more[k] is not None:
                 alloc.release([node.more[k]])
                 node.more[k] = None
+        if node.snap is not None:
+            self._drop_snapshot(node)
+
+    def _drop_snapshot(self, node: _TrieNode) -> None:
+        del self.snap_nodes[node.snap]
+        self.snaps.release([node.snap])
+        node.snap = None
+        self.evicted_snapshots += 1
+
+    def evict_snapshots(self, needed: int) -> int:
+        """Give back up to ``needed`` snapshot entries that only the trie
+        holds, the least recently restored-from first (the blocks stay,
+        with their pages: a later hit is served as far as the deepest
+        snapshot above them).  Returns entries freed."""
+        found = sorted((n for e, n in self.snap_nodes.items()
+                        if self.snaps.refs[e] == 1),
+                       key=lambda n: (n.snap_tick, n.snap))
+        for n in found[:max(needed, 0)]:
+            self._drop_snapshot(n)
+        return min(len(found), max(needed, 0))
 
     def evict_window(self, k: int, pages_needed: int) -> int:
         """Evict up to ``pages_needed`` pages of the k-th further kind
@@ -865,6 +992,7 @@ class PrefixCache:
             self._drop_more(n)
         self.root = _TrieNode()
         self.host_pages = 0
+        assert not self.snap_nodes
 
     def assert_consistent(self) -> None:
         """The checked trie/tier contract (hammer + teardown): every
@@ -908,12 +1036,29 @@ class PrefixCache:
             if dead:
                 raise AssertionError(
                     f"trie nodes hold dead pages {dead} of kind {k + 1}")
+        held = [n.snap for n in self._nodes() if n.snap is not None]
+        if len(set(held)) != len(held):
+            raise AssertionError("a state snapshot held by two trie nodes")
+        if {e: id(n) for e, n in self.snap_nodes.items()} != {
+                n.snap: id(n) for n in self._nodes() if n.snap is not None}:
+            raise AssertionError("snap_nodes is not the trie's snapshots")
+        dead = [e for e in held if self.snaps.refs[e] <= 0]
+        if dead:
+            raise AssertionError(f"trie nodes hold dead snapshots {dead}")
 
     @property
     def cached_pages(self) -> int:
         return sum(1 for n in self._nodes() if n.host_kv is None)
 
+    @property
+    def snapshots_live(self) -> int:
+        return len(self.snap_nodes)
+
     def stats(self) -> Dict[str, int]:
+        state = {} if self.snaps is None else {
+            "snapshots_live": self.snapshots_live,
+            "snapshots_taken": self.snapshots_taken,
+            "snapshots_evicted": self.evicted_snapshots}
         return {"lookups": self.lookups, "hits": self.hits,
                 "hit_tokens": self.hit_tokens,
                 "cached_pages": self.cached_pages,
@@ -923,7 +1068,7 @@ class PrefixCache:
                 "host_pages": self.host_pages,
                 "host_hits": self.host_hits,
                 "demoted_pages": self.demoted_pages,
-                "promoted_pages": self.promoted_pages}
+                "promoted_pages": self.promoted_pages, **state}
 
 
 class _KindPages:
@@ -980,7 +1125,10 @@ class ContinuousBatchingEngine:
     kind's name to its pool's pages (an int gives every kind that many);
     ``prefill_token_budget`` is the most prompt tokens a step carries
     (256: what the ledger's Mistral cell is timed at).  ``pages_per_step``
-    left unset is the layout's rule (``PagedLayout.pages_per_step``)."""
+    left unset is the layout's rule (``PagedLayout.pages_per_step``).
+    ``state_snapshots``: for a model whose sequences hold a recurrent
+    state (``PagedLayout.state``), the entries of the state pools that
+    keep snapshots for the prefix cache, beside an entry a slot."""
 
     def __init__(self, cfg, params, max_slots: int = 8,
                  num_pages: int = 64, page_size: int = 128,
@@ -991,7 +1139,8 @@ class ContinuousBatchingEngine:
                  draft_params=None, draft_cfg=None,
                  speculative_k: int = 0,
                  prefill_only: bool = False,
-                 host_tier_pages: int = 0):
+                 host_tier_pages: int = 0,
+                 state_snapshots: int = 0):
         from ..models.generation import _CFGS, register_config
 
         self.cfg = cfg
@@ -1029,11 +1178,21 @@ class ContinuousBatchingEngine:
                                               for k in kinds[1:]):
             raise ValueError("a layout's first kind of page retains every "
                              "position and each further kind a window")
-        if self.layout.name != "kv" or len(kinds) > 1:
+        if self.layout.state and len(kinds) > 1:
+            raise ValueError("a layout with a recurrent state has one kind "
+                             "of page")
+        if int(state_snapshots) and not (self.layout.state
+                                         and enable_prefix_cache):
+            raise ValueError("state_snapshots are what the prefix cache of "
+                             "a layout with a recurrent state keeps")
+        if self.layout.name != "kv" or len(kinds) > 1 or self.layout.state:
             # the step is all that knows these pools (PagedLayout); what
             # follows moves, mirrors or calibrates K/V pages of ONE kind
-            pools = (f"{self.layout.name} pools" if len(kinds) == 1 else
-                     f"{self.layout.name} pools of {len(kinds)} kinds of page")
+            # and knows of no state beside them
+            pools = (f"{self.layout.name} pools of {len(kinds)} kinds of page"
+                     if len(kinds) > 1 else
+                     f"{self.layout.name} pools beside a recurrent state"
+                     if self.layout.state else f"{self.layout.name} pools")
             for what, asked in (
                     ("a draft model: its mirror launches assume the "
                      "target's K/V geometry", draft_params is not None
@@ -1078,11 +1237,41 @@ class ContinuousBatchingEngine:
         # whole-layer dynamic-update per layer per step)
         # (whatever the layout's two pools hold, they go by k_pages and
         # v_pages here: for "latent", latent rows and index keys)
+        # (a layer of no kind has no pools: ``k_pages`` goes by the
+        # layers that have pages, in their order)
         kind_of = {i: kp for kp in self.pages for i in kp.kind.layers}
         shapes = [self.layout.pool_shapes(kind_of[i].num_pages,
-                                          self.page_size) for i in range(L)]
+                                          self.page_size)
+                  for i in sorted(kind_of)]
         self.k_pages = tuple(jnp.zeros(ka, dt) for ka, _ in shapes)
         self.v_pages = tuple(jnp.zeros(vb, dt) for _, vb in shapes)
+        # the SECOND sort of state: a pool ``[entries, *shape]`` a state
+        # layer for each array of a slot's recurrent state.  Entry s is
+        # slot s's own, the next ``state_snapshots`` are snapshots (the
+        # prefix cache's, by ``snap_alloc``), the last is the trash entry
+        # that padding rows name.  ``state_src[s]`` is the entry slot
+        # s's NEXT launch starts from: below zero (zeros) or a snapshot
+        # for a slot just admitted, its own once a launch was packed
+        self.state = None
+        self.state_snapshots = int(state_snapshots)
+        self.snap_alloc = PageAllocator(self.state_snapshots)
+        self.state_src = np.full(self.max_slots, -1, np.int32)
+        self.slot_snap_ref: Dict[int, int] = {}   # a slot's restore ref
+        self.slot_snaps: Dict[int, List[tuple]] = {}  # taken, not inserted
+        self._admitted_state = [0, 0]     # restored, lost since the marker
+        self._snaps_seen = [0, 0]         # taken, evicted at the marker
+        if self.layout.state:
+            entries = self.max_slots + self.state_snapshots + 1
+            self.state_trash = entries - 1
+            self.state = tuple(
+                tuple(jnp.zeros((entries, *shape), dt if sdt is None
+                                else jnp.dtype(sdt))
+                      for _ in range(self.layout.state_layers))
+                for shape, sdt in self.layout.state)
+        #: the packed rows' columns: 4 + one a kind of page + 3 of state
+        self.row_cols = 4 + len(self.pages) + (3 if self.layout.state else 0)
+        self.count_names = (*self.layout.count_names,
+                            *(STATE_COUNTS if self.layout.state else ()))
         # host-side slot state
         self.seq_lens = np.zeros(self.max_slots, np.int32)
         self.active = np.zeros(self.max_slots, bool)
@@ -1142,7 +1331,8 @@ class ContinuousBatchingEngine:
                        else None),
             promote_fn=(self._promote_page if self.host_tier_pages
                         else None),
-            windows=tuple((kp.alloc, kp.window) for kp in self.pages[1:]))
+            windows=tuple((kp.alloc, kp.window) for kp in self.pages[1:]),
+            snaps=self.snap_alloc if self.layout.state else None)
             if enable_prefix_cache else None)
         # static packed-row capacity of one launch: one decode row per
         # slot (k+1 under speculation) + the prefill chunk
@@ -1176,7 +1366,7 @@ class ContinuousBatchingEngine:
             ("steps", "rows", "rows_cap", "decode_rows", "prefill_rows",
              "ahead", "stale_rows", "admitted", "queue_wait_us",
              "queue_wait_us_max", "prefill_us", "prefill_us_max",
-             *self.layout.count_names), 0)
+             *self.count_names), 0)
         # spec telemetry: one entry per verify window, bounded so a
         # long-running server doesn't grow it without limit
         self.accepted_lengths: Deque[int] = deque(maxlen=65536)
@@ -1516,11 +1706,23 @@ class ContinuousBatchingEngine:
         (``cancel``)."""
         for kp in self.pages:
             kp.release(slot)
+        # the state entries the slot holds a reference on: the snapshot
+        # it was to restore from, those its chunks took and the trie has
+        # not been given, the one a chunk in flight is taking.  The
+        # slot's OWN entry needs no release: its next tenant starts from
+        # zeros or a snapshot, in a launch after any stale row's
+        taken = [e for _, e in self.slot_snaps.pop(slot, ())]
+        if slot in self.slot_snap_ref:
+            taken.append(self.slot_snap_ref.pop(slot))
+        if self._flight is not None and slot in self._flight.snaps:
+            taken.append(self._flight.snaps.pop(slot)[1])
+        self.snap_alloc.release(taken)
+        self.state_src[slot] = -1
         if self._flight is not None:
             # rows already enqueued for this slot are STALE: they run
             # (the device executes launches in order, so they write this
-            # slot's pages before any later launch can reuse them) and
-            # what they sample is never committed
+            # slot's pages, and its state entry, before any later launch
+            # can reuse them) and what they sample is never committed
             for m in self._flight.metas:
                 if m[1] == slot:        # a slot has one entry a launch
                     self._flight.counts["stale_rows"] += m[3]
@@ -1601,6 +1803,9 @@ class ContinuousBatchingEngine:
         carries the scheduler state the decode side needs (first
         sampled token, committed length, frozen int8 scales).  Pages
         stay reserved until ``release_handoff``."""
+        if self.layout.state:
+            raise ValueError("the KV handoff's wire format is K and V "
+                             "pages: it carries no recurrent state")
         info = self.handoff_ready[slot]
         npg = self._pages_needed(info["seq_len"])
         pg = jnp.asarray(np.asarray(self.slot_pages[slot][:npg],
@@ -1662,10 +1867,13 @@ class ContinuousBatchingEngine:
         if self.prefill_only:
             raise ValueError("adopt_request needs a decode-capable "
                              "engine")
-        if self.layout.name != "kv" or len(self.pages) > 1:
+        if self.layout.name != "kv" or len(self.pages) > 1 \
+                or self.layout.state:
             raise ValueError(f"{self.layout.name} pools"
                              + (f" of {len(self.pages)} kinds of page"
                                 if len(self.pages) > 1 else "")
+                             + (" beside a recurrent state"
+                                if self.layout.state else "")
                              + " do not support the KV handoff: the wire "
                              "format is K and V pages of one kind")
         plen = int(meta["seq_len"])
@@ -1813,12 +2021,17 @@ class ContinuousBatchingEngine:
             need = self._pages_needed(
                 plen if self.prefill_only else plen + req.max_new_tokens)
             shared: List[List[int]] = [[] for _ in self.pages]
-            matched = 0
+            matched, snap, lost = 0, None, 0
             if self.prefix_cache is not None:
-                shared, matched = self.prefix_cache.lookup_all(req.prompt)
+                # a hit is worth what BOTH sorts of state cover of it:
+                # pages and the deepest snapshot of the recurrent state
+                shared, matched, snap, lost = self.prefix_cache.lookup_all(
+                    req.prompt)
             if not self._reserve(need, shared):
                 for kp, pages in zip(self.pages, shared):
                     kp.alloc.release(pages)   # aborted hit: refs back
+                if snap is not None:
+                    self.snap_alloc.release([snap])
                 break               # head-of-line waits for pages
             self.queue.popleft()
             slot = free_slots[si]
@@ -1841,6 +2054,23 @@ class ContinuousBatchingEngine:
                     kp.tables[slot, :kp.lo[slot]] = kp.trash
                 lo = kp.lo[slot]
                 kp.tables[slot, lo:lo + len(kp.held[slot])] = kp.held[slot]
+            state_args = {}
+            if self.layout.state:
+                # the slot's first launch starts from the snapshot (the
+                # slot holds its reference until that launch is
+                # committed) or from zeros: no copy, no launch of its own
+                self.state_src[slot] = -1
+                if snap is not None:
+                    self.state_src[slot] = self.max_slots + snap
+                    self.slot_snap_ref[slot] = snap
+                self._admitted_state[0] += matched
+                self._admitted_state[1] += lost
+                # state_entry: where the request's state lives (its
+                # slot's own entry), and stays once it has ended until
+                # the slot's next tenant's first launch
+                state_args = {"state_restored_tokens": matched,
+                              "state_lost_tokens": lost,
+                              "state_entry": slot}
             self.active[slot] = True
             self.seq_lens[slot] = matched
             self.cur_tok[slot] = 0
@@ -1854,7 +2084,7 @@ class ContinuousBatchingEngine:
             self.prompt_lens[req.rid] = plen
             self.prefill_stats[req.rid] = {
                 "prompt_len": plen, "cached_tokens": matched,
-                "prefilled": 0}
+                "prefilled": 0, **state_args}
             if self.prefix_cache is not None:
                 self.prefix_cache.record_hit(matched)
             admitted.append((slot, plen))
@@ -1866,7 +2096,7 @@ class ContinuousBatchingEngine:
             tot["queue_wait_us_max"] = max(tot["queue_wait_us_max"], wait_us)
             with RecordEvent("serving.admit_request", rid=req.rid,
                              queue_wait_us=wait_us, prompt_len=plen,
-                             cached_tokens=matched):
+                             cached_tokens=matched, **state_args):
                 pass
         return admitted
 
@@ -2167,7 +2397,11 @@ class ContinuousBatchingEngine:
                     # of lowering at 16 layers (PERF.md, PR 24).  The
                     # tables are COPIED: a commit changes them while
                     # this launch may not have taken them yet
-                    self.k_pages, self.v_pages, new.out = \
+                    # (a layout with a recurrent state takes its pools
+                    # as ``state=`` and returns them fourth: the SAME
+                    # program reads a slot's entry, a snapshot's or
+                    # zeros, as the rows say)
+                    self.k_pages, self.v_pages, new.out, *state = \
                         self.layout.step(
                             self.params, self.k_pages, self.v_pages,
                             jnp.asarray(rows),
@@ -2179,7 +2413,11 @@ class ContinuousBatchingEngine:
                             kv_scales=self.kv_scales,
                             gather=jnp.asarray(gather),
                             prev_tokens=(queued[-1].out[1] if queued
-                                         else self._no_tokens))
+                                         else self._no_tokens),
+                            **({"state": self.state} if self.layout.state
+                               else {}))
+                    if state:
+                        (self.state,) = state
                     if self.draft is not None:
                         # mirror the SAME rows through the draft: its
                         # paged cache tracks the target's committed
@@ -2230,10 +2468,19 @@ class ContinuousBatchingEngine:
                     sum(kp.recycled for kp in windows))))
                 for kp in windows:
                     kp.recycled = 0
+            if self.layout.state:
+                pc = self.prefix_cache
+                now = [pc.snapshots_taken, pc.evicted_snapshots] \
+                    if pc is not None else [0, 0]
+                counts.update(zip(STATE_COUNTS, (
+                    pc.snapshots_live if pc is not None else 0,
+                    now[0] - self._snaps_seen[0],
+                    now[1] - self._snaps_seen[1], *self._admitted_state)))
+                self._snaps_seen, self._admitted_state = now, [0, 0]
             for k in ("rows", "rows_cap", "decode_rows", "prefill_rows",
                       "ahead", "stale_rows"):
                 tot[k] += counts[k]
-            for k in self.layout.count_names:
+            for k in self.count_names:
                 if k.endswith("_max"):
                     tot[k] = max(tot[k], counts.get(k, 0))
                 else:
@@ -2259,9 +2506,15 @@ class ContinuousBatchingEngine:
         the step's counts for ``serving.step_counts``."""
         enc = np.zeros(self.max_slots, np.int32)
         dec = np.zeros(self.max_slots, np.int32)
-        rows = np.zeros((self.rows_cap, 4 + len(self.pages)), np.int32)
+        rows = np.zeros((self.rows_cap, self.row_cols), np.int32)
         rows[:, 1] = self.trash_page
         rows[:, 4] = -1
+        stateful = bool(self.layout.state)
+        sc = 4 + len(self.pages)        # the first of the state columns
+        snaps: Dict[int, tuple] = {}
+        if stateful:
+            rows[:, sc:sc + 2] = self.state_trash
+            rows[:, sc + 2] = -1
         # consumed-row gather schedule: metas carry GATHERED offsets, so
         # the commit loop indexes the gathered tokens directly
         gather = np.zeros(self.gather_cap, np.int32)
@@ -2283,6 +2536,9 @@ class ContinuousBatchingEngine:
                 gathered.append((int(self.slot_rid[s]), p))
                 g += 1
                 r += 1
+            if stateful:
+                rows[r - len(window):r, sc:sc + 2] = (self.state_src[s], s)
+                self.state_src[s] = s
             dec[s] = base
             kv_ctx += base + len(window)
             metas.append(("verify", s, gstart, len(window)))
@@ -2292,6 +2548,8 @@ class ContinuousBatchingEngine:
             if left <= 0:
                 break
             chunk = min(len(pend), left)
+            if stateful:
+                chunk = self._state_chunk(base, chunk, len(pend))
             if self.more_pages:
                 self._map_pages(s, base + chunk)
             for j in range(chunk):
@@ -2299,6 +2557,13 @@ class ContinuousBatchingEngine:
                 rows[r, :5] = (int(pend[j]), self._phys(s, p),
                                p % self.page_size, p + 1, s)
                 r += 1
+            if stateful:
+                rows[r - chunk:r, sc:sc + 2] = (self.state_src[s], s)
+                self.state_src[s] = s
+                entry = self._snapshot_entry(s, base + chunk, len(snaps))
+                if entry is not None:
+                    snaps[s] = ((base + chunk) // self.page_size, entry)
+                    rows[r - 1, sc + 2] = self.max_slots + entry
             left -= chunk
             enc[s] = chunk
             dec[s] = base
@@ -2330,7 +2595,42 @@ class ContinuousBatchingEngine:
             rows[:r, k] = kp.tables[rows[:r, 4],
                                     (rows[:r, 3] - 1) // self.page_size]
         return rows, gather, _Launch(metas, gathered, counts, enc, dec,
-                                     props)
+                                     props, snaps=snaps)
+
+    def _state_chunk(self, base: int, chunk: int, pending: int) -> int:
+        """A prefill chunk of a sequence with a recurrent state, cut so
+        that it ENDS where a snapshot can be taken: a snapshot is the
+        state at a chunk's end, and the prefix cache can only use one at
+        a block's end.  A chunk that does not finish its prompt ends on
+        a multiple of the chunk budget where it reaches one (the same
+        positions for every request, so two prompts that share a prefix
+        take and find their snapshots at the same places), else on a
+        page boundary, else where the budget ends it."""
+        if chunk >= pending:
+            return chunk
+        page = self.page_size
+        for grid in (max(page, self._init_prefill_budget // page * page),
+                     page):
+            cut = (base + chunk) // grid * grid
+            if cut > base:
+                return cut - base
+        return chunk
+
+    def _snapshot_entry(self, slot: int, end: int, taken: int):
+        """The snapshot entry for the state of ``slot`` at position
+        ``end``, the end of the chunk being packed, or None: no prefix
+        cache, not the end of a block the prompt's insert will commit,
+        this launch's snapshots taken, or every entry in use by a
+        restore."""
+        pc = self.prefix_cache
+        prompt_len = self.prompt_lens[int(self.slot_rid[slot])]
+        if (pc is None or end % self.page_size or end > prompt_len
+                or taken >= self.layout.state_snapshots_a_step):
+            return None
+        entry = self.snap_alloc.alloc()
+        if entry is None and pc.evict_snapshots(1):
+            entry = self.snap_alloc.alloc()
+        return entry
 
     def _commit_unified(self, launch: _Launch, tokens: np.ndarray,
                         logits: Optional[np.ndarray],
@@ -2353,6 +2653,15 @@ class ContinuousBatchingEngine:
             req.chunks += 1
             self.seq_lens[s] += n
             self.prefill_stats[rid]["prefilled"] += n
+            if self.layout.state:
+                # the launch that read the snapshot the slot started
+                # from has run: its reference goes back; the snapshot
+                # this chunk took waits for the prompt's insert
+                if s in self.slot_snap_ref:
+                    self.snap_alloc.release([self.slot_snap_ref.pop(s)])
+                if s in launch.snaps:
+                    self.slot_snaps.setdefault(s, []).append(
+                        launch.snaps.pop(s))
             pend = self.pending_prompt[s]
             if n < len(pend):
                 self.pending_prompt[s] = pend[n:]
@@ -2365,7 +2674,8 @@ class ContinuousBatchingEngine:
             if self.prefix_cache is not None:
                 self.prefix_cache.insert(
                     req.prompt, self.slot_pages[s],
-                    [(kp.lo[s], kp.held[s]) for kp in self.pages[1:]])
+                    [(kp.lo[s], kp.held[s]) for kp in self.pages[1:]],
+                    snaps=self.slot_snaps.pop(s, ()))
             tok = (int(tokens[gstart]) if req.temperature <= 0
                    else self._sample_row(logits[gstart], req))
             prefill_us = int((time.perf_counter() - req.admitted) * 1e6)
@@ -2431,6 +2741,38 @@ class ContinuousBatchingEngine:
                     f"page leak at teardown: "
                     f"{kp.alloc.total - kp.alloc.available} pages still "
                     f"referenced")
+        self.snap_alloc.assert_consistent()
+        if self.snap_alloc.available != self.snap_alloc.total:
+            raise AssertionError(
+                f"state snapshot leak at teardown: "
+                f"{self.snap_alloc.total - self.snap_alloc.available} "
+                f"entries still referenced")
+
+    def assert_balanced(self) -> None:
+        """Every allocator consistent, and every state snapshot entry
+        that is live accounted for: the prefix cache's blocks hold one
+        reference each, a slot one on the snapshot it is to restore from
+        and one on each its chunks took that the trie has not been
+        given.  Callable mid-flight; after a drain nothing but the
+        cache's are left."""
+        for kp in self.pages:
+            kp.alloc.assert_consistent()
+        self.snap_alloc.assert_consistent()
+        refs = [0] * self.snap_alloc.total
+        if self.prefix_cache is not None:
+            self.prefix_cache.assert_consistent()
+            for e in self.prefix_cache.snap_nodes:
+                refs[e] += 1
+        held = [*self.slot_snap_ref.values(),
+                *(e for v in self.slot_snaps.values() for _, e in v),
+                *(e for _, e in (self._flight.snaps.values()
+                                 if self._flight is not None else ()))]
+        for e in held:
+            refs[e] += 1
+        if refs != self.snap_alloc.refs:
+            raise AssertionError(
+                f"state snapshot entries out of balance: held {refs}, "
+                f"counted {self.snap_alloc.refs}")
 
     def serving_stats(self) -> Dict[str, Any]:
         """Serving-plane telemetry: prefix-cache counters, per-request
@@ -2452,7 +2794,7 @@ class ContinuousBatchingEngine:
                 **{k: t[k] for k in ("steps", "rows", "rows_cap",
                                      "decode_rows", "prefill_rows",
                                      "ahead", "stale_rows", "admitted",
-                                     *self.layout.count_names)},
+                                     *self.count_names)},
                 "queue_wait_s": {"sum": t["queue_wait_us"] / 1e6,
                                  "max": t["queue_wait_us_max"] / 1e6},
                 "prefill_s": {"sum": t["prefill_us"] / 1e6,
@@ -2519,10 +2861,13 @@ class ContinuousBatchingEngine:
             report = paddle_tpu.analysis.check(
                 fn, *args, kwargs=kwargs, options=options)
         """
-        rows = np.zeros((self.rows_cap, 4 + len(self.pages)), np.int32)
+        rows = np.zeros((self.rows_cap, self.row_cols), np.int32)
         rows[:, 4] = -1
-        for k, kp in zip((1, *range(5, rows.shape[1])), self.pages):
+        for k, kp in zip((1, *range(5, 4 + len(self.pages))), self.pages):
             rows[:, k] = kp.trash
+        if self.layout.state:
+            rows[:, -3:-1] = self.state_trash
+            rows[:, -1] = -1
         kv_scales = self.kv_scales
         if kv_scales is None and self.cache_dtype == jnp.int8:
             # doctor sweep BEFORE the first admission calibrated: unit
@@ -2540,7 +2885,8 @@ class ContinuousBatchingEngine:
                       pages_per_step=self.pages_per_step,
                       kv_scales=kv_scales,
                       gather=jnp.zeros(self.gather_cap, jnp.int32),
-                      prev_tokens=self._no_tokens)
+                      prev_tokens=self._no_tokens,
+                      **({"state": self.state} if self.layout.state else {}))
         # min_bytes sized to the page pools, not the 1MB production
         # default: tiny test/debug engines must still FAIL the doctor if
         # the pools stop being donated (a vacuous gate passes when the

@@ -92,7 +92,7 @@ class _Weights:
     def is_moe_layer(self, i) -> bool:
         """A layer is MoE iff the checkpoint carries its stacked expert
         weights (sparse checkpoints may mix dense and MoE layers)."""
-        return f"model.layers.{i}.mlp.experts.gate_proj.weight" in self.p
+        return f"model.layers.{i}.mlp.experts.up_proj.weight" in self.p
 
     def expert(self, i, proj, idx):
         """Gather-then-dequant expert slices from the stacked
@@ -250,9 +250,12 @@ _MOE_ROUTES = {"softmax": _route_softmax_topk,
 @jax.named_scope("moe_experts")
 def _moe_experts(w: _Weights, i, x2, top_ids, top_p, lo, hi, e_all, stats):
     """The held experts' part of ``_moe_ffn``: the sorted ragged
-    dispatch of the token copies, three grouped matmuls, the weighted
-    combine.  ``x2`` [T, hidden]; ``top_ids`` / ``top_p`` [T, k] over
-    the router's ``e_all`` experts, of which [lo, hi) are in the bank."""
+    dispatch of the token copies, the experts' grouped matmuls, the
+    weighted combine.  ``x2`` [T, width]; ``top_ids`` / ``top_p`` [T, k]
+    over the router's ``e_all`` experts, of which [lo, hi) are in the
+    bank.  The expert's FORM is the config's: gated SwiGLU (gate, up,
+    down: three launches) unless it states ``mlp_hidden_act = "relu2"``
+    (up, squared relu, down: two; the bank has no ``gate_proj``)."""
     from ..ops.pallas.grouped_matmul import (align_rows,
                                              grouped_matmul_raw,
                                              segment_starts)
@@ -260,6 +263,7 @@ def _moe_experts(w: _Weights, i, x2, top_ids, top_p, lo, hi, e_all, stats):
     cfg = w.cfg
     pre = f"model.layers.{i}.mlp."
     e, k = hi - lo, top_ids.shape[-1]
+    gated = _gated(cfg)
     # ---- sorted ragged dispatch: copies argsorted by expert tile the
     # block-aligned segment windows the kernel contract wants.  A copy
     # of an absent expert sorts behind every segment (id ``e``), lands
@@ -318,9 +322,12 @@ def _moe_experts(w: _Weights, i, x2, top_ids, top_p, lo, hi, e_all, stats):
             dest = seg_st[sorted_ids] + pos
         xr = jnp.zeros((rpad, x2.shape[1]), x2.dtype).at[dest].set(
             x2[token_of])
-        gate = gmm(xr, "gate_proj")
-        up = gmm(xr, "up_proj")
-        eo = gmm(jax.nn.silu(gate) * up, "down_proj")     # [rpad, h]
+        if gated:
+            gate = gmm(xr, "gate_proj")
+            up = gmm(xr, "up_proj")
+            eo = gmm(jax.nn.silu(gate) * up, "down_proj")     # [rpad, h]
+        else:
+            eo = gmm(_relu2(gmm(xr, "up_proj")), "down_proj")
         # ---- combine: gather each copy's expert output, weighted
         # scatter-add back into token order
         ys = eo[dest]
@@ -340,8 +347,23 @@ def _moe_experts(w: _Weights, i, x2, top_ids, top_p, lo, hi, e_all, stats):
                     lambda: experts_of(tk))
 
 
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def _gated(cfg) -> bool:
+    """Whether the config's experts are gated SwiGLU (gate, up, down) or,
+    where it states ``mlp_hidden_act = "relu2"``, up, squared relu, down."""
+    return getattr(cfg, "mlp_hidden_act", None) != "relu2"
+
+
 @jax.named_scope("shared_expert")
 def _shared_expert(w: _Weights, i, x2):
+    """The always-on expert, in the routed experts' form and at its own
+    width (its leaves' shapes say which)."""
+    if not _gated(w.cfg):
+        su = x2 @ w.layer(i, "mlp.shared_expert.up_proj.weight")
+        return _relu2(su) @ w.layer(i, "mlp.shared_expert.down_proj.weight")
     sg = x2 @ w.layer(i, "mlp.shared_expert.gate_proj.weight")
     su = x2 @ w.layer(i, "mlp.shared_expert.up_proj.weight")
     return (jax.nn.silu(sg) * su) @ w.layer(
@@ -378,7 +400,10 @@ def _moe_ffn(w: _Weights, i, xm, stats=None):
     copy routed to an absent expert takes part in the gates'
     normalisation and adds nothing: what the other chips would add is
     left out, and nothing stands in for them.  A checkpoint with
-    ``mlp.shared_expert.*`` leaves adds that always-on SwiGLU once;
+    ``mlp.shared_expert.*`` leaves adds that always-on expert once, and
+    one with ``mlp.latent_down`` / ``mlp.latent_up`` runs the routed
+    experts in that latent (Nemotron-3's LatentMoE); the experts' form
+    is the config's (``_moe_experts``);
     ``mlp.router.bias`` is the selection bias of ``sigmoid_groups``.
     ``stats`` (a dict with the rows' ``valid`` mask) takes the layer's
     counts: copies routed, copies of held experts, the fullest expert;
@@ -394,7 +419,7 @@ def _moe_ffn(w: _Weights, i, xm, stats=None):
     e_all = int(router.shape[-1])
     lo, hi = getattr(cfg, "experts_held", None) or (0, e_all)
     e = hi - lo
-    bank_e = int(w.p[pre + "experts.gate_proj.weight"].shape[0])
+    bank_e = int(w.p[pre + "experts.up_proj.weight"].shape[0])
     if bank_e != e or not 0 <= lo < hi <= e_all:
         raise ValueError(
             f"layer {i}: router routes {e_all} experts, experts "
@@ -409,8 +434,17 @@ def _moe_ffn(w: _Weights, i, xm, stats=None):
         top_ids, top_p = _MOE_ROUTES[getattr(cfg, "moe_scoring", "softmax")](
             cfg, logits, w.p.get(pre + "router.bias"))    # [T, k] each
 
-    y = _moe_experts(w, i, x2, top_ids, top_p, lo, hi, e_all, stats)
-    if pre + "shared_expert.gate_proj.weight" in w.p:
+    if pre + "latent_down.weight" in w.p:
+        # LatentMoE: the routed experts live in a narrower latent,
+        # between two projections that every chip computes alike
+        with jax.named_scope("moe_latent_down"):
+            lat = x2 @ w.layer(i, "mlp.latent_down.weight")
+        y = _moe_experts(w, i, lat, top_ids, top_p, lo, hi, e_all, stats)
+        with jax.named_scope("moe_latent_up"):
+            y = y @ w.layer(i, "mlp.latent_up.weight")
+    else:
+        y = _moe_experts(w, i, x2, top_ids, top_p, lo, hi, e_all, stats)
+    if pre + "shared_expert.up_proj.weight" in w.p:
         y = y + _shared_expert(w, i, x2)
     return y.reshape(shape)
 
@@ -719,6 +753,11 @@ def generate(model, input_ids, max_new_tokens: int = 32,
             "generate() attends every layer's whole context and takes one "
             "rope table a config: a model that mixes window and full "
             "layers is served by inference.ContinuousBatchingEngine")
+    if hasattr(cfg, "paged_layout") and cfg.paged_layout().state:
+        raise NotImplementedError(
+            "generate() carries a K/V cache and nothing else from token to "
+            "token: a model whose layers keep a recurrent state is served "
+            "by inference.ContinuousBatchingEngine")
     max_new_tokens = int(max_new_tokens)
     if max_new_tokens <= 0:
         return Tensor(ids)

@@ -37,3 +37,14 @@ from benchmarks.tests.test_mellum2_cell import (  # noqa: E402,F401
     test_mellum2_sound_run_is_correct_and_hits_beyond_the_window,
     test_the_mellum2_cell_is_not_under_the_one_kind_roofline,
     test_the_real_mellum2_cell_loads_with_its_readers)
+
+
+# PR 33: the Nemotron-3-Super cell's rehearsal (runner ``serve_nemotron_h``
+# at a tiny size with a recurrent state beside its pages, its five
+# controls, its files and its reader) runs with the tier-1 tests too
+from benchmarks.tests.test_nemotron_h_cell import (  # noqa: E402,F401
+    nemotron_root, test_a_nemotron_control_comes_out_not_correct,
+    test_a_state_pool_of_another_type_is_not_correct,
+    test_nemotron_sound_run_is_correct_and_restores_its_preamble,
+    test_scan_roofline_reader_counts_least_work,
+    test_the_real_nemotron_cell_loads_with_its_readers)

@@ -314,7 +314,7 @@ def test_a_hit_shrinks_to_what_is_whole():
     node = dict(enumerate(_chain(cache)))
 
     def ask(matched, first):
-        pages, got = cache.lookup_all(prompt)
+        pages, got, _, _ = cache.lookup_all(prompt)
         assert got == matched and len(pages[0]) == matched // PAGE
         assert pages[1] == wp[first:matched // PAGE]
         full.release(pages[0]), win.release(pages[1])

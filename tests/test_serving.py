@@ -158,7 +158,7 @@ def test_engine_has_one_step_path(tiny_model):
 
     cfg, model, params = tiny_model
     sig = inspect.signature(ContinuousBatchingEngine.__init__).parameters
-    assert len(sig) == 3 + 14 and "decode_chunk_steps" not in sig
+    assert len(sig) == 3 + 15 and "decode_chunk_steps" not in sig
     assert "auto" not in [p.default for p in sig.values()]
     assert sig["prefill_token_budget"].default == 256 \
         and sig["page_size"].default == 128

@@ -525,3 +525,80 @@ def test_two_kinds_counts_hold_together(traced_kinds):
     assert counts[-1]["window_pages_live"] == 0
     assert any(c["window_pages_recycled"] for c in counts)
     assert any(c["attn_row_ctx_window"] < c["attn_row_ctx"] for c in busy)
+
+
+# ---- a layout with a recurrent state beside its pages -------------------
+
+@pytest.fixture(scope="module")
+def traced_state(tmp_path_factory):
+    """One traced run of a small Nemotron-H-shaped engine: a state entry
+    a slot, snapshots in the prefix cache, two requests that restore."""
+    from paddle_tpu.models.nemotron_h import NemotronHConfig
+
+    cfg = NemotronHConfig.debug(experts_held=(4, 12))
+    rng = np.random.default_rng(0)
+    params = {k: jnp.asarray(
+        np.ones(s) if k.endswith(".D") else np.log(rng.uniform(1, 16, s))
+        if k.endswith("A_log") else 1.0 + 0.1 * rng.normal(size=s)
+        if len(s) == 1 else 0.3 * rng.normal(size=s), jnp.float32)
+        for k, s in cfg.leaf_shapes().items()}
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=2, num_pages=40, page_size=4, max_seq_len=64,
+        prefill_token_budget=8, enable_prefix_cache=True, state_snapshots=2)
+    pre = rng.integers(1, 96, 17)
+    tasks = [rng.integers(1, 96, n) for n in (5, 9)]
+    trace_dir = tmp_path_factory.mktemp("trace-state")
+    with _trace(trace_dir):
+        for task in (*tasks, tasks[0]):
+            eng.add_request(np.concatenate([pre, task]).astype(np.int32),
+                            max_new_tokens=4)
+            eng.run()
+        eng.step()
+    stats = eng.serving_stats()
+    eng.assert_balanced()
+    eng.shutdown()
+    return {"spans": _program_spans(trace_dir), "stats": stats}
+
+
+@pytest.mark.parametrize("key", [
+    "ssm_state_slots", "ssm_rows", "ssm_prefill_rows",
+    "state_snapshots_live", "state_snapshots_taken",
+    "state_snapshots_evicted", "state_restored_tokens",
+    "moe_rows_routed", "moe_rows_held", "moe_experts_hit"])
+def test_state_counts_add_up_to_serving_stats(traced_state, key):
+    kept = traced_state["stats"]["steps"][key]
+    counts = [c for *_, c in _named(traced_state, "serving.step_counts")]
+    assert kept == sum(c.get(key, 0) for c in counts) > 0
+
+
+def test_state_counts_hold_together(traced_state):
+    counts = [c for *_, c in _named(traced_state, "serving.step_counts")]
+    for c in (c for c in counts if c["rows"]):
+        assert c["ssm_rows"] == c["rows"]
+        assert c["ssm_state_slots"] == c["slots"]
+        assert c["ssm_prefill_rows"] <= c["prefill_rows"]
+        assert c["state_snapshots_live"] <= 2
+        # 2 expert layers, 3 copies a row and layer, 8 of 16 experts held
+        assert c["moe_rows_routed"] == 6 * c["rows"]
+        assert c["moe_rows_held"] <= c["moe_rows_routed"]
+        assert c["moe_experts_total"] == 16
+    # the second and third requests restored the snapshot at 16 tokens;
+    # the third matched 20 tokens of pages, 4 of them beyond it
+    admits = [a for *_, a in _named(traced_state, "serving.admit_request")]
+    assert [(a["cached_tokens"], a["state_restored_tokens"],
+             a["state_lost_tokens"]) for a in admits] == [
+        (0, 0, 0), (16, 16, 0), (16, 16, 4)]
+    # where each request's state lives: its slot's own entry of the pools
+    prefill = traced_state["stats"]["prefill"]
+    assert [a["state_entry"] for a in admits] == [
+        prefill[a["rid"]]["state_entry"] for a in admits]
+    assert all(0 <= a["state_entry"] < 2 for a in admits)
+    steps = traced_state["stats"]["steps"]
+    assert steps["state_lost_tokens"] == 4
+    assert steps["state_restored_tokens"] == 32
+    # the first prompt's chunks end at 8 and 16, the second's at 24: three
+    # snapshots for two entries, so the least recently restored-from went
+    pc = traced_state["stats"]["prefix_cache"]
+    assert pc["snapshots_taken"] == steps["state_snapshots_taken"] == 3
+    assert pc["snapshots_evicted"] == steps["state_snapshots_evicted"] == 1
+    assert pc["snapshots_live"] == 2

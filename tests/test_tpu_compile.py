@@ -559,6 +559,78 @@ def test_mellum2_step_compiles_with_both_kinds_written_in_place(
         assert len(found) == n and found <= aliased, (pool, found, aliased)
 
 
+def test_ssd_scan_compiles_at_the_cells_geometry(one_chip):
+    """The Nemotron-3 cell's scan: 640 packed rows (128 slots and a
+    512-token chunk) in tiles of 128, 128 heads of 64 in 8 groups, a
+    state of 128, 161 state entries of 4.19 MB."""
+    from paddle_tpu.ops.pallas.ssd_scan import (SSD_SCAN_KERNEL,
+                                                mamba2_ssd_scan,
+                                                ssd_max_units)
+
+    T, H, P, G, N, E = 640, 128, 64, 8, 128, 161
+    ids = ((T,), jnp.int32)
+    _compile(lambda x, dt, a, B, C, pool, slot, lens, src, dst:
+             mamba2_ssd_scan(x, dt, a, B, C, pool, slot, lens, src, dst,
+                             tile_rows=128,
+                             max_units=ssd_max_units(T, 128, 128)),
+             one_chip, ((T, H, P), jnp.bfloat16), ((T, H), jnp.float32),
+             ((T, H), jnp.float32), ((T, G, N), jnp.bfloat16),
+             ((T, G, N), jnp.bfloat16), ((E, H, P, N), jnp.float32),
+             ids, ids, ids, ids, kernels=[SSD_SCAN_KERNEL])
+
+
+def test_nemotron_step_compiles_with_state_and_pages_written_in_place(
+        one_chip, monkeypatch):
+    """The Nemotron-H unified step (one M, one E and one * layer at the
+    published widths, 64 of 512 experts held) holds the three kernels of
+    the cell, copies or transposes no whole pool of either sort, and
+    every pool (K/V pages, SSM states, conv tails) is updated in the
+    buffer it came in."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models import nemotron_h
+    from paddle_tpu.ops.pallas import decode_attention, grouped_matmul
+
+    for mod in (nemotron_h, decode_attention, grouped_matmul):
+        monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
+    cfg = nemotron_h.NemotronHConfig(
+        num_hidden_layers=3, hybrid_override_pattern="ME*",
+        experts_held=(0, 64), vocab_size=16384)
+    params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+              for k, s in cfg.leaf_shapes().items()}
+    slots, pages, snaps = 8, 24, 2
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=slots, num_pages=pages, page_size=128,
+        max_seq_len=7680, prefill_token_budget=120, enable_prefix_cache=True,
+        state_snapshots=snaps)
+    fn, args, kwargs, _ = eng.analysis_entry()
+    assert args[3].shape == (128, 8)
+    entries = slots + snaps + 1
+    pools = {(pages, 2, 128, 128): 0, (entries, 128, 64, 128): 0,
+             (entries, 3, 10240): 0}
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step")}
+    text = fn.lower(*jax.tree.map(described, args), **static,
+                    **jax.tree.map(described, kwargs)).compile().as_text()
+    names = {m.group(1) for m in re.finditer(
+        r"%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call", text)}
+    assert {"mamba2_ssd_scan", "ragged_paged_attention",
+            "grouped_matmul_blocks"} <= names, names
+    moved = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                     r"(copy|transpose)\(", ln)
+        if m and any(np.prod([int(x) for x in m.group(1).split(",")])
+                     == np.prod(p) for p in pools):
+            moved.append(ln.strip()[:160])
+    assert not moved, "\n".join(moved)
+    header = next(ln for ln in text.splitlines() if "HloModule" in ln)
+    aliased = len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header))
+    assert aliased == 4                 # K, V, one SSM pool, one conv pool
+
+
 def test_one_kind_of_page_lowers_the_step_it_lowered(one_chip, monkeypatch):
     """A Llama config has one kind of page, and its step is the program
     it was before layouts had kinds: one table, five columns a row, the
