@@ -12,16 +12,30 @@ with its ``seq_lens_encoder`` / ``seq_lens_decoder`` /
 The host owns what is cheap and branchy: slots, page tables, the
 refcounted allocator, the radix prefix cache, admission, temperature
 sampling, speculative accept/reject, eviction.  The device runs ONE
-program of static shape a step, the model's ``PagedLayout.step`` (the
-Llama family's is ``_unified_step_jit``, DeepSeek-V3.2's its own under
-the same signature): a packed batch of ``rows_cap`` token rows from many
-sequences through one forward, with attention served by a ragged paged
-kernel whose cost follows the live rows, and the greedy token of every
+step function a model, the model's ``PagedLayout.step`` (the Llama
+family's is ``_unified_step_jit``, DeepSeek-V3.2's its own under the
+same signature): a packed batch of token rows from many sequences
+through one forward, with attention served by a ragged paged kernel
+whose cost follows the live rows, and the greedy token of every
 consumed row sampled at its end.  A row is a decode slot's token, one
 of the k+1 tokens of a speculative verify window, or one prompt token
 of a prefill chunk; at most ``prefill_token_budget`` prompt tokens ride
 a step, so a decode slot emits a token EVERY step whatever prompt is
-prefilled beside it.  Padding rows are the price of the static shape:
+prefilled beside it.  The step's one free parameter is its number of
+rows, read off its input, and where the layout states its kernels' row
+tile (``PagedLayout.tile_rows``: the Llama family's does; one that
+states none keeps the capacity alone) the engine compiles it at a short
+LADDER of row counts (``step_ladder``: every decode row; a quarter and
+a half of the prefill budget above them; the capacity ``rows_cap``),
+all of them before its first launch (``_padding_launches``), and
+launches each
+step at the smallest rung that holds the rows it packed: everything
+outside the kernels (embedding, norms, projections, the K/V scatters,
+the experts' dispatch) is XLA work at the launched size, so a step of 4
+decode rows does not pay for the 288 rows of a prefill chunk it does
+not hold.  Nothing else about a launch depends on the rung: the same
+rows in the same order, the same gather, the same tables, the same
+commit.  Padding rows are the price of a static shape, up to the rung:
 they compute garbage that is never read and write it to the TRASH page,
 the last physical page, which no slot owns.
 
@@ -51,9 +65,9 @@ made: the engine decides that from what it is serving, a step at a
 time, and nothing selects it from outside.
 
 - Everything the host tells the device rides in ONE int32 upload a
-  step: ``rows`` ``[rows_cap, 5]`` = (input token or reference, physical
-  page its K/V is written to, in-page offset, causal visibility, slot),
-  beside the page tables.
+  step: ``rows`` ``[rung, 5]`` (a rung of ``ladder``, not ``rows_cap``)
+  = (input token or reference, physical page its K/V is written to,
+  in-page offset, causal visibility, slot), beside the page tables.
 - A model may have several KINDS of page (``PagedLayout.kinds``: window
   and full attention layers mixed).  The engine then holds, for each
   kind, a pool size, an allocator, a table ``[slots, pages_per_seq]``
@@ -100,10 +114,11 @@ time, and nothing selects it from outside.
 - The CONSUMED rows alone (every verify-window row and each prefill
   chunk's final row) are gathered on the device before the final norm
   and the vocabulary projection: the head matmul and the fp32 logits
-  are sized to ``gather_cap``, not ``rows_cap``.  Their first maxima
-  (``sample_greedy``) are what a step copies back, 4 bytes a row; the
-  logits themselves cross only for a request with a temperature, or
-  when ``last_logits`` is read.
+  are sized to ``gather_cap`` whatever the rung, so a launch's sampled
+  tokens have one shape and launches of different rungs chain.  Their
+  first maxima (``sample_greedy``) are what a step copies back, 4 bytes
+  a row; the logits themselves cross only for a request with a
+  temperature, or when ``last_logits`` is read.
 
 Weight-only int8 params (models/generation.quantize_params_int8) run
 through the same program: dequant fuses into the consumer dots.  An
@@ -128,7 +143,11 @@ call (``serving.step_counts``: the counts of the launch the call
 COMMITS, with ``ahead`` and ``stale_rows``) and one per request at
 admission and at its first token (``serving.admit_request``,
 ``serving.first_token``) carry the counts.  The same counts are summed in
-``serving_stats()["steps"]`` whether or not anything traces.
+``serving_stats()["steps"]`` whether or not anything traces.  A step's
+``rows_cap`` count is the rows of the program LAUNCHED (the rung), so
+``rows`` over ``rows_cap`` says how full the launched shapes were, and
+``launches_by_rows`` how many launches each rung took; ``engine.rows_cap``
+stays the capacity.
 """
 
 from __future__ import annotations
@@ -291,6 +310,13 @@ class PagedLayout:
     ``count_names``.  ``pages_per_step(page_size, pages_per_seq,
     itemsize)`` is how many pages the step's kernels take a turn of
     their page walk, where the constructor is given no number.
+    ``tile_rows``: the packed rows a tile of the step's kernels holds,
+    where the step may be launched at any number of whole tiles: the
+    engine then compiles it at a ladder of row counts
+    (``step_ladder``).  0, the default, where the layout states none:
+    its step is compiled at the capacity alone, as ever (DeepSeek-V3.2's
+    and Nemotron-H's today: PERF.md section 6, PR 36, says what stands
+    in the way of each).
 
     ``state``: ``(shape, dtype)`` of each array a slot's recurrent
     state has in ONE state layer (dtype None: the cache's).  The engine
@@ -317,6 +343,7 @@ class PagedLayout:
     device_counts: tuple = ()
     count_names: tuple = ()             # row_counts' keys + device_counts
     pages_per_step: Any = None
+    tile_rows: int = 0
     kinds: tuple = ()
     state: tuple = ()
     state_layers: int = 0
@@ -429,7 +456,25 @@ def kv_layout(cfg) -> PagedLayout:
         count_names=("kv_ctx_tokens", "attn_kv_tokens_read",
                      *((*WINDOW_ROW_COUNTS, *WINDOW_PAGE_COUNTS)
                        if windows else ()), *device_counts),
-        pages_per_step=pages_per_step, kinds=kinds)
+        pages_per_step=pages_per_step, tile_rows=tile_rows, kinds=kinds)
+
+
+def step_ladder(decode_rows: int, prefill_budget: int,
+                tile_rows: int = 1) -> tuple:
+    """The row counts the engine's step is compiled at, ascending: the
+    decode rows alone, a quarter and a half of the prefill budget above
+    them, and the capacity, ``decode_rows + prefill_budget``; each
+    rounded up to whole tiles of the step's kernels and held to the
+    capacity.  A launch takes the smallest that holds its rows, so a
+    step of decode rows alone does not compute a prefill chunk's
+    padding.  (Mistral's cell: 32, 96, 160, 288.)  With no tile stated
+    (``tile_rows`` 0: ``PagedLayout``) the capacity alone."""
+    cap = decode_rows + prefill_budget
+    if not tile_rows:
+        return (cap,)
+    rungs = {min(cap, -(-(decode_rows + -(-prefill_budget * q // 4))
+                        // tile_rows) * tile_rows) for q in (0, 1, 2)}
+    return tuple(sorted(rungs | {cap}))
 
 
 def _counts_experts(cfg) -> bool:
@@ -1243,8 +1288,15 @@ class ContinuousBatchingEngine:
         shapes = [self.layout.pool_shapes(kind_of[i].num_pages,
                                           self.page_size)
                   for i in sorted(kind_of)]
-        self.k_pages = tuple(jnp.zeros(ka, dt) for ka, _ in shapes)
-        self.v_pages = tuple(jnp.zeros(vb, dt) for _, vb in shapes)
+        # (what the step takes from the device is COMMITTED to it from
+        # the start, as every result of the step is: a program called
+        # through ``kept_lowering`` is lowered again for an argument
+        # that turns from uncommitted to committed)
+        home = next(iter(jnp.zeros((), jnp.int32).devices()))
+        self.k_pages = tuple(jnp.zeros(ka, dt, device=home)
+                             for ka, _ in shapes)
+        self.v_pages = tuple(jnp.zeros(vb, dt, device=home)
+                             for _, vb in shapes)
         # the SECOND sort of state: a pool ``[entries, *shape]`` a state
         # layer for each array of a slot's recurrent state.  Entry s is
         # slot s's own, the next ``state_snapshots`` are snapshots (the
@@ -1265,7 +1317,7 @@ class ContinuousBatchingEngine:
             self.state_trash = entries - 1
             self.state = tuple(
                 tuple(jnp.zeros((entries, *shape), dt if sdt is None
-                                else jnp.dtype(sdt))
+                                else jnp.dtype(sdt), device=home)
                       for _ in range(self.layout.state_layers))
                 for shape, sdt in self.layout.state)
         #: the packed rows' columns: 4 + one a kind of page + 3 of state
@@ -1345,10 +1397,18 @@ class ContinuousBatchingEngine:
         # intermediate rows never reach the host)
         self.gather_cap = self.max_slots * (1 + self.spec_k) \
             + self.max_slots
+        # the row counts the step is compiled at (``step_ladder``): a
+        # launch takes the smallest that holds its rows.  All of them
+        # are compiled by the first launch (``_padding_launches``)
+        self.ladder = step_ladder(self.max_slots * (1 + self.spec_k),
+                                  self.prefill_budget,
+                                  self.layout.tile_rows)
+        self.launches_by_rows: Dict[int, int] = dict.fromkeys(self.ladder, 0)
+        self._programs: Optional[Dict[int, Any]] = None   # the step, by rung
         # what the first launch resolves token references against (it
-        # holds none): the same shape as a launch's sampled tokens, so
-        # the step is one compiled program
-        self._no_tokens = jnp.zeros(self.gather_cap, jnp.int32)
+        # holds none): the same shape as a launch's sampled tokens
+        # whatever the rung, so that launches of any two rungs chain
+        self._no_tokens = jnp.zeros(self.gather_cap, jnp.int32, device=home)
         # runtime degradation floors: throttle() may shed work but
         # never grow past the constructor's static shapes
         self._init_spec_k = self.spec_k
@@ -1502,8 +1562,9 @@ class ContinuousBatchingEngine:
         the Ragged Paged Attention paper: decode latency is bounded by
         the launch, not by any co-scheduled prompt's length.
 
-        ``rows`` is the packed host schedule, ONE int32 [rows_cap, 5]
-        upload per launch: columns (input token, physical page to write
+        ``rows`` is the packed host schedule, ONE int32 [T, 5] upload
+        per launch (T a rung of the engine's ladder, ``step_ladder``;
+        everything here is sized by ``rows.shape[0]``): columns (input token, physical page to write
         this token's K/V, in-page offset, causal visibility = absolute
         position + 1, page-table row / slot).  Padding rows carry
         slot -1 / visibility 0 and scatter into the trash page.
@@ -2391,41 +2452,49 @@ class ContinuousBatchingEngine:
                 # 1: enqueued before the launch before it was read
                 new.counts["ahead"] = len(queued)
                 with RecordEvent("serving.launch"):
-                    # called from HERE, not from a helper: JAX writes the
-                    # Python call stack into every operation's location,
-                    # and one more frame under the first call cost 0.9 s
-                    # of lowering at 16 layers (PERF.md, PR 24).  The
-                    # tables are COPIED: a commit changes them while
+                    launches = [(rows, gather, queued[-1].out[1] if queued
+                                 else self._no_tokens)]
+                    if self._programs is None:
+                        # the first launch of the engine's life: before
+                        # it, every rung once over padding rows, so that
+                        # each is compiled here and no launch ever
+                        # compiles again
+                        self._programs = self._step_programs()
+                        launches = [*self._padding_launches(), *launches]
+                    # called from HERE, not from a helper, the ladder's
+                    # first launches too: a tier-1 test holds the call
+                    # where it is (PERF.md section 6, PRs 24 and 36).
+                    # The tables are COPIED: a commit changes them while
                     # this launch may not have taken them yet
                     # (a layout with a recurrent state takes its pools
                     # as ``state=`` and returns them fourth: the SAME
                     # program reads a slot's entry, a snapshot's or
                     # zeros, as the rows say)
-                    self.k_pages, self.v_pages, new.out, *state = \
-                        self.layout.step(
-                            self.params, self.k_pages, self.v_pages,
-                            jnp.asarray(rows),
-                            tuple(jnp.asarray(kp.tables.copy())
-                                  for kp in self.pages),
-                            self.cos_tab, self.sin_tab,
-                            self_cfg_id=self.cfg_id,
-                            pages_per_step=self.pages_per_step,
-                            kv_scales=self.kv_scales,
-                            gather=jnp.asarray(gather),
-                            prev_tokens=(queued[-1].out[1] if queued
-                                         else self._no_tokens),
-                            **({"state": self.state} if self.layout.state
-                               else {}))
-                    if state:
-                        (self.state,) = state
-                    if self.draft is not None:
-                        # mirror the SAME rows through the draft: its
-                        # paged cache tracks the target's committed
-                        # stream (prefill chunks included), so the next
-                        # proposal round starts in sync — rejected-draft
-                        # positions land above the rolled-back length,
-                        # exactly like the target's own window writes
-                        self._draft_launch(rows, need_logits=False)
+                    for l_rows, l_gather, l_prev in launches:
+                        self.k_pages, self.v_pages, new.out, *state = \
+                            self._programs[len(l_rows)](
+                                self.params, self.k_pages, self.v_pages,
+                                jnp.asarray(l_rows),
+                                tuple(jnp.asarray(kp.tables.copy())
+                                      for kp in self.pages),
+                                self.cos_tab, self.sin_tab,
+                                kv_scales=self.kv_scales,
+                                gather=jnp.asarray(l_gather),
+                                prev_tokens=l_prev,
+                                **({"state": self.state}
+                                   if self.layout.state else {}))
+                        if state:
+                            (self.state,) = state
+                        if self.draft is not None:
+                            # mirror the SAME rows through the draft: its
+                            # paged cache tracks the target's committed
+                            # stream (prefill chunks included), so the
+                            # next proposal round starts in sync:
+                            # rejected-draft positions land above the
+                            # rolled-back length, exactly like the
+                            # target's own window writes
+                            self._draft_launch(l_rows, need_logits=False)
+                self.launches_by_rows[len(rows)] += 1
                 queued.append(new)
             cur = queued[0] if queued else None
             self._flight = queued[1] if len(queued) > 1 else None
@@ -2498,23 +2567,72 @@ class ContinuousBatchingEngine:
                 pass
         return produced
 
+    def _padding_rows(self, n: int) -> np.ndarray:
+        """``n`` packed rows that belong to no slot: they write the trash
+        page of every kind and the trash entry of the state pools, see
+        nothing and take no snapshot."""
+        rows = np.zeros((n, self.row_cols), np.int32)
+        rows[:, 4] = -1
+        for k, kp in zip((1, *range(5, 4 + len(self.pages))), self.pages):
+            rows[:, k] = kp.trash
+        if self.layout.state:
+            rows[:, -3:-1] = self.state_trash
+            rows[:, -1] = -1
+        return rows
+
+    def _step_programs(self) -> Dict[int, Any]:
+        """The model's step (``layout.step``) by rung of ``ladder``, each
+        a function of the step's arguments with ``self_cfg_id`` and
+        ``pages_per_step`` bound.  Every rung traces the kernels' bodies
+        again, seconds of Python each; where a persistent compile cache
+        is configured the rungs' lowerings are kept beside it
+        (``compile_cache.kept_lowering``), so that an engine that starts
+        again pays for none of them."""
+        from ..utils.compile_cache import kept_lowering
+
+        _, args, kwargs, _ = self.analysis_entry()
+        static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step")}
+        if len(self.ladder) == 1:
+            # one shape: JAX's own caches serve it, as they always have
+            return {self.rows_cap: partial(self.layout.step, **static)}
+        state = ("state",) if self.layout.state else ()
+        programs = {}
+        for n in self.ladder:
+            rung = (*args[:3], self._padding_rows(n), *args[4:])
+            programs[n] = kept_lowering(
+                self.layout.step, rung, kwargs, static, donate_argnums=(1, 2),
+                donate_argnames=state, what=repr(self.cfg))
+        return programs
+
+    def _padding_launches(self):
+        """One launch of padding rows a rung of ``ladder``: what
+        ``_step_unified`` launches before the engine's first launch, so
+        that every shape the step will ever take is compiled by then
+        (with a draft model, the proposals' launch too).  None where the
+        ladder has one rung: the first launch compiles it."""
+        if len(self.ladder) == 1:
+            return []
+        if self.draft is not None:
+            self._draft_launch(self._padding_rows(self.max_slots))
+        gather = np.zeros(self.gather_cap, np.int32)
+        return [(self._padding_rows(n), gather, self._no_tokens)
+                for n in self.ladder]
+
     def _pack_unified(self, decode, prefill, props: Dict[int, tuple]):
         """The packed row schedule of one launch (``_unified_step_jit``'s
         ``rows`` and ``gather``) for what ``_schedule`` found, and the
         ``_Launch`` that commits it: what each gathered row is, the
         commit loop's ``metas``, the prompt tokens scheduled by slot and
-        the step's counts for ``serving.step_counts``."""
+        the step's counts for ``serving.step_counts``.  ``rows`` has the
+        rows of the smallest rung of ``ladder`` that holds what was
+        packed (its ``rows_cap`` count); ``gather`` is ``[gather_cap]``
+        whatever the rung."""
         enc = np.zeros(self.max_slots, np.int32)
         dec = np.zeros(self.max_slots, np.int32)
-        rows = np.zeros((self.rows_cap, self.row_cols), np.int32)
-        rows[:, 1] = self.trash_page
-        rows[:, 4] = -1
+        rows = self._padding_rows(self.rows_cap)
         stateful = bool(self.layout.state)
         sc = 4 + len(self.pages)        # the first of the state columns
         snaps: Dict[int, tuple] = {}
-        if stateful:
-            rows[:, sc:sc + 2] = self.state_trash
-            rows[:, sc + 2] = -1
         # consumed-row gather schedule: metas carry GATHERED offsets, so
         # the commit loop indexes the gathered tokens directly
         gather = np.zeros(self.gather_cap, np.int32)
@@ -2574,8 +2692,10 @@ class ContinuousBatchingEngine:
             gathered.append((int(self.slot_rid[s]), base + chunk - 1))
             metas.append(("prefill", s, g, chunk))
             g += 1
+        # the launched step's rows: the smallest rung that holds them
+        rung = next(n for n in self.ladder if n >= r)
         counts = {
-            "rows": r, "rows_cap": self.rows_cap,
+            "rows": r, "rows_cap": rung,
             "decode_rows": decode_rows, "prefill_rows": r - decode_rows,
             "slots": len(metas), "gathered": g,
             # whether the launch was enqueued before the one before it
@@ -2591,11 +2711,10 @@ class ContinuousBatchingEngine:
         # the page a row writes in each further kind of page, from that
         # kind's table (columns 5..; a padding row's is the kind's trash)
         for k, kp in enumerate(self.more_pages, 5):
-            rows[:, k] = kp.trash
             rows[:r, k] = kp.tables[rows[:r, 4],
                                     (rows[:r, 3] - 1) // self.page_size]
-        return rows, gather, _Launch(metas, gathered, counts, enc, dec,
-                                     props, snaps=snaps)
+        return rows[:rung], gather, _Launch(metas, gathered, counts, enc,
+                                            dec, props, snaps=snaps)
 
     def _state_chunk(self, base: int, chunk: int, pending: int) -> int:
         """A prefill chunk of a sequence with a recurrent state, cut so
@@ -2795,6 +2914,7 @@ class ContinuousBatchingEngine:
                                      "decode_rows", "prefill_rows",
                                      "ahead", "stale_rows", "admitted",
                                      *self.count_names)},
+                "launches_by_rows": dict(self.launches_by_rows),
                 "queue_wait_s": {"sum": t["queue_wait_us"] / 1e6,
                                  "max": t["queue_wait_us_max"] / 1e6},
                 "prefill_s": {"sum": t["prefill_us"] / 1e6,
@@ -2848,11 +2968,11 @@ class ContinuousBatchingEngine:
     def analysis_entry(self):
         """(fn, args, kwargs, options) for ``paddle_tpu.analysis.check``
         over the step program: the SAME jit the scheduler launches
-        (``layout.step``), at its static row capacity (decode rows +
-        spec windows + a full prefill chunk).  ``options`` declares the
-        donation contract: params and the rope tables persist across
-        steps BY DESIGN (every step re-reads them; donating would force
-        a re-upload), while the page pools are donated through the
+        (``layout.step``), at the TOP rung of its ladder, its row
+        capacity (decode rows + spec windows + a full prefill chunk).
+        ``options`` declares the donation contract: params and the rope
+        tables persist across steps BY DESIGN (every step re-reads
+        them; donating would force a re-upload), while the page pools are donated through the
         program (donate_argnums=(1, 2)) and the doctor verifies that
         stays true; the packed row schedule and page table are per-step
         uploads (small int32, below the donation floor by construction).
@@ -2861,13 +2981,7 @@ class ContinuousBatchingEngine:
             report = paddle_tpu.analysis.check(
                 fn, *args, kwargs=kwargs, options=options)
         """
-        rows = np.zeros((self.rows_cap, self.row_cols), np.int32)
-        rows[:, 4] = -1
-        for k, kp in zip((1, *range(5, 4 + len(self.pages))), self.pages):
-            rows[:, k] = kp.trash
-        if self.layout.state:
-            rows[:, -3:-1] = self.state_trash
-            rows[:, -1] = -1
+        rows = self._padding_rows(self.rows_cap)
         kv_scales = self.kv_scales
         if kv_scales is None and self.cache_dtype == jnp.int8:
             # doctor sweep BEFORE the first admission calibrated: unit

@@ -1,0 +1,209 @@
+"""Toy engines of the four layouts whose derived ladders have several
+rungs (the kernels' row tile is 8 or 16 at these widths; four rungs for
+the Llama-shaped engine, two for Mellum2's, whose step compiles slowest
+here, and the ONE that DeepSeek's and Nemotron-H's layouts keep while
+they state no tile), the mixed
+trace they serve, and the check that a ladder serves what the top rung
+serves: shared by ``test_serving_ladder.py`` and
+``test_serving_ladder_kinds_state.py`` (two files, so that two workers
+share the compiles)."""
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.inference.serving import ContinuousBatchingEngine
+
+# ---- the four layouts at toy widths ------------------------------------
+
+def _draw(cfg, seed, scale=0.08):
+    rng = np.random.default_rng(seed)
+    out = {}
+    for name, shape in cfg.leaf_shapes().items():
+        if name.endswith(("norm.weight", "layernorm.weight")):
+            out[name] = jnp.ones(shape, jnp.float32)
+        elif name.endswith(".A_log"):
+            out[name] = jnp.asarray(np.log(rng.uniform(1, 4, shape)),
+                                    jnp.float32)
+        elif name.endswith((".dt_bias", ".D", ".bias")):
+            out[name] = jnp.asarray(rng.normal(0, 0.1, shape), jnp.float32)
+        else:
+            out[name] = jnp.asarray(rng.normal(0, scale, shape), jnp.float32)
+    return out
+
+
+def _llama():
+    """A Llama-shaped engine with a draft model: verify windows of 3."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.models.generation import self_draft_params
+
+    state = paddle.get_rng_state()
+    paddle.seed(36)
+    cfg = LlamaConfig.debug(vocab=64, hidden=64, layers=2, heads=8,
+                            kv_heads=1, inter=64, max_pos=256)
+    model = LlamaForCausalLM(cfg)
+    params = {k: jnp.asarray(v) for k, v in model.functional_state().items()}
+    paddle.set_rng_state(state)
+    dcfg, dparams = self_draft_params(cfg, params, 1)
+    return lambda: ContinuousBatchingEngine(
+        cfg, params, max_slots=2, num_pages=40, page_size=16,
+        max_seq_len=256, prefill_token_budget=64, enable_prefix_cache=True,
+        draft_cfg=dcfg, draft_params=dparams, speculative_k=2)
+
+
+def _small():
+    """One Llama layer, no draft model: the cheapest engine whose ladder
+    has more rungs than one."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    state = paddle.get_rng_state()
+    paddle.seed(37)
+    cfg = LlamaConfig.debug(vocab=64, hidden=64, layers=1, heads=8,
+                            kv_heads=1, inter=64, max_pos=128)
+    model = LlamaForCausalLM(cfg)
+    params = {k: jnp.asarray(v) for k, v in model.functional_state().items()}
+    paddle.set_rng_state(state)
+    return lambda: ContinuousBatchingEngine(
+        cfg, params, max_slots=3, num_pages=40, page_size=16,
+        max_seq_len=128, prefill_token_budget=32, enable_prefix_cache=True)
+
+
+def _mellum2():
+    from paddle_tpu.models.mellum2 import Mellum2Config
+
+    cfg = Mellum2Config.debug(
+        num_attention_heads=16, num_key_value_heads=1, num_hidden_layers=2,
+        layer_types=("sliding_attention", "full_attention"), num_experts=4)
+    params = _draw(cfg, 1)
+    return lambda: ContinuousBatchingEngine(
+        cfg, params, max_slots=3, num_pages={"full": 60, "window": 24},
+        page_size=4, max_seq_len=128, prefill_token_budget=8,
+        enable_prefix_cache=True)
+
+
+def _deepseek():
+    from paddle_tpu.models.deepseek_v32 import DeepseekV32Config
+
+    cfg = DeepseekV32Config.debug(experts_held=(4, 8))
+    params = _draw(cfg, 2)
+    return lambda: ContinuousBatchingEngine(
+        cfg, params, max_slots=3, num_pages=60, page_size=8,
+        max_seq_len=128, prefill_token_budget=32, enable_prefix_cache=True)
+
+
+def _nemotron():
+    from paddle_tpu.models.nemotron_h import NemotronHConfig
+
+    cfg = NemotronHConfig.debug(experts_held=(4, 12), num_attention_heads=16,
+                                num_key_value_heads=1, num_hidden_layers=3,
+                                hybrid_override_pattern="ME*")
+    params = _draw(cfg, 3)
+    return lambda: ContinuousBatchingEngine(
+        cfg, params, max_slots=3, num_pages=80, page_size=4,
+        max_seq_len=128, prefill_token_budget=8, enable_prefix_cache=True,
+        state_snapshots=4)
+
+
+LAYOUTS = {"small": (_small, (16, 32, 35)),
+           "llama": (_llama, (16, 32, 48, 70)),
+           "mellum2": (_mellum2, (8, 11)),
+           "deepseek": (_deepseek, (35,)),
+           "nemotron": (_nemotron, (11,))}
+# a mixed trace: prompts that fill a whole chunk and more, short ones
+# that ride beside decode rows, two that share a prefix
+PROMPTS = (70, 5, 33, 12, 41)
+NEW_TOKENS = 6
+
+
+def _serve(eng, vocab, compiles=None):
+    """Serve the trace; returns the tokens by request and, a launch,
+    the rows that were packed (without the padding) and the rung."""
+    rng = np.random.default_rng(7)
+    shared = rng.integers(1, vocab, 24)
+    prompts = [np.concatenate([shared, rng.integers(1, vocab, n - 24)])
+               if n > 30 else rng.integers(1, vocab, n) for n in PROMPTS]
+    packed = []
+    pack = eng._pack_unified
+
+    def spy(*a, **k):
+        rows, gather, launch = pack(*a, **k)
+        r = launch.counts["rows"]
+        if r:
+            packed.append((rows[:r].copy(), gather.copy(), len(rows),
+                           launch.counts["rows_cap"]))
+        return rows, gather, launch
+
+    eng._pack_unified = spy
+    for p in prompts[:3]:
+        eng.add_request(p.astype(np.int32), max_new_tokens=NEW_TOKENS)
+    eng.step()                          # the engine's first launch
+    after_first = None if compiles is None else compiles[0]
+    for _ in range(3):
+        eng.step()
+    for p in prompts[3:]:               # arrive while the others decode
+        eng.add_request(p.astype(np.int32), max_new_tokens=NEW_TOKENS)
+    tokens = {f.rid: f.tokens.tolist() for f in eng.run()}
+    if compiles is not None:
+        # no program is compiled after the engine's first launch
+        assert compiles[0] == after_first
+    return tokens, packed
+
+
+@pytest.fixture(scope="module")
+def compiles():
+    """JAX's count of backend compilations, as the benchmark's
+    ``CompileClock`` takes it (benchmarks/harness/clocks.py)."""
+    import jax.monitoring
+
+    count = [0]
+
+    def on(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            count[0] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    return count
+
+
+def check_a_ladder_serves_what_the_top_rung_serves(name, compiles):
+    build, want = LAYOUTS[name]
+    make = build()
+    eng = make()
+    assert eng.ladder == want and eng.ladder[-1] == eng.rows_cap
+    vocab = eng.cfg.vocab_size
+    tokens, packed = _serve(eng, vocab, compiles)
+    steps = eng.serving_stats()["steps"]
+    eng.shutdown()
+
+    top = make()                        # the same engine, one rung
+    top.ladder = (top.rows_cap,)
+    top.launches_by_rows = {top.rows_cap: 0}
+    top_tokens, top_packed = _serve(top, vocab)
+    top.shutdown()
+
+    assert tokens == top_tokens
+    assert all(len(t) == NEW_TOKENS for t in tokens.values())
+    # the same launches carry the same rows in the same order, and the
+    # same gather: only the padding differs
+    assert len(packed) == len(top_packed)
+    for (rows, gather, n, cap), (t_rows, t_gather, t_n, t_cap) in zip(
+            packed, top_packed):
+        np.testing.assert_array_equal(rows, t_rows)
+        np.testing.assert_array_equal(gather, t_gather)
+        assert n == cap == min(m for m in want if m >= len(rows))
+        assert t_n == t_cap == want[-1]
+    # the trace reaches more rungs than one: decode-only steps, small
+    # chunks and full ones
+    by_rows = steps["launches_by_rows"]
+    assert sum(by_rows.values()) == len(packed)
+    # (a layout that states no tile has the one rung: DeepSeek's and
+    # Nemotron-H's serve as ever, and the check is that they do)
+    used = [n for n in want if by_rows[n]]
+    assert want[0] in used and len(used) >= min(3, len(want) - 1)
+    assert want[-1] in used or len(want) < 4
+    assert steps["rows"] <= steps["rows_cap"] <= steps["steps"] * eng.rows_cap
+    assert steps["rows_cap"] < steps["steps"] * eng.rows_cap \
+        or len(want) == 1
+    assert steps["rows_cap"] >= sum(n * c for n, c in by_rows.items())

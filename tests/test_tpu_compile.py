@@ -650,6 +650,96 @@ def test_one_kind_of_page_lowers_the_step_it_lowered(one_chip, monkeypatch):
     assert len(tables) == 1 and rows == ["5"]
 
 
+def _rung_engine(layout, monkeypatch):
+    """A small engine of one layout at the published widths and its
+    cell's capacities (slots, prefill budget), with the kernels steered
+    to their compiled form, and the kernels its step holds."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models import nemotron_h
+    from paddle_tpu.models.deepseek_v32 import DeepseekV32Config
+    from paddle_tpu.models.mellum2 import Mellum2Config
+    from paddle_tpu.ops.pallas import (decode_attention, grouped_matmul,
+                                       sparse_mla)
+
+    for mod in (nemotron_h, decode_attention, grouped_matmul, sparse_mla):
+        monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
+    kw = dict(num_pages=24, page_size=128, enable_prefix_cache=True)
+    if layout == "kv":
+        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+
+        cfg = LlamaConfig.debug(vocab=256, hidden=HEADS * 128, layers=1,
+                                heads=HEADS, kv_heads=KV_HEADS, inter=256,
+                                max_pos=_CELL_SEQ)
+        params = {k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16) for k, v in
+                  LlamaForCausalLM(cfg).functional_state().items()}
+        return ContinuousBatchingEngine(
+            cfg, params, max_slots=_CELL_SLOTS, max_seq_len=_CELL_SEQ,
+            prefill_token_budget=_CELL_BUDGET, **kw), (
+                "ragged_paged_attention",), (32, 96, 160, 288)
+    if layout == "kinds":
+        cfg = Mellum2Config(num_hidden_layers=4)
+        kernels = ("ragged_paged_attention", "ragged_paged_attention_window",
+                   "grouped_matmul_blocks")
+        cap = dict(max_slots=_M2_SLOTS, max_seq_len=_M2_SEQ,
+                   prefill_token_budget=_M2_BUDGET)
+        ladder = (32, 160, 288, 544)
+    elif layout == "latent":
+        cfg = DeepseekV32Config(num_hidden_layers=2, first_k_dense_replace=1,
+                                experts_held=(0, 16), vocab_size=16160)
+        kernels = ("lightning_index_scores", "sparse_mla_attention",
+                   "grouped_matmul_blocks")
+        cap = dict(max_slots=_DS_SLOTS, max_seq_len=_DS_SEQ,
+                   prefill_token_budget=512)
+        ladder = (528,)                 # the layout states no tile yet
+    else:
+        cfg = nemotron_h.NemotronHConfig(
+            num_hidden_layers=3, hybrid_override_pattern="ME*",
+            experts_held=(0, 64), vocab_size=16384)
+        kernels = ("mamba2_ssd_scan", "ragged_paged_attention",
+                   "grouped_matmul_blocks")
+        cap = dict(max_slots=128, max_seq_len=7680, prefill_token_budget=512,
+                   state_snapshots=2)
+        ladder = (640,)                 # the layout states no tile yet
+    params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+              for k, s in cfg.leaf_shapes().items()}
+    return ContinuousBatchingEngine(cfg, params, **cap, **kw), kernels, ladder
+
+
+@pytest.mark.parametrize("layout", ["kv", "kinds", "latent", "state"])
+def test_the_lowest_rung_of_each_layout_compiles(one_chip, monkeypatch,
+                                                 layout):
+    """The engine launches a step of decode rows alone at the LOWEST
+    rung of its ladder (``serving.step_ladder``): 32 rows in the Llama
+    family's two cells, where the capacity is 288 and 544.  Each
+    layout's step compiles at that size with every kernel of its cell
+    in it: the ragged kernel (one tile, or two of Mellum2's 16 rows)
+    and the experts' grouped matmul; the two layouts that state no tile
+    yet (DeepSeek's sparse-MLA kernels, Nemotron's scan) have the one
+    rung, their capacity, and compile there.  (Every rung the rule
+    gives all four cells, 16 to 640 rows, compiled for the described
+    chip by hand: PERF.md section 6, PR 36.)"""
+    eng, kernels, ladder = _rung_engine(layout, monkeypatch)
+    assert eng.ladder == ladder and ladder[-1] == eng.rows_cap
+    assert all(n % max(eng.layout.tile_rows, 1) == 0 for n in ladder)
+    fn, args, kwargs, _ = eng.analysis_entry()
+    rows = eng._padding_rows(ladder[0])
+    args = (*args[:3], rows, *args[4:])
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step")}
+    text = fn.lower(*jax.tree.map(described, args), **static,
+                    **jax.tree.map(described, kwargs)).compile().as_text()
+    names = {m.group(1) for m in re.finditer(
+        r"%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call", text)}
+    assert set(kernels) <= names, names
+    entry = text[text.index("\nENTRY "):]
+    assert re.search(rf"= s32\[{ladder[0]},{eng.row_cols}\]\S* parameter\(",
+                     entry)
+    assert len(ladder) == 1 or f"[{eng.rows_cap}," not in entry
+
+
 def test_grouped_outer_compiles(one_chip):
     """The dW half of the grouped-matmul backward: (K, N) is tiled in
     the grid — held whole in fp32 it asked for 33 MB of 16 MB VMEM."""
