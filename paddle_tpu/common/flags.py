@@ -307,8 +307,8 @@ define_flag("FLAGS_use_shm_cache", False,
 define_flag("FLAGS_dataloader_use_file_descriptor", False,
             "compat: see FLAGS_use_shm_cache.")
 define_flag("FLAGS_enable_record_memory", False,
-            "Alias of FLAGS_log_memory_stats (wired: profiler reads "
-            "either).")
+            "compat: FLAGS_log_memory_stats is the one the profiler "
+            "reads.")
 define_flag("FLAGS_get_host_by_name_time", 120,
             "Rendezvous DNS wait budget in seconds (wired: launch/TCPStore "
             "connect retry window).")

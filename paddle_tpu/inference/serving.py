@@ -140,10 +140,15 @@ runs, whoever started it, holds them on the device's clock
 (``serving.fetch_logits`` keeps its name: it is where the host waits for
 the device and copies back what it needs, the tokens); one marker a
 call (``serving.step_counts``: the counts of the launch the call
-COMMITS, with ``ahead`` and ``stale_rows``) and one per request at
-admission and at its first token (``serving.admit_request``,
-``serving.first_token``) carry the counts.  The same counts are summed in
-``serving_stats()["steps"]`` whether or not anything traces.  A step's
+COMMITS, with ``ahead``, ``stale_rows`` and ``launch``, that launch's
+serial, which ``serving.launch`` carries too where it is enqueued) and
+one per request at admission and at its first token
+(``serving.admit_request``, ``serving.first_token``) carry the counts.
+The same counts are summed in ``serving_stats()["steps"]`` whether or
+not anything traces.  The step's ``jax.named_scope``s are
+``profiler.device_trace.DEVICE_SCOPES``: a device trace's operations
+carry them, and ``device_time_by_scope`` there adds the device's time up
+by scope and by launch.  A step's
 ``rows_cap`` count is the rows of the program LAUNCHED (the rung), so
 ``rows`` over ``rows_cap`` says how full the launched shapes were, and
 ``launches_by_rows`` how many launches each rung took; ``engine.rows_cap``
@@ -1595,35 +1600,37 @@ class ContinuousBatchingEngine:
         h, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                      cfg.head_dim)
         T = rows.shape[0]
-        tok = rows[:, 0]
-        if prev_tokens is not None:
-            tok = resolve_row_tokens(tok, prev_tokens)
-        phys = rows[:, 1]
-        off = rows[:, 2]
-        lens = rows[:, 3]
-        slot = rows[:, 4]
         # a layer's kind of page: its table, the column of the page a
         # row writes, its window (one kind: the table, column 1, none)
         kinds = page_kinds(cfg)
         kind_of = {i: k for k, kind in enumerate(kinds) for i in kind.layers}
-        phys_of = [phys] + [rows[:, 4 + k] for k in range(1, len(tables))]
-        x = w.embed(tok)                              # [T, hidden]
-        pos = jnp.maximum(lens - 1, 0)
+        # the scopes are ``profiler.device_trace.DEVICE_SCOPES``: the
+        # SAME names in every layer, so that a layer's parts add up
+        # across layers in the device's time by scope; scopes are
+        # metadata and change nothing that is compiled
+        with jax.named_scope("embed"):
+            tok = rows[:, 0]
+            if prev_tokens is not None:
+                tok = resolve_row_tokens(tok, prev_tokens)
+            phys = rows[:, 1]
+            off = rows[:, 2]
+            lens = rows[:, 3]
+            slot = rows[:, 4]
+            phys_of = [phys] + [rows[:, 4 + k] for k in range(1, len(tables))]
+            x = w.embed(tok)                          # [T, hidden]
+            pos = jnp.maximum(lens - 1, 0)
 
-        def rope_rows(tab):
-            return jnp.take(tab, pos, axis=0)[:, None, :].astype(x.dtype)
+            def rope_rows(tab):
+                return jnp.take(tab, pos, axis=0)[:, None, :].astype(x.dtype)
 
-        # rope tables by kind of layer where the config has them
-        cos, sin = jax.tree.map(rope_rows, (cos_tab, sin_tab))
+            # rope tables by kind of layer where the config has them
+            cos, sin = jax.tree.map(rope_rows, (cos_tab, sin_tab))
+            stats = None
+            if _counts_experts(cfg):
+                stats = {"valid": slot >= 0,
+                         **{c: [] for c in MOE_DEVICE_COUNTS[:4]}}
         new_k, new_v = list(k_pages), list(v_pages)
         rep_ = h // kvh
-        stats = None
-        if _counts_experts(cfg):
-            stats = {"valid": slot >= 0,
-                     **{c: [] for c in MOE_DEVICE_COUNTS[:4]}}
-        # the SAME scope names in every layer, so that a trace viewer
-        # adds a layer's parts up across layers; scopes are metadata and
-        # change nothing that is compiled
         for i in range(L):
             ki = kind_of.get(i, 0)
             phys = phys_of[ki]
@@ -1683,29 +1690,32 @@ class ContinuousBatchingEngine:
             # effect: skip the [T, hidden] x [hidden, vocab] head matmul
             # and the fp32 logits allocation entirely
             return tuple(new_k), tuple(new_v), None
-        if gather is not None:
-            # device-side gather of the CONSUMED rows (every verify-
-            # window row + each prefill chunk's final row) BEFORE the
-            # final norm/vocab projection: the head matmul, the fp32
-            # logits buffer and the device->host copy shrink from
-            # rows_cap to gather_cap — a prefill chunk's intermediate
-            # rows exist only for their K/V scatter and never produce
-            # (or transfer) logits
-            x = jnp.take(x, gather, axis=0)
         with jax.named_scope("lm_head"):
+            if gather is not None:
+                # device-side gather of the CONSUMED rows (every verify-
+                # window row + each prefill chunk's final row) BEFORE the
+                # final norm/vocab projection: the head matmul, the fp32
+                # logits buffer and the device->host copy shrink from
+                # rows_cap to gather_cap — a prefill chunk's intermediate
+                # rows exist only for their K/V scatter and never produce
+                # (or transfer) logits
+                x = jnp.take(x, gather, axis=0)
             x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
             logits = w.head(x).astype(jnp.float32)    # [G, vocab]
-        out = (logits, sample_greedy(logits))
-        if stats is not None:
-            # MOE_DEVICE_COUNTS, over this step's expert layers
-            zero = jnp.zeros((), jnp.int32)
-            hit = stats["moe_experts_hit"]
-            out = (*out, jnp.stack([
-                sum(stats["moe_rows_routed"], zero),
-                sum(stats["moe_rows_held"], zero),
-                jnp.max(jnp.stack(stats["moe_expert_rows_max"] or [zero])),
-                sum(hit, zero),
-                zero + len(hit) * int(cfg.num_experts)]).astype(jnp.int32))
+        with jax.named_scope("sample"):
+            out = (logits, sample_greedy(logits))
+            if stats is not None:
+                # MOE_DEVICE_COUNTS, over this step's expert layers
+                zero = jnp.zeros((), jnp.int32)
+                hit = stats["moe_experts_hit"]
+                out = (*out, jnp.stack([
+                    sum(stats["moe_rows_routed"], zero),
+                    sum(stats["moe_rows_held"], zero),
+                    jnp.max(jnp.stack(stats["moe_expert_rows_max"]
+                                      or [zero])),
+                    sum(hit, zero),
+                    zero + len(hit) * int(cfg.num_experts)]
+                ).astype(jnp.int32))
         return tuple(new_k), tuple(new_v), out
 
     # ---------------- host scheduler ----------------
@@ -2451,7 +2461,12 @@ class ContinuousBatchingEngine:
                     break
                 # 1: enqueued before the launch before it was read
                 new.counts["ahead"] = len(queued)
-                with RecordEvent("serving.launch"):
+                # the serial rides on the span that enqueues the launch
+                # and on the marker of the call that commits it, so that
+                # a reader of the device's trace joins the k-th launch
+                # the device ran to both (profiler/device_trace.py)
+                with RecordEvent("serving.launch",
+                                 launch=new.counts["launch"]):
                     launches = [(rows, gather, queued[-1].out[1] if queued
                                  else self._no_tokens)]
                     if self._programs is None:
@@ -2701,6 +2716,9 @@ class ContinuousBatchingEngine:
             # whether the launch was enqueued before the one before it
             # was read, and its rows whose slot had ended by then
             "ahead": 0, "stale_rows": 0,
+            # its serial among the engine's launches, from 1 (it is
+            # enqueued next); 0: no rows, nothing will be launched
+            "launch": 1 + sum(self.launches_by_rows.values()) if r else 0,
             # sum of the rows' visibilities: the attention's arithmetic
             "attn_row_ctx": int(rows[:r, 3].sum()),
             # the K/V the step has to read at least: its bytes

@@ -357,6 +357,7 @@ def attention_part(cfg, w, i, x, lat_pool, idx_pool, phys, off, lens, slot,
         qf = (qf * cfg.softmax_scale).astype(x.dtype)
         o_lat = sparse_mla_attention_raw(
             qf, lat_pool, scores, sel, lens, slot, tables, dv=dc, **walk)
+    with jax.named_scope("attn_out"):
         o = jnp.einsum("thc,chd->thd", o_lat.astype(x.dtype), wkvb[..., dn:])
         x = x + o.reshape(T, H * dv) @ w.layer(i, at + "o_proj.weight")
     return x, lat_pool, idx_pool, (scores, sel)
@@ -388,17 +389,19 @@ def unified_step_jit(params, lat_pages, idx_pages, rows, tables, cos_tab,
 
     cfg, _, _ = _CFGS[self_cfg_id]
     w = _Weights(cfg, params)
-    tok, phys, off, lens, slot = (rows[:, c] for c in range(5))
     (tables,) = tables                  # a table a kind of page: it has one
-    if prev_tokens is not None:
-        tok = resolve_row_tokens(tok, prev_tokens)
-    lens = jnp.where(slot < 0, 0, lens)
-    x = w.embed(tok)
-    pos = jnp.maximum(lens - 1, 0)
-    cos = jnp.take(cos_tab, pos, axis=0).astype(x.dtype)
-    sin = jnp.take(sin_tab, pos, axis=0).astype(x.dtype)
+    # the scopes are ``profiler.device_trace.DEVICE_SCOPES``
+    with jax.named_scope("embed"):
+        tok, phys, off, lens, slot = (rows[:, c] for c in range(5))
+        if prev_tokens is not None:
+            tok = resolve_row_tokens(tok, prev_tokens)
+        lens = jnp.where(slot < 0, 0, lens)
+        x = w.embed(tok)
+        pos = jnp.maximum(lens - 1, 0)
+        cos = jnp.take(cos_tab, pos, axis=0).astype(x.dtype)
+        sin = jnp.take(sin_tab, pos, axis=0).astype(x.dtype)
+        stats = {"valid": slot >= 0, **{k: [] for k in DEVICE_COUNTS}}
     new_lat, new_idx = list(lat_pages), list(idx_pages)
-    stats = {"valid": slot >= 0, **{k: [] for k in DEVICE_COUNTS}}
     masks = []
     for i in range(cfg.num_hidden_layers):
         x, new_lat[i], new_idx[i], (scores, sel) = attention_part(
@@ -406,26 +409,25 @@ def unified_step_jit(params, lat_pages, idx_pages, rows, tables, cos_tab,
             tables, cos, sin, pages_per_step)
         if debug_select:
             masks.append(selected_mask(scores, sel, lens))
-        xm = _rms_norm(x, w.layer(i, "post_attention_layernorm.weight"),
-                       cfg.rms_norm_eps)
-        if w.is_moe_layer(i):
+        with jax.named_scope("mlp"):
+            xm = _rms_norm(x, w.layer(i, "post_attention_layernorm.weight"),
+                           cfg.rms_norm_eps)
             x = x + _ffn(w, i, xm, stats)
-        else:
-            with jax.named_scope("mlp"):
-                x = x + _ffn(w, i, xm)
     if not with_head:
         return tuple(new_lat), tuple(new_idx), None
-    if gather is not None:
-        x = jnp.take(x, gather, axis=0)
     with jax.named_scope("lm_head"):
+        if gather is not None:
+            x = jnp.take(x, gather, axis=0)
         x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
         logits = w.head(x).astype(jnp.float32)
-    zero = jnp.zeros((), jnp.int32)
-    counts = jnp.stack([
-        sum(stats["moe_rows_held"], zero), sum(stats["moe_rows_routed"], zero),
-        jnp.max(jnp.stack(stats["moe_expert_rows_max"] or [zero]))]
-    ).astype(jnp.int32)
-    out = (logits, sample_greedy(logits), counts)
+    with jax.named_scope("sample"):
+        zero = jnp.zeros((), jnp.int32)
+        counts = jnp.stack([
+            sum(stats["moe_rows_held"], zero),
+            sum(stats["moe_rows_routed"], zero),
+            jnp.max(jnp.stack(stats["moe_expert_rows_max"] or [zero]))]
+        ).astype(jnp.int32)
+        out = (logits, sample_greedy(logits), counts)
     if debug_select:
         out = (*out, masks)
     return tuple(new_lat), tuple(new_idx), out

@@ -398,23 +398,27 @@ def unified_step_jit(params, k_pages, v_pages, rows, tables, cos_tab,
 
     cfg, _, _ = _CFGS[self_cfg_id]
     w = _Weights(cfg, params)
-    tok, phys, off, lens, slot, src, dst, snap = (rows[:, c]
-                                                  for c in range(8))
     (table,) = tables
-    if prev_tokens is not None:
-        tok = resolve_row_tokens(tok, prev_tokens)
-    lens = jnp.where(slot < 0, 0, lens)
-    x = w.embed(tok)
     new_k, new_v = list(k_pages), list(v_pages)
     ssm, conv = (list(p) for p in state)
-    stats = {"valid": slot >= 0, **{c: [] for c in MOE_DEVICE_COUNTS[:4]}}
-    # the snapshots this step takes: (the slot's entry, the snapshot's),
-    # trash to trash where there are fewer
-    trash = ssm[0].shape[0] - 1
-    (at,) = jnp.nonzero(snap >= 0, size=SNAPSHOTS_A_STEP, fill_value=0)
-    taken = snap[at] >= 0
-    snap_from = jnp.where(taken, dst[at], trash)
-    snap_to = jnp.where(taken, snap[at], trash)
+    # the scopes are ``profiler.device_trace.DEVICE_SCOPES``
+    with jax.named_scope("embed"):
+        tok, phys, off, lens, slot, src, dst, snap = (rows[:, c]
+                                                      for c in range(8))
+        if prev_tokens is not None:
+            tok = resolve_row_tokens(tok, prev_tokens)
+        lens = jnp.where(slot < 0, 0, lens)
+        x = w.embed(tok)
+        stats = {"valid": slot >= 0,
+                 **{c: [] for c in MOE_DEVICE_COUNTS[:4]}}
+    with jax.named_scope("state_snapshot"):
+        # the snapshots this step takes: (the slot's entry, the
+        # snapshot's), trash to trash where there are fewer
+        trash = ssm[0].shape[0] - 1
+        (at,) = jnp.nonzero(snap >= 0, size=SNAPSHOTS_A_STEP, fill_value=0)
+        taken = snap[at] >= 0
+        snap_from = jnp.where(taken, dst[at], trash)
+        snap_to = jnp.where(taken, snap[at], trash)
     n_attn = n_state = 0
     for i, letter in enumerate(cfg.pattern):
         if letter == "M":
@@ -433,22 +437,25 @@ def unified_step_jit(params, k_pages, v_pages, rows, tables, cos_tab,
                 slot, table, pages_per_step)
             n_attn += 1
         else:
-            xm = _rms_norm(x, w.layer(i, "norm.weight"), cfg.norm_eps)
-            x = x + _ffn(w, i, xm, stats)
+            with jax.named_scope("mlp"):
+                xm = _rms_norm(x, w.layer(i, "norm.weight"), cfg.norm_eps)
+                x = x + _ffn(w, i, xm, stats)
     state = (tuple(ssm), tuple(conv))
     if not with_head:
         return tuple(new_k), tuple(new_v), None, state
-    if gather is not None:
-        x = jnp.take(x, gather, axis=0)
     with jax.named_scope("lm_head"):
+        if gather is not None:
+            x = jnp.take(x, gather, axis=0)
         x = _rms_norm(x, w["model.norm.weight"], cfg.norm_eps)
         logits = w.head(x).astype(jnp.float32)
-    zero = jnp.zeros((), jnp.int32)
-    hit = stats["moe_experts_hit"]
-    lo, hi = cfg.experts_held or (0, cfg.n_routed_experts)
-    counts = jnp.stack([
-        sum(stats["moe_rows_routed"], zero), sum(stats["moe_rows_held"], zero),
-        jnp.max(jnp.stack(stats["moe_expert_rows_max"] or [zero])),
-        sum(hit, zero), zero + len(hit) * (hi - lo)]).astype(jnp.int32)
-    return (tuple(new_k), tuple(new_v),
-            (logits, sample_greedy(logits), counts), state)
+    with jax.named_scope("sample"):
+        zero = jnp.zeros((), jnp.int32)
+        hit = stats["moe_experts_hit"]
+        lo, hi = cfg.experts_held or (0, cfg.n_routed_experts)
+        counts = jnp.stack([
+            sum(stats["moe_rows_routed"], zero),
+            sum(stats["moe_rows_held"], zero),
+            jnp.max(jnp.stack(stats["moe_expert_rows_max"] or [zero])),
+            sum(hit, zero), zero + len(hit) * (hi - lo)]).astype(jnp.int32)
+        out = (logits, sample_greedy(logits), counts)
+    return tuple(new_k), tuple(new_v), out, state

@@ -6,39 +6,31 @@ that runs, whoever started it, on the same clock as the device's
 operations and with its keyword arguments as the event's stats; while a
 ``Profiler`` of this module records, the span is also kept in memory
 for ``summary()`` and the chrome trace.  With no trace running a span
-is a no-op.  Plus the in-training throughput meter (reference:
-python/paddle/profiler/timer.py).
+is a no-op.
+
+On a TPU ``Profiler.start()`` also writes the device's trace into
+``profiler.trace_dir``, and ``summary()`` appends the DEVICE's time by
+the serving step's own scopes (``device_trace.DEVICE_SCOPES``: the
+``jax.named_scope``s of the step, read from the trace's operation
+metadata): a row a scope and kind of operation (``pallas`` kernel or
+``xla``), with the launches, ms a launch, share of the device's busy
+time, and XLA's own GFLOP and MB a launch.  ``device_trace`` reads any
+``.xplane.pb`` the same way (``load_xplane``, ``device_time_by_scope``,
+``scope_table``); the benchmark's per-layer metrics are its other reader.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import tempfile
 import threading
 import time
-from dataclasses import dataclass, field
-from enum import Enum
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 import jax
 
 from ..core.device import is_tpu as _is_tpu
-
-
-class ProfilerTarget(Enum):
-    CPU = 0
-    TPU = 1
-    CUSTOM_DEVICE = 2
-
-
-class ProfilerState(Enum):
-    CLOSED = 0
-    READY = 1
-    RECORD = 2
-    RECORD_AND_RETURN = 3
-
 
 _host_events: List[Dict[str, Any]] = []
 _recording = [False]
@@ -106,9 +98,7 @@ class RecordEvent:
 
 
 class Profiler:
-    def __init__(self, targets=None, scheduler=None, on_trace_ready=None,
-                 timer_only=False, record_shapes=False, profile_memory=False,
-                 with_flops=False):
+    def __init__(self, timer_only=False):
         self.timer_only = timer_only
         self.trace_dir = None       # where the device's trace was written
         self._tracing = False
@@ -138,8 +128,7 @@ class Profiler:
 
         if not self._running:
             return
-        if (_flags.get_flag("FLAGS_log_memory_stats")
-                or _flags.get_flag("FLAGS_enable_record_memory")):
+        if _flags.get_flag("FLAGS_log_memory_stats"):
             from .. import device as _device
 
             _host_events.append({
@@ -169,9 +158,26 @@ class Profiler:
         """Aggregated statistics table (the profiler_statistic.py analog:
         python/paddle/profiler/profiler_statistic.py) — per-event-name
         calls / total / avg / max / min and share of the profiled span,
-        sorted by total self time."""
-        return summarize_events(_host_events, time_unit=time_unit,
-                                top_n=top_n)
+        sorted by total self time.  After a recording on a TPU, the
+        device's time by the serving step's scopes follows (the module
+        docstring; nothing where the trace holds no launch of a step)."""
+        table = summarize_events(_host_events, time_unit=time_unit,
+                                 top_n=top_n)
+        if self.trace_dir is None or self._tracing:
+            return table
+        from . import device_trace
+
+        path = device_trace.find_xplane(self.trace_dir)
+        if path is None:
+            return table
+        trace = device_trace.load_xplane(path)
+        for plane in sorted(trace.ops):
+            times = device_trace.device_time_by_scope(
+                trace.ops[plane], trace.modules.get(plane, ()),
+                spans=trace.spans)
+            if times.launches:
+                table += f"\n{plane}\n{device_trace.scope_table(times)}"
+        return table
 
 
 def summarize_events(events, time_unit="ms", top_n: int = 30) -> str:
@@ -243,37 +249,3 @@ def summarize_chrome_trace(path: str, time_unit="ms", top_n: int = 30) -> str:
         data = json.load(f)
     events = data.get("traceEvents", data if isinstance(data, list) else [])
     return summarize_events(events, time_unit=time_unit, top_n=top_n)
-
-
-class Timer:
-    """Throughput meter (analog of python/paddle/profiler/timer.py)."""
-
-    def __init__(self):
-        self.reset()
-
-    def reset(self):
-        self._start = None
-        self._steps = 0
-        self._samples = 0
-
-    def begin(self):
-        self._start = time.perf_counter()
-
-    def step(self, num_samples=1):
-        self._steps += 1
-        self._samples += num_samples
-
-    def ips(self):
-        if not self._start or self._steps == 0:
-            return 0.0
-        elapsed = time.perf_counter() - self._start
-        return self._samples / elapsed
-
-    def steps_per_sec(self):
-        if not self._start or self._steps == 0:
-            return 0.0
-        return self._steps / (time.perf_counter() - self._start)
-
-
-def benchmark():
-    return Timer()

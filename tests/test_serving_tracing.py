@@ -26,7 +26,8 @@ MARKERS = ("serving.admit_request", "serving.first_token",
 STEP_COUNTS = ("step", "admitted", "queued", "free_pages", "rows", "rows_cap",
                "decode_rows", "prefill_rows", "slots", "prefill_backlog",
                "attn_row_ctx", "kv_ctx_tokens", "attn_kv_tokens_read",
-               "gathered", "produced", "finished", "ahead", "stale_rows")
+               "gathered", "produced", "finished", "ahead", "stale_rows",
+               "launch")
 PROMPT_LENS = (20, 9, 13, 30)
 NEW_TOKENS = 5
 
@@ -183,7 +184,8 @@ def test_a_calls_marker_carries_the_launch_it_commits(tiny, tmp_path):
     launch (the budget is spent by what is in flight) and commits the
     second.  Each call's marker, joined to its ``serving.step`` by
     ``step``, counts the launch the device ran while the host was
-    inside that call."""
+    inside that call, and its serial (``launch``), which the
+    ``serving.launch`` span that enqueued it carries too."""
     cfg, params = tiny
     eng = ContinuousBatchingEngine(cfg, params, max_slots=1, num_pages=9,
                                    page_size=16, max_seq_len=32,
@@ -197,11 +199,14 @@ def test_a_calls_marker_carries_the_launch_it_commits(tiny, tmp_path):
     marks = [a for n, *_, a in spans if n == "serving.step_counts"]
     assert [m["step"] for m in marks] == steps == [1, 2, 3]
     want = [dict(rows=5, prefill_rows=5, decode_rows=0, ahead=0, admitted=1,
-                 attn_row_ctx=15, kv_ctx_tokens=5, gathered=1, finished=0),
+                 attn_row_ctx=15, kv_ctx_tokens=5, gathered=1, finished=0,
+                 launch=1),
             dict(rows=1, prefill_rows=0, decode_rows=1, ahead=1, admitted=0,
-                 attn_row_ctx=6, kv_ctx_tokens=6, gathered=1, finished=0),
+                 attn_row_ctx=6, kv_ctx_tokens=6, gathered=1, finished=0,
+                 launch=2),
             dict(rows=1, prefill_rows=0, decode_rows=1, ahead=1, admitted=0,
-                 attn_row_ctx=7, kv_ctx_tokens=7, gathered=1, finished=1)]
+                 attn_row_ctx=7, kv_ctx_tokens=7, gathered=1, finished=1,
+                 launch=3)]
     for mark, w in zip(marks, want):
         assert {k: mark[k] for k in w} == w
         assert mark["produced"] == 1 and mark["stale_rows"] == 0
@@ -212,6 +217,11 @@ def test_a_calls_marker_carries_the_launch_it_commits(tiny, tmp_path):
     assert launches == [["serving.pack", "serving.launch"] * 2,
                         ["serving.pack", "serving.launch"],
                         ["serving.pack"]]
+    # a launch is enqueued one call before the call that commits it
+    enqueued = [[a["launch"] for n, a_, b, a in spans if n == "serving.launch"
+                 and s <= a_ and b <= e]
+                for name, s, e, _ in spans if name == "serving.step"]
+    assert enqueued == [[1, 2], [3], []]
     eng.shutdown()
 
 

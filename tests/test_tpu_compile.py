@@ -738,6 +738,15 @@ def test_the_lowest_rung_of_each_layout_compiles(one_chip, monkeypatch,
     assert re.search(rf"= s32\[{ladder[0]},{eng.row_cols}\]\S* parameter\(",
                      entry)
     assert len(ladder) == 1 or f"[{eng.rows_cap}," not in entry
+    # the chip's compiler leaves no working instruction of the step
+    # outside the program's scopes (tests/test_device_scopes.py holds the
+    # same at debug widths): the device's time by scope then names all
+    # of a launch but the compiler's own copies
+    from test_device_scopes import hlo_scopes
+
+    found = hlo_scopes(text)
+    assert not found.get("unscoped"), found["unscoped"]
+    assert {"embed", "lm_head", "sample"} < set(found)
 
 
 def test_grouped_outer_compiles(one_chip):
