@@ -8,9 +8,11 @@ Source: https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16
 
 - ``M``: ``[z | xBC | dt] = u W_in`` (widths ``d_in``, ``d_in + 2 G N``,
   ``H``; ``d_in = H P``); ``xBC <- silu(conv)``, a causal depthwise
-  convolution over the last ``conv_kernel`` positions plus a bias;
-  ``xBC`` splits into ``x [H, P]``, ``B [G, N]``, ``C [G, N]`` (head h
-  reads group ``h // (H / G)``); ``dt <- softplus(dt + dt_bias)``, ``A =
+  convolution over the last ``conv_kernel`` positions plus a bias: the
+  kernel of ``ops/pallas/causal_conv.py`` over the packed rows (on the
+  CPU its reference, the same in XLA's terms); ``xBC`` splits into ``x
+  [H, P]``, ``B [G, N]``, ``C [G, N]`` (head h reads group ``h // (H /
+  G)``); ``dt <- softplus(dt + dt_bias)``, ``A =
   -exp(A_log)``; the scan of ``ops/pallas/ssd_scan.py``; ``y <- y + D
   x``; ``y <- RMSNorm over each of the G groups of (y * silu(z))`` with a
   gain; ``out = y W_out``.  What a sequence carries from token to token
@@ -255,20 +257,13 @@ class NemotronHConfig:
         return out
 
 
-def _run_index(slot):
-    """For each packed row, how many rows of its slot lie before it in
-    this launch (a slot's rows are consecutive)."""
-    from ..ops.pallas.ssd_scan import run_first
-
-    idx = jnp.arange(slot.shape[0], dtype=jnp.int32)
-    return idx - jax.lax.cummax(jnp.where(run_first(slot), idx, 0))
-
-
 def mamba_part(cfg, w, i, x, ssm_pool, conv_pool, slot, lens, src, dst):
     """Layer ``i``'s Mamba-2 mixer on the packed rows ``x`` ``[T,
     hidden]``: each slot's rows start from state entry ``src`` (below
     zero: zeros) and leave the state in entry ``dst``, in both pools.
     Returns ``(x + mixer, ssm pool, conv pool)``."""
+    from ..ops.pallas.causal_conv import (packed_causal_conv,
+                                          packed_causal_conv_reference)
     from ..ops.pallas.ssd_scan import (mamba2_ssd_scan, ssd_max_units,
                                        ssd_scan_reference)
     from .generation import _rms_norm
@@ -276,8 +271,7 @@ def mamba_part(cfg, w, i, x, ssm_pool, conv_pool, slot, lens, src, dst):
     T = x.shape[0]
     H, P, G, N = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
                   cfg.ssm_state_size)
-    K, d_in, cd = cfg.conv_kernel, cfg.d_inner, cfg.conv_dim
-    live = slot >= 0
+    d_in, cd = cfg.d_inner, cfg.conv_dim
     mx = "mixer."
     with jax.named_scope("mamba_in_proj"):
         u = _rms_norm(x, w.layer(i, "norm.weight"), cfg.norm_eps)
@@ -285,35 +279,11 @@ def mamba_part(cfg, w, i, x, ssm_pool, conv_pool, slot, lens, src, dst):
         z, xbc, dt = (zxd[:, :d_in], zxd[:, d_in:d_in + cd],
                       zxd[:, d_in + cd:])
     with jax.named_scope("mamba_conv"):
-        # XLA, not the kernel: a row's taps are the rows before it in its
-        # slot's run, else the slot's tail; the window [T, K, channels]
-        # is one gather and one select, a hundredth of the scan's bytes
-        j = _run_index(slot)
-        tail = jnp.where((src < 0)[:, None, None], 0,
-                         conv_pool[jnp.maximum(src, 0)])      # [T, K-1, cd]
-        taps = []
-        for back in range(K - 1, 0, -1):
-            shifted = jnp.concatenate(
-                [jnp.zeros((back, cd), xbc.dtype), xbc[:-back]])
-            # the tap `back` rows before row t: in the run if j >= back,
-            # else entry K - 1 - back + j of the tail
-            from_tail = jnp.take_along_axis(
-                tail, jnp.clip(K - 1 - back + j, 0, K - 2)[:, None, None],
-                axis=1)[:, 0].astype(xbc.dtype)
-            taps.append(jnp.where((j >= back)[:, None], shifted, from_tail))
-        taps.append(xbc)
-        win = jnp.stack(taps, axis=1)                         # [T, K, cd]
-        cw = w.layer(i, mx + "conv1d.weight")
-        conv = jnp.einsum("tkc,kc->tc", win.astype(jnp.float32),
-                          cw.astype(jnp.float32)) \
-            + w.layer(i, mx + "conv1d.bias").astype(jnp.float32)
-        xbc = jax.nn.silu(conv).astype(x.dtype)
-        # a slot's last row leaves its window's newest K - 1 entries
-        nxt = jnp.concatenate([slot[1:], jnp.full((1,), -2, slot.dtype)])
-        trash = conv_pool.shape[0] - 1
-        conv_pool = conv_pool.at[
-            jnp.where(live & (slot != nxt), dst, trash)].set(
-                win[:, 1:].astype(conv_pool.dtype))
+        conv = packed_causal_conv_reference if pallas_interpret() \
+            else partial(packed_causal_conv, tile_rows=cfg.chunk_size)
+        xbc, conv_pool = conv(xbc, w.layer(i, mx + "conv1d.weight"),
+                              w.layer(i, mx + "conv1d.bias"), conv_pool,
+                              slot, src, dst)
     with jax.named_scope("ssd_scan"):
         xs = xbc[:, :d_in].reshape(T, H, P)
         B = xbc[:, d_in:d_in + G * N].reshape(T, G, N)

@@ -3,8 +3,8 @@ Mamba-2 layers whose per-slot state lives in ONE pool beside the paged
 K/V, snapshots of that state for prefix reuse, and the relu2 experts in
 their latent, through the engine's ONE step against the plain reference
 ``benchmarks/reference/nemotron_h_ref.py``, which shares no code with the
-program; and the scan kernel (interpret mode) against the sequential
-scan."""
+program; and the scan kernel and the convolution kernel (interpret mode)
+against the sequential scan and the convolution in XLA's terms."""
 
 import dataclasses
 
@@ -17,6 +17,8 @@ from paddle_tpu.inference.serving import (STATE_COUNTS,
                                           ContinuousBatchingEngine)
 from paddle_tpu.models import generation
 from paddle_tpu.models.nemotron_h import NemotronHConfig
+from paddle_tpu.ops.pallas.causal_conv import (packed_causal_conv,
+                                               packed_causal_conv_reference)
 from paddle_tpu.ops.pallas.ssd_scan import (mamba2_ssd_scan, ssd_max_units,
                                             ssd_scan_reference)
 
@@ -335,10 +337,9 @@ def lw_of(lw, lo, hi):
 
 # ---- the scan kernel (interpret mode) against the sequential scan ----
 
-def scan_case(runs, rows, tile, dtype=jnp.float32, seed=0, entries=7):
-    """Packed rows of ``runs`` ``(slot, rows, entry to start from)``."""
-    H, P, G, N = 4, 8, 2, 16
-    rng = np.random.default_rng(seed)
+def row_columns(runs, rows, entries):
+    """``slot``, ``lens``, ``src``, ``dst`` of packed rows of ``runs``
+    ``(slot, rows, entry to start from)``; the last entry is the trash."""
     slot = np.full(rows, -1, np.int32)
     src = np.full(rows, entries - 1, np.int32)
     dst = np.full(rows, entries - 1, np.int32)
@@ -348,23 +349,35 @@ def scan_case(runs, rows, tile, dtype=jnp.float32, seed=0, entries=7):
         slot[r:r + n], src[r:r + n], dst[r:r + n] = s, start, s
         lens[r:r + n] = np.arange(1, n + 1) + 10
         r += n
+    return [jnp.asarray(v) for v in (slot, lens, src, dst)]
+
+
+def scan_case(runs, rows, tile, dtype=jnp.float32, seed=0, entries=7):
+    """Packed rows of ``runs`` ``(slot, rows, entry to start from)``."""
+    H, P, G, N = 4, 8, 2, 16
+    rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.normal(size=(rows, H, P)), dtype)
     dt = jnp.asarray(rng.uniform(0.01, 0.5, (rows, H)), jnp.float32)
     a = dt * -jnp.asarray(rng.uniform(1, 4, (H,)), jnp.float32)
     B = jnp.asarray(rng.normal(size=(rows, G, N)), dtype)
     C = jnp.asarray(rng.normal(size=(rows, G, N)), dtype)
     pool = jnp.asarray(rng.normal(size=(entries, H, P, N)), jnp.float32)
-    ids = [jnp.asarray(v) for v in (slot, lens, src, dst)]
-    return (x, dt, a, B, C, pool), ids
+    return (x, dt, a, B, C, pool), row_columns(runs, rows, entries)
 
 
-@pytest.mark.parametrize("runs, rows", [
-    ([(0, 1, 0), (1, 1, -1), (2, 1, 5)], 16),           # decode rows only
-    ([(0, 13, -1)], 16),                                # a chunk, 2 tiles
-    ([(2, 20, 5)], 24),                                 # from a snapshot
-    ([(0, 1, 0), (1, 1, 1), (2, 11, -1), (3, 3, 4)], 24),   # mixed
-    ([(1, 1, 1), (0, 7, 0), (3, 9, 3), (2, 1, -1)], 24),    # tile edges
-], ids=["decode", "chunk", "restore", "mixed", "edges"])
+#: packed rows as ``(runs of (slot, rows, entry to start from), rows)``,
+#: in tiles of 8
+ROW_LAYOUTS = {
+    "decode": ([(0, 1, 0), (1, 1, -1), (2, 1, 5)], 16),
+    "chunk": ([(0, 13, -1)], 16),                       # 2 tiles
+    "restore": ([(2, 20, 5)], 24),                      # from a snapshot
+    "mixed": ([(0, 1, 0), (1, 1, 1), (2, 11, -1), (3, 3, 4)], 24),
+    "edges": ([(1, 1, 1), (0, 7, 0), (3, 9, 3), (2, 1, -1)], 24),
+}
+
+
+@pytest.mark.parametrize("runs, rows", list(ROW_LAYOUTS.values()),
+                         ids=list(ROW_LAYOUTS))
 @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5),
                                         (jnp.bfloat16, 2e-2)],
                          ids=["f32", "bf16"])
@@ -383,6 +396,51 @@ def test_scan_kernel_matches_the_sequential_scan(runs, rows, dtype, tol):
     for e in range(p0.shape[0] - 1):
         if e not in touched:            # snapshots and idle slots: as found
             assert jnp.array_equal(p1[e], args[5][e])
+
+
+#: the conv's own: runs shorter than its K - 1 = 3 rows (the tail left
+#: mixes old entries and new rows: fresh, from a snapshot, from the
+#: slot's own), and runs whose first rows end a tile (a row's window
+#: holds the tail and rows of the tile before)
+CONV_LAYOUTS = {
+    **ROW_LAYOUTS,
+    "short": ([(0, 2, 0), (1, 2, -1), (3, 2, 5), (2, 1, 2)], 16),
+    "halo": ([(0, 5, 0), (1, 6, 1), (2, 4, 5), (3, 2, -1)], 24),
+}
+
+
+@pytest.mark.parametrize("name", list(CONV_LAYOUTS))
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-6),
+                                        (jnp.bfloat16, 2.0 ** -8)],
+                         ids=["f32", "bf16"])
+def test_conv_kernel_matches_the_reference(name, dtype, tol):
+    """Live rows' outputs to 1e-6 of the largest (float32 sums in
+    another order) and to one bf16 ulp; the tails of touched entries
+    bit for bit, every other entry as found."""
+    runs, rows = CONV_LAYOUTS[name]
+    K, C, entries = 4, 96, 7
+    rng = np.random.default_rng(3)
+    slot, _, src, dst = row_columns(runs, rows, entries)
+    x = jnp.asarray(rng.normal(size=(rows, C)), dtype)
+    w = jnp.asarray(rng.normal(size=(K, C)), dtype)
+    b = jnp.asarray(rng.normal(size=(C,)), dtype)
+    pool = jnp.asarray(rng.normal(size=(entries, K - 1, C)), dtype)
+    y0, p0 = packed_causal_conv_reference(x, w, b, pool, slot, src, dst)
+    y1, p1 = packed_causal_conv(x, w, b, pool, slot, src, dst, tile_rows=8,
+                                interpret=True)
+    assert y1.dtype == x.dtype and p1.dtype == pool.dtype
+    live = np.asarray(slot) >= 0
+    y0, y1 = (np.asarray(y, np.float32) for y in (y0, y1))
+    assert np.isfinite(y1).all()
+    if dtype == jnp.float32:
+        assert np.abs(y0 - y1)[live].max() < tol * np.abs(y0[live]).max()
+    else:
+        assert (np.abs(y0 - y1)[live]
+                <= tol * np.abs(y0[live]) + 1e-30).all()
+    touched = {s for s, _, _ in runs}
+    for e in range(entries - 1):        # the trash entry is free to differ
+        want = p0[e] if e in touched else pool[e]
+        assert jnp.array_equal(p1[e], want), e
 
 
 def test_one_chunk_equals_three(model):

@@ -579,10 +579,27 @@ def test_ssd_scan_compiles_at_the_cells_geometry(one_chip):
              ids, ids, ids, ids, kernels=[SSD_SCAN_KERNEL])
 
 
+def test_causal_conv_compiles_at_the_cells_geometry(one_chip):
+    """The Nemotron-3 cell's convolution: 640 packed rows of 10240
+    channels in tiles of 128, four taps, 161 tails of ``[3, 10240]``
+    bf16."""
+    from paddle_tpu.ops.pallas.causal_conv import (CAUSAL_CONV_KERNEL,
+                                                   packed_causal_conv)
+
+    T, C, K, E = 640, 10240, 4, 161
+    ids = ((T,), jnp.int32)
+    _compile(lambda x, w, b, pool, slot, src, dst:
+             packed_causal_conv(x, w, b, pool, slot, src, dst,
+                                tile_rows=128),
+             one_chip, ((T, C), jnp.bfloat16), ((K, C), jnp.bfloat16),
+             ((C,), jnp.bfloat16), ((E, K - 1, C), jnp.bfloat16),
+             ids, ids, ids, kernels=[CAUSAL_CONV_KERNEL])
+
+
 def test_nemotron_step_compiles_with_state_and_pages_written_in_place(
         one_chip, monkeypatch):
     """The Nemotron-H unified step (one M, one E and one * layer at the
-    published widths, 64 of 512 experts held) holds the three kernels of
+    published widths, 64 of 512 experts held) holds the four kernels of
     the cell, copies or transposes no whole pool of either sort, and
     every pool (K/V pages, SSM states, conv tails) is updated in the
     buffer it came in."""
@@ -616,8 +633,15 @@ def test_nemotron_step_compiles_with_state_and_pages_written_in_place(
                     **jax.tree.map(described, kwargs)).compile().as_text()
     names = {m.group(1) for m in re.finditer(
         r"%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call", text)}
-    assert {"mamba2_ssd_scan", "ragged_paged_attention",
+    assert {"mamba2_causal_conv", "mamba2_ssd_scan", "ragged_paged_attention",
             "grouped_matmul_blocks"} <= names, names
+    # a device trace files each kernel under its layer's scope
+    from paddle_tpu.profiler.device_trace import scope_of
+    scopes = {m.group(1): scope_of(m.group(2)) for m in re.finditer(
+        r'%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call[^\n]*op_name="([^"]*)"',
+        text)}
+    assert scopes["mamba2_causal_conv"] == "mamba_conv", scopes
+    assert scopes["mamba2_ssd_scan"] == "ssd_scan", scopes
     moved = []
     for ln in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\]\S* "
@@ -695,8 +719,8 @@ def _rung_engine(layout, monkeypatch):
         cfg = nemotron_h.NemotronHConfig(
             num_hidden_layers=3, hybrid_override_pattern="ME*",
             experts_held=(0, 64), vocab_size=16384)
-        kernels = ("mamba2_ssd_scan", "ragged_paged_attention",
-                   "grouped_matmul_blocks")
+        kernels = ("mamba2_causal_conv", "mamba2_ssd_scan",
+                   "ragged_paged_attention", "grouped_matmul_blocks")
         cap = dict(max_slots=128, max_seq_len=7680, prefill_token_budget=512,
                    state_snapshots=2)
         ladder = (640,)                 # the layout states no tile yet
