@@ -79,6 +79,13 @@ time, and nothing selects it from outside.
   of its pages whatever its context; the prefix cache holds a page of
   each kind a block and serves a hit as far as every kind is whole.  A
   layout of one kind runs the same code with a tuple of length one.
+- A page may have FURTHER pools beside the two (``PagedLayout.more_pools``:
+  a cache of compressed keys, a row for every 16 tokens): the same page
+  id names a page in each, so tables, allocators and the prefix cache
+  share them as they share K and V.  The engine holds one such pool a
+  layer that has pages, hands them to the step as ``pools=`` and takes
+  them back as its LAST result; what a page of one holds, and when a row
+  of it is final, is the step's business.
 - A model may hold a SECOND SORT of state beside its pages
   (``PagedLayout.state``: Mamba-2's per-sequence SSM state and conv
   tail): overwritten every token, so not paged, not addressed by
@@ -282,7 +289,9 @@ class PagedLayout:
     """What a model tells the engine of what a sequence holds on the
     device and of its part of the unified step.  Every layer THAT HAS
     PAGES (a layer of some kind: all of them where ``kinds`` is empty)
-    has TWO pools, and one page id names a page in each pool of every
+    has TWO pools whose page holds a row a token (``rows``) and may have
+    FURTHER pools whose page has a shape of its own (``more_pools``), and
+    one page id names a page in each pool of every
     layer OF ONE KIND, so slots, tables, the allocators and the prefix
     cache never know what a page holds.  A model may hold a second SORT
     of state beside its pages (``state``): a recurrent state of fixed
@@ -308,7 +317,9 @@ class PagedLayout:
     step, a jitted function under ``_unified_step_jit``'s signature
     whose third result is ``(logits, tokens)``; if ``device_counts``
     names counts it takes on the device, ``(logits, tokens, those
-    counts)``.  ``row_counts(rows,
+    counts)``; what a step appends after these, a row a gathered row,
+    stays on the device until ``ContinuousBatchingEngine.last_extras``
+    is read (a model's block selection, for a check to hold).  ``row_counts(rows,
     ctx_tokens, page_size, pages_per_seq)`` gives the step's counts the
     packed rows determine.  Both kinds ride on ``serving.step_counts``
     and are summed in ``serving_stats()["steps"]`` under
@@ -322,6 +333,16 @@ class PagedLayout:
     its step is compiled at the capacity alone, as ever (DeepSeek-V3.2's
     and Nemotron-H's today: PERF.md section 6, PR 36, says what stands
     in the way of each).
+
+    ``more_pools``: a function a further pool, from the page size to the
+    shape of ONE PAGE of it (a cache of compressed keys: ``page // 16``
+    rows of ``kvh * d``), in the cache's dtype.  The engine holds a pool
+    ``[pages, *shape]`` a layer that has pages for each
+    (``ContinuousBatchingEngine.more_pools``), passes them to ``step`` as
+    ``pools=`` (donated) and takes them back as the step's LAST result.
+    What is in a page of one has to depend on the tokens up to that
+    page's end alone, since the prefix cache shares it under the page's
+    id.
 
     ``state``: ``(shape, dtype)`` of each array a slot's recurrent
     state has in ONE state layer (dtype None: the cache's).  The engine
@@ -338,8 +359,8 @@ class PagedLayout:
 
     What the engine can do with K/V pages of one kind alone (a draft
     model's mirror, an int8 cache, the host tier, the prefill-only
-    handoff) refuses other pools, more kinds than one, and a recurrent
-    state, at construction."""
+    handoff) refuses other pools, further pools, more kinds than one,
+    and a recurrent state, at construction."""
     name: str
     rows: tuple
     head_major: bool = True
@@ -350,6 +371,7 @@ class PagedLayout:
     pages_per_step: Any = None
     tile_rows: int = 0
     kinds: tuple = ()
+    more_pools: tuple = ()
     state: tuple = ()
     state_layers: int = 0
     state_snapshots_a_step: int = 0
@@ -603,10 +625,11 @@ class _TrieNode:
     Where sequences hold a recurrent state (``PagedLayout.state``),
     ``snap`` is the entry of the state pools that holds the state AT the
     end of this block (a snapshot; None: none was kept) and
-    ``snap_tick`` when it was last restored from."""
+    ``snap_tick`` when it was last restored from (when it was taken,
+    while ``snap_used`` is False: nobody has restored from it yet)."""
 
     __slots__ = ("children", "key", "page", "parent", "tick", "host_kv",
-                 "more", "snap", "snap_tick")
+                 "more", "snap", "snap_tick", "snap_used")
 
     def __init__(self, key=None, page=None, parent=None, kinds: int = 0):
         self.children: Dict[tuple, "_TrieNode"] = {}
@@ -618,6 +641,7 @@ class _TrieNode:
         self.more: List[Optional[int]] = [None] * kinds
         self.snap: Optional[int] = None
         self.snap_tick = 0
+        self.snap_used = False
 
     @property
     def tier(self) -> str:
@@ -676,8 +700,8 @@ class PrefixCache:
     BOTH: ``lookup_all`` serves a hit as far as the deepest block of
     the walk that has a snapshot, and says how many matched tokens lay
     beyond it (they are prefilled again).  Snapshots have a budget and
-    an LRU of their own (``evict_snapshots``, by when one was last
-    restored from; ``snap_nodes`` is the few blocks that hold one, by
+    an LRU of their own (``evict_snapshots``: those never restored
+    from first, then by when one was last restored from; ``snap_nodes`` is the few blocks that hold one, by
     entry, so neither eviction nor the live count walks the trie); a
     block that goes takes its snapshot along."""
 
@@ -714,9 +738,14 @@ class PrefixCache:
         self.promoted_pages = 0
 
     def _chunks(self, tokens, npages: int):
+        """The keys of a prompt's first ``npages`` blocks: each block's
+        token ids as bytes (a session of 64k tokens is 512 keys a lookup
+        and as many an insert: a tuple of Python ints a block cost 8 ms
+        a pass)."""
         ps = self.page_size
-        return [tuple(int(t) for t in tokens[i * ps:(i + 1) * ps])
-                for i in range(npages)]
+        ids = np.ascontiguousarray(np.asarray(tokens)[:npages * ps],
+                                   dtype=np.int32)
+        return [ids[i * ps:(i + 1) * ps].tobytes() for i in range(npages)]
 
     def lookup(self, prompt):
         """Walk the trie with the prompt's full pages; returns
@@ -799,6 +828,7 @@ class PrefixCache:
             lost = (whole - blocks) * self.page_size
             if blocks:
                 path[blocks - 1].snap_tick = self._tick
+                path[blocks - 1].snap_used = True
                 snap = self.snaps.acquire(path[blocks - 1].snap)
         # what lies past a hit that shrank is handed back
         self.alloc.release([n.page for n in path[blocks:]])
@@ -862,6 +892,7 @@ class PrefixCache:
             child.tick = self._tick
             if child.snap is None and i + 1 in at:
                 child.snap, child.snap_tick = at.pop(i + 1), self._tick
+                child.snap_used = False
                 self.snap_nodes[child.snap] = child
                 self.snapshots_taken += 1
             node = child
@@ -932,14 +963,24 @@ class PrefixCache:
         node.snap = None
         self.evicted_snapshots += 1
 
-    def evict_snapshots(self, needed: int) -> int:
+    def evict_snapshots(self, needed: int, used: bool = True) -> int:
         """Give back up to ``needed`` snapshot entries that only the trie
-        holds, the least recently restored-from first (the blocks stay,
-        with their pages: a later hit is served as far as the deepest
-        snapshot above them).  Returns entries freed."""
+        holds: those nobody ever restored from first (the snapshots a
+        prompt leaves inside its own suffix), then the least recently
+        restored-from (the blocks stay, with their pages: a later hit is
+        served as far as the deepest snapshot above them).  A snapshot
+        that sessions come back to so outlives a burst of prompts that
+        each leave a few nobody will ask for: losing the one at the end
+        of a 64k-token history costs every turn of that session 128
+        chunk steps until one of them has taken it again.  ``used``
+        False: only those nobody restored from (what a snapshot INSIDE a
+        prompt may displace: it is a bet that someone comes back to that
+        point, and does not outbid one that sessions have come back to).
+        Returns entries freed."""
         found = sorted((n for e, n in self.snap_nodes.items()
-                        if self.snaps.refs[e] == 1),
-                       key=lambda n: (n.snap_tick, n.snap))
+                        if self.snaps.refs[e] == 1
+                        and (used or not n.snap_used)),
+                       key=lambda n: (n.snap_used, n.snap_tick, n.snap))
         for n in found[:max(needed, 0)]:
             self._drop_snapshot(n)
         return min(len(found), max(needed, 0))
@@ -1235,14 +1276,18 @@ class ContinuousBatchingEngine:
                                          and enable_prefix_cache):
             raise ValueError("state_snapshots are what the prefix cache of "
                              "a layout with a recurrent state keeps")
-        if self.layout.name != "kv" or len(kinds) > 1 or self.layout.state:
+        if self.layout.name != "kv" or len(kinds) > 1 or self.layout.state \
+                or self.layout.more_pools:
             # the step is all that knows these pools (PagedLayout); what
             # follows moves, mirrors or calibrates K/V pages of ONE kind
             # and knows of no state beside them
             pools = (f"{self.layout.name} pools of {len(kinds)} kinds of page"
                      if len(kinds) > 1 else
                      f"{self.layout.name} pools beside a recurrent state"
-                     if self.layout.state else f"{self.layout.name} pools")
+                     if self.layout.state else
+                     f"{self.layout.name} pools with further pools a page"
+                     if self.layout.more_pools else
+                     f"{self.layout.name} pools")
             for what, asked in (
                     ("a draft model: its mirror launches assume the "
                      "target's K/V geometry", draft_params is not None
@@ -1302,6 +1347,12 @@ class ContinuousBatchingEngine:
                              for ka, _ in shapes)
         self.v_pages = tuple(jnp.zeros(vb, dt, device=home)
                              for _, vb in shapes)
+        # the FURTHER pools of a page (``PagedLayout.more_pools``), one a
+        # layer that has pages each, under the same page ids
+        self.more_pools = tuple(
+            tuple(jnp.zeros((kind_of[i].num_pages, *shape(self.page_size)),
+                            dt, device=home) for i in sorted(kind_of))
+            for shape in self.layout.more_pools)
         # the SECOND sort of state: a pool ``[entries, *shape]`` a state
         # layer for each array of a slot's recurrent state.  Entry s is
         # slot s's own, the next ``state_snapshots`` are snapshots (the
@@ -2484,9 +2535,10 @@ class ContinuousBatchingEngine:
                     # (a layout with a recurrent state takes its pools
                     # as ``state=`` and returns them fourth: the SAME
                     # program reads a slot's entry, a snapshot's or
-                    # zeros, as the rows say)
+                    # zeros, as the rows say; one with further pools a
+                    # page takes them as ``pools=`` and returns them last)
                     for l_rows, l_gather, l_prev in launches:
-                        self.k_pages, self.v_pages, new.out, *state = \
+                        self.k_pages, self.v_pages, new.out, *more = \
                             self._programs[len(l_rows)](
                                 self.params, self.k_pages, self.v_pages,
                                 jnp.asarray(l_rows),
@@ -2496,10 +2548,11 @@ class ContinuousBatchingEngine:
                                 kv_scales=self.kv_scales,
                                 gather=jnp.asarray(l_gather),
                                 prev_tokens=l_prev,
-                                **({"state": self.state}
-                                   if self.layout.state else {}))
-                        if state:
-                            (self.state,) = state
+                                **self._more_arguments())
+                        if self.layout.state:
+                            self.state = more.pop(0)
+                        if self.layout.more_pools:
+                            self.more_pools = more.pop(0)
                         if self.draft is not None:
                             # mirror the SAME rows through the draft: its
                             # paged cache tracks the target's committed
@@ -2531,7 +2584,7 @@ class ContinuousBatchingEngine:
                     if any(self.req_info[m[1]].temperature > 0
                            for m in cur.metas):
                         logits = np.asarray(cur.out[0])
-                self._last_logits = (cur.gathered, cur.out[0])
+                self._last_logits = (cur.gathered, *cur.out)
                 with RecordEvent("serving.commit"):
                     produced = self._commit_unified(cur, tokens, logits,
                                                     this_dec)
@@ -2610,7 +2663,7 @@ class ContinuousBatchingEngine:
         if len(self.ladder) == 1:
             # one shape: JAX's own caches serve it, as they always have
             return {self.rows_cap: partial(self.layout.step, **static)}
-        state = ("state",) if self.layout.state else ()
+        state = tuple(self._more_arguments())
         programs = {}
         for n in self.ladder:
             rung = (*args[:3], self._padding_rows(n), *args[4:])
@@ -2618,6 +2671,15 @@ class ContinuousBatchingEngine:
                 self.layout.step, rung, kwargs, static, donate_argnums=(1, 2),
                 donate_argnames=state, what=repr(self.cfg))
         return programs
+
+    def _more_arguments(self) -> Dict[str, Any]:
+        """What the step takes beside the K and V pools, donated, and
+        returns after its third result in this order: the recurrent
+        state's pools (``state=``) and a page's further pools
+        (``pools=``), for a layout that has them."""
+        return {**({"state": self.state} if self.layout.state else {}),
+                **({"pools": self.more_pools}
+                   if self.layout.more_pools else {})}
 
     def _padding_launches(self):
         """One launch of padding rows a rung of ``ladder``: what
@@ -2758,16 +2820,32 @@ class ContinuousBatchingEngine:
         ``end``, the end of the chunk being packed, or None: no prefix
         cache, not the end of a block the prompt's insert will commit,
         this launch's snapshots taken, or every entry in use by a
-        restore."""
+        restore.  A prompt holds at most half the entries before its
+        insert: past that, and where no entry can be had, it gives up
+        its own shallowest."""
         pc = self.prefix_cache
         prompt_len = self.prompt_lens[int(self.slot_rid[slot])]
         if (pc is None or end % self.page_size or end > prompt_len
                 or taken >= self.layout.state_snapshots_a_step):
             return None
-        entry = self.snap_alloc.alloc()
-        if entry is None and pc.evict_snapshots(1):
+        # taken by a committed launch and not yet the cache's (the
+        # prompt's insert hands them over): nobody can restore from them
+        own = self.slot_snaps.get(slot, [])
+        if len(own) < max(1, self.state_snapshots // 2):
             entry = self.snap_alloc.alloc()
-        return entry
+            # (only the snapshot at a prompt's END, where a session goes
+            # on, may displace one that was restored from)
+            if entry is None and pc.evict_snapshots(1,
+                                                    used=end == prompt_len):
+                entry = self.snap_alloc.alloc()
+            if entry is not None:
+                return entry
+        # a long prompt passes more chunk ends than there are entries:
+        # its own SHALLOWEST pending snapshot makes room for the deeper
+        # one (a hit is served as far as the deepest), so that one
+        # session's prefill neither ends without a snapshot at its end
+        # nor drains the cache of every other session's
+        return own.pop(0)[1] if own else None
 
     def _commit_unified(self, launch: _Launch, tokens: np.ndarray,
                         logits: Optional[np.ndarray],
@@ -2955,8 +3033,22 @@ class ContinuousBatchingEngine:
         them.  Setting it to ``None`` forgets them."""
         if self._last_logits is None:
             return None
-        gathered, logits = self._last_logits
+        gathered, logits = self._last_logits[:2]
         return gathered, np.asarray(logits)[:len(gathered)]
+
+    @property
+    def last_extras(self) -> tuple:
+        """What the newest committed launch's step returned of its
+        gathered rows beyond logits, tokens and the device's counts
+        (``PagedLayout.step``: whatever a model adds for a check to read,
+        a row a gathered row each), copied from the device when this is
+        read and cut to the gathered rows; ``()`` where there is none."""
+        if self._last_logits is None:
+            return ()
+        gathered = self._last_logits[0]
+        skip = 3 + bool(self.layout.device_counts)
+        return tuple(np.asarray(x)[:len(gathered)]
+                     for x in self._last_logits[skip:])
 
     @last_logits.setter
     def last_logits(self, value) -> None:
@@ -3017,8 +3109,7 @@ class ContinuousBatchingEngine:
                       pages_per_step=self.pages_per_step,
                       kv_scales=kv_scales,
                       gather=jnp.zeros(self.gather_cap, jnp.int32),
-                      prev_tokens=self._no_tokens,
-                      **({"state": self.state} if self.layout.state else {}))
+                      prev_tokens=self._no_tokens, **self._more_arguments())
         # min_bytes sized to the page pools, not the 1MB production
         # default: tiny test/debug engines must still FAIL the doctor if
         # the pools stop being donated (a vacuous gate passes when the

@@ -257,11 +257,13 @@ class NemotronHConfig:
         return out
 
 
-def mamba_part(cfg, w, i, x, ssm_pool, conv_pool, slot, lens, src, dst):
+def mamba_part(cfg, w, i, x, ssm_pool, conv_pool, slot, lens, src, dst,
+               max_slots: int):
     """Layer ``i``'s Mamba-2 mixer on the packed rows ``x`` ``[T,
     hidden]``: each slot's rows start from state entry ``src`` (below
-    zero: zeros) and leave the state in entry ``dst``, in both pools.
-    Returns ``(x + mixer, ssm pool, conv pool)``."""
+    zero: zeros) and leave the state in entry ``dst``, in both pools;
+    ``max_slots`` bounds the slots the rows can name (the scan's units
+    of work).  Returns ``(x + mixer, ssm pool, conv pool)``."""
     from ..ops.pallas.causal_conv import (packed_causal_conv,
                                           packed_causal_conv_reference)
     from ..ops.pallas.ssd_scan import (mamba2_ssd_scan, ssd_max_units,
@@ -298,8 +300,7 @@ def mamba_part(cfg, w, i, x, ssm_pool, conv_pool, slot, lens, src, dst):
             y, ssm_pool = mamba2_ssd_scan(
                 xs, dt, a, B, C, ssm_pool, slot, lens, src, dst,
                 tile_rows=cfg.chunk_size,
-                max_units=ssd_max_units(T, cfg.chunk_size,
-                                        ssm_pool.shape[0]))
+                max_units=ssd_max_units(T, cfg.chunk_size, max_slots))
         y = y + xs.astype(jnp.float32) \
             * w.layer(i, mx + "D").astype(jnp.float32)[None, :, None]
     with jax.named_scope("mamba_out"):
@@ -394,7 +395,7 @@ def unified_step_jit(params, k_pages, v_pages, rows, tables, cos_tab,
         if letter == "M":
             x, ssm[n_state], conv[n_state] = mamba_part(
                 cfg, w, i, x, ssm[n_state], conv[n_state], slot, lens, src,
-                dst)
+                dst, table.shape[0])
             with jax.named_scope("state_snapshot"):
                 ssm[n_state] = ssm[n_state].at[snap_to].set(
                     ssm[n_state][snap_from])

@@ -16,7 +16,12 @@ carry the same two numbers.
 
 Units of work are ``decode_attention.ragged_units``': a maximal run of
 one slot's rows inside one tile of ``tile_rows`` (the model's
-``chunk_size``).  The grid is (group of heads, unit); a unit of one row
+``chunk_size``).  The grid is (block of heads, unit): a block of ``hb``
+heads holds one or more whole GROUPS, each head reading its own group's
+``B`` and ``C`` (Mamba-2: a block is one group of ``H / G`` heads that
+share them; linear attention, where every head has its own key and
+query, ``G = H``: a block of ``heads_per_step`` heads with a ``B`` and a
+``C`` each); a unit of one row
 takes the recurrent form (the state is read, decayed, given one outer
 product, read out against ``C`` and written: two passes over the state
 and nothing else of its size); a longer unit takes the chunked form:
@@ -140,15 +145,18 @@ def _dot_f32(p, f, mm):
 
 def _ssd_kernel(row0_ref, cnt_ref, mode_ref, tfirst_ref, in_ref, out_ref,
                 tile_ref, xT_ref, b_ref, c_ref, ar_ref, cs_ref, s_ref,
-                yT_ref, o_ref, *, tile: int, hb: int, P: int, N: int):
-    """One unit of work of one group of ``hb`` heads (module docstring).
+                yT_ref, o_ref, *, tile: int, hb: int, P: int, N: int,
+                hpg: int):
+    """One unit of work of one block of ``hb`` heads, ``hpg`` heads a
+    group (module docstring).
     Prefetched, a unit: its first packed row, its rows (0: padding),
     where its state comes from (0 the input block, 1 zeros, 2 the unit
     before it), whether it is its tile's first, the entries and the
     tile.  ``xT_ref`` ``[hb P, tile]``, ``b_ref`` / ``c_ref`` ``[tile,
-    N]``, ``ar_ref`` ``[1, tile, hb]`` (``a``, rows down), ``cs_ref``
-    ``[1, 2 hb, tile]`` (``dt`` then ``a``, rows along), ``s_ref`` /
-    ``o_ref`` ``[1, hb, P, N]``, ``yT_ref`` ``[hb P, tile]``."""
+    N]`` (a block of one group) or ``[hb / hpg, tile, N]``, ``ar_ref`` ``[1,
+    tile, hb]`` (``a``, rows down), ``cs_ref`` ``[1, 2 hb, tile]``
+    (``dt`` then ``a``, rows along), ``s_ref`` / ``o_ref`` ``[1, hb, P,
+    N]``, ``yT_ref`` ``[hb P, tile]``."""
     u = pl.program_id(1)
     cnt, mode = cnt_ref[u], mode_ref[u]
     r = row0_ref[u] - tile_ref[u] * tile
@@ -162,6 +170,13 @@ def _ssd_kernel(row0_ref, cnt_ref, mode_ref, tfirst_ref, in_ref, out_ref,
         return jnp.where(mode == 2, o_ref[0],
                          jnp.where(mode == 1, 0.0, s_ref[0]))
 
+    def of_group(ref, h, rows=slice(None)):
+        """Head ``h``'s group's rows of ``b_ref`` / ``c_ref``: ``[tile,
+        N]`` where the block is one group, else ``[groups, tile, N]``."""
+        if hb == hpg:
+            return ref[rows, :]
+        return ref[h // hpg, rows, :]
+
     @pl.when(cnt == 1)
     def _():
         # the recurrent form: row r of the tile, broadcast along lanes
@@ -171,14 +186,24 @@ def _ssd_kernel(row0_ref, cnt_ref, mode_ref, tfirst_ref, in_ref, out_ref,
         w = jnp.dot(cs_ref[0], pick.astype(jnp.float32), precision=_HI,
                     preferred_element_type=jnp.float32)       # [2 hb, N]
         dt_b, a_b = w[:hb], w[hb:]
-        brow = b_ref[pl.ds(r, 1), :]                          # [1, N]
-        crow = c_ref[pl.ds(r, 1), :]
-        s1 = s * jnp.exp(a_b)[:, None, :] \
-            + (xb * dt_b[:, None, :]) * brow[None]
-        o_ref[0] = s1
         place = lax.broadcasted_iota(jnp.int32, (N, tile), 1) == r
-        yT_ref[...] += _dot_f32((s1 * crow[None]).reshape(hb * P, N),
-                                place, mm)
+        if hb == hpg:
+            # ONE group: its row of B and of C serves every head
+            brow = b_ref[pl.ds(r, 1), :]                      # [1, N]
+            crow = c_ref[pl.ds(r, 1), :]
+            s1 = s * jnp.exp(a_b)[:, None, :] \
+                + (xb * dt_b[:, None, :]) * brow[None]
+            o_ref[0] = s1
+            yT_ref[...] += _dot_f32((s1 * crow[None]).reshape(hb * P, N),
+                                    place, mm)
+        else:
+            for h in range(hb):
+                s1 = s[h] * jnp.exp(a_b[h:h + 1]) \
+                    + (xb[h] * dt_b[h:h + 1]) \
+                    * of_group(b_ref, h, pl.ds(r, 1))
+                o_ref[0, h] = s1
+                yT_ref[h * P:(h + 1) * P, :] += _dot_f32(
+                    s1 * of_group(c_ref, h, pl.ds(r, 1)), place, mm)
 
     @pl.when(cnt > 1)
     def _():
@@ -207,17 +232,21 @@ def _ssd_kernel(row0_ref, cnt_ref, mode_ref, tfirst_ref, in_ref, out_ref,
                         precision=_HI, preferred_element_type=jnp.float32)
         tot_n = jnp.dot(a_c, jnp.ones((tile, N), jnp.float32),
                         precision=_HI, preferred_element_type=jnp.float32)
-        bm, cm = b_ref[...].astype(mm), c_ref[...].astype(mm)
-        gT = _dot(bm, cm, ((1,), (1,)))                       # [r, t]
         valid = (i1 >= i0) & m_col & m_row                    # t >= r, live
+        groups = {}
         for h in range(hb):
+            if h // hpg not in groups:
+                bm = of_group(b_ref, h).astype(mm)
+                groups[h // hpg] = (bm, _dot(
+                    bm, of_group(c_ref, h).astype(mm), ((1,), (1,))))
+            bm, gT = groups[h // hpg]                         # gT [r, t]
             s0 = s[h]                                         # [P, N]
             lt = LT[h:h + 1, :]                               # [1, tile]
             dec = jnp.exp(jnp.where(valid, lt - L[:, h:h + 1], -jnp.inf))
             xdt = xT_ref[h * P:(h + 1) * P, :].astype(jnp.float32) \
                 * dt_c[h:h + 1, :]                            # [P, tile]
             y = _dot(xdt.astype(mm), (gT * dec).astype(mm))   # [P, t]
-            y0 = lax.dot_general(s0, c_ref[...],
+            y0 = lax.dot_general(s0, of_group(c_ref, h),
                                  (((1,), (1,)), ((), ())), precision=_HI,
                                  preferred_element_type=jnp.float32)
             y = y + y0 * jnp.exp(lt)
@@ -228,19 +257,27 @@ def _ssd_kernel(row0_ref, cnt_ref, mode_ref, tfirst_ref, in_ref, out_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("tile_rows", "max_units",
-                                             "interpret"))
+                                             "heads_per_step", "interpret"))
 def mamba2_ssd_scan(x, dt, a, B, C, pool, slot, lens, src, dst, *,
-                    tile_rows: int, max_units=None, interpret=False):
+                    tile_rows: int, max_units=None, heads_per_step=None,
+                    interpret=False):
     """The kernel over packed rows, under ``ssd_scan_reference``'s
     arguments and results plus ``lens`` (the rows' visibilities, for
     ``ragged_units``).  ``pool``'s last entry is the trash entry;
-    ``tile_rows`` divides the rows."""
+    ``tile_rows`` divides the rows.  ``heads_per_step``: the heads of a
+    grid step's block, whole groups of them (left unset: one group)."""
     T, H, P = x.shape
     G, N = B.shape[1], B.shape[2]
-    hb, tile = H // G, int(tile_rows)
+    tile = int(tile_rows)
     if T % tile or H % G:
         raise ValueError(f"{T} rows in tiles of {tile}, {H} heads in "
                          f"{G} groups")
+    hpg = H // G
+    hb = int(heads_per_step or hpg)
+    if hb % hpg or H % hb:
+        raise ValueError(f"a step's {hb} heads are whole groups of {hpg} "
+                         f"and divide the {H} heads")
+    gb, G = hb // hpg, H // hb          # groups a block; blocks
     U = int(max_units or T)
     trash = pool.shape[0] - 1
     count, _ = ragged_units(slot, lens, tile, jnp)
@@ -266,13 +303,20 @@ def mamba2_ssd_scan(x, dt, a, B, C, pool, slot, lens, src, dst, *,
                          axis=-1).transpose(1, 2, 0)          # [G, 2hb, T]
     # float32: a decode unit slices ONE row of them, which a packed
     # dtype's tiles do not give; the chunked form casts its tiles
-    Bf = B.reshape(T, G * N).astype(jnp.float32)
-    Cf = C.reshape(T, G * N).astype(jnp.float32)
+    if gb == 1:
+        Bf = B.reshape(T, G * N).astype(jnp.float32)
+        Cf = C.reshape(T, G * N).astype(jnp.float32)
+        bc_spec = pl.BlockSpec((tile, N), lambda g, u, *r: (r[6][u], g))
+    else:
+        # group-major: a group's rows are a [tile, N] slab of the block
+        Bf = B.astype(jnp.float32).transpose(1, 0, 2)         # [groups, T, N]
+        Cf = C.astype(jnp.float32).transpose(1, 0, 2)
+        bc_spec = pl.BlockSpec((gb, tile, N),
+                               lambda g, u, *r: (g, r[6][u], 0))
 
     in_specs = [
         pl.BlockSpec((hb * P, tile), lambda g, u, *r: (g, r[6][u])),
-        pl.BlockSpec((tile, N), lambda g, u, *r: (r[6][u], g)),
-        pl.BlockSpec((tile, N), lambda g, u, *r: (r[6][u], g)),
+        bc_spec, bc_spec,
         pl.BlockSpec((1, tile, hb), lambda g, u, *r: (g, r[6][u], 0)),
         pl.BlockSpec((1, 2 * hb, tile), lambda g, u, *r: (g, 0, r[6][u])),
         pl.BlockSpec((1, hb, P, N), lambda g, u, *r: (r[4][u], g, 0, 0)),
@@ -282,7 +326,7 @@ def mamba2_ssd_scan(x, dt, a, B, C, pool, slot, lens, src, dst, *,
         pl.BlockSpec((1, hb, P, N), lambda g, u, *r: (r[5][u], g, 0, 0)),
     ]
     yT, pool = pl.pallas_call(
-        functools.partial(_ssd_kernel, tile=tile, hb=hb, P=P, N=N),
+        functools.partial(_ssd_kernel, tile=tile, hb=hb, P=P, N=N, hpg=hpg),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7, grid=(G, U), in_specs=in_specs,
             out_specs=out_specs),

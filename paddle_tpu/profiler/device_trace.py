@@ -37,14 +37,15 @@ from typing import (Any, Dict, Iterable, Iterator, List, NamedTuple, Optional,
 
 # every scope a serving step opens (``inference/serving.py``,
 # ``models/generation.py``, ``models/deepseek_v32.py``,
-# ``models/nemotron_h.py``); tests/test_device_scopes.py holds each
+# ``models/nemotron_h.py``, ``models/minicpm_sala.py``);
+# tests/test_device_scopes.py holds each
 # compiled step's working instructions to it
 DEVICE_SCOPES = (
     "embed", "attn_qkv", "kv_scatter", "paged_attn", "attn_out", "mlp",
     "moe_route", "moe_experts", "shared_expert", "moe_latent_down",
     "moe_latent_up", "mla_qkv", "index_select", "sparse_attn", "mamba_in_proj",
     "mamba_conv", "ssd_scan", "state_snapshot", "mamba_out", "lm_head",
-    "sample")
+    "sample", "lightning_qkv", "lightning_out", "block_select", "ckey_write")
 UNSCOPED = "unscoped"       # a path, and no component of it in the set
 COMPILER = "compiler"       # no path: XLA's own operation
 

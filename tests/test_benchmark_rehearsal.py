@@ -90,3 +90,52 @@ def test_the_real_nemotron_cell_loads_with_its_readers(monkeypatch):
         *accepted.NEW_METRICS, *sorted(BY_SCOPE), "moe_dense_ms.serve",
         "mamba_proj_ms.serve", "mamba_conv_ms.serve"))
     accepted.test_the_real_nemotron_cell_loads_with_its_readers()
+
+
+# PR 39: the MiniCPM-SALA cell's rehearsal (runner ``serve_minicpm_sala``
+# at a tiny size with three pools a page and a recurrent state, its six
+# controls, a token altered, a state pool of another type, a row made
+# dense, its files and its readers) and the saturated Mistral cell's
+# files run with the tier-1 tests too
+from benchmarks.tests.test_minicpm_sala_cell import (  # noqa: E402,F401
+    sala_root, test_a_row_made_dense_is_not_correct,
+    test_a_sala_control_comes_out_not_correct,
+    test_a_token_altered_is_not_correct,
+    test_block_sparse_roofline_reader_counts_least_work,
+    test_lightning_scan_roofline_reader_counts_least_work,
+    test_sala_sound_run_is_correct_and_restores_its_histories,
+    test_the_real_sala_cell_loads_with_its_readers,
+    test_the_references_own_selection_reads_the_same_in_float32,
+    test_the_saturated_cell_is_data_beside_the_chat_cell)
+from benchmarks.tests.test_minicpm_sala_cell import (  # noqa: E402,F401
+    test_a_state_pool_of_another_type_is_not_correct as
+    test_a_sala_state_pool_of_another_type_is_not_correct)
+
+
+def test_the_saturated_mix_runs_closed_loop_through_runner_serve(
+        tiny_root):  # noqa: F811
+    """The accepted rehearsal's tiny Llama cell under a closed-loop mix of
+    the saturated cell's form: clients keep the engine full, every
+    request sent finishes or is cut by the drain, and ``correct`` holds."""
+    import json
+
+    b = tiny_root / "benchmarks"
+    chat = json.loads((b / "traffic/tiny-chat.json").read_text())
+    (b / "traffic/tiny-saturated.json").write_text(json.dumps({
+        **chat, "arrivals": {"process": "closed", "clients": 5, "pool": 64}}))
+    man = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    if not any(w["name"] == "tiny-serve.saturated" for w in man["workloads"]):
+        man["workloads"].append({"name": "tiny-serve.saturated",
+                                 "config": "tiny-serve",
+                                 "traffic": "tiny-saturated", "chips": 1,
+                                 "why": "rehearsal"})
+        for m in man["end_to_end"] + man["per_layer"]:
+            if "tiny-serve.chat" in m.get("workloads", []) \
+                    and m["name"] != "ttft_p95_ms":
+                m["workloads"].append("tiny-serve.saturated")
+        (tiny_root / "BENCHMARK.json").write_text(json.dumps(man))
+    line = _run(tiny_root, "tiny-serve.saturated")
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] >= 5       # (a tiny model cycles the pool)
+    assert "ttft_p95_ms" not in line["metrics"]
+    assert {"serve_tokens_per_s", "setup_s"} <= set(line["metrics"])

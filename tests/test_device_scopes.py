@@ -375,6 +375,16 @@ def _nemotron():
         state_snapshots=2)
 
 
+def _minicpm_sala():
+    from paddle_tpu.models.minicpm_sala import MiniCPMSALAConfig
+
+    cfg = MiniCPMSALAConfig.debug()
+    return ContinuousBatchingEngine(
+        cfg, _draw(cfg), max_slots=2, num_pages=40, page_size=8,
+        max_seq_len=64, prefill_token_budget=8, enable_prefix_cache=True,
+        state_snapshots=2)
+
+
 WORKS = re.compile(r" (fusion|dot|convolution|custom-call|scatter|gather|sort"
                    r"|reduce|reduce-window|while|conditional|call)\(")
 
@@ -405,7 +415,11 @@ def hlo_scopes(text):
                  "paged_attn", "attn_out", "mlp", "moe_route", "moe_experts",
                  "shared_expert", "moe_latent_down", "moe_latent_up",
                  "lm_head", "sample"}),
-], ids=["llama", "mellum2", "deepseek_v32", "nemotron_h"])
+    (_minicpm_sala, {"embed", "lightning_qkv", "ssd_scan", "lightning_out",
+                     "state_snapshot", "attn_qkv", "kv_scatter", "ckey_write",
+                     "paged_attn", "block_select", "sparse_attn", "attn_out",
+                     "mlp", "lm_head", "sample"}),
+], ids=["llama", "mellum2", "deepseek_v32", "nemotron_h", "minicpm_sala"])
 def test_every_working_instruction_of_a_step_is_under_a_scope(engine, must):
     """The compiled step at debug widths: every instruction of its entry
     computation that does work carries an ``op_name`` with a component
@@ -428,7 +442,8 @@ def test_the_steps_open_no_scope_outside_the_set():
     ``DEVICE_SCOPES``, and every scope of the set is opened somewhere."""
     opened = set()
     for rel in ("inference/serving.py", "models/generation.py",
-                "models/deepseek_v32.py", "models/nemotron_h.py"):
+                "models/deepseek_v32.py", "models/nemotron_h.py",
+                "models/minicpm_sala.py"):
         opened |= set(re.findall(r'jax\.named_scope\("(\w+)"\)',
                                  (ROOT / "paddle_tpu" / rel).read_text()))
     assert opened == set(dt.DEVICE_SCOPES)

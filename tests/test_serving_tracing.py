@@ -625,3 +625,68 @@ def test_state_counts_hold_together(traced_state):
     assert pc["snapshots_taken"] == steps["state_snapshots_taken"] == 3
     assert pc["snapshots_evicted"] == steps["state_snapshots_evicted"] == 1
     assert pc["snapshots_live"] == 2
+
+
+# ---- a layout with three pools a page and a recurrent state -------------
+
+@pytest.fixture(scope="module")
+def traced_sala(tmp_path_factory):
+    """One traced run of a small MiniCPM-SALA-shaped engine: a session
+    history past ``dense_len`` in the prefix cache, two turns that
+    restore its snapshot and select blocks from its compressed keys."""
+    from paddle_tpu.models.minicpm_sala import MiniCPMSALAConfig
+
+    cfg = MiniCPMSALAConfig.debug()
+    rng = np.random.default_rng(0)
+    params = {k: jnp.asarray(1.0 + 0.1 * rng.normal(size=s) if len(s) == 1
+                             else 0.3 * rng.normal(size=s), jnp.float32)
+              for k, s in cfg.leaf_shapes().items()}
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=2, num_pages=40, page_size=8, max_seq_len=96,
+        prefill_token_budget=8, enable_prefix_cache=True, state_snapshots=3)
+    history = rng.integers(1, 96, 48)
+    turns = [rng.integers(1, 96, n) for n in (5, 11)]
+    trace_dir = tmp_path_factory.mktemp("trace-sala")
+    with _trace(trace_dir):
+        for turn in ([], *turns):
+            eng.add_request(np.concatenate([history, turn]).astype(np.int32),
+                            max_new_tokens=4)
+            eng.run()
+        eng.step()
+    stats = eng.serving_stats()
+    eng.assert_balanced()
+    eng.shutdown()
+    return {"spans": _program_spans(trace_dir), "stats": stats}
+
+
+@pytest.mark.parametrize("key", [
+    "sparse_rows", "dense_rows", "ckey_ctx", "ckey_slot_ctx", "sel_blocks",
+    "sel_kv_tokens", "sel_kv_tokens_read", "state_rows", "state_slots",
+    "state_snapshots_taken", "state_restored_tokens"])
+def test_sala_counts_add_up_to_serving_stats(traced_sala, key):
+    kept = traced_sala["stats"]["steps"][key]
+    counts = [c for *_, c in _named(traced_sala, "serving.step_counts")]
+    assert kept == sum(c.get(key, 0) for c in counts) > 0
+
+
+def test_sala_counts_hold_together(traced_sala):
+    counts = [c for *_, c in _named(traced_sala, "serving.step_counts")]
+    for c in (c for c in counts if c["rows"]):
+        assert c["sparse_rows"] + c["dense_rows"] == c["state_rows"] \
+            == c["rows"]
+        assert c["state_slots"] == c["slots"]
+        # 2 K/V groups, 4 blocks of 4 tokens a selecting row and group,
+        # each fetched once
+        assert c["sel_blocks"] == 8 * c["sparse_rows"]
+        assert c["sel_kv_tokens_read"] == c["sel_kv_tokens"] \
+            == 4 * c["sel_blocks"]
+        # a slot's compressed keys are scored by each of its rows
+        assert c["ckey_slot_ctx"] <= c["ckey_ctx"]
+        assert (c["ckey_ctx"] > 0) == (c["sparse_rows"] > 0)
+    # the first 32 tokens of the history attend densely, the rest select
+    assert sum(c["dense_rows"] for c in counts) == 32
+    # both turns restored the snapshot at the history's end, 48 tokens
+    admits = [a for *_, a in _named(traced_sala, "serving.admit_request")]
+    assert [(a["cached_tokens"], a["state_restored_tokens"],
+             a["state_lost_tokens"]) for a in admits] == [
+        (0, 0, 0), (48, 48, 0), (48, 48, 0)]

@@ -655,6 +655,95 @@ def test_nemotron_step_compiles_with_state_and_pages_written_in_place(
     assert aliased == 4                 # K, V, one SSM pool, one conv pool
 
 
+def test_ssd_scan_compiles_at_lightnings_geometry(one_chip):
+    """The MiniCPM-SALA cell's scan: 640 packed rows (96 slots and a
+    512-token chunk, padded to tiles of 128), 32 heads of 128 with a key
+    and a query EACH (32 groups of one head), 16 heads a grid step, 129
+    state entries of 2.1 MB."""
+    from paddle_tpu.ops.pallas.ssd_scan import (SSD_SCAN_KERNEL,
+                                                mamba2_ssd_scan,
+                                                ssd_max_units)
+
+    T, H, P, N, E = 640, 32, 128, 128, 129
+    ids = ((T,), jnp.int32)
+    _compile(lambda x, dt, a, B, C, pool, slot, lens, src, dst:
+             mamba2_ssd_scan(x, dt, a, B, C, pool, slot, lens, src, dst,
+                             tile_rows=128, heads_per_step=16,
+                             max_units=ssd_max_units(T, 128, 96)),
+             one_chip, ((T, H, P), jnp.bfloat16), ((T, H), jnp.float32),
+             ((T, H), jnp.float32), ((T, H, N), jnp.bfloat16),
+             ((T, H, N), jnp.bfloat16), ((E, H, P, N), jnp.float32),
+             ids, ids, ids, ids, kernels=[SSD_SCAN_KERNEL])
+
+
+def test_block_sparse_kernels_compile_at_the_cells_geometry(one_chip):
+    """The MiniCPM-SALA cell's two kernels: 608 packed rows of 32 query
+    heads over 2 K/V heads of 128; 96 slots of 4480 flat compressed keys
+    (552 pages, padded to whole lanes); 4096 pages of 128 tokens, 64
+    selected blocks of 64 tokens a row and group, the selection in SMEM
+    a tile of 8 rows."""
+    from paddle_tpu.ops.pallas import block_sparse_attention as bsa
+
+    T, H, d, kvh, slots, pages, W = 608, 32, 128, 2, 96, 4096, 4480
+    ids = ((T,), jnp.int32)
+    _compile(lambda q, ck, slot, nck: bsa.infllm_block_scores(
+                 q, ck, slot, nck, max_units=slots + T // 16),
+             one_chip, ((T, H, d), jnp.bfloat16),
+             ((slots, W, kvh * d), jnp.bfloat16), ids, ids,
+             kernels=[bsa.BLOCK_SCORES_KERNEL])
+    pool = ((pages, kvh, 128, d), jnp.bfloat16)
+    _compile(lambda q, k, v, sel, lens, slot, table, live:
+             bsa.block_sparse_paged_attention(q, k, v, sel, lens, slot, table,
+                                              live, block=64),
+             one_chip, ((T, H, d), jnp.bfloat16), pool, pool,
+             ((T, kvh, 64), jnp.int32), ids, ids,
+             ((slots, 552), jnp.int32), ((T,), jnp.bool_),
+             kernels=[bsa.BLOCK_SPARSE_KERNEL])
+
+
+def test_minicpm_sala_step_compiles_with_three_pools_and_state_in_place(
+        one_chip, monkeypatch):
+    """The MiniCPM-SALA unified step (one minicpm4 and one lightning-attn
+    layer at the published widths, the whole vocabulary) holds the four
+    kernels of the cell, each under its layer's scope, and every pool (K,
+    V and compressed-key pages, lightning states) is updated in the
+    buffer it came in."""
+    from paddle_tpu.inference.serving import ContinuousBatchingEngine
+    from paddle_tpu.models import minicpm_sala
+    from paddle_tpu.ops.pallas import decode_attention
+
+    for mod in (minicpm_sala, decode_attention):
+        monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
+    cfg = minicpm_sala.MiniCPMSALAConfig(layers_run=(9, 11))
+    params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+              for k, s in cfg.leaf_shapes().items()}
+    slots, pages, snaps = 8, 24, 2
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_slots=slots, num_pages=pages, page_size=128,
+        max_seq_len=70656, prefill_token_budget=120, enable_prefix_cache=True,
+        state_snapshots=snaps)
+    fn, args, kwargs, _ = eng.analysis_entry()
+    assert args[3].shape == (128, 8)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    static = {k: kwargs.pop(k) for k in ("self_cfg_id", "pages_per_step")}
+    text = fn.lower(*jax.tree.map(described, args), **static,
+                    **jax.tree.map(described, kwargs)).compile().as_text()
+    from paddle_tpu.profiler.device_trace import scope_of
+    scopes = {m.group(1): scope_of(m.group(2)) for m in re.finditer(
+        r'%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call[^\n]*op_name="([^"]*)"',
+        text)}
+    assert scopes == {"mamba2_ssd_scan": "ssd_scan",
+                      "ragged_paged_attention": "paged_attn",
+                      "infllm_block_scores": "block_select",
+                      "block_sparse_paged_attention": "sparse_attn"}, scopes
+    header = next(ln for ln in text.splitlines() if "HloModule" in ln)
+    aliased = len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header))
+    assert aliased == 4                 # K, V, compressed keys, one state
+
+
 def test_one_kind_of_page_lowers_the_step_it_lowered(one_chip, monkeypatch):
     """A Llama config has one kind of page, and its step is the program
     it was before layouts had kinds: one table, five columns a row, the
