@@ -35,7 +35,10 @@ through its one ``step()``: ``paged_layout()`` says which layers have
 pages (the ``minicpm4`` layers, one kind), that a page has a THIRD pool
 there beside K and V (the compressed keys, ``PagedLayout.more_pools``),
 what a slot's recurrent state is a ``lightning-attn`` layer
-(``PagedLayout.state``) and gives ``unified_step_jit``, this model's
+(``PagedLayout.state``), how many packed rows are whole tiles of every
+kernel of the step (``PagedLayout.tile_rows``: the engine compiles the
+step at a ladder of such row counts and a launch takes the smallest
+that holds its rows) and gives ``unified_step_jit``, this model's
 part of the unified step, whose packed rows are Nemotron-H's: the five
 columns every model has, then the state entry the row's slot starts
 from, the entry its state is left in and the entry a snapshot goes to.
@@ -191,11 +194,19 @@ class MiniCPMSALAConfig:
 
     def paged_layout(self):
         from ..inference.serving import PagedLayout, PageKind
-        from ..ops.pallas.decode_attention import default_pages_per_step
+        from ..ops.pallas import block_sparse_attention as bsa
+        from ..ops.pallas.decode_attention import (default_pages_per_step,
+                                                   ragged_tile_rows)
 
         c = self
         kvh, d = c.num_key_value_heads, c.head_dim
         H, dl = c.lightning_nh, c.lightning_head_dim
+        # the least row count that is whole tiles of every kernel of the
+        # step: the scores', the block-sparse kernel's, the dense walk's
+        # and the scan's (128 at the published widths: the scan's)
+        tile_rows = math.lcm(
+            bsa.SCORES_TILE_ROWS, bsa.SPARSE_TILE_ROWS,
+            ragged_tile_rows(c.num_attention_heads, kvh, d), SCAN_TILE_ROWS)
 
         def row_counts(rows, ctx_tokens, page_size, pages_per_seq):
             # a layer's: the attention rows by path, what the selecting
@@ -229,6 +240,7 @@ class MiniCPMSALAConfig:
                          "state_rows", "state_slots"),
             pages_per_step=lambda page, pps, itemsize: default_pages_per_step(
                 page, kvh, d, pps, itemsize),
+            tile_rows=tile_rows,
             kinds=(PageKind("pages", c.layers_of(_S)),),
             more_pools=(lambda page: (page // c.kernel_stride, kvh * d),),
             state=(((H, dl, dl), "float32"),),

@@ -1,12 +1,12 @@
-"""Toy engines of the four layouts whose derived ladders have several
-rungs (the kernels' row tile is 8 or 16 at these widths; four rungs for
-the Llama-shaped engine, two for Mellum2's, whose step compiles slowest
-here, and the ONE that DeepSeek's and Nemotron-H's layouts keep while
-they state no tile), the mixed
-trace they serve, and the check that a ladder serves what the top rung
-serves: shared by ``test_serving_ladder.py`` and
-``test_serving_ladder_kinds_state.py`` (two files, so that two workers
-share the compiles)."""
+"""Toy engines of the five layouts: those whose derived ladders have
+several rungs (the kernels' row tile is 8 or 16 at these widths; four
+rungs for the Llama-shaped engine, two for Mellum2's, whose step
+compiles slowest here, three for MiniCPM-SALA's with its scan's tile
+cut to 16 rows) and the ONE that DeepSeek's and Nemotron-H's layouts
+keep while they state no tile, the mixed trace they serve, and the
+check that a ladder serves what the top rung serves: shared by
+``test_serving_ladder.py`` and ``test_serving_ladder_kinds_state.py``
+(two files, so that two workers share the compiles)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -14,7 +14,7 @@ import pytest
 
 from paddle_tpu.inference.serving import ContinuousBatchingEngine
 
-# ---- the four layouts at toy widths ------------------------------------
+# ---- the five layouts at toy widths ------------------------------------
 
 def _draw(cfg, seed, scale=0.08):
     rng = np.random.default_rng(seed)
@@ -106,11 +106,35 @@ def _nemotron():
         state_snapshots=4)
 
 
+#: the rows of the scan's tile in the MiniCPM-SALA toy (the published
+#: 128 would pass the toy's capacity and leave one rung)
+SALA_SCAN_TILE = 16
+
+
+def _minicpm_sala():
+    """One ``minicpm4`` and one ``lightning-attn`` layer: three pools a
+    page, a state layer, snapshots; rows select once their context
+    passes 32 tokens.  Its layout derives the tile from the kernels'
+    (``paged_layout``): 16 rows with the dense walk's 16 (8 query heads
+    a group) once the caller has cut the scan's tile to
+    ``SALA_SCAN_TILE`` (``models.minicpm_sala.SCAN_TILE_ROWS``; on the
+    CPU the scan's reference runs, which has no tile)."""
+    from paddle_tpu.models.minicpm_sala import MiniCPMSALAConfig
+
+    cfg = MiniCPMSALAConfig.debug(num_attention_heads=16, layers_run=(0, 2))
+    params = _draw(cfg, 4, scale=0.2)
+    return lambda: ContinuousBatchingEngine(
+        cfg, params, max_slots=3, num_pages=80, page_size=8,
+        max_seq_len=128, prefill_token_budget=32, enable_prefix_cache=True,
+        state_snapshots=4)
+
+
 LAYOUTS = {"small": (_small, (16, 32, 35)),
            "llama": (_llama, (16, 32, 48, 70)),
            "mellum2": (_mellum2, (8, 11)),
            "deepseek": (_deepseek, (35,)),
-           "nemotron": (_nemotron, (11,))}
+           "nemotron": (_nemotron, (11,)),
+           "minicpm_sala": (_minicpm_sala, (16, 32, 35))}
 # a mixed trace: prompts that fill a whole chunk and more, short ones
 # that ride beside decode rows, two that share a prefix
 PROMPTS = (70, 5, 33, 12, 41)
@@ -118,8 +142,10 @@ NEW_TOKENS = 6
 
 
 def _serve(eng, vocab, compiles=None):
-    """Serve the trace; returns the tokens by request and, a launch,
-    the rows that were packed (without the padding) and the rung."""
+    """Serve the trace; returns the tokens by request, a launch the rows
+    that were packed (without the padding) and the rung, and a call of
+    ``step()`` what the newest committed launch's step added to its
+    gathered rows (``last_extras``: MiniCPM-SALA's selections)."""
     rng = np.random.default_rng(7)
     shared = rng.integers(1, vocab, 24)
     prompts = [np.concatenate([shared, rng.integers(1, vocab, n - 24)])
@@ -136,19 +162,27 @@ def _serve(eng, vocab, compiles=None):
         return rows, gather, launch
 
     eng._pack_unified = spy
+    extras = []
+
+    def step():
+        eng.step()
+        extras.append(eng.last_extras)
+
     for p in prompts[:3]:
         eng.add_request(p.astype(np.int32), max_new_tokens=NEW_TOKENS)
-    eng.step()                          # the engine's first launch
+    step()                              # the engine's first launch
     after_first = None if compiles is None else compiles[0]
     for _ in range(3):
-        eng.step()
+        step()
     for p in prompts[3:]:               # arrive while the others decode
         eng.add_request(p.astype(np.int32), max_new_tokens=NEW_TOKENS)
+    while eng.queue or eng.active.any():
+        step()
     tokens = {f.rid: f.tokens.tolist() for f in eng.run()}
     if compiles is not None:
         # no program is compiled after the engine's first launch
         assert compiles[0] == after_first
-    return tokens, packed
+    return tokens, packed, extras
 
 
 @pytest.fixture(scope="module")
@@ -173,14 +207,14 @@ def check_a_ladder_serves_what_the_top_rung_serves(name, compiles):
     eng = make()
     assert eng.ladder == want and eng.ladder[-1] == eng.rows_cap
     vocab = eng.cfg.vocab_size
-    tokens, packed = _serve(eng, vocab, compiles)
+    tokens, packed, extras = _serve(eng, vocab, compiles)
     steps = eng.serving_stats()["steps"]
     eng.shutdown()
 
     top = make()                        # the same engine, one rung
     top.ladder = (top.rows_cap,)
     top.launches_by_rows = {top.rows_cap: 0}
-    top_tokens, top_packed = _serve(top, vocab)
+    top_tokens, top_packed, top_extras = _serve(top, vocab)
     top.shutdown()
 
     assert tokens == top_tokens
@@ -194,6 +228,10 @@ def check_a_ladder_serves_what_the_top_rung_serves(name, compiles):
         np.testing.assert_array_equal(gather, t_gather)
         assert n == cap == min(m for m in want if m >= len(rows))
         assert t_n == t_cap == want[-1]
+    # and what a step adds to its gathered rows is the same, call by call
+    for got, t_got in zip(extras, top_extras, strict=True):
+        for a, b in zip(got, t_got, strict=True):
+            np.testing.assert_array_equal(a, b)
     # the trace reaches more rungs than one: decode-only steps, small
     # chunks and full ones
     by_rows = steps["launches_by_rows"]
@@ -207,3 +245,4 @@ def check_a_ladder_serves_what_the_top_rung_serves(name, compiles):
     assert steps["rows_cap"] < steps["steps"] * eng.rows_cap \
         or len(want) == 1
     assert steps["rows_cap"] >= sum(n * c for n, c in by_rows.items())
+    return steps, extras

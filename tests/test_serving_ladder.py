@@ -18,14 +18,16 @@ from serving_ladder_toys import (  # noqa: F401 - compiles is a fixture
     compiles)
 
 # (max_slots, speculative_k, prefill_token_budget, the kernels' row
-# tile) of the benchmark's four cells (benchmarks/configs/*.json; the
-# tile from the layout: ragged_tile_rows, the sparse-MLA tile of 8, the
-# scan's chunk of 128) and of an engine with a draft model
+# tile) of the benchmark's five serving configurations
+# (benchmarks/configs/*.json; the tile from the layout: ragged_tile_rows,
+# the sparse-MLA tile of 8, the scan's chunk or tile of 128) and of an
+# engine with a draft model
 CAPACITIES = {
     "mistral": ((32, 0, 256, 32), (32, 96, 160, 288)),
     "mellum2": ((32, 0, 512, 16), (32, 160, 288, 544)),
     "nemotron": ((128, 0, 512, 128), (128, 256, 384, 640)),
     "deepseek": ((16, 0, 512, 8), (16, 144, 272, 528)),
+    "minicpm_sala": ((96, 0, 512, 128), (128, 256, 384, 608)),
     "spec_k_2": ((8, 2, 100, 16), (32, 64, 80, 124)),
     "tile_past_capacity": ((3, 0, 8, 64), (11,)),
 }
@@ -46,12 +48,14 @@ def test_the_ladders_rule(cell):
 
 def test_the_cells_layouts_state_the_tiles_of_the_rule():
     """The tiles the table above takes for the Llama family's two cells
-    are what their layouts state at the published widths."""
+    and for MiniCPM-SALA's are what their layouts state at the published
+    widths."""
     from paddle_tpu.inference.serving import kv_layout
-    from paddle_tpu.models import LlamaConfig
+    from paddle_tpu.models import LlamaConfig, minicpm_sala
     from paddle_tpu.models.deepseek_v32 import DeepseekV32Config
     from paddle_tpu.models.mellum2 import Mellum2Config
     from paddle_tpu.models.nemotron_h import NemotronHConfig
+    from paddle_tpu.ops.pallas import block_sparse_attention as bsa
 
     mistral = LlamaConfig(vocab_size=32768, hidden_size=4096,
                           intermediate_size=14336, num_hidden_layers=1,
@@ -59,9 +63,16 @@ def test_the_cells_layouts_state_the_tiles_of_the_rule():
                           max_position_embeddings=4096)
     assert kv_layout(mistral).tile_rows == 32
     assert kv_layout(Mellum2Config()).tile_rows == 16
-    # the two layouts that bring a step of their own state no tile yet
-    # (PERF.md section 6, PR 36): their engines keep ONE rung, the
-    # capacity; the rule above says what theirs would be
+    # MiniCPM-SALA's: whole tiles of its four kernels, which is the
+    # scan's, the largest (the scores' 16, the block-sparse kernel's 8
+    # and the dense walk's 8 divide it)
+    sala = minicpm_sala.MiniCPMSALAConfig().paged_layout().tile_rows
+    assert sala == minicpm_sala.SCAN_TILE_ROWS == 128
+    assert sala % bsa.SCORES_TILE_ROWS == sala % bsa.SPARSE_TILE_ROWS == 0
+    assert sala == CAPACITIES["minicpm_sala"][0][3]
+    # two layouts that bring a step of their own state no tile yet
+    # (PERF.md section 6, PRs 36 and 40): their engines keep ONE rung,
+    # the capacity; the rule above says what theirs would be
     for cfg, slots in ((NemotronHConfig(), 128), (DeepseekV32Config(), 16)):
         assert cfg.paged_layout().tile_rows == 0
         assert step_ladder(slots, 512, 0) == (slots + 512,)
@@ -115,14 +126,14 @@ def test_kept_lowerings_serve_the_same_and_are_found_again(
     make = build()
     plain = make()
     vocab = plain.cfg.vocab_size
-    want_tokens, _ = _serve(plain, vocab)
+    want_tokens, *_ = _serve(plain, vocab)
     plain.shutdown()
 
     was = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", str(tmp_path))
     try:
         eng = make()
-        tokens, _ = _serve(eng, vocab, compiles)
+        tokens, *_ = _serve(eng, vocab, compiles)
         eng.shutdown()
         kept = sorted(p.name for p in tmp_path.glob("paddle_tpu-lowering-*"))
         assert len(kept) == len(want)
@@ -132,7 +143,7 @@ def test_kept_lowerings_serve_the_same_and_are_found_again(
         monkeypatch.setattr(jax.export, "export", lambda *a, **k: (
             traced.append(a), real(*a, **k))[1])
         again = make()
-        again_tokens, _ = _serve(again, vocab, compiles)
+        again_tokens, *_ = _serve(again, vocab, compiles)
         again.shutdown()
         assert not traced               # every rung was read back
         assert kept == sorted(

@@ -701,6 +701,13 @@ def test_block_sparse_kernels_compile_at_the_cells_geometry(one_chip):
              kernels=[bsa.BLOCK_SPARSE_KERNEL])
 
 
+def _aliased(text) -> int:
+    """The arguments a compiled program writes in the buffers they came
+    in, by its module's header."""
+    header = next(ln for ln in text.splitlines() if "HloModule" in ln)
+    return len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header))
+
+
 def test_minicpm_sala_step_compiles_with_three_pools_and_state_in_place(
         one_chip, monkeypatch):
     """The MiniCPM-SALA unified step (one minicpm4 and one lightning-attn
@@ -739,9 +746,7 @@ def test_minicpm_sala_step_compiles_with_three_pools_and_state_in_place(
                       "ragged_paged_attention": "paged_attn",
                       "infllm_block_scores": "block_select",
                       "block_sparse_paged_attention": "sparse_attn"}, scopes
-    header = next(ln for ln in text.splitlines() if "HloModule" in ln)
-    aliased = len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header))
-    assert aliased == 4                 # K, V, compressed keys, one state
+    assert _aliased(text) == 4          # K, V, compressed keys, one state
 
 
 def test_one_kind_of_page_lowers_the_step_it_lowered(one_chip, monkeypatch):
@@ -768,13 +773,14 @@ def _rung_engine(layout, monkeypatch):
     cell's capacities (slots, prefill budget), with the kernels steered
     to their compiled form, and the kernels its step holds."""
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
-    from paddle_tpu.models import nemotron_h
+    from paddle_tpu.models import minicpm_sala, nemotron_h
     from paddle_tpu.models.deepseek_v32 import DeepseekV32Config
     from paddle_tpu.models.mellum2 import Mellum2Config
     from paddle_tpu.ops.pallas import (decode_attention, grouped_matmul,
                                        sparse_mla)
 
-    for mod in (nemotron_h, decode_attention, grouped_matmul, sparse_mla):
+    for mod in (minicpm_sala, nemotron_h, decode_attention, grouped_matmul,
+                sparse_mla):
         monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
     kw = dict(num_pages=24, page_size=128, enable_prefix_cache=True)
     if layout == "kv":
@@ -804,6 +810,15 @@ def _rung_engine(layout, monkeypatch):
         cap = dict(max_slots=_DS_SLOTS, max_seq_len=_DS_SEQ,
                    prefill_token_budget=512)
         ladder = (528,)                 # the layout states no tile yet
+    elif layout == "pools_state":
+        # one minicpm4 and one lightning-attn layer of MiniCPM-SALA
+        cfg = minicpm_sala.MiniCPMSALAConfig(layers_run=(9, 11),
+                                             vocab_size=16384)
+        kernels = ("infllm_block_scores", "block_sparse_paged_attention",
+                   "mamba2_ssd_scan", "ragged_paged_attention")
+        cap = dict(max_slots=96, max_seq_len=70656, prefill_token_budget=512,
+                   state_snapshots=2)
+        ladder = (128, 256, 384, 608)   # the tile is the scan's 128 rows
     else:
         cfg = nemotron_h.NemotronHConfig(
             num_hidden_layers=3, hybrid_override_pattern="ME*",
@@ -818,22 +833,28 @@ def _rung_engine(layout, monkeypatch):
     return ContinuousBatchingEngine(cfg, params, **cap, **kw), kernels, ladder
 
 
-@pytest.mark.parametrize("layout", ["kv", "kinds", "latent", "state"])
+@pytest.mark.parametrize("layout", ["kv", "kinds", "latent", "state",
+                                    "pools_state"])
 def test_the_lowest_rung_of_each_layout_compiles(one_chip, monkeypatch,
                                                  layout):
     """The engine launches a step of decode rows alone at the LOWEST
     rung of its ladder (``serving.step_ladder``): 32 rows in the Llama
-    family's two cells, where the capacity is 288 and 544.  Each
+    family's two cells, where the capacity is 288 and 544, and 128, one
+    tile of the scan, in MiniCPM-SALA's, where it is 608.  Each
     layout's step compiles at that size with every kernel of its cell
     in it: the ragged kernel (one tile, or two of Mellum2's 16 rows)
-    and the experts' grouped matmul; the two layouts that state no tile
+    and the experts' grouped matmul; MiniCPM-SALA's two block-sparse
+    kernels, its scan and the dense walk, with the three pools a page
+    and the state aliased in place; the two layouts that state no tile
     yet (DeepSeek's sparse-MLA kernels, Nemotron's scan) have the one
     rung, their capacity, and compile there.  (Every rung the rule
     gives all four cells, 16 to 640 rows, compiled for the described
     chip by hand: PERF.md section 6, PR 36.)"""
     eng, kernels, ladder = _rung_engine(layout, monkeypatch)
     assert eng.ladder == ladder and ladder[-1] == eng.rows_cap
-    assert all(n % max(eng.layout.tile_rows, 1) == 0 for n in ladder)
+    # (the capacity need not be whole tiles: a step's kernels pad their
+    # rows themselves, as MiniCPM-SALA's 608 are to the scan's 640)
+    assert all(n % max(eng.layout.tile_rows, 1) == 0 for n in ladder[:-1])
     fn, args, kwargs, _ = eng.analysis_entry()
     rows = eng._padding_rows(ladder[0])
     args = (*args[:3], rows, *args[4:])
@@ -851,6 +872,11 @@ def test_the_lowest_rung_of_each_layout_compiles(one_chip, monkeypatch,
     assert re.search(rf"= s32\[{ladder[0]},{eng.row_cols}\]\S* parameter\(",
                      entry)
     assert len(ladder) == 1 or f"[{eng.rows_cap}," not in entry
+    # every pool (MiniCPM-SALA's: K, V and compressed keys of the one
+    # minicpm4 layer, the one lightning layer's state) is written in the
+    # buffer it came in
+    assert _aliased(text) == len(jax.tree.leaves(
+        (eng.k_pages, eng.v_pages, eng._more_arguments())))
     # the chip's compiler leaves no working instruction of the step
     # outside the program's scopes (tests/test_device_scopes.py holds the
     # same at debug widths): the device's time by scope then names all
