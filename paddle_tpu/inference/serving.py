@@ -23,8 +23,8 @@ of a prefill chunk; at most ``prefill_token_budget`` prompt tokens ride
 a step, so a decode slot emits a token EVERY step whatever prompt is
 prefilled beside it.  The step's one free parameter is its number of
 rows, read off its input, and where the layout states its kernels' row
-tile (``PagedLayout.tile_rows``: the Llama family's and MiniCPM-SALA's
-do; one that states none, DeepSeek-V3.2's and Nemotron-H's today, keeps
+tile (``PagedLayout.tile_rows``: the Llama family's, MiniCPM-SALA's
+and Nemotron-H's do; one that states none, DeepSeek-V3.2's today, keeps
 the capacity alone) the engine compiles it at a short
 LADDER of row counts (``step_ladder``: every decode row; a quarter and
 a half of the prefill budget above them; the capacity ``rows_cap``),
@@ -330,11 +330,11 @@ class PagedLayout:
     ``tile_rows``: the packed rows a tile of the step's kernels holds,
     where the step may be launched at any number of whole tiles: the
     engine then compiles it at a ladder of row counts
-    (``step_ladder``): the Llama family's layouts (``kv_layout``) and
-    MiniCPM-SALA's state theirs.  0, the default, where the layout
-    states none: its step is compiled at the capacity alone, as ever
-    (DeepSeek-V3.2's and Nemotron-H's today: PERF.md section 6, PRs 36
-    and 40, say what stands in the way of each).
+    (``step_ladder``): the Llama family's layouts (``kv_layout``),
+    MiniCPM-SALA's and Nemotron-H's state theirs.  0, the default, where
+    the layout states none: its step is compiled at the capacity alone,
+    as ever (DeepSeek-V3.2's today: PERF.md section 6, PR 36, says what
+    stands in its way).
 
     ``more_pools``: a function a further pool, from the page size to the
     shape of ONE PAGE of it (a cache of compressed keys: ``page // 16``

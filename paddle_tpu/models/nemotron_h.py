@@ -31,7 +31,10 @@ Source: https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16
 The engine (``inference/serving.ContinuousBatchingEngine``) serves this
 through its one ``step()``: ``paged_layout()`` says which layers have
 pages (the ``*`` layers, one kind), what a slot's recurrent state is a
-state layer (``PagedLayout.state``) and gives ``unified_step_jit``, this
+state layer (``PagedLayout.state``), how many packed rows are whole
+tiles of every kernel of the step (``PagedLayout.tile_rows``: the engine
+compiles the step at a ladder of such row counts and a launch takes the
+smallest that holds its rows) and gives ``unified_step_jit``, this
 model's part of the unified step.  A packed row carries, after the
 columns every model's rows have, the state entry its slot starts from
 (below zero: zeros), the entry the state is left in, and, on a slot's
@@ -42,6 +45,7 @@ none).  The multi-token-prediction module is not loaded.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -58,6 +62,21 @@ _PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
 
 #: state snapshots a step may take (``PagedLayout.state_snapshots_a_step``)
 SNAPSHOTS_A_STEP = 2
+
+#: whole tiles of the step's kernels in the least rows the step is
+#: compiled at.  At ONE tile (128 rows at the published widths) the
+#: compiled step does not end on the chip: every rung compiles and its
+#: padding launch ends, and the first launch of a few decode rows at
+#: that rung never comes back.  It takes the expert layer's Mosaic
+#: grouped matmul INSIDE the step compiled at that size (PERF.md
+#: section 6, PR 43: the expert layer alone at 128 rows, the same step
+#: at two and at three tiles, and the step with the grouped matmul in
+#: XLA's terms all end; the shared expert computed first, 64 MiB of
+#: scoped VMEM for the kernel, its operands pinned to HBM, its other
+#: form, wider blocks, a plain route and no ``lax.cond`` do not mend
+#: it).  The cause is not found, so the layout's tile is two of the
+#: kernels': a finding of the chip beside its reason, not an option
+STEP_TILES = 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,7 +202,13 @@ class NemotronHConfig:
 
         c = self
         kvh, d = c.num_key_value_heads, c.head_dim
-        tile_rows = ragged_tile_rows(c.num_attention_heads, kvh, d)
+        attn_tile = ragged_tile_rows(c.num_attention_heads, kvh, d)
+        # whole tiles of every kernel of the step: the ragged walk's,
+        # and the scan's and the convolution's, which is ``chunk_size``
+        # (``mamba_part``; 128 at the published widths); the experts'
+        # grouped matmul pads its own copies to whole blocks, whatever
+        # the rows.  ``STEP_TILES`` of them: 256
+        tile_rows = STEP_TILES * math.lcm(attn_tile, c.chunk_size)
         n_state = len(c.layers_of("M"))
 
         def row_counts(rows, ctx_tokens, page_size, pages_per_seq):
@@ -193,7 +218,7 @@ class NemotronHConfig:
             decode = int((np.bincount(rows[:, 4], minlength=1) == 1).sum()) \
                 if len(rows) else 0
             return {"attn_kv_tokens_read": ragged_kv_tokens_read(
-                        rows[:, 4], rows[:, 3], tile_rows, page_size,
+                        rows[:, 4], rows[:, 3], attn_tile, page_size,
                         pages_per_seq),
                     "ssm_state_slots": slots, "ssm_rows": len(rows),
                     "ssm_prefill_rows": len(rows) - decode}
@@ -206,6 +231,7 @@ class NemotronHConfig:
                          *MOE_DEVICE_COUNTS),
             pages_per_step=lambda page, pps, itemsize: default_pages_per_step(
                 page, kvh, d, pps, itemsize),
+            tile_rows=tile_rows,
             kinds=(PageKind("pages", c.layers_of("*")),),
             state=(((c.mamba_num_heads, c.mamba_head_dim, c.ssm_state_size),
                     "float32"),

@@ -1,12 +1,13 @@
 """Toy engines of the five layouts: those whose derived ladders have
 several rungs (the kernels' row tile is 8 or 16 at these widths; four
 rungs for the Llama-shaped engine, two for Mellum2's, whose step
-compiles slowest here, three for MiniCPM-SALA's with its scan's tile
-cut to 16 rows) and the ONE that DeepSeek's and Nemotron-H's layouts
-keep while they state no tile, the mixed trace they serve, and the
-check that a ladder serves what the top rung serves: shared by
-``test_serving_ladder.py`` and ``test_serving_ladder_kinds_state.py``
-(two files, so that two workers share the compiles)."""
+compiles slowest here, three for Nemotron-H's, whose tile is two of
+its ``chunk_size`` of 8, and for MiniCPM-SALA's with its scan's tile
+cut to 16 rows) and the ONE that DeepSeek's layout keeps while it
+states no tile, the mixed trace they serve, and the check that a ladder
+serves what the top rung serves: shared by ``test_serving_ladder.py``
+and ``test_serving_ladder_kinds_state.py`` (two files, so that two
+workers share the compiles)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -94,6 +95,11 @@ def _deepseek():
 
 
 def _nemotron():
+    """One layer of each letter.  Its layout derives the tile from the
+    kernels' (``paged_layout``): the scan's and the convolution's
+    ``chunk_size`` 8 and the ragged walk's 8 (16 query heads a group),
+    two of them (``nemotron_h.STEP_TILES``): 16 rows; a budget of 32
+    gives the rungs 16 / 32 / 35."""
     from paddle_tpu.models.nemotron_h import NemotronHConfig
 
     cfg = NemotronHConfig.debug(experts_held=(4, 12), num_attention_heads=16,
@@ -102,7 +108,7 @@ def _nemotron():
     params = _draw(cfg, 3)
     return lambda: ContinuousBatchingEngine(
         cfg, params, max_slots=3, num_pages=80, page_size=4,
-        max_seq_len=128, prefill_token_budget=8, enable_prefix_cache=True,
+        max_seq_len=128, prefill_token_budget=32, enable_prefix_cache=True,
         state_snapshots=4)
 
 
@@ -133,7 +139,7 @@ LAYOUTS = {"small": (_small, (16, 32, 35)),
            "llama": (_llama, (16, 32, 48, 70)),
            "mellum2": (_mellum2, (8, 11)),
            "deepseek": (_deepseek, (35,)),
-           "nemotron": (_nemotron, (11,)),
+           "nemotron": (_nemotron, (16, 32, 35)),
            "minicpm_sala": (_minicpm_sala, (16, 32, 35))}
 # a mixed trace: prompts that fill a whole chunk and more, short ones
 # that ride beside decode rows, two that share a prefix
@@ -201,6 +207,14 @@ def compiles():
     return count
 
 
+def one_rung(eng):
+    """``eng`` with its capacity as its only rung (before its first
+    launch): what a ladder's engine is compared with."""
+    eng.ladder = (eng.rows_cap,)
+    eng.launches_by_rows = {eng.rows_cap: 0}
+    return eng
+
+
 def check_a_ladder_serves_what_the_top_rung_serves(name, compiles):
     build, want = LAYOUTS[name]
     make = build()
@@ -211,9 +225,7 @@ def check_a_ladder_serves_what_the_top_rung_serves(name, compiles):
     steps = eng.serving_stats()["steps"]
     eng.shutdown()
 
-    top = make()                        # the same engine, one rung
-    top.ladder = (top.rows_cap,)
-    top.launches_by_rows = {top.rows_cap: 0}
+    top = one_rung(make())              # the same engine, one rung
     top_tokens, top_packed, top_extras = _serve(top, vocab)
     top.shutdown()
 
@@ -236,8 +248,8 @@ def check_a_ladder_serves_what_the_top_rung_serves(name, compiles):
     # chunks and full ones
     by_rows = steps["launches_by_rows"]
     assert sum(by_rows.values()) == len(packed)
-    # (a layout that states no tile has the one rung: DeepSeek's and
-    # Nemotron-H's serve as ever, and the check is that they do)
+    # (a layout that states no tile has the one rung: DeepSeek's serves
+    # as ever, and the check is that it does)
     used = [n for n in want if by_rows[n]]
     assert want[0] in used and len(used) >= min(3, len(want) - 1)
     assert want[-1] in used or len(want) < 4

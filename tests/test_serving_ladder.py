@@ -7,6 +7,7 @@ engines derive have four rungs at capacities of a few dozen rows.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -20,12 +21,13 @@ from serving_ladder_toys import (  # noqa: F401 - compiles is a fixture
 # (max_slots, speculative_k, prefill_token_budget, the kernels' row
 # tile) of the benchmark's five serving configurations
 # (benchmarks/configs/*.json; the tile from the layout: ragged_tile_rows,
-# the sparse-MLA tile of 8, the scan's chunk or tile of 128) and of an
-# engine with a draft model
+# the sparse-MLA tile of 8, the scan's chunk or tile of 128, two of
+# them for Nemotron-H: nemotron_h.STEP_TILES) and of an engine with a
+# draft model
 CAPACITIES = {
     "mistral": ((32, 0, 256, 32), (32, 96, 160, 288)),
     "mellum2": ((32, 0, 512, 16), (32, 160, 288, 544)),
-    "nemotron": ((128, 0, 512, 128), (128, 256, 384, 640)),
+    "nemotron": ((128, 0, 512, 256), (256, 512, 640)),
     "deepseek": ((16, 0, 512, 8), (16, 144, 272, 528)),
     "minicpm_sala": ((96, 0, 512, 128), (128, 256, 384, 608)),
     "spec_k_2": ((8, 2, 100, 16), (32, 64, 80, 124)),
@@ -47,15 +49,16 @@ def test_the_ladders_rule(cell):
 
 
 def test_the_cells_layouts_state_the_tiles_of_the_rule():
-    """The tiles the table above takes for the Llama family's two cells
-    and for MiniCPM-SALA's are what their layouts state at the published
-    widths."""
+    """The tiles the table above takes for the Llama family's two cells,
+    for MiniCPM-SALA's and for Nemotron-H's are what their layouts state
+    at the published widths."""
     from paddle_tpu.inference.serving import kv_layout
-    from paddle_tpu.models import LlamaConfig, minicpm_sala
+    from paddle_tpu.models import LlamaConfig, minicpm_sala, nemotron_h
     from paddle_tpu.models.deepseek_v32 import DeepseekV32Config
     from paddle_tpu.models.mellum2 import Mellum2Config
     from paddle_tpu.models.nemotron_h import NemotronHConfig
     from paddle_tpu.ops.pallas import block_sparse_attention as bsa
+    from paddle_tpu.ops.pallas.decode_attention import ragged_tile_rows
 
     mistral = LlamaConfig(vocab_size=32768, hidden_size=4096,
                           intermediate_size=14336, num_hidden_layers=1,
@@ -70,12 +73,27 @@ def test_the_cells_layouts_state_the_tiles_of_the_rule():
     assert sala == minicpm_sala.SCAN_TILE_ROWS == 128
     assert sala % bsa.SCORES_TILE_ROWS == sala % bsa.SPARSE_TILE_ROWS == 0
     assert sala == CAPACITIES["minicpm_sala"][0][3]
-    # two layouts that bring a step of their own state no tile yet
-    # (PERF.md section 6, PRs 36 and 40): their engines keep ONE rung,
-    # the capacity; the rule above says what theirs would be
-    for cfg, slots in ((NemotronHConfig(), 128), (DeepseekV32Config(), 16)):
-        assert cfg.paged_layout().tile_rows == 0
-        assert step_ladder(slots, 512, 0) == (slots + 512,)
+    # Nemotron-H's: whole tiles of the ragged walk (8 rows) and of the
+    # scan and the convolution, whose tile is the config's chunk_size;
+    # STEP_TILES of them, since the step compiled at one does not end on
+    # the chip (PERF.md section 6, PR 43)
+    nemo = NemotronHConfig()
+    tile = nemo.paged_layout().tile_rows
+    assert tile == nemotron_h.STEP_TILES * math.lcm(
+        ragged_tile_rows(nemo.num_attention_heads, nemo.num_key_value_heads,
+                         nemo.head_dim), nemo.chunk_size) == 256
+    assert tile == CAPACITIES["nemotron"][0][3]
+    assert step_ladder(128, 512, tile) == CAPACITIES["nemotron"][1]
+    # and the toy's by the same line: its chunk_size, 8
+    toy = NemotronHConfig.debug()
+    assert toy.paged_layout().tile_rows == nemotron_h.STEP_TILES * math.lcm(
+        ragged_tile_rows(toy.num_attention_heads, toy.num_key_value_heads,
+                         toy.head_dim), toy.chunk_size)
+    # the one layout that brings a step of its own and states no tile
+    # yet (PERF.md section 6, PR 36): its engine keeps ONE rung, the
+    # capacity; the rule above says what its ladder would be
+    assert DeepseekV32Config().paged_layout().tile_rows == 0
+    assert step_ladder(16, 512, 0) == (16 + 512,)
 
 
 # ---- toy engines whose ladders have four rungs (serving_ladder_toys) ----

@@ -827,7 +827,7 @@ def _rung_engine(layout, monkeypatch):
                    "ragged_paged_attention", "grouped_matmul_blocks")
         cap = dict(max_slots=128, max_seq_len=7680, prefill_token_budget=512,
                    state_snapshots=2)
-        ladder = (640,)                 # the layout states no tile yet
+        ladder = (256, 512, 640)        # the tile is two scan chunks
     params = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16)
               for k, s in cfg.leaf_shapes().items()}
     return ContinuousBatchingEngine(cfg, params, **cap, **kw), kernels, ladder
@@ -839,17 +839,20 @@ def test_the_lowest_rung_of_each_layout_compiles(one_chip, monkeypatch,
                                                  layout):
     """The engine launches a step of decode rows alone at the LOWEST
     rung of its ladder (``serving.step_ladder``): 32 rows in the Llama
-    family's two cells, where the capacity is 288 and 544, and 128, one
-    tile of the scan, in MiniCPM-SALA's, where it is 608.  Each
-    layout's step compiles at that size with every kernel of its cell
-    in it: the ragged kernel (one tile, or two of Mellum2's 16 rows)
-    and the experts' grouped matmul; MiniCPM-SALA's two block-sparse
-    kernels, its scan and the dense walk, with the three pools a page
-    and the state aliased in place; the two layouts that state no tile
-    yet (DeepSeek's sparse-MLA kernels, Nemotron's scan) have the one
-    rung, their capacity, and compile there.  (Every rung the rule
-    gives all four cells, 16 to 640 rows, compiled for the described
-    chip by hand: PERF.md section 6, PR 36.)"""
+    family's two cells, where the capacity is 288 and 544, 128, one
+    tile of the scan, in MiniCPM-SALA's, where it is 608, and 256, two
+    tiles (``nemotron_h.STEP_TILES``), in Nemotron-H's, where it is
+    640.  Each layout's step compiles at that size with every
+    kernel of its cell in it: the ragged kernel (one tile, or two of
+    Mellum2's 16 rows) and the experts' grouped matmul; MiniCPM-SALA's
+    two block-sparse kernels, its scan and the dense walk, with the
+    three pools a page and the state aliased in place; Nemotron-H's
+    convolution, scan, walk and grouped matmul, its two state pools a
+    layer aliased in place; the one layout that states no tile yet
+    (DeepSeek's sparse-MLA kernels) has the one rung, its capacity, and
+    compiles there.  (Every rung the rule gives the cells, 16 to 640
+    rows, compiled for the described chip by hand: PERF.md section 6,
+    PRs 36 and 43.)"""
     eng, kernels, ladder = _rung_engine(layout, monkeypatch)
     assert eng.ladder == ladder and ladder[-1] == eng.rows_cap
     # (the capacity need not be whole tiles: a step's kernels pad their
