@@ -247,15 +247,36 @@ _MOE_ROUTES = {"softmax": _route_softmax_topk,
                "sigmoid_groups": _route_sigmoid_groups}
 
 
+#: the most cells of a ``[rows, T]`` matrix the dispatch's product forms
+#: may lay out (32 MB in bf16): they are quadratic in the tokens, so a
+#: step past this, a long prompt prefilled whole, moves its copies by
+#: row gathers instead.  On a v5e the product is the faster up to a
+#: step's 544 tokens x 8,512 rows and level with the gather there
+_MOE_PRODUCT_CELLS = 1 << 24
+
+
 @jax.named_scope("moe_experts")
 def _moe_experts(w: _Weights, i, x2, top_ids, top_p, lo, hi, e_all, stats):
     """The held experts' part of ``_moe_ffn``: the sorted ragged
     dispatch of the token copies, the experts' grouped matmuls, the
     weighted combine.  ``x2`` [T, width]; ``top_ids`` / ``top_p`` [T, k]
-    over the router's ``e_all`` experts, of which [lo, hi) are in the
-    bank.  The expert's FORM is the config's: gated SwiGLU (gate, up,
-    down: three launches) unless it states ``mlp_hidden_act = "relu2"``
-    (up, squared relu, down: two; the bank has no ``gate_proj``)."""
+    over the router's ``e_all`` experts (a token's k are distinct, as
+    ``top_k``'s are), of which [lo, hi) are in the bank.  The expert's
+    FORM is the config's: gated SwiGLU (gate, up, down: three launches)
+    unless it states ``mlp_hidden_act = "relu2"`` (up, squared relu,
+    down: two; the bank has no ``gate_proj``).
+
+    The copies travel by READS alone.  Each row of the experts' buffer
+    knows the sorted copy it holds, so the buffer is the product of a
+    one-hot ``[rows, T]`` with ``x2`` on the MXU (exact) or, past
+    ``_MOE_PRODUCT_CELLS``, one gather of ``x2``'s rows; it is allocated
+    with the grouped matmul's park block as its last and handed through
+    the launches as it stands.  A token's output is the gate-weighted
+    sum of its copies' rows in float32: where the bank holds every
+    expert each token has k of them and they are gathered token-major;
+    where it holds a share, few of a token's copies are live and the sum
+    is the product of the gates laid out ``[T, rows]`` with the live
+    rows (token-major again past ``_MOE_PRODUCT_CELLS``)."""
     from ..ops.pallas.grouped_matmul import (align_rows,
                                              grouped_matmul_raw,
                                              segment_starts)
@@ -263,21 +284,24 @@ def _moe_experts(w: _Weights, i, x2, top_ids, top_p, lo, hi, e_all, stats):
     cfg = w.cfg
     pre = f"model.layers.{i}.mlp."
     e, k = hi - lo, top_ids.shape[-1]
+    t, dt = x2.shape[0], x2.dtype
     gated = _gated(cfg)
-    # ---- sorted ragged dispatch: copies argsorted by expert tile the
+    # ---- sorted ragged dispatch: copies sorted by expert tile the
     # block-aligned segment windows the kernel contract wants.  A copy
-    # of an absent expert sorts behind every segment (id ``e``), lands
-    # in a row of its own past them and carries weight 0
+    # of an absent expert sorts behind every segment (id ``e``), has no
+    # row and weighs 0
     bm = int(getattr(cfg, "moe_block_rows", _MOE_FFN_BLOCK_ROWS))
-    tk = x2.shape[0] * k
+    tk = t * k
     held = (top_ids >= lo) & (top_ids < hi)
     if stats is not None:
         held = held & stats["valid"][:, None]
     absent = e < e_all or stats is not None   # may a copy have no segment?
-    flat_ids = (jnp.where(held, top_ids - lo, e) if absent
-                else top_ids).reshape(-1).astype(jnp.int32)
-    order = jnp.argsort(flat_ids)                     # stable; absent last
-    counts = jnp.bincount(flat_ids, length=e + 1).astype(jnp.int32)[:e]
+    ids = (jnp.where(held, top_ids - lo, e) if absent
+           else top_ids).astype(jnp.int32)                      # [T, k]
+    gates = jnp.where(held, top_p, 0.0).astype(jnp.float32)
+    chose = ids[:, :, None] == jnp.arange(e + 1, dtype=jnp.int32)
+    chosen = jnp.sum(chose, axis=1, dtype=jnp.int32)  # [T, e + 1] copies
+    counts = jnp.sum(chosen, axis=0)[:e]
     seg_st = segment_starts(counts, bm)
     run_st = jnp.cumsum(counts) - counts              # unaligned starts
     if stats is not None:
@@ -286,55 +310,83 @@ def _moe_experts(w: _Weights, i, x2, top_ids, top_p, lo, hi, e_all, stats):
         stats["moe_expert_rows_max"].append(jnp.max(counts))
         if "moe_experts_hit" in stats:      # experts with a row at least
             stats["moe_experts_hit"].append(jnp.sum(counts > 0))
+    # the copies' tokens and gates in expert order (stable: absent last)
+    _, tok_sorted, gate_sorted = lax.sort(
+        (ids.reshape(-1), jnp.arange(tk, dtype=jnp.int32) // k,
+         gates.reshape(-1)), num_keys=1, is_stable=True)
 
     def bank(proj):
         name = pre + f"experts.{proj}.weight"
         wq = w.p[name]
         sc = w.p.get(name + "._scale")
         if sc is None:
-            return wq.astype(x2.dtype), None
+            return wq.astype(dt), None
         return wq, sc                                 # int8 + [E, out]
 
     wids = jnp.arange(e, dtype=jnp.int32)
+    tokens = jnp.arange(t, dtype=jnp.int32)
+    # a product that moves rows must not round them: float32 rows take
+    # the MXU's exact passes, 16-bit rows are exact in one
+    exact = lax.Precision.HIGHEST if dt == jnp.float32 else None
 
     def gmm(xin, proj):
         wq, sc = bank(proj)
         # the sorted dispatch's segments tile the rows densely: the row
-        # blocks alone are the grid, however many experts the bank has
+        # blocks alone are the grid, however many experts the bank has,
+        # and the buffer's last block is the launch's park block
         return grouped_matmul_raw(xin, wq, seg_st, counts, wids,
                                   block_rows=bm, w_scale=sc, dense=True)
 
     def experts_of(n):
-        """The layer's routed part from the first ``n`` sorted copies
-        (they must hold every copy that has a segment)."""
-        order_n = order[:n]
-        token_of = order_n // k
-        sorted_ids = flat_ids[order_n]
-        wsorted = jnp.where(held, top_p, 0.0).reshape(-1)[order_n]
-        rpad = int(align_rows(n, bm) + e * bm)        # static worst case
-        if absent:
-            seg_of = jnp.minimum(sorted_ids, e - 1)
-            pos = jnp.arange(n, dtype=jnp.int32) - run_st[seg_of]
-            dest = jnp.where(sorted_ids < e, seg_st[seg_of] + pos, rpad)
-            rpad += bm
+        """The layer's routed part from a buffer sized for ``n`` copies
+        (they must be all that have a segment)."""
+        # static worst case, and the park block
+        nblk = int(align_rows(n, bm)) // bm + e + 1
+        # ---- in: a buffer row reads the token of the sorted copy it
+        # holds; a block lies in one segment, so blocks find theirs
+        blk = jnp.arange(nblk, dtype=jnp.int32)
+        ends = (seg_st + align_rows(counts, bm)) // bm
+        seg = jnp.minimum(jnp.searchsorted(ends, blk, side="right",
+                                           method="compare_all"), e - 1)
+        first = run_st[seg] + blk * bm - seg_st[seg]   # sorted copy of row 0
+        stop = run_st[seg] + counts[seg]
+        src = first[:, None] + jnp.arange(bm, dtype=jnp.int32)[None, :]
+        live = (src < stop[:, None]).reshape(-1)      # slack, unused, park
+        src = jnp.minimum(src, tk - 1).reshape(-1)
+        tok_row = tok_sorted[src]
+        small = t * nblk * bm <= _MOE_PRODUCT_CELLS
+        if small:
+            into = ((tok_row[:, None] == tokens) & live[:, None]).astype(dt)
+            xr = jnp.dot(into, x2, precision=exact,
+                         preferred_element_type=jnp.float32).astype(dt)
         else:
-            pos = jnp.arange(n, dtype=jnp.int32) - run_st[sorted_ids]
-            dest = seg_st[sorted_ids] + pos
-        xr = jnp.zeros((rpad, x2.shape[1]), x2.dtype).at[dest].set(
-            x2[token_of])
+            xr = x2[tok_row]       # a row that is not live: some token's
         if gated:
             gate = gmm(xr, "gate_proj")
             up = gmm(xr, "up_proj")
-            eo = gmm(jax.nn.silu(gate) * up, "down_proj")     # [rpad, h]
+            eo = gmm(jax.nn.silu(gate) * up, "down_proj")     # [rows, h]
         else:
             eo = gmm(_relu2(gmm(xr, "up_proj")), "down_proj")
-        # ---- combine: gather each copy's expert output, weighted
-        # scatter-add back into token order
-        ys = eo[dest]
-        if absent:             # the absent copies' row is unspecified
-            ys = jnp.where((sorted_ids < e)[:, None], ys, 0)
-        return jnp.zeros_like(x2).at[token_of].add(
-            ys * wsorted.astype(x2.dtype)[:, None])
+        # ---- out: rows of ``eo`` that no step wrote (slack aside: past
+        # the last used block, the park block) hold anything, NaN
+        # included, so what is not live is selected away, never weighed
+        if e == e_all or not small:
+            # copies of earlier tokens, by expert: a copy's rank in its
+            # segment, as the stable sort ranks it
+            before = (jnp.cumsum(chosen, axis=0) - chosen)[:, :e]
+            dest = jnp.sum(jnp.where(chose[:, :, :e],
+                                     (seg_st + before)[:, None, :], 0),
+                           axis=-1)                            # [T, k]
+            ys = eo[jnp.where(held, dest, 0)].astype(jnp.float32)
+            return jnp.sum(jnp.where(held[:, :, None],
+                                     ys * gates[:, :, None], 0),
+                           axis=1).astype(dt)
+        gate_row = jnp.where(live, gate_sorted[src], 0.0)
+        back = jnp.where(tok_row[None, :] == tokens[:, None],
+                         gate_row[None, :], 0.0).astype(dt)    # [T, rows]
+        return jnp.dot(back, jnp.where(live[:, None], eo, 0),
+                       precision=exact,
+                       preferred_element_type=jnp.float32).astype(dt)
 
     # a chip that holds e of e_all experts sees about tk * e / e_all of
     # the copies: the dispatch is sized to twice that, and to all tk
@@ -377,8 +429,9 @@ def _moe_ffn(w: _Weights, i, xm, stats=None):
     default, or ``sigmoid_groups``) -> token copies argsorted by expert
     into block-aligned ragged segments -> ONE grouped-matmul launch per
     projection (ops/pallas/grouped_matmul) applying each expert's
-    ``[in, out]`` slice to its row window, SwiGLU, then a weighted
-    scatter back to token order.
+    ``[in, out]`` slice to its row window, SwiGLU, then each token's
+    gate-weighted sum of its copies' rows (``_moe_experts``: the copies
+    travel both ways by reads, no scatter).
 
     This replaces the round-18 masked-dense expert loop (every token
     through every expert, flops scaling E/k-fold): compute is now the

@@ -32,12 +32,16 @@ accumulate correctly and the same kernel serves the ROADMAP's
 multi-adapter item.
 
 Contract (callers: parallel/expert.py dropless body, models/generation.py
-``_moe_ffn``):
+``_moe_experts``):
 - ``x`` [R, K] with R a multiple of ``block_rows``; ``seg_starts`` are
   ``block_rows``-aligned and ascending (cumsum of block-aligned lens);
-- rows of x inside a segment's alignment slack ``[len, align(len))``
-  must be zero (the dispatch scatter guarantees it) — they then
-  contribute exact zeros to dW;
+- the DIFFERENTIABLE path (``grouped_matmul``, training: the ``(S,
+  nbmax)`` form and ``grouped_outer_raw``) needs the rows of x inside a
+  segment's alignment slack ``[len, align(len))`` to be zero (the
+  training dispatch's scatter guarantees it): they then contribute exact
+  zeros to dW.  A forward launch alone does not: a slack row's product
+  lands in a slack row of the output, which nobody reads, so the serving
+  dispatch fills its slack with whatever finite row is at hand;
 - output rows outside ``[start, start+len)`` of some segment are
   unspecified; callers only gather valid rows.
 - a weight slice of up to ``_WHOLE_SLICE_BYTES`` rides in one block of
@@ -45,11 +49,18 @@ Contract (callers: parallel/expert.py dropless body, models/generation.py
   29 MB) takes the BLOCK-MAJOR form instead: the grid is (N tiles, row
   blocks), each row block reads its segment's weight id from a
   scalar-prefetched table, and with the N tile outermost a slice's tile
-  is fetched once however many row blocks its segment has.  A caller
-  whose segments tile the rows densely (``dense=True``: the serving
-  step's sorted dispatch) takes it whatever the slice's size: its grid
-  is the row blocks alone, where the ``(S, nbmax)`` grid walks ``nbmax``
-  steps for every segment, nearly all of them parked.
+  is fetched once however many row blocks its segment has.
+- a caller whose segments tile the rows densely (``dense=True``: the
+  serving step's sorted dispatch) takes the block-major form whatever
+  the slice's size: its grid is the row blocks alone, where the ``(S,
+  nbmax)`` grid walks ``nbmax`` steps for every segment, nearly all of
+  them parked.  Its ``x`` is taken AS IT STANDS: the LAST block of the
+  caller's buffer is the park block (no segment may reach it; it is
+  never read, and may hold anything), nothing is appended and nothing
+  cut off, and the output has ``x``'s rows, the park block, which holds
+  anything, included.  Any other caller's ``x`` gets the pad block
+  appended and its output cut to ``R`` rows, as in the ``(S, nbmax)``
+  form.
 
 int8 expert banks: pass the raw quantized bank as ``w`` plus the
 per-(slice, out-channel) dequant scales ``w_scale`` [E, N] — the kernel
@@ -116,7 +127,7 @@ def _gmm_kernel(*refs, block_rows: int, has_scale: bool):
             xb, wb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         if scale_ref is not None:
-            acc = acc * scale_ref[0][None, :]
+            acc = acc * scale_ref[0]
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -157,7 +168,7 @@ def _gmm_blocks_kernel(*refs, has_scale: bool):
             xb, wb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         if scale_ref is not None:
-            acc = acc * scale_ref[0][None, :]
+            acc = acc * scale_ref[0]
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -165,18 +176,20 @@ def _grouped_matmul_blocks(x, w, starts, lens, wids, bm: int, tn: int,
                            w_scale, interpret):
     """Block-major grouped matmul (module docstring).  Segments tile
     ``[0, sum(align(len)))`` densely, so row block ``b`` belongs to the
-    segment whose aligned window holds it; blocks past the last
-    segment's end park on the pad block and skip their work."""
+    segment whose aligned window holds it.  The LAST block of ``x`` is
+    the park block: no segment reaches it, no step reads it, and the
+    steps past the last segment's end write there and skip their work.
+    The output has ``x``'s rows, the park block included."""
     R, K = x.shape
     N = w.shape[2]
-    nblocks = R // bm
+    nblocks = R // bm - 1                                 # the park block apart
     ends = (starts + align_rows(lens, bm)) // bm          # in blocks
     blk = jnp.arange(nblocks, dtype=jnp.int32)
-    seg_of = jnp.minimum(jnp.searchsorted(ends, blk, side="right"),
-                         starts.shape[0] - 1)
+    seg_of = jnp.minimum(
+        jnp.searchsorted(ends, blk, side="right", method="compare_all"),
+        starts.shape[0] - 1)
     blk_wid = wids[seg_of].astype(jnp.int32)
     used = jnp.max(ends).astype(jnp.int32).reshape(1)
-    xp = jnp.concatenate([x, jnp.zeros((bm, K), x.dtype)], axis=0)
 
     def live(b, used_ref):
         # the last used block again (DMA elided) once past the end
@@ -192,24 +205,24 @@ def _grouped_matmul_blocks(x, w, starts, lens, wids, bm: int, tn: int,
         return (jnp.where(b < used_ref[0], b, nblocks), n)
 
     in_specs = [pl.BlockSpec((bm, K), x_map), pl.BlockSpec((1, K, tn), w_map)]
-    operands = [xp, w]
+    operands = [x, w]
     if w_scale is not None:
+        # [E, 1, N]: a block's last two dims are then (whole, lanes)
         in_specs.append(pl.BlockSpec(
-            (1, tn), lambda n, b, wid_ref, used_ref:
-            (wid_ref[live(b, used_ref)], n)))
-        operands.append(w_scale.astype(jnp.float32))
-    out = pl.pallas_call(
+            (1, 1, tn), lambda n, b, wid_ref, used_ref:
+            (wid_ref[live(b, used_ref)], 0, n)))
+        operands.append(w_scale.astype(jnp.float32)[:, None, :])
+    return pl.pallas_call(
         functools.partial(_gmm_blocks_kernel, has_scale=w_scale is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(N // tn, nblocks),
             in_specs=in_specs, out_specs=pl.BlockSpec((bm, tn), o_map)),
-        out_shape=_sds((R + bm, N), x.dtype),
+        out_shape=_sds((R, N), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         name=GROUPED_MATMUL_BLOCKS_KERNEL,
         interpret=interpret,
     )(blk_wid, used, *operands)
-    return out[:R]
 
 
 def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
@@ -224,7 +237,9 @@ def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
     forces the block-major form with that N tile (tests); left None the
     slice's size decides.  ``dense=True`` says the segments tile
     ``[0, sum(align(len)))`` densely, as the block-major form needs, and
-    takes it with the slice's own tile (the slice whole where it fits)."""
+    takes it with the slice's own tile (the slice whole where it fits);
+    x's last block is then the caller's park block and y has x's rows
+    (module contract)."""
     R, K = x.shape
     E, Kw, N = w.shape
     if Kw != K:
@@ -237,11 +252,19 @@ def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
         interpret = pallas_interpret()
     if R == 0 or S == 0:
         return jnp.zeros((R, N), x.dtype)
+    if dense and R < 2 * bm:
+        raise ValueError(f"a dense launch's {R} rows hold no block before "
+                         f"the park block of {bm}")
     tn = _tile_n(K, N, w.dtype.itemsize) if tile_n is None else int(tile_n)
     if tn != N or dense:
-        return _grouped_matmul_blocks(
-            x, w, seg_starts.astype(jnp.int32), seg_lens.astype(jnp.int32),
+        # a dense caller's buffer ends in its own park block and goes in
+        # and out as it stands; any other gets one appended and cut off
+        xp = x if dense else jnp.concatenate(
+            [x, jnp.zeros((bm, K), x.dtype)], axis=0)
+        out = _grouped_matmul_blocks(
+            xp, w, seg_starts.astype(jnp.int32), seg_lens.astype(jnp.int32),
             seg_wids.astype(jnp.int32), bm, tn, w_scale, interpret)
+        return out if dense else out[:R]
     pad_blk = R // bm                       # the appended safe block
     nbmax = R // bm                         # worst case: one segment owns all
 
@@ -267,9 +290,9 @@ def grouped_matmul_raw(x, w, seg_starts, seg_lens, seg_wids,
     operands = [xp, w]
     if w_scale is not None:
         def scale_map(si, j, starts_ref, lens_ref, wids_ref):
-            return (wids_ref[si], 0)
-        in_specs.append(pl.BlockSpec((1, N), scale_map))
-        operands.append(w_scale.astype(jnp.float32))
+            return (wids_ref[si], 0, 0)
+        in_specs.append(pl.BlockSpec((1, 1, N), scale_map))
+        operands.append(w_scale.astype(jnp.float32)[:, None, :])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

@@ -57,13 +57,16 @@ def _valid_mask(R, starts, lens):
 ])
 @pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
 # dense: the block-major form, the slice whole (the serving step's
-# sorted dispatch asks for it: its segments tile the rows densely)
+# sorted dispatch asks for it: its segments tile the rows densely, and
+# its buffer ends in the park block, which goes in and comes out)
 @pytest.mark.parametrize("dense", [False, True], ids=["segments", "blocks"])
 def test_grouped_matmul_matches_dense_loop(lens, dtype, dense):
     rng = np.random.default_rng(0)
     bm, K, N = 8, 16, 24
     S = len(lens)
     x, starts, R = _pack(lens, bm, K, rng)
+    if dense:           # the park block: anything, NaN included
+        x = np.concatenate([x, np.full((bm, K), np.nan, x.dtype)])
     w = rng.standard_normal((S + 1, K, N)).astype(np.float32)
     wids = np.arange(S, dtype=np.int32)  # slice S is deliberately unused
 
@@ -74,7 +77,8 @@ def test_grouped_matmul_matches_dense_loop(lens, dtype, dense):
         jnp.asarray(wids), block_rows=bm, dense=dense), np.float32)
     ref = _dense_reference(np.asarray(xj, np.float32),
                            np.asarray(wj, np.float32), starts, lens, wids)
-    m = _valid_mask(R, starts, lens)
+    assert y.shape == (x.shape[0], N)
+    m = _valid_mask(x.shape[0], starts, lens)
     tol = 1e-6 if dtype == np.float32 else 2e-2
     np.testing.assert_allclose(y[m], ref[m], rtol=tol, atol=tol)
 

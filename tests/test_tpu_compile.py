@@ -352,14 +352,17 @@ def test_flash_packed_forward_backward_compiles(one_chip, d):
 _MOE_ROWS, _MOE_K, _MOE_N, _MOE_SEGS = 4096, 2048, 1408, 8
 
 
-def test_grouped_matmul_compiles(one_chip):
+@pytest.mark.parametrize("bank", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_grouped_matmul_compiles(one_chip, bank):
     from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul_raw
 
     seg = ((_MOE_SEGS,), jnp.int32)
-    _compile(lambda x, w, s, l, i: grouped_matmul_raw(
-        x, w, s, l, i, interpret=False), one_chip,
+    scale = [((_MOE_SEGS, _MOE_N), jnp.float32)] if bank == jnp.int8 else []
+    _compile(lambda x, w, s, l, i, sc=None: grouped_matmul_raw(
+        x, w, s, l, i, w_scale=sc, interpret=False), one_chip,
         ((_MOE_ROWS, _MOE_K), jnp.bfloat16),
-        ((_MOE_SEGS, _MOE_K, _MOE_N), jnp.bfloat16), seg, seg, seg)
+        ((_MOE_SEGS, _MOE_K, _MOE_N), bank), seg, seg, seg, *scale)
 
 
 # the DeepSeek-V3.2 serving cell (benchmarks/configs/
@@ -377,6 +380,43 @@ def test_grouped_matmul_block_major_compiles(one_chip, k, n):
         x, w, s, l, i, block_rows=16, interpret=False), one_chip,
         ((4496, k), jnp.bfloat16), ((16, k, n), jnp.bfloat16), seg, seg, seg,
         kernels=["grouped_matmul_blocks"])
+
+
+# the Mellum2 serving cell's top rung (benchmarks/configs/
+# mellum2-12b-a2.5b-serve-l8.json): 544 rows x 8 copies in 64-row
+# blocks, a block of slack an expert and the park block: 8,512 rows
+_M2_MOE_ROWS, _M2_MOE_K, _M2_MOE_N, _M2_MOE_E = 4352 + 64 * 64 + 64, 2304, 896, 64
+
+
+@pytest.mark.parametrize("bank", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_grouped_matmul_dense_takes_its_buffer_as_it_stands(one_chip, bank):
+    """The serving dispatch's launch: the buffer's last block is the
+    park block, so nothing is appended to it and nothing cut off the
+    output, which has the buffer's rows; no copy of either is made
+    around the kernel."""
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul_raw
+
+    seg = ((_M2_MOE_E,), jnp.int32)
+    shapes = [((_M2_MOE_ROWS, _M2_MOE_K), jnp.bfloat16),
+              ((_M2_MOE_E, _M2_MOE_K, _M2_MOE_N), bank), seg, seg, seg]
+    if bank == jnp.int8:
+        shapes.append(((_M2_MOE_E, _M2_MOE_N), jnp.float32))
+
+    def launch(x, w, s, l, i, scale=None):
+        return grouped_matmul_raw(x, w, s, l, i, block_rows=64,
+                                  w_scale=scale, dense=True, interpret=False)
+
+    text = _compile(launch, one_chip, *shapes,
+                    kernels=["grouped_matmul_blocks"])
+    entry = text[text.index("\nENTRY "):]
+    assert re.search(rf"ROOT %\S+ = bf16\[{_M2_MOE_ROWS},{_M2_MOE_N}\]\S* "
+                     r"custom-call\(", entry), entry
+    moved = [ln.strip()[:160] for ln in text.splitlines() if re.match(
+        rf"\s*(?:ROOT )?%[\w.\-]+ = \w+\[({_M2_MOE_ROWS}|"
+        rf"{_M2_MOE_ROWS + 64}|{_M2_MOE_ROWS - 64}),\d+\]\S* "
+        r"(copy|pad|concatenate|slice|fusion)\(", ln)]
+    assert not moved, "\n".join(moved)
 
 
 _DS_ROWS, _DS_PAGES, _DS_PAGE, _DS_SLOTS, _DS_SEQ = 528, 3072, 128, 16, 24704
