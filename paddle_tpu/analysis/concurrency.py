@@ -39,6 +39,7 @@ ALLOWLIST_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 # the sweep means a lock ADDED there is analyzed from its first commit.
 CONTROL_PLANE_MODULES = (
     "inference/serving.py",
+    "inference/page_cache.py",
     "inference/fleet.py",
     "inference/disagg.py",
     "distributed/watchdog.py",

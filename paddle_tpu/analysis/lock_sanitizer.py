@@ -275,7 +275,7 @@ def hammer_page_allocator(num_pages: int = 8, threads: int = 4,
     barrier-stepped fake scheduler (single real thread, seeded order)."""
     import os
 
-    from ..inference.serving import PageAllocator
+    from ..inference.page_cache import PageAllocator
 
     alloc = PageAllocator(num_pages)
     monitor = instrument_lock(alloc, "_lock", name="_lock")
@@ -290,11 +290,11 @@ def hammer_page_allocator(num_pages: int = 8, threads: int = 4,
         run_threaded(op_lists)
     alloc.assert_consistent()       # the checked contract, under fire
     src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "inference", "serving.py")
+        os.path.abspath(__file__))), "inference", "page_cache.py")
     from .passes.lock_discipline import guarded_write_map
 
     with open(src, "r", encoding="utf-8") as f:
-        static_map = guarded_write_map(f.read(), "inference/serving.py")
+        static_map = guarded_write_map(f.read(), "inference/page_cache.py")
     xc = monitor.cross_check(static_map, "_lock")
     ok = (not monitor.order_violations()
           and alloc.available == alloc.total

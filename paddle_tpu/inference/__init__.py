@@ -341,8 +341,9 @@ def create_predictor(config_or_layer, layer=None):
 # engine of its pools and its kinds of page through a PagedLayout (window
 # and full attention layers mixed: a pool, an allocator, a table and a
 # budget a PageKind).
-from .serving import (ContinuousBatchingEngine, PageAllocator,  # noqa: E402
-                      PagedLayout, PageKind, PrefixCache)
+from .page_cache import PageAllocator, PrefixCache  # noqa: E402
+from .paged_layout import PagedLayout, PageKind  # noqa: E402
+from .serving import ContinuousBatchingEngine  # noqa: E402
 # round-13 serving resilience plane: replica fleet manager + SLO-aware
 # router + request-level fault tolerance
 from .fleet import (FleetConfig, FleetRouter, OverloadRejected,  # noqa: E402

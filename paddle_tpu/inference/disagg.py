@@ -41,7 +41,7 @@ composes them:
 
 - **tiered prefix cache** — the radix cache's LRU now DEMOTES
   refcount-0 full pages to ``pinned_host`` (parallel/memory.py
-  residency primitives) instead of evicting, and promotes on hit (serving.PrefixCache,
+  residency primitives) instead of evicting, and promotes on hit (page_cache.PrefixCache,
   ``host_tier_pages``).  The router makes a host-tier page on ANY
   replica reachable fleet-wide: ``PrefixCache.probe`` answers
   cross-replica reachability queries and ``DisaggRouter`` prefers the

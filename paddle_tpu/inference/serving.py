@@ -1,44 +1,35 @@
-"""Continuous-batching LLM serving engine over a paged cache.
+"""Continuous-batching LLM serving engine over a paged cache: the
+scheduler.
 
 The capability the reference's block_multihead_attention signature exists
 for (paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu;
 Python entry python/paddle/incubate/nn/functional/
 block_multihead_attention.py): a scheduler that ADMITS new prompts into a
 RUNNING batch, grows sequences page by page, EVICTS finished ones and
-reuses their pages.  The reference models the mixed prefill/decode step
-with its ``seq_lens_encoder`` / ``seq_lens_decoder`` /
-``seq_lens_this_time`` triplet, which ``last_report`` mirrors.
+reuses their pages.
 
-The host owns what is cheap and branchy: slots, page tables, the
-refcounted allocator, the radix prefix cache, admission, temperature
-sampling, speculative accept/reject, eviction.  The device runs ONE
-step function a model, the model's ``PagedLayout.step`` (the Llama
-family's is ``_unified_step_jit``, DeepSeek-V3.2's its own under the
-same signature): a packed batch of token rows from many sequences
-through one forward, with attention served by a ragged paged kernel
-whose cost follows the live rows, and the greedy token of every
-consumed row sampled at its end.  A row is a decode slot's token, one
-of the k+1 tokens of a speculative verify window, or one prompt token
-of a prefill chunk; at most ``prefill_token_budget`` prompt tokens ride
-a step, so a decode slot emits a token EVERY step whatever prompt is
-prefilled beside it.  The step's one free parameter is its number of
+This file holds the scheduler and nothing of any model.  It learns a
+model from ``cfg.paged_layout()`` (``inference/paged_layout.py``: what a
+sequence holds on the device and the contract of the model's step,
+which lives in ``models/<name>.py``); the host side of the cache
+(allocators, the radix prefix cache, a kind of page's tables) is
+``inference/page_cache.py``.
+
+The host owns what is cheap and branchy: slots, page tables, admission,
+temperature sampling, speculative accept/reject, eviction.  The device
+runs ONE step function a model, ``PagedLayout.step``: a packed batch of
+token rows from many sequences through one forward, the greedy token of
+every consumed row sampled at its end.  A row is a decode slot's token,
+one of the k+1 tokens of a speculative verify window, or one prompt
+token of a prefill chunk; at most ``prefill_token_budget`` prompt tokens
+ride a step, so a decode slot emits a token EVERY step whatever prompt
+is prefilled beside it.  The step's one free parameter is its number of
 rows, read off its input, and where the layout states its kernels' row
-tile (``PagedLayout.tile_rows``: the Llama family's, MiniCPM-SALA's
-and Nemotron-H's do; one that states none, DeepSeek-V3.2's today, keeps
-the capacity alone) the engine compiles it at a short
-LADDER of row counts (``step_ladder``: every decode row; a quarter and
-a half of the prefill budget above them; the capacity ``rows_cap``),
-all of them before its first launch (``_padding_launches``), and
-launches each
-step at the smallest rung that holds the rows it packed: everything
-outside the kernels (embedding, norms, projections, the K/V scatters,
-the experts' dispatch) is XLA work at the launched size, so a step of 4
-decode rows does not pay for the 288 rows of a prefill chunk it does
-not hold.  Nothing else about a launch depends on the rung: the same
-rows in the same order, the same gather, the same tables, the same
-commit.  Padding rows are the price of a static shape, up to the rung:
-they compute garbage that is never read and write it to the TRASH page,
-the last physical page, which no slot owns.
+tile (``PagedLayout.tile_rows``) the engine compiles it at a short
+LADDER of row counts (``paged_layout.step_ladder``), all of them before
+its first launch (``_padding_launches``), and launches each step at the
+smallest rung that holds the rows it packed; nothing else about a
+launch depends on the rung.
 
 ``engine.step()`` runs ONE STEP AHEAD of what it has read
 (``_step_unified``).  With launch n enqueued by the call before, a call
@@ -49,91 +40,31 @@ fetch launch n's tokens and commit them.  The one thing launch n+1
 needs from launch n that the host does not know when it packs is one
 token a decode row: that input is a REFERENCE into the tokens launch n
 sampled, which never leave the device on their way
-(``resolve_row_tokens``).  The device so always has a launch queued,
-and a token reaches the host when its own device step ends.  What is
-scheduled (a slot's position and budget as the packing sees them)
-advances at launch; what is committed (``out_tokens``, ``cur_tok``,
-``finished``, pages and slots freed, the prefix cache's insert, the
-handoff record) changes at commit.  A slot that ends on ``eos_id``, or
-is canceled, with a row enqueued runs that row STALE: it writes inside
-the slot's own pages, which are freed after it was enqueued and so
-cannot be reused under it (a device runs its launches in order), and
-its token is dropped.  Where the host has to see a step before it can
-pack the next (a request with a temperature draws from its own seeded
-numpy stream; a draft model's window is accepted or rejected on the
-host: ``_host_samples``) the call reads and commits the launch it just
-made: the engine decides that from what it is serving, a step at a
-time, and nothing selects it from outside.
+(``paged_layout.resolve_row_tokens``).  The device so always has a
+launch queued, and a token reaches the host when its own device step
+ends.  What is scheduled (a slot's position and budget as the packing
+sees them) advances at launch; what is committed (``out_tokens``,
+``cur_tok``, ``finished``, pages and slots freed, the prefix cache's
+insert, the handoff record) changes at commit.  A slot that ends on
+``eos_id``, or is canceled, with a row enqueued runs that row STALE: it
+writes inside the slot's own pages, which are freed after it was
+enqueued and so cannot be reused under it (a device runs its launches
+in order), and its token is dropped.  Where the host has to see a step
+before it can pack the next (a request with a temperature draws from
+its own seeded numpy stream; a draft model's window is accepted or
+rejected on the host: ``_host_samples``) the call reads and commits the
+launch it just made: the engine decides that from what it is serving, a
+step at a time, and nothing selects it from outside.
 
-- Everything the host tells the device rides in ONE int32 upload a
-  step: ``rows`` ``[rung, 5]`` (a rung of ``ladder``, not ``rows_cap``)
-  = (input token or reference, physical page its K/V is written to,
-  in-page offset, causal visibility, slot), beside the page tables.
-- A model may have several KINDS of page (``PagedLayout.kinds``: window
-  and full attention layers mixed).  The engine then holds, for each
-  kind, a pool size, an allocator, a table ``[slots, pages_per_seq]``
-  and a budget (``_KindPages``), ``rows`` carries one more column a
-  further kind (the page the row writes there) and the step takes a
-  table a kind.  A kind that retains a window maps a block when a
-  launch first writes into it and gives it back when the launch that
-  last read it is committed, so a slot holds at most ``window_bound``
-  of its pages whatever its context; the prefix cache holds a page of
-  each kind a block and serves a hit as far as every kind is whole.  A
-  layout of one kind runs the same code with a tuple of length one.
-- A page may have FURTHER pools beside the two (``PagedLayout.more_pools``:
-  a cache of compressed keys, a row for every 16 tokens): the same page
-  id names a page in each, so tables, allocators and the prefix cache
-  share them as they share K and V.  The engine holds one such pool a
-  layer that has pages, hands them to the step as ``pools=`` and takes
-  them back as its LAST result; what a page of one holds, and when a row
-  of it is final, is the step's business.
-- A model may hold a SECOND SORT of state beside its pages
-  (``PagedLayout.state``: Mamba-2's per-sequence SSM state and conv
-  tail): overwritten every token, so not paged, not addressed by
-  position and not shareable by reference.  The engine holds ONE pool
-  of entries a state layer for each of its arrays, donated through the
-  step like the page pools: an entry a slot, then the snapshot entries,
-  then a trash entry for the padding rows.  A packed row carries three
-  columns more: the entry its slot STARTS from (its own; a snapshot's
-  for a slot just admitted on a prefix hit; below zero, zeros, for a
-  fresh one), the entry the state is LEFT in, and, on a slot's last
-  row, the entry a snapshot of that state is copied to.  So admission,
-  restore and recycling are numbers in the one upload: no host copy,
-  no launch of their own that the run-ahead would wait for, no second
-  program.  The prefix cache keeps a snapshot at a block beside its
-  pages (taken where a prefill chunk ends on a page boundary:
-  ``_state_chunk`` cuts chunks so that they do) and serves a hit as far
-  as the deepest block that has BOTH; what the pages matched beyond it
-  is prefilled again (``state_lost_tokens``).  Snapshots have their own
-  budget (``state_snapshots``) and LRU.  A stale row writes its slot's
-  own entry, which the slot's next tenant never reads: it starts from
-  zeros or a snapshot, in a later launch.  Such a layout serves only
-  layers with pages through ``k_pages`` (one pool a layer that has
-  pages) and refuses what cannot carry state: a draft model, the int8
-  cache, the host tier, ``prefill_only``, ``adopt_request`` and
-  ``export_handoff``.
-- The page pools are PER-LAYER arrays, donated through the step, so a
-  layer's cache update is one scatter into its own pool; a fused
-  ``[L, pages, ...]`` slab cost a slice and a whole-layer update a
-  layer.  The write (``_write_kv_rows``) indexes (page, head, offset),
-  so it scatters rows of ``d`` in place; with the head left as a slice
-  XLA relaid the whole pool around the kernel (PERF.md section 6,
-  PR 25).
-- The CONSUMED rows alone (every verify-window row and each prefill
-  chunk's final row) are gathered on the device before the final norm
-  and the vocabulary projection: the head matmul and the fp32 logits
-  are sized to ``gather_cap`` whatever the rung, so a launch's sampled
-  tokens have one shape and launches of different rungs chain.  Their
-  first maxima (``sample_greedy``) are what a step copies back, 4 bytes
-  a row; the logits themselves cross only for a request with a
-  temperature, or when ``last_logits`` is read.
-
-Weight-only int8 params (models/generation.quantize_params_int8) run
-through the same program: dequant fuses into the consumer dots.  An
-int8 K/V cache takes its scales from ONE calibration pass over the
-first submitted prompt (``_calibrate_int8_unified``: absmax per (layer,
-kv head), 2x headroom, frozen); the step quantizes every row it
-scatters with them.
+A layout with a recurrent state serves only layers with pages through
+``k_pages`` and refuses what cannot carry state: a draft model, the
+int8 cache, the host tier, ``prefill_only``, ``adopt_request`` and
+``export_handoff``.  Weight-only int8 params
+(models/generation.quantize_params_int8) run through the same program:
+dequant fuses into the consumer dots.  An int8 K/V cache takes its
+scales from ONE calibration pass over the first submitted prompt
+(``_calibrate_int8_unified``: absmax per (layer, kv head), 2x headroom,
+frozen); the step quantizes every row it scatters with them.
 
 ``cancel(rid)`` withdraws a request with no ``Finished`` record (the
 fleet router's migration and retry primitive, inference/fleet.py);
@@ -144,30 +75,20 @@ Measurement: every phase of ``_step_unified`` is a
 ``profiler.RecordEvent`` (``serving.step`` > ``serving.admit``,
 ``serving.propose``, ``serving.pack``, ``serving.launch``,
 ``serving.fetch_logits``, ``serving.commit``), so a profiler trace that
-runs, whoever started it, holds them on the device's clock
-(``serving.fetch_logits`` keeps its name: it is where the host waits for
-the device and copies back what it needs, the tokens); one marker a
-call (``serving.step_counts``: the counts of the launch the call
+runs, whoever started it, holds them on the device's clock; one marker
+a call (``serving.step_counts``: the counts of the launch the call
 COMMITS, with ``ahead``, ``stale_rows`` and ``launch``, that launch's
 serial, which ``serving.launch`` carries too where it is enqueued) and
 one per request at admission and at its first token
 (``serving.admit_request``, ``serving.first_token``) carry the counts.
 The same counts are summed in ``serving_stats()["steps"]`` whether or
-not anything traces.  The step's ``jax.named_scope``s are
-``profiler.device_trace.DEVICE_SCOPES``: a device trace's operations
-carry them, and ``device_time_by_scope`` there adds the device's time up
-by scope and by launch.  A step's
-``rows_cap`` count is the rows of the program LAUNCHED (the rung), so
-``rows`` over ``rows_cap`` says how full the launched shapes were, and
-``launches_by_rows`` how many launches each rung took; ``engine.rows_cap``
-stays the capacity.
+not anything traces.  A step's ``rows_cap`` count is the rows of the
+program LAUNCHED (the rung); ``engine.rows_cap`` stays the capacity.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import heapq
-import threading
 import time
 from collections import deque
 from functools import partial
@@ -178,6 +99,12 @@ import jax
 import jax.numpy as jnp
 
 from ..profiler import RecordEvent
+from .page_cache import PageAllocator, PrefixCache, _KindPages
+from .paged_layout import (STATE_COUNTS, WINDOW_PAGE_COUNTS, PageKind,
+                           step_ladder)
+# benchmarks/tools/microbench_ragged_attention.py reads it under this
+# module's name
+from .paged_layout import ragged_kv_tokens_read  # noqa: F401
 
 
 @dataclasses.dataclass
@@ -208,15 +135,12 @@ class _Launch:
     its tokens come back: ``metas`` ``(kind, slot, first gathered row,
     rows)`` a scheduled slot, ``gathered`` ``(rid, position)`` a
     gathered row, the step's ``counts`` for ``serving.step_counts``,
-    ``enc`` / ``dec`` for ``last_report``, the draft's ``props``,
-    ``out``, the program's third result, still on the device, and
-    ``snaps``: by slot, the ``(blocks, entry)`` of the state snapshot
+    the draft's ``props``, ``out``, the program's third result, still
+    on the device, and ``snaps``: by slot, the ``(blocks, entry)`` of the state snapshot
     the launch takes at the end of that slot's chunk."""
     metas: List[tuple]
     gathered: List[tuple]
     counts: Dict[str, int]
-    enc: np.ndarray
-    dec: np.ndarray
     props: Dict[int, tuple]
     out: Any = None
     snaps: Dict[int, tuple] = dataclasses.field(default_factory=dict)
@@ -231,980 +155,6 @@ def _softmax_np(logits: np.ndarray, temperature: float) -> np.ndarray:
     x = x - x.max()
     e = np.exp(x)
     return e / e.sum()
-
-
-def _round_int8(x):
-    """Round-half-away-from-zero to int8 range (the reference's
-    quant_round_type=1; shared by calibration-time and decode-time
-    quantization)."""
-    y = jnp.sign(x) * jnp.floor(jnp.abs(x) + 0.5)
-    return jnp.clip(y, -127, 127).astype(jnp.int8)
-
-
-def _write_kv_rows(pool, phys, off, x):
-    """Write token rows ``x`` [T, kvh, d] into ``pool`` [pages, kvh,
-    page, d]: row ``t`` to page ``phys[t]``, in-page offset ``off[t]``.
-    The index carries the HEAD too, so the update window is one
-    contiguous row of ``d`` and XLA scatters in place into the donated
-    pool.  ``pool.at[phys, :, off, :]`` has a strided window [kvh, d],
-    for which XLA copied the WHOLE pool into another layout and back
-    (PERF.md section 6, PR 25)."""
-    heads = jnp.arange(pool.shape[1])
-    return pool.at[phys[:, None], heads[None, :], off[:, None], :].set(
-        x.astype(pool.dtype))
-
-
-def resolve_row_tokens(tok, prev_tokens):
-    """The packed rows' input tokens, references resolved.  ``tok >= 0``
-    is a token the host knew when it packed the step; ``tok < 0`` names
-    entry ``-1 - tok`` of ``prev_tokens``, what the launch before this
-    one sampled (``sample_greedy`` of its gathered rows): the host
-    enqueued this launch before it had read that token.  Every model's
-    step calls this before its embedding."""
-    ref = jnp.take(prev_tokens, jnp.maximum(-1 - tok, 0), mode="clip")
-    return jnp.where(tok < 0, ref, tok)
-
-
-def sample_greedy(logits):
-    """int32 ``[G]``: the first maximum of each row of fp32 logits
-    ``[G, vocab]``, which is what ``np.argmax`` of the same row gives
-    the host.  It stays on the device for the next launch's
-    ``resolve_row_tokens`` and is all a greedy step copies back."""
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-
-@dataclasses.dataclass(frozen=True)
-class PageKind:
-    """One KIND of page of a model: the layers whose pools hold it and
-    what those layers retain of a context, every position (``window``
-    None) or the last ``window`` positions.  A kind has a pool size, an
-    allocator and a page table ``[slots, pages_per_seq]`` of its own in
-    the engine (``_KindPages``)."""
-    name: str
-    layers: tuple
-    window: Optional[int] = None
-
-
-@dataclasses.dataclass(frozen=True)
-class PagedLayout:
-    """What a model tells the engine of what a sequence holds on the
-    device and of its part of the unified step.  Every layer THAT HAS
-    PAGES (a layer of some kind: all of them where ``kinds`` is empty)
-    has TWO pools whose page holds a row a token (``rows``) and may have
-    FURTHER pools whose page has a shape of its own (``more_pools``), and
-    one page id names a page in each pool of every
-    layer OF ONE KIND, so slots, tables, the allocators and the prefix
-    cache never know what a page holds.  A model may hold a second SORT
-    of state beside its pages (``state``): a recurrent state of fixed
-    size a sequence, in ``state_layers`` layers, which is overwritten
-    every token and so is neither addressed by position nor shared by
-    reference.
-
-    ``kinds``: the kinds of page the model has (``PageKind``), empty for
-    ONE kind that every layer shares.  The first kind retains every
-    position; each further kind retains a window, and the engine then
-    holds at most a bounded number of its pages a slot, whatever the
-    context (``ContinuousBatchingEngine.window_bound``).  A packed row
-    carries the physical page it writes in each kind (``rows`` column 1
-    for the first, columns 5.. for the others) and the step takes a
-    table a kind.
-
-    ``rows``: the shape of one token's row in each pool.  A Llama-shaped
-    decoder's (``kv_layout``) are its K and V rows ``[kvh, d]``, laid
-    out head-major as the paged kernels read them (``[pages, kvh, page,
-    d]``); a model with ``paged_layout()`` on its config brings its own
-    (DeepSeek-V3.2: a latent row ``[640]`` and an index key ``[128]``,
-    ``[pages, page, n]``).  ``step`` is the model's part of the engine
-    step, a jitted function under ``_unified_step_jit``'s signature
-    whose third result is ``(logits, tokens)``; if ``device_counts``
-    names counts it takes on the device, ``(logits, tokens, those
-    counts)``; what a step appends after these, a row a gathered row,
-    stays on the device until ``ContinuousBatchingEngine.last_extras``
-    is read (a model's block selection, for a check to hold).  ``row_counts(rows,
-    ctx_tokens, page_size, pages_per_seq)`` gives the step's counts the
-    packed rows determine.  Both kinds ride on ``serving.step_counts``
-    and are summed in ``serving_stats()["steps"]`` under
-    ``count_names``.  ``pages_per_step(page_size, pages_per_seq,
-    itemsize)`` is how many pages the step's kernels take a turn of
-    their page walk, where the constructor is given no number.
-    ``tile_rows``: the packed rows a tile of the step's kernels holds,
-    where the step may be launched at any number of whole tiles: the
-    engine then compiles it at a ladder of row counts
-    (``step_ladder``): the Llama family's layouts (``kv_layout``),
-    MiniCPM-SALA's and Nemotron-H's state theirs.  0, the default, where
-    the layout states none: its step is compiled at the capacity alone,
-    as ever (DeepSeek-V3.2's today: PERF.md section 6, PR 36, says what
-    stands in its way).
-
-    ``more_pools``: a function a further pool, from the page size to the
-    shape of ONE PAGE of it (a cache of compressed keys: ``page // 16``
-    rows of ``kvh * d``), in the cache's dtype.  The engine holds a pool
-    ``[pages, *shape]`` a layer that has pages for each
-    (``ContinuousBatchingEngine.more_pools``), passes them to ``step`` as
-    ``pools=`` (donated) and takes them back as the step's LAST result.
-    What is in a page of one has to depend on the tokens up to that
-    page's end alone, since the prefix cache shares it under the page's
-    id.
-
-    ``state``: ``(shape, dtype)`` of each array a slot's recurrent
-    state has in ONE state layer (dtype None: the cache's).  The engine
-    then holds, for each, one pool ``[entries, *shape]`` a state layer
-    (``ContinuousBatchingEngine.state``: a slot's own entry is its
-    number, then the snapshot entries, the last the trash entry),
-    passes them to ``step`` as ``state=`` (donated; the step returns
-    them as a FOURTH result) and gives every packed row three columns
-    more, after those of the kinds of page: the entry the row's slot
-    starts from (below zero: zeros), the entry its state is left in,
-    and, on a slot's last row, the entry a snapshot of that state is
-    copied to (below zero: none; at most ``state_snapshots_a_step`` a
-    launch).
-
-    What the engine can do with K/V pages of one kind alone (a draft
-    model's mirror, an int8 cache, the host tier, the prefill-only
-    handoff) refuses other pools, further pools, more kinds than one,
-    and a recurrent state, at construction."""
-    name: str
-    rows: tuple
-    head_major: bool = True
-    step: Any = None
-    row_counts: Any = None
-    device_counts: tuple = ()
-    count_names: tuple = ()             # row_counts' keys + device_counts
-    pages_per_step: Any = None
-    tile_rows: int = 0
-    kinds: tuple = ()
-    more_pools: tuple = ()
-    state: tuple = ()
-    state_layers: int = 0
-    state_snapshots_a_step: int = 0
-
-    def pool_shapes(self, num_pages: int, page_size: int):
-        if self.head_major:
-            return tuple((num_pages, r[0], page_size, *r[1:])
-                         for r in self.rows)
-        return tuple((num_pages, page_size, *r) for r in self.rows)
-
-
-def ragged_kv_tokens_read(row_slot, row_lens, tile_rows: int, page: int,
-                          max_pages: int) -> int:
-    """K/V positions the ragged kernel's walk fetches for these packed
-    rows in one layer: whole pages, each unit's slot as far as the
-    unit's reach."""
-    from ..ops.pallas.decode_attention import ragged_units
-
-    _, reach = ragged_units(np.asarray(row_slot), np.asarray(row_lens),
-                            tile_rows, np)
-    pages = np.minimum(-(-reach // page), max_pages)
-    return int(pages.sum()) * page
-
-
-#: what the Llama family's step counts on the device where a layer has
-#: experts, in the order it returns them: token copies routed and those
-#: of an expert in the bank, the fullest expert's rows, the experts that
-#: got at least one row (how much of the bank a step streams) and the
-#: experts there are, both summed over the expert layers
-MOE_DEVICE_COUNTS = ("moe_rows_routed", "moe_rows_held",
-                     "moe_expert_rows_max", "moe_experts_hit",
-                     "moe_experts_total")
-#: what the packed rows give where a kind retains a window
-WINDOW_ROW_COUNTS = ("attn_row_ctx_window", "kv_ctx_tokens_window")
-#: what the engine counts of a window kind's pages at a commit
-WINDOW_PAGE_COUNTS = ("window_pages_live", "window_pages_recycled")
-#: what the engine counts a call where sequences hold a recurrent state
-#: (``PagedLayout.state``): snapshots the prefix cache holds, those this
-#: call's commit gave it and those evicted since the last call; of the
-#: requests this call admitted, the prompt tokens a snapshot restored
-#: and those the cache's pages matched beyond the deepest snapshot,
-#: which are prefilled again
-STATE_COUNTS = ("state_snapshots_live", "state_snapshots_taken",
-                "state_snapshots_evicted", "state_restored_tokens",
-                "state_lost_tokens")
-
-
-def page_kinds(cfg) -> tuple:
-    """The kinds of page of a Llama-shaped config, from its
-    ``layer_types``: the ``full_attention`` layers first, then the
-    ``sliding_attention`` layers with ``cfg.sliding_window``.  Empty
-    where every layer retains its whole context (no ``layer_types``, or
-    none of them sliding): ONE kind, as ever."""
-    types = tuple(getattr(cfg, "layer_types", None) or ())
-    sliding = tuple(i for i, t in enumerate(types)
-                    if t == "sliding_attention")
-    if not sliding:
-        return ()
-    full = tuple(i for i, t in enumerate(types) if t != "sliding_attention")
-    if not full:
-        raise ValueError("every layer slides: the first kind of page "
-                         "retains every position (PagedLayout)")
-    return (PageKind("full", full),
-            PageKind("window", sliding, int(cfg.sliding_window)))
-
-
-def kv_layout(cfg) -> PagedLayout:
-    """The Llama family's layout: K rows and V rows, ``_unified_step_jit``
-    and what its ragged kernel's walk reads; two kinds of page where
-    ``cfg.layer_types`` mixes window and full layers (``page_kinds``)."""
-    from ..ops.pallas.decode_attention import (default_pages_per_step,
-                                               ragged_tile_rows)
-
-    kvh, d = cfg.num_key_value_heads, cfg.head_dim
-    # rows of a query tile of the ragged kernel: the K/V its walk reads
-    # are counted by the kernel's own units of work
-    tile_rows = ragged_tile_rows(cfg.num_attention_heads, kvh, d)
-    kinds = page_kinds(cfg)
-    windows = [k.window for k in kinds if k.window is not None]
-
-    def row_counts(rows, ctx_tokens, page_size, pages_per_seq):
-        # what the walk fetches in one layer (whole pages, a slot once
-        # for each of its units of work): over kv_ctx_tokens, the
-        # re-read factor
-        out = {"attn_kv_tokens_read": ragged_kv_tokens_read(
-            rows[:, 4], rows[:, 3], tile_rows, page_size, pages_per_seq)}
-        if windows:
-            # a window layer's least work: a row's arithmetic over
-            # min(visibility, W) keys, a slot's bytes over min(context,
-            # W) positions (a slot's context is its rows' largest
-            # visibility)
-            w, vis = windows[0], rows[:, 3]
-            ctx = np.zeros(int(rows[:, 4].max(initial=-1)) + 1, np.int64)
-            np.maximum.at(ctx, rows[:, 4], vis)
-            out.update(zip(WINDOW_ROW_COUNTS,
-                           (int(np.minimum(vis, w).sum()),
-                            int(np.minimum(ctx, w).sum()))))
-        return out
-
-    def pages_per_step(page_size, pages_per_seq, itemsize):
-        return default_pages_per_step(page_size, kvh, d, pages_per_seq,
-                                      itemsize)
-
-    device_counts = MOE_DEVICE_COUNTS if _counts_experts(cfg) else ()
-    return PagedLayout(
-        name="kv", rows=((kvh, d), (kvh, d)),
-        step=ContinuousBatchingEngine._unified_step_jit,
-        row_counts=row_counts, device_counts=device_counts,
-        count_names=("kv_ctx_tokens", "attn_kv_tokens_read",
-                     *((*WINDOW_ROW_COUNTS, *WINDOW_PAGE_COUNTS)
-                       if windows else ()), *device_counts),
-        pages_per_step=pages_per_step, tile_rows=tile_rows, kinds=kinds)
-
-
-def step_ladder(decode_rows: int, prefill_budget: int,
-                tile_rows: int = 1) -> tuple:
-    """The row counts the engine's step is compiled at, ascending: the
-    decode rows alone, a quarter and a half of the prefill budget above
-    them, and the capacity, ``decode_rows + prefill_budget``; each
-    rounded up to whole tiles of the step's kernels and held to the
-    capacity.  A launch takes the smallest that holds its rows, so a
-    step of decode rows alone does not compute a prefill chunk's
-    padding.  (Mistral's cell: 32, 96, 160, 288.)  With no tile stated
-    (``tile_rows`` 0: ``PagedLayout``) the capacity alone."""
-    cap = decode_rows + prefill_budget
-    if not tile_rows:
-        return (cap,)
-    rungs = {min(cap, -(-(decode_rows + -(-prefill_budget * q // 4))
-                        // tile_rows) * tile_rows) for q in (0, 1, 2)}
-    return tuple(sorted(rungs | {cap}))
-
-
-def _counts_experts(cfg) -> bool:
-    """Whether the Llama family's step takes the expert layers' counts
-    on the device (``MOE_DEVICE_COUNTS``): a config that states its
-    experts.  ``kv_layout`` and the step ask the same question."""
-    return int(getattr(cfg, "num_experts", 0) or 0) > 0
-
-
-class PageAllocator:
-    """Host-side physical-page free list with EXPLICIT refcounts (reuse
-    is LIFO so hot pages stay cache/TLB friendly).
-
-    Round-11: pages are shared copy-on-write between the prefix-cache
-    trie and any number of live requests, so ownership is counted —
-    ``alloc`` hands out a page at refcount 1, every additional sharer
-    ``acquire``\\ s it, and ``release`` only returns it to the free list
-    when the count reaches zero.  The invariant ``available + live ==
-    num_pages`` is a CHECKED CONTRACT (``assert_consistent``) callable
-    at any point — under the race sanitizer's thread hammer and at
-    engine teardown — so a COW bug (double release, leaked ref)
-    surfaces as a hard failure instead of silent pool exhaustion.
-
-    Concurrency Doctor round: every mutation runs under ``_lock``
-    (whole method bodies — a bare ``if not self.free`` outside the lock
-    is exactly the check-then-act shape RACE004 flags).  The serving
-    tick itself is single-threaded; the lock is for the multi-host
-    control plane (hammer harness today, replica-per-host tomorrow) and
-    is uncontended — and therefore cheap — in the common path."""
-
-    def __init__(self, num_pages: int):
-        self.free: List[int] = list(range(num_pages - 1, -1, -1))
-        self.total = num_pages
-        self.refs: List[int] = [0] * num_pages
-        self._lock = threading.Lock()
-
-    def alloc(self) -> Optional[int]:
-        with self._lock:
-            if not self.free:
-                return None
-            p = self.free.pop()
-            self.refs[p] = 1
-            return p
-
-    def acquire(self, page: int) -> int:
-        """Add a reference to an already-live page (prefix sharing)."""
-        with self._lock:
-            if self.refs[page] <= 0:
-                raise AssertionError(
-                    f"acquire of dead page {page} (refcount "
-                    f"{self.refs[page]}) — prefix-cache/table corruption")
-            self.refs[page] += 1
-            return page
-
-    def release(self, pages) -> None:
-        """Drop one reference per page; a page returns to the free list
-        only when its last reference is gone."""
-        with self._lock:
-            for p in reversed(list(pages)):
-                p = int(p)
-                if self.refs[p] <= 0:
-                    raise AssertionError(
-                        f"release of free page {p} — double release")
-                self.refs[p] -= 1
-                if self.refs[p] == 0:
-                    self.free.append(p)
-
-    @property
-    def available(self) -> int:
-        # lock-free snapshot: advisory under concurrency, exact when the
-        # pool is quiescent (scheduler decisions re-check under alloc)
-        return len(self.free)
-
-    @property
-    def live(self) -> int:
-        return sum(1 for r in self.refs if r > 0)
-
-    def assert_consistent(self) -> None:
-        """The checked pool contract, atomically under the lock:
-        every page is exactly one of free or live
-        (``available + live == total``), no refcount is negative, free
-        pages carry no references, and the free list holds unique
-        in-range page ids."""
-        with self._lock:
-            live = sum(1 for r in self.refs if r > 0)
-            if len(self.free) + live != self.total:
-                raise AssertionError(
-                    f"page pool out of balance: available={len(self.free)} "
-                    f"+ live={live} != total={self.total}")
-            neg = [p for p, r in enumerate(self.refs) if r < 0]
-            if neg:
-                raise AssertionError(f"negative refcounts on pages {neg}")
-            bad = [p for p in self.free if self.refs[p] != 0]
-            if bad:
-                raise AssertionError(f"free pages with live refs: {bad}")
-            if len(set(self.free)) != len(self.free):
-                raise AssertionError("duplicate pages on the free list")
-            oob = [p for p in self.free if not 0 <= p < self.total]
-            if oob:
-                raise AssertionError(f"out-of-range pages on free list: {oob}")
-
-    def assert_balanced(self) -> None:
-        """Back-compat alias for the pre-round-18 leak check."""
-        self.assert_consistent()
-
-
-class _TrieNode:
-    """One committed full page of tokens in the prefix cache.
-
-    Round 16 (the tiered KV plane): a node lives in one of two TIERS —
-    ``device`` (``page`` is a live pool page id, the trie holds one
-    allocator ref on it) or ``host`` (``page`` is None and ``host_kv``
-    carries the page's per-layer K/V stacked [L, kvh, page, d] pair,
-    placed in the pinned-host memory space).
-
-    Where the model has further kinds of page (``PagedLayout.kinds``),
-    ``more`` holds the block's page of each: a window kind's page, or
-    None once it was evicted alone (or the prefill that committed the
-    block had already given it back).
-
-    Where sequences hold a recurrent state (``PagedLayout.state``),
-    ``snap`` is the entry of the state pools that holds the state AT the
-    end of this block (a snapshot; None: none was kept) and
-    ``snap_tick`` when it was last restored from (when it was taken,
-    while ``snap_used`` is False: nobody has restored from it yet)."""
-
-    __slots__ = ("children", "key", "page", "parent", "tick", "host_kv",
-                 "more", "snap", "snap_tick", "snap_used")
-
-    def __init__(self, key=None, page=None, parent=None, kinds: int = 0):
-        self.children: Dict[tuple, "_TrieNode"] = {}
-        self.key = key
-        self.page = page
-        self.parent = parent
-        self.tick = 0
-        self.host_kv = None
-        self.more: List[Optional[int]] = [None] * kinds
-        self.snap: Optional[int] = None
-        self.snap_tick = 0
-        self.snap_used = False
-
-    @property
-    def tier(self) -> str:
-        return "device" if self.host_kv is None else "host"
-
-
-class PrefixCache:
-    """Radix/trie prefix cache over the engine's page pools.
-
-    Keys are page-granular token chunks (``page_size`` tokens per edge),
-    values are PHYSICAL page ids in the per-layer pools.  A node exists
-    only for pages whose prompt tokens were fully committed by a
-    completed prefill, and the trie holds its own allocator reference on
-    each node's page — so cached prefixes survive the requests that
-    produced them, and ``lookup`` can hand the same physical pages to a
-    new request copy-on-write (the new request only ever WRITES at
-    positions at or past its private suffix, so shared pages are
-    read-only by construction; the last partial prompt page is always
-    private because only full pages are keyed, and at least one suffix
-    token is always left to prefill so the hit request still produces
-    first-token logits).
-
-    Eviction is LRU over refcount-0 leaves (allocator refcount 1 = the
-    trie's own reference, no live request) under pool pressure — interior
-    nodes become leaves as their children evict, so a cold chain drains
-    bottom-up.
-
-    Round 16 — the TIERED cache (``host_tier_pages > 0``): under pool
-    pressure, LRU refcount-0 pages are DEMOTED to the pinned-host
-    memory space (``demote_fn`` — parallel/memory.place_on_host through
-    the engine's pool gather) instead of evicted; a later lookup that
-    reaches a host-tier node PROMOTES it back into a device page
-    (``promote_fn``) and the hit proceeds exactly as a device hit — the
-    demote→promote round trip is bit-identical (pure residency moves,
-    no re-quantization).  Demotion needs no leaf-ness (the trie
-    structure is untouched), so interior pages demote too; only when
-    the host tier itself overflows its cap are LRU host-tier LEAVES
-    truly dropped, bottom-up like classic eviction.
-
-    KINDS of page (``windows``: one ``(allocator, window)`` a further
-    kind of the layout): a cached block holds a page of each kind.  The
-    rows that continue a hit of ``P`` tokens read the first kind's pages
-    of ``[0, P)`` and a window kind's pages of the positions from ``P +
-    1 - window`` on, so a hit is served as far as BOTH are whole
-    (``lookup_all``) and shrinks to the longest prefix of which that
-    holds, never to something wrong.  A window kind's page may be
-    evicted ALONE, from any block (``evict_window``): first those no
-    possible hit can need (the run of blocks a hit would read is broken
-    already), then the least recently used.
-
-    SNAPSHOTS of a recurrent state (``snaps``: the allocator of the
-    state pools' snapshot entries): a block may hold, beside its pages,
-    the entry with the state at its end.  Pages say what the attention
-    layers saw of a prefix, a snapshot what the recurrent layers made
-    of it, and a sequence can only go on from a point where it has
-    BOTH: ``lookup_all`` serves a hit as far as the deepest block of
-    the walk that has a snapshot, and says how many matched tokens lay
-    beyond it (they are prefilled again).  Snapshots have a budget and
-    an LRU of their own (``evict_snapshots``: those never restored
-    from first, then by when one was last restored from; ``snap_nodes`` is the few blocks that hold one, by
-    entry, so neither eviction nor the live count walks the trie); a
-    block that goes takes its snapshot along."""
-
-    def __init__(self, page_size: int, alloc: PageAllocator, *,
-                 host_tier_pages: int = 0, demote_fn=None,
-                 promote_fn=None, windows=(), snaps=None):
-        self.page_size = int(page_size)
-        self.alloc = alloc
-        self.windows = tuple(windows)       # (allocator, window) a kind
-        self.snaps: Optional[PageAllocator] = snaps
-        self.snap_nodes: Dict[int, _TrieNode] = {}  # entry -> its block
-        self.snapshots_taken = 0
-        self.evicted_snapshots = 0
-        self.evicted_window_pages = 0
-        self.root = _TrieNode()
-        self._tick = 0
-        self.hits = 0
-        self.lookups = 0
-        self.hit_tokens = 0
-        self.inserted_pages = 0
-        self.evicted_pages = 0
-        # host tier (round 16)
-        self.host_tier_pages = int(host_tier_pages)
-        self.demote_fn = demote_fn
-        self.promote_fn = promote_fn
-        if self.host_tier_pages > 0 and (demote_fn is None
-                                         or promote_fn is None):
-            raise ValueError(
-                "host_tier_pages > 0 needs demote_fn/promote_fn (the "
-                "engine's pool residency hooks)")
-        self.host_pages = 0
-        self.host_hits = 0
-        self.demoted_pages = 0
-        self.promoted_pages = 0
-
-    def _chunks(self, tokens, npages: int):
-        """The keys of a prompt's first ``npages`` blocks: each block's
-        token ids as bytes (a session of 64k tokens is 512 keys a lookup
-        and as many an insert: a tuple of Python ints a block cost 8 ms
-        a pass)."""
-        ps = self.page_size
-        ids = np.ascontiguousarray(np.asarray(tokens)[:npages * ps],
-                                   dtype=np.int32)
-        return [ids[i * ps:(i + 1) * ps].tobytes() for i in range(npages)]
-
-    def lookup(self, prompt):
-        """Walk the trie with the prompt's full pages; returns
-        ``(pages, matched_tokens)`` with one allocator ref acquired per
-        returned page (the caller owns them like alloc'd pages).  At
-        most ``(len(prompt) - 1) // page_size`` pages match, so the
-        suffix containing the last prompt token — whose logits seed
-        generation — is always prefilled privately.
-
-        Hit STATS are committed separately (``record_hit``) by the
-        engine once the request is actually admitted — a lookup whose
-        admission aborts on pool pressure releases its refs and must
-        not count as a served hit."""
-        if self.windows:
-            raise ValueError("a cache over several kinds of page is "
-                             "asked through lookup_all")
-        pages, matched, _, _ = self.lookup_all(prompt)
-        return pages[0], matched
-
-    def _first_read(self, blocks: int, window: int) -> int:
-        """The first block whose window-kind page the rows continuing a
-        hit of ``blocks`` blocks read: the one holding position ``P + 1
-        - window``."""
-        return max(0, blocks * self.page_size + 1 - window) // self.page_size
-
-    def lookup_all(self, prompt):
-        """``lookup`` for every kind of page and the recurrent state:
-        ``(pages, matched_tokens, snapshot entry or None, tokens matched
-        beyond it)`` with ``pages[0]`` the first kind's pages of ``[0,
-        matched)`` and ``pages[k]`` the k-th kind's pages of the blocks
-        from ``_first_read`` to the hit's last, a ref acquired on each.
-        The hit is the longest prefix of the walk whose window-kind
-        pages are all there.  In a cache with ``snaps`` it ends at the
-        deepest block of that prefix that holds a snapshot, a ref
-        acquired on the entry too: the caller gives it back once the
-        launch that reads it is committed.  Without, the last two are
-        ``None, 0``."""
-        self.lookups += 1
-        self._tick += 1
-        limit = max(0, (len(prompt) - 1) // self.page_size)
-        node = self.root
-        path: List[_TrieNode] = []
-        for key in self._chunks(prompt, limit):
-            child = node.children.get(key)
-            if child is None:
-                break
-            # freshen recency FIRST: the promote hook may itself demote
-            # under pool pressure and trim the host tier — the node
-            # being promoted must never be the LRU drop candidate
-            child.tick = self._tick
-            if child.host_kv is not None:
-                # host-tier hit: promote back into a device page before
-                # handing it out.  No capacity to promote into (even
-                # after the promote hook's own demotion attempt) ends
-                # the walk — the suffix simply prefills cold.
-                page = self.promote_fn(child.host_kv)
-                if page is None:
-                    break
-                child.page, child.host_kv = int(page), None
-                self.host_pages -= 1
-                self.promoted_pages += 1
-                self.host_hits += 1
-            self.alloc.acquire(child.page)
-            path.append(child)
-            node = child
-        # the longest prefix whose window-kind pages are whole: run[k]
-        # counts the blocks ending at the current one that hold kind k's
-        blocks, run = 0, [0] * len(self.windows)
-        for j, n in enumerate(path, 1):
-            run = [r + 1 if n.more[k] is not None else 0
-                   for k, r in enumerate(run)]
-            if all(r >= j - self._first_read(j, w)
-                   for r, (_, w) in zip(run, self.windows)):
-                blocks = j
-        snap, lost = None, 0
-        if self.snaps is not None:
-            whole = blocks
-            while blocks and path[blocks - 1].snap is None:
-                blocks -= 1
-            lost = (whole - blocks) * self.page_size
-            if blocks:
-                path[blocks - 1].snap_tick = self._tick
-                path[blocks - 1].snap_used = True
-                snap = self.snaps.acquire(path[blocks - 1].snap)
-        # what lies past a hit that shrank is handed back
-        self.alloc.release([n.page for n in path[blocks:]])
-        pages = [[n.page for n in path[:blocks]]]
-        for k, (alloc, w) in enumerate(self.windows):
-            pages.append([alloc.acquire(n.more[k]) for n in
-                          path[self._first_read(blocks, w):blocks]])
-        return pages, blocks * self.page_size, snap, lost
-
-    def probe(self, prompt) -> int:
-        """Matched FULL-PAGE tokens for ``prompt`` across BOTH tiers,
-        with no refs acquired and no stats/LRU mutation — the fleet
-        router's cross-replica reachability query (a host-tier page on
-        any replica makes that replica the preferred prefill target)."""
-        limit = max(0, (len(prompt) - 1) // self.page_size)
-        node = self.root
-        matched = 0
-        for key in self._chunks(prompt, limit):
-            child = node.children.get(key)
-            if child is None:
-                break
-            matched += self.page_size
-            node = child
-        return matched
-
-    def record_hit(self, matched_tokens: int) -> None:
-        if matched_tokens > 0:
-            self.hits += 1
-            self.hit_tokens += matched_tokens
-
-    def insert(self, prompt, pages, more=(), snaps=()) -> int:
-        """Commit a completed prefill's FULL prompt pages.  New nodes
-        acquire a trie reference on their page; existing nodes are left
-        untouched (a concurrent prefill of the same prefix keeps its
-        private copy, which simply frees when that request finishes).
-        ``more[k]`` is ``(first block, pages)``: the k-th further kind's
-        pages the slot still holds, from that block on; a block that
-        lacks its page of that kind, new or not, takes it.  ``snaps``
-        is ``(blocks, entry)`` a state snapshot the prefill took: the
-        block that ends there takes the entry over (the caller's
-        reference becomes the trie's) unless it has one, in which case,
-        or where no such block is committed, the entry is given back.
-        Returns the number of newly committed pages."""
-        self._tick += 1
-        n = min(len(prompt) // self.page_size, len(pages))
-        node = self.root
-        added = 0
-        at = {int(b): int(e) for b, e in snaps}
-        for i, key in enumerate(self._chunks(prompt, n)):
-            child = node.children.get(key)
-            if child is None:
-                child = _TrieNode(key, self.alloc.acquire(int(pages[i])),
-                                  node, len(self.windows))
-                node.children[key] = child
-                self.inserted_pages += 1
-                added += 1
-            for k, (first, held) in enumerate(more):
-                if child.more[k] is None and 0 <= i - first < len(held):
-                    child.more[k] = self.windows[k][0].acquire(
-                        int(held[i - first]))
-            child.tick = self._tick
-            if child.snap is None and i + 1 in at:
-                child.snap, child.snap_tick = at.pop(i + 1), self._tick
-                child.snap_used = False
-                self.snap_nodes[child.snap] = child
-                self.snapshots_taken += 1
-            node = child
-        if at:
-            self.snaps.release(at.values())
-        return added
-
-    def _nodes(self):
-        stack = list(self.root.children.values())
-        while stack:
-            n = stack.pop()
-            yield n
-            stack.extend(n.children.values())
-
-    def evict(self, pages_needed: int) -> int:
-        """LRU-evict refcount-0 leaves (trie-only pages) until
-        ``pages_needed`` pages were freed or nothing evictable is left.
-        Returns pages actually freed.
-
-        One traversal collects the evictable leaves into a tick-ordered
-        heap; a parent that becomes an evictable leaf when its last
-        child is freed is pushed then — O(nodes + m log m) for m freed
-        pages instead of re-walking the trie per page.  Ticks are
-        stable within the call (no lookup/insert runs concurrently).
-
-        With the host tier enabled this DEMOTES instead: LRU refcount-0
-        DEVICE pages (leaf or interior — demotion keeps the trie
-        structure) move to pinned host, freeing their pool pages; the
-        host tier's own overflow then drops LRU host LEAVES."""
-        if self.host_tier_pages > 0:
-            return self._demote_lru(pages_needed)
-        freed = 0
-        seq = 0                      # tie-break: heap never compares nodes
-        heap = []
-        for n in self._nodes():
-            if not n.children and self.alloc.refs[n.page] == 1:
-                heap.append((n.tick, seq, n))
-                seq += 1
-        heapq.heapify(heap)
-        while freed < pages_needed and heap:
-            _, _, victim = heapq.heappop(heap)
-            parent = victim.parent
-            del parent.children[victim.key]
-            self.alloc.release([victim.page])
-            self._drop_more(victim)
-            self.evicted_pages += 1
-            freed += 1
-            if (parent is not self.root and not parent.children
-                    and self.alloc.refs[parent.page] == 1):
-                heap_entry = (parent.tick, seq, parent)
-                seq += 1
-                heapq.heappush(heap, heap_entry)
-        return freed
-
-    def _drop_more(self, node: _TrieNode) -> None:
-        """Give back a block's pages of the further kinds and its state
-        snapshot (the block itself is going)."""
-        for k, (alloc, _) in enumerate(self.windows):
-            if node.more[k] is not None:
-                alloc.release([node.more[k]])
-                node.more[k] = None
-        if node.snap is not None:
-            self._drop_snapshot(node)
-
-    def _drop_snapshot(self, node: _TrieNode) -> None:
-        del self.snap_nodes[node.snap]
-        self.snaps.release([node.snap])
-        node.snap = None
-        self.evicted_snapshots += 1
-
-    def evict_snapshots(self, needed: int, used: bool = True) -> int:
-        """Give back up to ``needed`` snapshot entries that only the trie
-        holds: those nobody ever restored from first (the snapshots a
-        prompt leaves inside its own suffix), then the least recently
-        restored-from (the blocks stay, with their pages: a later hit is
-        served as far as the deepest snapshot above them).  A snapshot
-        that sessions come back to so outlives a burst of prompts that
-        each leave a few nobody will ask for: losing the one at the end
-        of a 64k-token history costs every turn of that session 128
-        chunk steps until one of them has taken it again.  ``used``
-        False: only those nobody restored from (what a snapshot INSIDE a
-        prompt may displace: it is a bet that someone comes back to that
-        point, and does not outbid one that sessions have come back to).
-        Returns entries freed."""
-        found = sorted((n for e, n in self.snap_nodes.items()
-                        if self.snaps.refs[e] == 1
-                        and (used or not n.snap_used)),
-                       key=lambda n: (n.snap_used, n.snap_tick, n.snap))
-        for n in found[:max(needed, 0)]:
-            self._drop_snapshot(n)
-        return min(len(found), max(needed, 0))
-
-    def evict_window(self, k: int, pages_needed: int) -> int:
-        """Evict up to ``pages_needed`` pages of the k-th further kind
-        that only the trie holds, from ANY block (the block stays, with
-        its first-kind page).  First those no possible hit can need: a
-        hit that ends at block j reads the pages of the blocks from
-        ``_first_read(j)`` to j, so a page is of use only while some
-        block at or below it, as far as a window reaches, still has its
-        whole run; then the least recently used.  Returns pages freed."""
-        alloc, w = self.windows[k]
-        # (useful, tick, n, node) of every candidate; a node's run is the
-        # count of blocks ending at it that hold the kind's page
-        found = []
-        stack = [(c, 1, 0) for c in self.root.children.values()]
-        order = []
-        while stack:
-            n, depth, run = stack.pop()
-            run = run + 1 if n.more[k] is not None else 0
-            whole = run >= depth - self._first_read(depth, w)
-            order.append((n, depth, whole))
-            stack.extend((c, depth + 1, run) for c in n.children.values())
-        # reach[n]: blocks down to the nearest block at or below n whose
-        # run is whole (children before parents: the walk's reverse)
-        reach: Dict[int, int] = {}
-        far = 1 << 30
-        for n, depth, whole in reversed(order):
-            r = 0 if whole else min(
-                (reach[id(c)] + 1 for c in n.children.values()), default=far)
-            reach[id(n)] = r
-            if n.more[k] is not None and alloc.refs[n.more[k]] == 1:
-                # of use to the hit that ends r blocks further down, if
-                # that hit's run reaches back as far as this block
-                useful = r < far and \
-                    depth > self._first_read(depth + r, w)
-                found.append((useful, n.tick, len(found), n))
-        found.sort(key=lambda f: f[:3])
-        freed = 0
-        for _, _, _, n in found[:pages_needed]:
-            alloc.release([n.more[k]])
-            n.more[k] = None
-            self.evicted_window_pages += 1
-            freed += 1
-        return freed
-
-    def _demote_lru(self, pages_needed: int) -> int:
-        """Tiered pressure relief: demote up to ``pages_needed`` LRU
-        refcount-0 device pages to the host tier (their pool pages
-        free), then trim the host tier back under its cap by dropping
-        LRU host LEAVES.  Returns device pages freed."""
-        freed = 0
-        seq = 0
-        heap = []
-        for n in self._nodes():
-            if n.host_kv is None and self.alloc.refs[n.page] == 1:
-                heap.append((n.tick, seq, n))
-                seq += 1
-        heapq.heapify(heap)
-        while freed < pages_needed and heap:
-            _, _, victim = heapq.heappop(heap)
-            victim.host_kv = self.demote_fn(victim.page)
-            victim.page = None
-            self.host_pages += 1
-            self.demoted_pages += 1
-            freed += 1
-        # host-tier overflow: drop LRU host LEAVES, one traversal + a
-        # heap (the evict() shape) — a parent that becomes a droppable
-        # host leaf is pushed as its child goes.  tick == _tick marks
-        # the lookup path currently being promoted (recency set before
-        # the promote hook runs) — never a drop candidate.
-        if self.host_pages > self.host_tier_pages:
-            trim = []
-            for n in self._nodes():
-                if (n.host_kv is not None and not n.children
-                        and n.tick < self._tick):
-                    trim.append((n.tick, seq, n))
-                    seq += 1
-            heapq.heapify(trim)
-            while self.host_pages > self.host_tier_pages and trim:
-                _, _, drop = heapq.heappop(trim)
-                parent = drop.parent
-                del parent.children[drop.key]
-                self.host_pages -= 1
-                self.evicted_pages += 1
-                if (parent is not self.root and not parent.children
-                        and parent.host_kv is not None
-                        and parent.tick < self._tick):
-                    heapq.heappush(trim, (parent.tick, seq, parent))
-                    seq += 1
-        return freed
-
-    def clear(self) -> None:
-        """Drop every trie reference (engine teardown); host-tier
-        payloads (no allocator ref) just drop."""
-        for n in list(self._nodes()):
-            if n.host_kv is None:
-                self.alloc.release([n.page])
-            self._drop_more(n)
-        self.root = _TrieNode()
-        self.host_pages = 0
-        assert not self.snap_nodes
-
-    def assert_consistent(self) -> None:
-        """The checked trie/tier contract (hammer + teardown): every
-        node lives in EXACTLY one tier (device page XOR host payload),
-        device pages are unique across the trie with a live allocator
-        refcount (the trie's own reference), and the ``host_pages``
-        counter matches the actual host-tier node count."""
-        seen_device: Dict[int, int] = {}
-        host_nodes = 0
-        for n in self._nodes():
-            has_page = n.page is not None
-            has_host = n.host_kv is not None
-            if has_page == has_host:
-                raise AssertionError(
-                    f"trie node {n.key!r} in "
-                    f"{'both tiers' if has_page else 'no tier'} — "
-                    f"page={n.page!r} host_kv set={has_host}")
-            if has_host:
-                host_nodes += 1
-                continue
-            if n.page in seen_device:
-                raise AssertionError(
-                    f"device page {n.page} held by two trie nodes "
-                    f"({seen_device[n.page]!r} and {n.key!r})")
-            seen_device[n.page] = n.key
-            if self.alloc.refs[n.page] <= 0:
-                raise AssertionError(
-                    f"trie node {n.key!r} holds dead page {n.page} "
-                    f"(refcount {self.alloc.refs[n.page]})")
-        if host_nodes != self.host_pages:
-            raise AssertionError(
-                f"host-tier counter drift: counter={self.host_pages} "
-                f"actual={host_nodes}")
-        for k, (alloc, _) in enumerate(self.windows):
-            held = [n.more[k] for n in self._nodes()
-                    if n.more[k] is not None]
-            if len(set(held)) != len(held):
-                raise AssertionError(
-                    f"a page of kind {k + 1} held by two trie nodes")
-            dead = [p for p in held if alloc.refs[p] <= 0]
-            if dead:
-                raise AssertionError(
-                    f"trie nodes hold dead pages {dead} of kind {k + 1}")
-        held = [n.snap for n in self._nodes() if n.snap is not None]
-        if len(set(held)) != len(held):
-            raise AssertionError("a state snapshot held by two trie nodes")
-        if {e: id(n) for e, n in self.snap_nodes.items()} != {
-                n.snap: id(n) for n in self._nodes() if n.snap is not None}:
-            raise AssertionError("snap_nodes is not the trie's snapshots")
-        dead = [e for e in held if self.snaps.refs[e] <= 0]
-        if dead:
-            raise AssertionError(f"trie nodes hold dead snapshots {dead}")
-
-    @property
-    def cached_pages(self) -> int:
-        return sum(1 for n in self._nodes() if n.host_kv is None)
-
-    @property
-    def snapshots_live(self) -> int:
-        return len(self.snap_nodes)
-
-    def stats(self) -> Dict[str, int]:
-        state = {} if self.snaps is None else {
-            "snapshots_live": self.snapshots_live,
-            "snapshots_taken": self.snapshots_taken,
-            "snapshots_evicted": self.evicted_snapshots}
-        return {"lookups": self.lookups, "hits": self.hits,
-                "hit_tokens": self.hit_tokens,
-                "cached_pages": self.cached_pages,
-                "inserted_pages": self.inserted_pages,
-                "evicted_pages": self.evicted_pages,
-                "evicted_window_pages": self.evicted_window_pages,
-                "host_pages": self.host_pages,
-                "host_hits": self.host_hits,
-                "demoted_pages": self.demoted_pages,
-                "promoted_pages": self.promoted_pages, **state}
-
-
-class _KindPages:
-    """One KIND of page (``PageKind``) as the host holds it: the pool's
-    size and its trash page (the last), the allocator, the table
-    ``[slots, pages_per_seq]`` and the pages each slot holds a reference
-    on, ``held[slot]``, in the order of the blocks they stand for from
-    block ``lo[slot]`` on.
-
-    A kind that retains every position (``window`` None) reserves a
-    slot's whole context at admission: ``lo`` stays 0.  A kind that
-    retains a window maps a block when a launch first writes into it
-    (``ContinuousBatchingEngine._map_pages``) and gives a block back
-    once no row to come can read it (``_recycle``); what it reserves at
-    admission is a CLAIM of at most ``bound`` pages, and the sum of the
-    live slots' claims never passes the pool.  Then a slot in need of a
-    page finds one: the pages no slot holds are at least the claims not
-    yet taken up, and each is free or held by the prefix cache alone,
-    which gives a window kind's page up on demand."""
-
-    def __init__(self, kind: PageKind, num_pages: int, max_slots: int,
-                 pages_per_seq: int, bound: Optional[int]):
-        self.kind = kind
-        self.window = kind.window
-        self.num_pages = int(num_pages)
-        self.trash = self.num_pages - 1
-        self.alloc = PageAllocator(self.num_pages - 1)
-        self.tables = np.full((max_slots, pages_per_seq), -1, np.int32)
-        self.held: Dict[int, List[int]] = {}
-        self.lo: Dict[int, int] = {}
-        self.bound = bound
-        self.claim: Dict[int, int] = {}
-        self.recycled = 0       # pages given back since the last marker
-
-    def claim_of(self, need: int) -> int:
-        """Pages a request of ``need`` blocks reserves of this kind."""
-        return need if self.bound is None else min(need, self.bound)
-
-    def release(self, slot: int) -> None:
-        self.alloc.release(self.held.pop(slot))
-        self.lo.pop(slot, None)
-        self.claim.pop(slot, None)
-        self.tables[slot] = -1
 
 
 class ContinuousBatchingEngine:
@@ -1240,8 +190,7 @@ class ContinuousBatchingEngine:
         self.params = params
         self.cfg_id = register_config(cfg)
         _, self.cos_tab, self.sin_tab = _CFGS[self.cfg_id]
-        self.layout = (cfg.paged_layout() if hasattr(cfg, "paged_layout")
-                       else kv_layout(cfg))
+        self.layout = cfg.paged_layout()
         self.max_slots = int(max_slots)
         self.max_seq_len = int(max_seq_len or cfg.max_position_embeddings)
         self.page_size = int(page_size)
@@ -1332,8 +281,6 @@ class ContinuousBatchingEngine:
         # PER-LAYER pools: a layer's cache write is one direct scatter
         # into its own pool (a fused [L, ...] slab would cost a slice +
         # whole-layer dynamic-update per layer per step)
-        # (whatever the layout's two pools hold, they go by k_pages and
-        # v_pages here: for "latent", latent rows and index keys)
         # (a layer of no kind has no pools: ``k_pages`` goes by the
         # layers that have pages, in their order)
         kind_of = {i: kp for kp in self.pages for i in kp.kind.layers}
@@ -1393,11 +340,6 @@ class ContinuousBatchingEngine:
         self.queue: deque[Request] = deque()
         self._next_rid = 0
         self.finished: List[Finished] = []
-        # step report (reference seq_lens_encoder/decoder/this_time
-        # semantics: encoder = prompt tokens prefilled this step,
-        # decoder = cached tokens of decoding slots, this_time = tokens
-        # processed this step)
-        self.last_report: Dict[str, np.ndarray] = {}
         # the launch that is enqueued and not yet read (``_step_unified``
         # runs one step ahead), and the newest COMMITTED launch's
         # gathered rows and logits, still on the device (``last_logits``)
@@ -1502,7 +444,7 @@ class ContinuousBatchingEngine:
             # same tables) so the one page table serves both models;
             # shared prefix pages are therefore shared for the draft
             # too (the donor's draft prefill wrote them)
-            dlayout = kv_layout(dcfg)
+            dlayout = dcfg.paged_layout()
             dka, dvb = dlayout.pool_shapes(self.num_pages, self.page_size)
             self.draft = {
                 "cfg": dcfg, "params": draft_params, "cfg_id": did,
@@ -1512,32 +454,6 @@ class ContinuousBatchingEngine:
             }
 
     # ---------------- device programs ----------------
-
-    @partial(jax.jit, static_argnames=("self_cfg_id", "bucket"))
-    def _calibration_prefill_jit(params, ids, cos_tab, sin_tab, self_cfg_id,
-                                 bucket):
-        """Dense causal forward of ONE prompt padded to ``bucket``, for
-        the int8 cache's scale calibration alone
-        (``_calibrate_int8_unified``): returns the per-layer K and V
-        ``[L, bucket, kvh, d]`` as the model computes them, unquantized.
-        Nothing is written to the pools."""
-        from ..models.generation import _CFGS, _Weights, _block
-
-        cfg, _, _ = _CFGS[self_cfg_id]
-        w = _Weights(cfg, params)
-        x = w.embed(ids[None])
-        pos = jnp.arange(bucket)
-        cos = jnp.take(cos_tab, pos, axis=0)[None, :, None, :].astype(x.dtype)
-        sin = jnp.take(sin_tab, pos, axis=0)[None, :, None, :].astype(x.dtype)
-        # causal, so the padding behind the prompt changes no real row
-        causal = jnp.where(jnp.tril(jnp.ones((bucket, bucket), bool)),
-                           0.0, -jnp.inf)
-        ks, vs = [], []
-        for i in range(cfg.num_hidden_layers):
-            x, k, v = _block(w, i, x, cos, sin, causal)
-            ks.append(k[0])
-            vs.append(v[0])
-        return jnp.stack(ks), jnp.stack(vs)
 
     @partial(jax.jit, donate_argnums=(0, 1))
     def _set_page_jit(k_pages, v_pages, k, v, page):
@@ -1603,173 +519,6 @@ class ContinuousBatchingEngine:
             self.k_pages, self.v_pages, place_on_device(k),
             place_on_device(v), jnp.asarray(p, jnp.int32))
         return p
-
-    @partial(jax.jit, static_argnames=("self_cfg_id", "pages_per_step",
-                                       "with_head"),
-             donate_argnums=(1, 2))
-    def _unified_step_jit(params, k_pages, v_pages, rows, tables,
-                          cos_tab, sin_tab, self_cfg_id, pages_per_step,
-                          kv_scales=None, with_head=True, gather=None,
-                          prev_tokens=None):
-        """ONE ragged engine step: a packed batch of tokens from many
-        sequences — decode slots (one row each), prefill chunks (one row
-        per prompt token) and speculative verify windows (k+1 rows) —
-        through a single forward, with attention served by the ragged
-        paged kernel (per-row page-table indirection + causal
-        visibility).  This is the unified prefill/decode formulation of
-        the Ragged Paged Attention paper: decode latency is bounded by
-        the launch, not by any co-scheduled prompt's length.
-
-        ``rows`` is the packed host schedule, ONE int32 [T, 5] upload
-        per launch (T a rung of the engine's ladder, ``step_ladder``;
-        everything here is sized by ``rows.shape[0]``): columns (input token, physical page to write
-        this token's K/V, in-page offset, causal visibility = absolute
-        position + 1, page-table row / slot).  Padding rows carry
-        slot -1 / visibility 0 and scatter into the trash page.
-        ``tables`` is a tuple of [slots, pages_per_seq] tables, one a
-        kind of page (``page_kinds``; most configs have one kind), that
-        feed the kernel's scalar-prefetch index maps.  Where window and
-        full layers mix, ``rows`` has a column more for each
-        further kind (the page the row writes there), a layer takes its
-        kind's, a window layer's kernel attends the last ``window``
-        positions, and ``cos_tab`` / ``sin_tab`` are dicts by
-        ``layer_types`` entry from which a layer takes its own.  An input token below zero is a
-        reference into ``prev_tokens``, the int32 ``[gather_cap]`` that
-        the launch before this one sampled (``resolve_row_tokens``): the
-        engine enqueues a greedy step before it has read the step
-        before.  Returns the updated (donated) page pools and
-        ``(logits, tokens)``: fp32 logits of the gathered rows and
-        their first maxima (``sample_greedy``).  The tokens are all a
-        greedy step copies back; the logits stay on the device unless
-        the host has to draw from them (a request with a temperature)
-        or something asks for ``last_logits``."""
-        from ..models.generation import (_CFGS, _Weights, _apply_rope,
-                                         _ffn, _rms_norm)
-        from ..ops.pallas.decode_attention import ragged_paged_decode_raw
-
-        cfg, _, _ = _CFGS[self_cfg_id]
-        w = _Weights(cfg, params)
-        L = cfg.num_hidden_layers
-        h, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                     cfg.head_dim)
-        T = rows.shape[0]
-        # a layer's kind of page: its table, the column of the page a
-        # row writes, its window (one kind: the table, column 1, none)
-        kinds = page_kinds(cfg)
-        kind_of = {i: k for k, kind in enumerate(kinds) for i in kind.layers}
-        # the scopes are ``profiler.device_trace.DEVICE_SCOPES``: the
-        # SAME names in every layer, so that a layer's parts add up
-        # across layers in the device's time by scope; scopes are
-        # metadata and change nothing that is compiled
-        with jax.named_scope("embed"):
-            tok = rows[:, 0]
-            if prev_tokens is not None:
-                tok = resolve_row_tokens(tok, prev_tokens)
-            phys = rows[:, 1]
-            off = rows[:, 2]
-            lens = rows[:, 3]
-            slot = rows[:, 4]
-            phys_of = [phys] + [rows[:, 4 + k] for k in range(1, len(tables))]
-            x = w.embed(tok)                          # [T, hidden]
-            pos = jnp.maximum(lens - 1, 0)
-
-            def rope_rows(tab):
-                return jnp.take(tab, pos, axis=0)[:, None, :].astype(x.dtype)
-
-            # rope tables by kind of layer where the config has them
-            cos, sin = jax.tree.map(rope_rows, (cos_tab, sin_tab))
-            stats = None
-            if _counts_experts(cfg):
-                stats = {"valid": slot >= 0,
-                         **{c: [] for c in MOE_DEVICE_COUNTS[:4]}}
-        new_k, new_v = list(k_pages), list(v_pages)
-        rep_ = h // kvh
-        for i in range(L):
-            ki = kind_of.get(i, 0)
-            phys = phys_of[ki]
-            lcos, lsin = cos, sin
-            if isinstance(cos, dict):
-                lcos, lsin = (cos[cfg.layer_types[i]],
-                              sin[cfg.layer_types[i]])
-            with jax.named_scope("attn_qkv"):
-                xin = _rms_norm(x, w.layer(i, "input_layernorm.weight"),
-                                cfg.rms_norm_eps)
-                q = (xin @ w.layer(i, "self_attn.q_proj.weight")
-                     ).reshape(T, h, d)
-                k = (xin @ w.layer(i, "self_attn.k_proj.weight")
-                     ).reshape(T, kvh, d)
-                v = (xin @ w.layer(i, "self_attn.v_proj.weight")
-                     ).reshape(T, kvh, d)
-                q, k = _apply_rope(q, k, lcos, lsin)
-            with jax.named_scope("kv_scatter"):
-                kw_, vw_, qd = k, v, q
-                if new_k[i].dtype == jnp.int8:
-                    kw_ = _round_int8(kw_.astype(jnp.float32)
-                                      * kv_scales["kq"][i][None, :, None])
-                    vw_ = _round_int8(vw_.astype(jnp.float32)
-                                      * kv_scales["vq"][i][None, :, None])
-                    kdq = jnp.repeat(kv_scales["kdq"][i], rep_)
-                    qd = (qd.astype(jnp.float32)
-                          * kdq[None, :, None]).astype(q.dtype)
-                # scatter ALL rows' K/V first (a chunk row must see its
-                # in-chunk predecessors), then one ragged kernel launch.
-                # The index carries the head: rows of d land in place, no
-                # whole-pool relayout (PERF.md section 6, PR 25)
-                kp = _write_kv_rows(new_k[i], phys, off, kw_)
-                vp = _write_kv_rows(new_v[i], phys, off, vw_)
-                new_k[i], new_v[i] = kp, vp
-            with jax.named_scope("paged_attn"):
-                ctx = ragged_paged_decode_raw(
-                    qd, kp, vp, lens, slot, tables[ki], scale=d ** -0.5,
-                    pages_per_step=pages_per_step,
-                    window=kinds[ki].window if kinds else None)
-                if kp.dtype == jnp.int8:
-                    vdq = jnp.repeat(kv_scales["vdq"][i], rep_)
-                    ctx = ctx.astype(jnp.float32) * vdq[None, :, None]
-            with jax.named_scope("attn_out"):
-                x = x + (ctx.reshape(T, h * d).astype(x.dtype)
-                         @ w.layer(i, "self_attn.o_proj.weight"))
-            with jax.named_scope("mlp"):
-                xm = _rms_norm(x, w.layer(i, "post_attention_layernorm"
-                                             ".weight"), cfg.rms_norm_eps)
-                # round-18 sparse serving: the shared FFN entry routes
-                # MoE layers through top-k expert gather-then-dequant
-                # (the int8 _Weights expert view), dense layers through
-                # SwiGLU — the unified ragged step serves sparse
-                # checkpoints unchanged
-                x = x + _ffn(w, i, xm, stats)
-        if not with_head:
-            # draft cache-mirror launches only need the K/V scatter side
-            # effect: skip the [T, hidden] x [hidden, vocab] head matmul
-            # and the fp32 logits allocation entirely
-            return tuple(new_k), tuple(new_v), None
-        with jax.named_scope("lm_head"):
-            if gather is not None:
-                # device-side gather of the CONSUMED rows (every verify-
-                # window row + each prefill chunk's final row) BEFORE the
-                # final norm/vocab projection: the head matmul, the fp32
-                # logits buffer and the device->host copy shrink from
-                # rows_cap to gather_cap — a prefill chunk's intermediate
-                # rows exist only for their K/V scatter and never produce
-                # (or transfer) logits
-                x = jnp.take(x, gather, axis=0)
-            x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
-            logits = w.head(x).astype(jnp.float32)    # [G, vocab]
-        with jax.named_scope("sample"):
-            out = (logits, sample_greedy(logits))
-            if stats is not None:
-                # MOE_DEVICE_COUNTS, over this step's expert layers
-                zero = jnp.zeros((), jnp.int32)
-                hit = stats["moe_experts_hit"]
-                out = (*out, jnp.stack([
-                    sum(stats["moe_rows_routed"], zero),
-                    sum(stats["moe_rows_held"], zero),
-                    jnp.max(jnp.stack(stats["moe_expert_rows_max"]
-                                      or [zero])),
-                    sum(hit, zero),
-                    zero + len(hit) * int(cfg.num_experts)]
-                ).astype(jnp.int32))
-        return tuple(new_k), tuple(new_v), out
 
     # ---------------- host scheduler ----------------
 
@@ -2090,9 +839,9 @@ class ContinuousBatchingEngine:
         bucket = max(16, 1 << (s - 1).bit_length())
         ids = np.zeros(bucket, np.int32)
         ids[:s] = prompt
-        ks, vs = ContinuousBatchingEngine._calibration_prefill_jit(
+        ks, vs = self.cfg.calibration_prefill(
             self.params, jnp.asarray(ids), self.cos_tab, self.sin_tab,
-            self_cfg_id=self.cfg_id, bucket=bucket)
+            self.cfg_id, bucket)
         kabs = jnp.max(jnp.abs(ks[:, :s].astype(jnp.float32)),
                        axis=(1, 3)) * 2.0 + 1e-6          # [L, kvh]
         vabs = jnp.max(jnp.abs(vs[:, :s].astype(jnp.float32)),
@@ -2534,11 +1283,6 @@ class ContinuousBatchingEngine:
                     # where it is (PERF.md section 6, PRs 24 and 36).
                     # The tables are COPIED: a commit changes them while
                     # this launch may not have taken them yet
-                    # (a layout with a recurrent state takes its pools
-                    # as ``state=`` and returns them fourth: the SAME
-                    # program reads a slot's entry, a snapshot's or
-                    # zeros, as the rows say; one with further pools a
-                    # page takes them as ``pools=`` and returns them last)
                     for l_rows, l_gather, l_prev in launches:
                         self.k_pages, self.v_pages, new.out, *more = \
                             self._programs[len(l_rows)](
@@ -2568,7 +1312,6 @@ class ContinuousBatchingEngine:
                 queued.append(new)
             cur = queued[0] if queued else None
             self._flight = queued[1] if len(queued) > 1 else None
-            this_dec = np.zeros(self.max_slots, np.int32)
             n_finished = len(self.finished)
             produced = 0
             if cur is not None:
@@ -2588,15 +1331,9 @@ class ContinuousBatchingEngine:
                         logits = np.asarray(cur.out[0])
                 self._last_logits = (cur.gathered, *cur.out)
                 with RecordEvent("serving.commit"):
-                    produced = self._commit_unified(cur, tokens, logits,
-                                                    this_dec)
+                    produced = self._commit_unified(cur, tokens, logits)
             else:
                 cur = idle
-            self.last_report = {
-                "seq_lens_encoder": cur.enc,
-                "seq_lens_decoder": cur.dec,
-                "seq_lens_this_time": cur.enc + this_dec,
-            }
             counts = cur.counts
             windows = self.more_pages
             if windows:
@@ -2698,7 +1435,7 @@ class ContinuousBatchingEngine:
                 for n in self.ladder]
 
     def _pack_unified(self, decode, prefill, props: Dict[int, tuple]):
-        """The packed row schedule of one launch (``_unified_step_jit``'s
+        """The packed row schedule of one launch (``PagedLayout.step``'s
         ``rows`` and ``gather``) for what ``_schedule`` found, and the
         ``_Launch`` that commits it: what each gathered row is, the
         commit loop's ``metas``, the prompt tokens scheduled by slot and
@@ -2706,8 +1443,6 @@ class ContinuousBatchingEngine:
         rows of the smallest rung of ``ladder`` that holds what was
         packed (its ``rows_cap`` count); ``gather`` is ``[gather_cap]``
         whatever the rung."""
-        enc = np.zeros(self.max_slots, np.int32)
-        dec = np.zeros(self.max_slots, np.int32)
         rows = self._padding_rows(self.rows_cap)
         stateful = bool(self.layout.state)
         sc = 4 + len(self.pages)        # the first of the state columns
@@ -2736,7 +1471,6 @@ class ContinuousBatchingEngine:
             if stateful:
                 rows[r - len(window):r, sc:sc + 2] = (self.state_src[s], s)
                 self.state_src[s] = s
-            dec[s] = base
             kv_ctx += base + len(window)
             metas.append(("verify", s, gstart, len(window)))
         decode_rows = r
@@ -2762,8 +1496,6 @@ class ContinuousBatchingEngine:
                     snaps[s] = ((base + chunk) // self.page_size, entry)
                     rows[r - 1, sc + 2] = self.max_slots + entry
             left -= chunk
-            enc[s] = chunk
-            dec[s] = base
             kv_ctx += base + chunk
             # only the chunk's FINAL row can seed generation — it is
             # the one prefill row the gather hands to the host
@@ -2795,8 +1527,8 @@ class ContinuousBatchingEngine:
         for k, kp in enumerate(self.more_pages, 5):
             rows[:r, k] = kp.tables[rows[:r, 4],
                                     (rows[:r, 3] - 1) // self.page_size]
-        return rows[:rung], gather, _Launch(metas, gathered, counts, enc,
-                                            dec, props, snaps=snaps)
+        return rows[:rung], gather, _Launch(metas, gathered, counts, props,
+                                            snaps=snaps)
 
     def _state_chunk(self, base: int, chunk: int, pending: int) -> int:
         """A prefill chunk of a sequence with a recurrent state, cut so
@@ -2850,19 +1582,16 @@ class ContinuousBatchingEngine:
         return own.pop(0)[1] if own else None
 
     def _commit_unified(self, launch: _Launch, tokens: np.ndarray,
-                        logits: Optional[np.ndarray],
-                        this_dec: np.ndarray) -> int:
+                        logits: Optional[np.ndarray]) -> int:
         """Commit every slot of ``launch`` that is still scheduled from
         the tokens the device sampled (``logits`` too where a request
-        draws from them); returns the tokens produced and fills
-        ``this_dec`` (the tokens each slot emitted)."""
+        draws from them); returns the tokens produced."""
         produced = 0
         for kind, s, gstart, n in launch.metas:
             rid = int(self.slot_rid[s])
             if kind == "verify":
                 take = self._commit_window(s, gstart, n, tokens, logits,
                                            launch.props.get(s))
-                this_dec[s] = len(take)
                 produced += len(take)
                 continue
             # prefill chunk: commit the scattered prompt K/V
@@ -2925,7 +1654,6 @@ class ContinuousBatchingEngine:
             self.cur_tok[s] = tok
             self.out_tokens[rid] = [tok]
             self.budget[s] = req.max_new_tokens - 1
-            this_dec[s] += 1
             produced += 1
             if tok == self.eos_id or self.budget[s] <= 0:
                 self._finish(s)

@@ -32,10 +32,10 @@ The rotated pairs are the two HALVES of the rotary dimensions
 (``generation._rotate_half``'s layout); the published code interleaves
 them, which permutes the columns of weights that are random here.
 
-The engine (``inference/serving.ContinuousBatchingEngine``) serves this
-through its one ``step()``: ``DeepseekV32Config.paged_layout()`` gives it
-the two pools' row shapes and ``unified_step_jit``, this model's part of
-the unified step.
+The engine (``ContinuousBatchingEngine``) serves this through its one
+``step()``: ``DeepseekV32Config.paged_layout()`` gives it the two pools'
+row shapes and ``unified_step_jit``, this model's part of the unified
+step, under the contract of ``inference/paged_layout.PagedLayout``.
 """
 
 from __future__ import annotations
@@ -48,6 +48,11 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+
+from ..inference.paged_layout import (PagedLayout, gathered_logits,
+                                      ragged_kv_tokens_read, row_columns,
+                                      sample_greedy)
+from .generation import _CFGS, _Weights, _ffn, _rms_norm, _rotate_half
 
 __all__ = ["DeepseekV32Config", "unified_step_jit"]
 
@@ -169,8 +174,6 @@ class DeepseekV32Config:
             beta_fast=rs["beta_fast"], beta_slow=rs["beta_slow"])
 
     def paged_layout(self):
-        from ..inference.serving import PagedLayout
-
         return PagedLayout(
             name="latent", rows=((self.latent_row,), (self.index_head_dim,)),
             head_major=False, step=unified_step_jit,
@@ -268,7 +271,6 @@ def _row_counts(cfg, rows: np.ndarray, ctx_tokens: int, page_size: int,
     pages a turn: an engine given another counts as if it had not).
     One function counts for both families: a turn of the walk here is
     what a page is to the ragged kernel's."""
-    from ..inference.serving import ragged_kv_tokens_read
     from ..ops.pallas.sparse_mla import walk_geometry
 
     vis = rows[:, 3]
@@ -282,8 +284,6 @@ def _row_counts(cfg, rows: np.ndarray, ctx_tokens: int, page_size: int,
 
 
 def _rope(x, cos, sin):
-    from .generation import _rotate_half
-
     return x * cos + _rotate_half(x) * sin
 
 
@@ -309,7 +309,6 @@ def attention_part(cfg, w, i, x, lat_pool, idx_pool, phys, off, lens, slot,
     from ..ops.pallas.sparse_mla import (lightning_index_scores_raw,
                                          select_top_k,
                                          sparse_mla_attention_raw)
-    from .generation import _rms_norm
 
     T = x.shape[0]
     H, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
@@ -371,30 +370,22 @@ def unified_step_jit(params, lat_pages, idx_pages, rows, tables, cos_tab,
                      with_head=True, gather=None, prev_tokens=None,
                      debug_select=False):
     """This model's part of the engine's ONE ragged step, under
-    ``ContinuousBatchingEngine._unified_step_jit``'s signature and row
-    schedule (its docstring): ``lat_pages`` / ``idx_pages`` are the
-    per-layer latent and index-key pools ``[pages, page, numbers]`` in
-    the engine's ``k_pages`` / ``v_pages`` places, donated and written
-    in place.  Input tokens below zero are references into
-    ``prev_tokens`` and the gathered rows are sampled on the device, as
-    there (``serving.resolve_row_tokens``, ``serving.sample_greedy``).
-    The third result is ``(logits [G, vocab] fp32, tokens [G] int32,
-    counts)``, ``counts`` the int32 ``DEVICE_COUNTS`` of this step's
-    expert layers (sums over layers; the fullest expert's rows, the
-    maximum).  With ``debug_select`` (tests) it is ``(logits, tokens,
+    ``PagedLayout.step``'s contract (``inference/paged_layout.py``).
+    Its own: ``lat_pages`` / ``idx_pages`` are the per-layer latent and
+    index-key pools ``[pages, page, numbers]`` in the engine's
+    ``k_pages`` / ``v_pages`` places; the counts after the tokens are
+    the int32 ``DEVICE_COUNTS`` of this step's expert layers (sums over
+    layers; the fullest expert's rows, the maximum).  With
+    ``debug_select`` (tests) the third result is ``(logits, tokens,
     counts, [the selection as a boolean [T, W], a layer])``."""
-    from ..inference.serving import resolve_row_tokens, sample_greedy
     from ..ops.pallas.sparse_mla import selected_mask
-    from .generation import _CFGS, _Weights, _ffn, _rms_norm
 
     cfg, _, _ = _CFGS[self_cfg_id]
     w = _Weights(cfg, params)
     (tables,) = tables                  # a table a kind of page: it has one
     # the scopes are ``profiler.device_trace.DEVICE_SCOPES``
     with jax.named_scope("embed"):
-        tok, phys, off, lens, slot = (rows[:, c] for c in range(5))
-        if prev_tokens is not None:
-            tok = resolve_row_tokens(tok, prev_tokens)
+        tok, phys, off, lens, slot = row_columns(rows, prev_tokens)
         lens = jnp.where(slot < 0, 0, lens)
         x = w.embed(tok)
         pos = jnp.maximum(lens - 1, 0)
@@ -415,12 +406,11 @@ def unified_step_jit(params, lat_pages, idx_pages, rows, tables, cos_tab,
             x = x + _ffn(w, i, xm, stats)
     if not with_head:
         return tuple(new_lat), tuple(new_idx), None
-    with jax.named_scope("lm_head"):
-        if gather is not None:
-            x = jnp.take(x, gather, axis=0)
-        x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
-        logits = w.head(x).astype(jnp.float32)
+    logits = gathered_logits(
+        x, gather, lambda y: _rms_norm(y, w["model.norm.weight"],
+                                       cfg.rms_norm_eps), w.head)
     with jax.named_scope("sample"):
+        # (its own three counts, in the order its runner reads them)
         zero = jnp.zeros((), jnp.int32)
         counts = jnp.stack([
             sum(stats["moe_rows_held"], zero),

@@ -502,6 +502,20 @@ def _moe_ffn(w: _Weights, i, xm, stats=None):
     return y.reshape(shape)
 
 
+def _moe_device_counts(stats, experts: int):
+    """int32 ``[5]``: ``inference.paged_layout.MOE_DEVICE_COUNTS`` over
+    a step's expert layers, from the ``stats`` they filled
+    (``_moe_experts``): sums over the layers, the fullest expert's rows
+    their maximum, ``experts`` a layer the experts there are."""
+    zero = jnp.zeros((), jnp.int32)
+    hit = stats["moe_experts_hit"]
+    return jnp.stack([
+        sum(stats["moe_rows_routed"], zero),
+        sum(stats["moe_rows_held"], zero),
+        jnp.max(jnp.stack(stats["moe_expert_rows_max"] or [zero])),
+        sum(hit, zero), zero + len(hit) * experts]).astype(jnp.int32)
+
+
 def _ffn(w: _Weights, i, xm, stats=None):
     """Layer ``i``'s FFN on the ``_Weights`` view: dense SwiGLU, or —
     when the checkpoint carries this layer's stacked expert weights —
@@ -806,7 +820,7 @@ def generate(model, input_ids, max_new_tokens: int = 32,
             "generate() attends every layer's whole context and takes one "
             "rope table a config: a model that mixes window and full "
             "layers is served by inference.ContinuousBatchingEngine")
-    if hasattr(cfg, "paged_layout") and cfg.paged_layout().state:
+    if cfg.paged_layout().state:
         raise NotImplementedError(
             "generate() carries a K/V cache and nothing else from token to "
             "token: a model whose layers keep a recurrent state is served "

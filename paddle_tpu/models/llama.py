@@ -77,6 +77,20 @@ class LlamaConfig:
         from ..parallel.roofline import llama_cost_sheet
         return llama_cost_sheet(self)
 
+    def paged_layout(self):
+        """What the serving engine learns of this model: K and V pages
+        and the family's step (``llama_paged.kv_layout``)."""
+        from .llama_paged import kv_layout
+        return kv_layout(self)
+
+    def calibration_prefill(self, params, ids, cos_tab, sin_tab, cfg_id,
+                            bucket):
+        """The dense forward an int8 K/V cache's scales are calibrated
+        from (``llama_paged.calibration_prefill_jit``)."""
+        from .llama_paged import calibration_prefill_jit
+        return calibration_prefill_jit(params, ids, cos_tab, sin_tab,
+                                       self_cfg_id=cfg_id, bucket=bucket)
+
     @staticmethod
     def llama3_8b() -> "LlamaConfig":
         return LlamaConfig(vocab_size=128256, hidden_size=4096,

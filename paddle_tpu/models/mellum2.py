@@ -22,10 +22,11 @@ it stands (``moe_scoring = "softmax"``).
 
 There is no step of this model's own.  ``Mellum2Config`` is a
 ``LlamaConfig`` with the published keys that class lacks, and the
-engine's one step (``serving.ContinuousBatchingEngine._unified_step_jit``)
-picks a layer's rope table, page table and window by ``layer_types[i]``;
-``serving.kv_layout`` gives such a config two KINDS of page, so a window
-layer holds ``sliding_window`` positions of a context and not all of it.
+family's step (``llama_paged.unified_step_jit``) picks a layer's rope
+table, page table and window by ``layer_types[i]``; the layout it
+inherits (``llama_paged.kv_layout``) gives such a config two KINDS of
+page, so a window layer holds ``sliding_window`` positions of a context
+and not all of it.
 """
 
 from __future__ import annotations

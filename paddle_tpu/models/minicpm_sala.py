@@ -30,18 +30,16 @@ E[token]``; ``h += (scale_depth / sqrt(L)) Mixer_l(RMSNorm(h))``; ``h +=
   and attends those alone (``ops/pallas/block_sparse_attention.py``);
   ``W_o (sigmoid(W_gate u) * concat o)``.
 
-The engine (``inference/serving.ContinuousBatchingEngine``) serves this
-through its one ``step()``: ``paged_layout()`` says which layers have
-pages (the ``minicpm4`` layers, one kind), that a page has a THIRD pool
-there beside K and V (the compressed keys, ``PagedLayout.more_pools``),
-what a slot's recurrent state is a ``lightning-attn`` layer
+The engine (``ContinuousBatchingEngine``) serves this through its one
+``step()``: ``paged_layout()`` says which layers have pages (the
+``minicpm4`` layers, one kind), that a page has a THIRD pool there
+beside K and V (the compressed keys, ``PagedLayout.more_pools``), what
+a slot's recurrent state is a ``lightning-attn`` layer
 (``PagedLayout.state``), how many packed rows are whole tiles of every
-kernel of the step (``PagedLayout.tile_rows``: the engine compiles the
-step at a ladder of such row counts and a launch takes the smallest
-that holds its rows) and gives ``unified_step_jit``, this model's
-part of the unified step, whose packed rows are Nemotron-H's: the five
-columns every model has, then the state entry the row's slot starts
-from, the entry its state is left in and the entry a snapshot goes to.
+kernel of the step (``PagedLayout.tile_rows``) and gives
+``unified_step_jit``, this model's part of the unified step, under the
+contract of ``inference/paged_layout.PagedLayout`` (the rows' state
+columns too).
 """
 
 from __future__ import annotations
@@ -56,6 +54,17 @@ import jax
 import jax.numpy as jnp
 
 from ..core.device import pallas_interpret
+from ..inference.paged_layout import (SNAPSHOTS_A_STEP, PagedLayout, PageKind,
+                                      _write_kv_rows, copy_snapshots,
+                                      gathered_logits, row_columns,
+                                      sample_greedy, snapshot_plan)
+from ..ops.pallas import block_sparse_attention as bsa
+from ..ops.pallas.decode_attention import (default_pages_per_step,
+                                           ragged_paged_decode_raw,
+                                           ragged_tile_rows)
+from ..ops.pallas.ssd_scan import (mamba2_ssd_scan, ssd_max_units,
+                                   ssd_scan_reference)
+from .generation import _CFGS, _Weights, _ffn, _rms_norm, _rotate_half
 
 __all__ = ["MiniCPMSALAConfig", "unified_step_jit", "lightning_part",
            "sparse_attention_part", "lightning_decay"]
@@ -64,8 +73,6 @@ _S, _L = "minicpm4", "lightning-attn"
 _MIXERS = ((_S,) + (_L,) * 8 + (_S,) + (_L,) * 6 + (_S, _S) + (_L,) * 4
            + (_S,) + (_L,) * 6 + (_S,) * 3)
 
-#: state snapshots a step may take (``PagedLayout.state_snapshots_a_step``)
-SNAPSHOTS_A_STEP = 2
 #: packed rows a tile of the scan (the rows are padded to whole tiles)
 SCAN_TILE_ROWS = 128
 #: heads a grid step of the scan holds, with a key and a query each
@@ -193,11 +200,6 @@ class MiniCPMSALAConfig:
         return z, z
 
     def paged_layout(self):
-        from ..inference.serving import PagedLayout, PageKind
-        from ..ops.pallas import block_sparse_attention as bsa
-        from ..ops.pallas.decode_attention import (default_pages_per_step,
-                                                   ragged_tile_rows)
-
         c = self
         kvh, d = c.num_key_value_heads, c.head_dim
         H, dl = c.lightning_nh, c.lightning_head_dim
@@ -298,8 +300,6 @@ def _rotary(x, pos, theta: float):
     """Float32 ``x`` ``[T, H, d]`` rotated by its row's position (the
     whole head, halves paired: ``generation._apply_rope``'s layout, the
     angles computed here)."""
-    from .generation import _rotate_half
-
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
@@ -309,8 +309,6 @@ def _rotary(x, pos, theta: float):
 
 
 def _mlp(cfg, w, l, x):
-    from .generation import _ffn, _rms_norm
-
     with jax.named_scope("mlp"):
         u = _rms_norm(x, w.layer(l, "post_attention_layernorm.weight"),
                       cfg.rms_norm_eps)
@@ -322,10 +320,6 @@ def lightning_part(cfg, w, l, x, pool, slot, lens, src, dst, max_slots: int):
     ``[T, hidden]``: each slot's rows start from state entry ``src``
     (below zero: zeros) and leave the state in entry ``dst``.  Returns
     ``(x + mixer, pool)``."""
-    from ..ops.pallas.ssd_scan import (mamba2_ssd_scan, ssd_max_units,
-                                       ssd_scan_reference)
-    from .generation import _rms_norm
-
     T = x.shape[0]
     H, d = cfg.lightning_nh, cfg.lightning_head_dim
     at = "self_attn."
@@ -385,11 +379,6 @@ def sparse_attention_part(cfg, w, l, x, k_pool, v_pool, c_pool, phys, off,
     context is at most ``dense_len`` and the selection and block-sparse
     attention for the others.  Returns ``(x + mixer, k pool, v pool,
     compressed keys' pool, the rows' selections [T, kvh, topk])``."""
-    from ..inference.serving import _write_kv_rows
-    from ..ops.pallas import block_sparse_attention as bsa
-    from ..ops.pallas.decode_attention import ragged_paged_decode_raw
-    from .generation import _rms_norm
-
     T = x.shape[0]
     h, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                  cfg.head_dim)
@@ -454,21 +443,16 @@ def unified_step_jit(params, k_pages, v_pages, rows, tables, cos_tab,
                      with_head=True, gather=None, prev_tokens=None,
                      state=None, pools=None):
     """This model's part of the engine's ONE ragged step, under
-    ``ContinuousBatchingEngine._unified_step_jit``'s signature and row
-    schedule (its docstring).  ``k_pages`` / ``v_pages`` are the pools
-    of the ``minicpm4`` layers alone, in their order, ``pools`` ``(the
-    compressed keys' pools,)`` of the same layers; ``state`` is ``(S
-    pools,)``, one ``[entries, H, d, d]`` pool a ``lightning-attn``
-    layer, the LAST entry the trash entry; all donated and written in
-    place.  ``rows`` ``[rows_cap, 8]`` as Nemotron-H's.  Returns ``(k
-    pools, v pools, (logits, tokens, selections), state, pools)``;
-    ``selections`` ``[gathered rows, minicpm4 layers, kvh, topk]``: the
-    blocks each gathered row selected (``engine.last_extras``; a check
-    holds the attention to them where bf16 and float32 order near-tied
-    blocks differently)."""
-    from ..inference.serving import resolve_row_tokens, sample_greedy
-    from .generation import _CFGS, _Weights, _rms_norm
-
+    ``PagedLayout.step``'s contract (``inference/paged_layout.py``).
+    Its own: ``k_pages`` / ``v_pages`` are the pools of the ``minicpm4``
+    layers alone, in their order, ``pools`` ``(the compressed keys'
+    pools,)`` of the same layers; ``state`` is ``(S pools,)``, one
+    ``[entries, H, d, d]`` pool a ``lightning-attn`` layer; ``rows``
+    ``[T, 8]``.  Returns ``(k pools, v pools, (logits, tokens,
+    selections), state, pools)``; ``selections`` ``[gathered rows,
+    minicpm4 layers, kvh, topk]``: the blocks each gathered row selected
+    (``engine.last_extras``; a check holds the attention to them where
+    bf16 and float32 order near-tied blocks differently)."""
     cfg, _, _ = _CFGS[self_cfg_id]
     w = _Weights(cfg, params)
     (table,) = tables
@@ -476,21 +460,12 @@ def unified_step_jit(params, k_pages, v_pages, rows, tables, cos_tab,
     new_c, pool = list(pools[0]), list(state[0])
     # the scopes are ``profiler.device_trace.DEVICE_SCOPES``
     with jax.named_scope("embed"):
-        tok, phys, off, lens, slot, src, dst, snap = (rows[:, c]
-                                                      for c in range(8))
-        if prev_tokens is not None:
-            tok = resolve_row_tokens(tok, prev_tokens)
+        tok, phys, off, lens, slot, src, dst, snap = row_columns(
+            rows, prev_tokens)
         lens = jnp.where(slot < 0, 0, lens)
         x = w.embed(tok)
         x = (x.astype(jnp.float32) * cfg.scale_emb).astype(x.dtype)
-    with jax.named_scope("state_snapshot"):
-        # the snapshots this step takes: (the slot's entry, the
-        # snapshot's), trash to trash where there are fewer
-        trash = pool[0].shape[0] - 1
-        (at,) = jnp.nonzero(snap >= 0, size=SNAPSHOTS_A_STEP, fill_value=0)
-        taken = snap[at] >= 0
-        snap_from = jnp.where(taken, dst[at], trash)
-        snap_to = jnp.where(taken, snap[at], trash)
+    snaps = snapshot_plan(snap, dst, pool[0].shape[0] - 1)
     n_attn = n_state = 0
     sels = []
     for l in cfg.layers:
@@ -498,9 +473,7 @@ def unified_step_jit(params, k_pages, v_pages, rows, tables, cos_tab,
             x, pool[n_state] = lightning_part(
                 cfg, w, l, x, pool[n_state], slot, lens, src, dst,
                 table.shape[0])
-            with jax.named_scope("state_snapshot"):
-                pool[n_state] = pool[n_state].at[snap_to].set(
-                    pool[n_state][snap_from])
+            pool[n_state] = copy_snapshots(pool[n_state], snaps)
             n_state += 1
         else:
             x, new_k[n_attn], new_v[n_attn], new_c[n_attn], sel = \
@@ -514,15 +487,14 @@ def unified_step_jit(params, k_pages, v_pages, rows, tables, cos_tab,
     state, pools = (tuple(pool),), (tuple(new_c),)
     if not with_head:
         return tuple(new_k), tuple(new_v), None, state, pools
+    def final_norm(y):
+        y = _rms_norm(y, w["model.norm.weight"], cfg.rms_norm_eps)
+        return (y.astype(jnp.float32)
+                / (cfg.hidden_size / cfg.dim_model_base)).astype(y.dtype)
+
     with jax.named_scope("lm_head"):
         sels = jnp.stack(sels, axis=1)
-        if gather is not None:
-            x = jnp.take(x, gather, axis=0)
-            sels = jnp.take(sels, gather, axis=0)
-        x = _rms_norm(x, w["model.norm.weight"], cfg.rms_norm_eps)
-        x = (x.astype(jnp.float32)
-             / (cfg.hidden_size / cfg.dim_model_base)).astype(x.dtype)
-        logits = w.head(x).astype(jnp.float32)
+    logits, sels = gathered_logits(x, gather, final_norm, w.head, (sels,))
     with jax.named_scope("sample"):
         out = (logits, sample_greedy(logits), sels)
     return tuple(new_k), tuple(new_v), out, state, pools

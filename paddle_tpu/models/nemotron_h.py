@@ -28,18 +28,14 @@ Source: https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16
   projections, a shared expert at the full hidden width, and
   ``experts_held``.
 
-The engine (``inference/serving.ContinuousBatchingEngine``) serves this
-through its one ``step()``: ``paged_layout()`` says which layers have
-pages (the ``*`` layers, one kind), what a slot's recurrent state is a
-state layer (``PagedLayout.state``), how many packed rows are whole
-tiles of every kernel of the step (``PagedLayout.tile_rows``: the engine
-compiles the step at a ladder of such row counts and a launch takes the
-smallest that holds its rows) and gives ``unified_step_jit``, this
-model's part of the unified step.  A packed row carries, after the
-columns every model's rows have, the state entry its slot starts from
-(below zero: zeros), the entry the state is left in, and, on a slot's
-last row, the entry a SNAPSHOT of that state is copied to (below zero:
-none).  The multi-token-prediction module is not loaded.
+The engine (``ContinuousBatchingEngine``) serves this through its one
+``step()``: ``paged_layout()`` says which layers have pages (the ``*``
+layers, one kind), what a slot's recurrent state is a state layer
+(``PagedLayout.state``), how many packed rows are whole tiles of every
+kernel of the step (``PagedLayout.tile_rows``) and gives
+``unified_step_jit``, this model's part of the unified step, under the
+contract of ``inference/paged_layout.PagedLayout`` (the rows' state
+columns too).  The multi-token-prediction module is not loaded.
 """
 
 from __future__ import annotations
@@ -54,14 +50,25 @@ import jax
 import jax.numpy as jnp
 
 from ..core.device import pallas_interpret
+from ..inference.paged_layout import (MOE_DEVICE_COUNTS, SNAPSHOTS_A_STEP,
+                                      PagedLayout, PageKind, copy_snapshots,
+                                      gathered_logits, ragged_kv_tokens_read,
+                                      row_columns, sample_greedy,
+                                      snapshot_plan)
+from ..ops.pallas.causal_conv import (packed_causal_conv,
+                                      packed_causal_conv_reference)
+from ..ops.pallas.decode_attention import (default_pages_per_step,
+                                           ragged_tile_rows)
+from ..ops.pallas.ssd_scan import (mamba2_ssd_scan, ssd_max_units,
+                                   ssd_scan_reference)
+from .generation import (_CFGS, _Weights, _ffn, _moe_device_counts,
+                         _rms_norm)
+from .llama_paged import gqa_paged_attention
 
 __all__ = ["NemotronHConfig", "unified_step_jit"]
 
 _PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
             "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
-
-#: state snapshots a step may take (``PagedLayout.state_snapshots_a_step``)
-SNAPSHOTS_A_STEP = 2
 
 #: whole tiles of the step's kernels in the least rows the step is
 #: compiled at.  At ONE tile (128 rows at the published widths) the
@@ -195,11 +202,6 @@ class NemotronHConfig:
         return z, z
 
     def paged_layout(self):
-        from ..inference.serving import (MOE_DEVICE_COUNTS, PagedLayout,
-                                         PageKind, ragged_kv_tokens_read)
-        from ..ops.pallas.decode_attention import (default_pages_per_step,
-                                                   ragged_tile_rows)
-
         c = self
         kvh, d = c.num_key_value_heads, c.head_dim
         attn_tile = ragged_tile_rows(c.num_attention_heads, kvh, d)
@@ -290,12 +292,6 @@ def mamba_part(cfg, w, i, x, ssm_pool, conv_pool, slot, lens, src, dst,
     zero: zeros) and leave the state in entry ``dst``, in both pools;
     ``max_slots`` bounds the slots the rows can name (the scan's units
     of work).  Returns ``(x + mixer, ssm pool, conv pool)``."""
-    from ..ops.pallas.causal_conv import (packed_causal_conv,
-                                          packed_causal_conv_reference)
-    from ..ops.pallas.ssd_scan import (mamba2_ssd_scan, ssd_max_units,
-                                       ssd_scan_reference)
-    from .generation import _rms_norm
-
     T = x.shape[0]
     H, P, G, N = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
                   cfg.ssm_state_size)
@@ -340,37 +336,6 @@ def mamba_part(cfg, w, i, x, ssm_pool, conv_pool, slot, lens, src, dst,
     return x, ssm_pool, conv_pool
 
 
-def attention_part(cfg, w, i, x, k_pool, v_pool, phys, off, lens, slot,
-                   table, pages_per_step: int):
-    """Layer ``i``'s attention on the packed rows: K and V rows written
-    at (``phys``, ``off``), then the ragged paged kernel.  No rotary
-    embedding."""
-    from ..inference.serving import _write_kv_rows
-    from ..ops.pallas.decode_attention import ragged_paged_decode_raw
-    from .generation import _rms_norm
-
-    T = x.shape[0]
-    h, kvh, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                 cfg.head_dim)
-    at = "self_attn."
-    with jax.named_scope("attn_qkv"):
-        xin = _rms_norm(x, w.layer(i, "norm.weight"), cfg.norm_eps)
-        q = (xin @ w.layer(i, at + "q_proj.weight")).reshape(T, h, d)
-        k = (xin @ w.layer(i, at + "k_proj.weight")).reshape(T, kvh, d)
-        v = (xin @ w.layer(i, at + "v_proj.weight")).reshape(T, kvh, d)
-    with jax.named_scope("kv_scatter"):
-        k_pool = _write_kv_rows(k_pool, phys, off, k)
-        v_pool = _write_kv_rows(v_pool, phys, off, v)
-    with jax.named_scope("paged_attn"):
-        ctx = ragged_paged_decode_raw(q, k_pool, v_pool, lens, slot, table,
-                                      scale=d ** -0.5,
-                                      pages_per_step=pages_per_step)
-    with jax.named_scope("attn_out"):
-        x = x + (ctx.reshape(T, h * d).astype(x.dtype)
-                 @ w.layer(i, at + "o_proj.weight"))
-    return x, k_pool, v_pool
-
-
 @partial(jax.jit, static_argnames=("self_cfg_id", "pages_per_step",
                                    "with_head"),
          donate_argnames=("k_pages", "v_pages", "state"))
@@ -379,20 +344,14 @@ def unified_step_jit(params, k_pages, v_pages, rows, tables, cos_tab,
                      with_head=True, gather=None, prev_tokens=None,
                      state=None):
     """This model's part of the engine's ONE ragged step, under
-    ``ContinuousBatchingEngine._unified_step_jit``'s signature and row
-    schedule (its docstring).  ``k_pages`` / ``v_pages`` are the pools
-    of the ``*`` layers alone, in their order; ``state`` is ``(ssm
-    pools, conv pools)``, one ``[entries, ...]`` pool a ``M`` layer
-    each, donated and written in place; the LAST entry is the trash
-    entry.  ``rows`` ``[rows_cap, 8]``: the five columns every model
-    has, then the state entry the row's slot starts from, the entry its
-    state is left in and the entry a snapshot of it goes to (on a slot's
-    last row; below zero: none).  Returns ``(k pools, v pools, (logits,
-    tokens, MOE_DEVICE_COUNTS), state)``."""
-    from ..inference.serving import (MOE_DEVICE_COUNTS, resolve_row_tokens,
-                                     sample_greedy)
-    from .generation import _CFGS, _Weights, _ffn, _rms_norm
-
+    ``PagedLayout.step``'s contract (``inference/paged_layout.py``).
+    Its own: ``k_pages`` / ``v_pages`` are the pools of the ``*`` layers
+    alone, in their order (their attention is the Llama family's,
+    ``llama_paged.gqa_paged_attention``, with no rotary embedding: the
+    Mamba layers carry position); ``state`` is ``(ssm pools, conv
+    pools)``, one ``[entries, ...]`` pool a ``M`` layer each; ``rows``
+    ``[T, 8]``.  Returns ``(k pools, v pools, (logits, tokens,
+    MOE_DEVICE_COUNTS), state)``."""
     cfg, _, _ = _CFGS[self_cfg_id]
     w = _Weights(cfg, params)
     (table,) = tables
@@ -400,38 +359,27 @@ def unified_step_jit(params, k_pages, v_pages, rows, tables, cos_tab,
     ssm, conv = (list(p) for p in state)
     # the scopes are ``profiler.device_trace.DEVICE_SCOPES``
     with jax.named_scope("embed"):
-        tok, phys, off, lens, slot, src, dst, snap = (rows[:, c]
-                                                      for c in range(8))
-        if prev_tokens is not None:
-            tok = resolve_row_tokens(tok, prev_tokens)
+        tok, phys, off, lens, slot, src, dst, snap = row_columns(
+            rows, prev_tokens)
         lens = jnp.where(slot < 0, 0, lens)
         x = w.embed(tok)
         stats = {"valid": slot >= 0,
                  **{c: [] for c in MOE_DEVICE_COUNTS[:4]}}
-    with jax.named_scope("state_snapshot"):
-        # the snapshots this step takes: (the slot's entry, the
-        # snapshot's), trash to trash where there are fewer
-        trash = ssm[0].shape[0] - 1
-        (at,) = jnp.nonzero(snap >= 0, size=SNAPSHOTS_A_STEP, fill_value=0)
-        taken = snap[at] >= 0
-        snap_from = jnp.where(taken, dst[at], trash)
-        snap_to = jnp.where(taken, snap[at], trash)
+    snaps = snapshot_plan(snap, dst, ssm[0].shape[0] - 1)
     n_attn = n_state = 0
     for i, letter in enumerate(cfg.pattern):
         if letter == "M":
             x, ssm[n_state], conv[n_state] = mamba_part(
                 cfg, w, i, x, ssm[n_state], conv[n_state], slot, lens, src,
                 dst, table.shape[0])
-            with jax.named_scope("state_snapshot"):
-                ssm[n_state] = ssm[n_state].at[snap_to].set(
-                    ssm[n_state][snap_from])
-                conv[n_state] = conv[n_state].at[snap_to].set(
-                    conv[n_state][snap_from])
+            ssm[n_state] = copy_snapshots(ssm[n_state], snaps)
+            conv[n_state] = copy_snapshots(conv[n_state], snaps)
             n_state += 1
         elif letter == "*":
-            x, new_k[n_attn], new_v[n_attn] = attention_part(
+            x, new_k[n_attn], new_v[n_attn] = gqa_paged_attention(
                 cfg, w, i, x, new_k[n_attn], new_v[n_attn], phys, off, lens,
-                slot, table, pages_per_step)
+                slot, table, pages_per_step, norm="norm.weight",
+                eps=cfg.norm_eps)
             n_attn += 1
         else:
             with jax.named_scope("mlp"):
@@ -440,19 +388,11 @@ def unified_step_jit(params, k_pages, v_pages, rows, tables, cos_tab,
     state = (tuple(ssm), tuple(conv))
     if not with_head:
         return tuple(new_k), tuple(new_v), None, state
-    with jax.named_scope("lm_head"):
-        if gather is not None:
-            x = jnp.take(x, gather, axis=0)
-        x = _rms_norm(x, w["model.norm.weight"], cfg.norm_eps)
-        logits = w.head(x).astype(jnp.float32)
+    logits = gathered_logits(
+        x, gather, lambda y: _rms_norm(y, w["model.norm.weight"],
+                                       cfg.norm_eps), w.head)
     with jax.named_scope("sample"):
-        zero = jnp.zeros((), jnp.int32)
-        hit = stats["moe_experts_hit"]
         lo, hi = cfg.experts_held or (0, cfg.n_routed_experts)
-        counts = jnp.stack([
-            sum(stats["moe_rows_routed"], zero),
-            sum(stats["moe_rows_held"], zero),
-            jnp.max(jnp.stack(stats["moe_expert_rows_max"] or [zero])),
-            sum(hit, zero), zero + len(hit) * (hi - lo)]).astype(jnp.int32)
+        counts = _moe_device_counts(stats, hi - lo)
         out = (logits, sample_greedy(logits), counts)
     return tuple(new_k), tuple(new_v), out, state

@@ -55,7 +55,7 @@ Three pieces:
 
 The serving half (top-k expert routing in the unified ragged step,
 gather-then-dequant int8 expert weights) lives in
-``models/generation.py`` / ``inference/serving.py``.
+``models/generation.py`` / ``models/llama_paged.py``.
 """
 
 from __future__ import annotations

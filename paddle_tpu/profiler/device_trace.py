@@ -35,9 +35,10 @@ import struct
 from typing import (Any, Dict, Iterable, Iterator, List, NamedTuple, Optional,
                     Sequence, Tuple)
 
-# every scope a serving step opens (``inference/serving.py``,
-# ``models/generation.py``, ``models/deepseek_v32.py``,
-# ``models/nemotron_h.py``, ``models/minicpm_sala.py``);
+# every scope a serving step opens (``inference/paged_layout.py``,
+# ``models/generation.py``, ``models/llama_paged.py``,
+# ``models/deepseek_v32.py``, ``models/nemotron_h.py``,
+# ``models/minicpm_sala.py``);
 # tests/test_device_scopes.py holds each
 # compiled step's working instructions to it
 DEVICE_SCOPES = (

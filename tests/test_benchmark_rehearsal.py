@@ -17,9 +17,9 @@ def test_serve_is_not_correct_when_a_committed_token_is_altered(
 
     real = E._commit_unified
 
-    def altered(self, launch, tokens, logits, this_dec):
+    def altered(self, launch, tokens, logits):
         bad = (np.asarray(tokens) + 1) % self.cfg.vocab_size
-        return real(self, launch, bad, logits, this_dec)
+        return real(self, launch, bad, logits)
 
     monkeypatch.setattr(E, "_commit_unified", altered)
     line = _run(tiny_root, "tiny-serve.chat")

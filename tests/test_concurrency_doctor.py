@@ -272,7 +272,7 @@ def test_watchdog_hammer_pins_single_writer_transition():
 
 
 def test_page_allocator_assert_consistent_positive_and_violations():
-    from paddle_tpu.inference.serving import PageAllocator
+    from paddle_tpu.inference.page_cache import PageAllocator
 
     alloc = PageAllocator(4)
     p = alloc.alloc()
@@ -302,7 +302,7 @@ def test_page_allocator_assert_consistent_positive_and_violations():
 
 
 def test_prefix_cache_assert_consistent():
-    from paddle_tpu.inference.serving import PageAllocator, PrefixCache
+    from paddle_tpu.inference.page_cache import PageAllocator, PrefixCache
 
     alloc = PageAllocator(8)
     cache = PrefixCache(page_size=2, alloc=alloc)
@@ -330,7 +330,7 @@ def test_assert_consistent_under_hammer_mid_flight():
     """The contract is callable DURING the storm, not just after: a
     checker thread asserts consistency concurrently with mutators."""
     from paddle_tpu.analysis.lock_sanitizer import run_threaded
-    from paddle_tpu.inference.serving import PageAllocator
+    from paddle_tpu.inference.page_cache import PageAllocator
 
     alloc = PageAllocator(8)
 

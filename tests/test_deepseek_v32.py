@@ -548,7 +548,7 @@ def test_the_units_of_a_packed_step_and_what_their_walk_fetches():
     a padding row none; a unit fetches its slot as far as its largest
     visibility in whole turns, and the layout's ``attn_kv_tokens_read``
     is their sum at the layer's tile (8) and the layout's turn."""
-    from paddle_tpu.inference.serving import ragged_kv_tokens_read
+    from paddle_tpu.inference.paged_layout import ragged_kv_tokens_read
     from paddle_tpu.ops.pallas.decode_attention import ragged_units
 
     c = _chunks_case()

@@ -441,9 +441,9 @@ def test_the_steps_open_no_scope_outside_the_set():
     """Every ``jax.named_scope`` of the serving steps' files is in
     ``DEVICE_SCOPES``, and every scope of the set is opened somewhere."""
     opened = set()
-    for rel in ("inference/serving.py", "models/generation.py",
-                "models/deepseek_v32.py", "models/nemotron_h.py",
-                "models/minicpm_sala.py"):
+    for rel in ("inference/paged_layout.py", "models/generation.py",
+                "models/llama_paged.py", "models/deepseek_v32.py",
+                "models/nemotron_h.py", "models/minicpm_sala.py"):
         opened |= set(re.findall(r'jax\.named_scope\("(\w+)"\)',
                                  (ROOT / "paddle_tpu" / rel).read_text()))
     assert opened == set(dt.DEVICE_SCOPES)

@@ -13,10 +13,10 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import mellum2_ref as ref
-from paddle_tpu.inference.serving import (ContinuousBatchingEngine,
-                                          PageAllocator, PrefixCache,
-                                          kv_layout, page_kinds)
+from paddle_tpu.inference.page_cache import PageAllocator, PrefixCache
+from paddle_tpu.inference.serving import ContinuousBatchingEngine
 from paddle_tpu.models import generation
+from paddle_tpu.models.llama_paged import kv_layout, page_kinds
 from paddle_tpu.models.mellum2 import FULL, SLIDING, Mellum2Config
 
 PAGE, BUDGET, SLOTS, SEQ, VOCAB = 4, 6, 3, 64, 96

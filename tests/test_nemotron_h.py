@@ -13,8 +13,8 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import nemotron_h_ref as ref
-from paddle_tpu.inference.serving import (STATE_COUNTS,
-                                          ContinuousBatchingEngine)
+from paddle_tpu.inference.paged_layout import STATE_COUNTS
+from paddle_tpu.inference.serving import ContinuousBatchingEngine
 from paddle_tpu.models import generation
 from paddle_tpu.models.nemotron_h import NemotronHConfig
 from paddle_tpu.ops.pallas.causal_conv import (packed_causal_conv,

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.inference.serving import ragged_kv_tokens_read
+from paddle_tpu.inference.paged_layout import ragged_kv_tokens_read
 from paddle_tpu.ops.pallas.decode_attention import (
     ragged_paged_decode_raw, ragged_tile_rows, ragged_units)
 

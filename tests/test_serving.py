@@ -8,11 +8,12 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from paddle_tpu.inference.serving import (ContinuousBatchingEngine,
-                                          PageAllocator, PrefixCache,
-                                          kv_layout, ragged_kv_tokens_read)
+from paddle_tpu.inference.page_cache import PageAllocator
+from paddle_tpu.inference.paged_layout import ragged_kv_tokens_read
+from paddle_tpu.inference.serving import ContinuousBatchingEngine
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.generation import generate, self_draft_params
+from paddle_tpu.models.llama_paged import kv_layout, unified_step_jit
 
 
 @pytest.fixture(scope="module")
@@ -166,7 +167,7 @@ def test_engine_has_one_step_path(tiny_model):
     assert not hasattr(eng, "unified")
     fn, *_ = eng.analysis_entry()
     assert fn is eng.layout.step is kv_layout(cfg).step \
-        is ContinuousBatchingEngine._unified_step_jit
+        is cfg.paged_layout().step is unified_step_jit
     # left unset, the pages a turn are the layout's rule; an int overrides
     assert eng.pages_per_step == eng.layout.pages_per_step(
         eng.page_size, eng.pages_per_seq, 4) == 8
@@ -374,16 +375,18 @@ def test_chunked_prefill_decode_latency_bound(tiny_model):
     prefill_steps = 0
     while eng.active[0]:
         before = len(eng.out_tokens[0])
+        rows = eng.serving_stats()["steps"]["prefill_rows"]
         eng.step()
-        rep = eng.last_report
+        # the prompt rows of the launch this call committed
+        chunk = eng.serving_stats()["steps"]["prefill_rows"] - rows
         if eng.active[0] or int(eng.slot_rid[0]) != 0:
             after = len(eng.out_tokens[0]) if 0 in eng.out_tokens else 21
         else:
             after = 21                  # finished this step: it emitted
         assert after > before, \
             "decode slot starved by a co-scheduled long prompt"
-        assert rep["seq_lens_encoder"].sum() <= 16   # chunk bound
-        if rep["seq_lens_encoder"].sum() > 0:
+        assert chunk <= 16                           # chunk bound
+        if chunk > 0:
             prefill_steps += 1
     assert prefill_steps >= 4           # 60 tokens / 16-token chunks
     done = sorted(eng.run(), key=lambda f: f.rid)
@@ -405,9 +408,10 @@ def test_chunked_prefill_splits_across_requests(tiny_model):
     eng.add_request(rng.integers(1, cfg.vocab_size, (30,))
                     .astype(np.int32), max_new_tokens=4)
     eng.step()
-    rep = eng.last_report
-    assert (rep["seq_lens_encoder"] > 0).sum() == 2   # both prefilled
-    assert rep["seq_lens_encoder"].sum() == 24        # budget exhausted
+    # the one launch this call committed held rows of both prompts
+    took = [st["prefilled"] for st in eng.prefill_stats.values()]
+    assert len(took) == 2 and min(took) > 0           # both prefilled
+    assert sum(took) == 24 == eng.serving_stats()["steps"]["prefill_rows"]
     done = eng.run()
     assert len(done) == 2
     eng.shutdown()
@@ -945,7 +949,7 @@ def test_write_kv_rows_equals_the_window_scatter(case, cache_dtype):
     scattered in place) against the spelling it replaced,
     ``pool.at[phys, :, off, :].set(x)``: the same values at the same
     addresses, bit for bit."""
-    from paddle_tpu.inference.serving import _write_kv_rows
+    from paddle_tpu.inference.paged_layout import _write_kv_rows
 
     pages, kvh, page, d = 7, 2, 16, 8
     phys, off = (jnp.asarray(a, jnp.int32) for a in _KV_WRITE_CASES[case])

@@ -1,5 +1,5 @@
 """The engine's ladder of step sizes: a launch takes the smallest
-compiled row count that holds what was packed (``serving.step_ladder``),
+compiled row count that holds what was packed (``paged_layout.step_ladder``),
 and nothing else about it changes.
 
 Toy widths whose kernels' row tile is 8 or 16, so that the ladders the
@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from paddle_tpu.inference.serving import step_ladder
+from paddle_tpu.inference.paged_layout import step_ladder
 from serving_ladder_toys import (  # noqa: F401 - compiles is a fixture
     LAYOUTS, _serve, check_a_ladder_serves_what_the_top_rung_serves,
     compiles)
@@ -52,9 +52,9 @@ def test_the_cells_layouts_state_the_tiles_of_the_rule():
     """The tiles the table above takes for the Llama family's two cells,
     for MiniCPM-SALA's and for Nemotron-H's are what their layouts state
     at the published widths."""
-    from paddle_tpu.inference.serving import kv_layout
     from paddle_tpu.models import LlamaConfig, minicpm_sala, nemotron_h
     from paddle_tpu.models.deepseek_v32 import DeepseekV32Config
+    from paddle_tpu.models.llama_paged import kv_layout
     from paddle_tpu.models.mellum2 import Mellum2Config
     from paddle_tpu.models.nemotron_h import NemotronHConfig
     from paddle_tpu.ops.pallas import block_sparse_attention as bsa

@@ -433,15 +433,16 @@ def test_the_jitted_step_is_called_from_the_step_itself(tiny, monkeypatch):
     3.2 s, and keeps the call where it is for every rung all the same)."""
     import sys
 
-    real = ContinuousBatchingEngine._unified_step_jit
+    from paddle_tpu.models import llama_paged
+
+    real = llama_paged.unified_step_jit
     callers = []
 
     def spy(*args, **kw):
         callers.append(sys._getframe(1).f_code.co_name)
         return real(*args, **kw)
 
-    monkeypatch.setattr(ContinuousBatchingEngine, "_unified_step_jit",
-                        staticmethod(spy))
+    monkeypatch.setattr(llama_paged, "unified_step_jit", spy)
     eng = _engine(*tiny)
     _serve(eng)
     # every rung of the ladder is launched once before the first launch
@@ -455,7 +456,9 @@ def _optimized_hlo(eng):
     """The optimized HLO of the engine's unified step with everything
     that names a source or a scope taken out: the tables of files and
     stack frames at its head and each instruction's ``metadata=``."""
-    raw = ContinuousBatchingEngine._unified_step_jit.__wrapped__
+    from paddle_tpu.models.llama_paged import unified_step_jit
+
+    raw = unified_step_jit.__wrapped__
     # a function of its own each time, or jit hands back the cached trace
     fn = jax.jit(lambda *a, **k: raw(*a, **k),
                  static_argnames=("self_cfg_id", "pages_per_step", "with_head"))

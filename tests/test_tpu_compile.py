@@ -878,7 +878,7 @@ def _rung_engine(layout, monkeypatch):
 def test_the_lowest_rung_of_each_layout_compiles(one_chip, monkeypatch,
                                                  layout):
     """The engine launches a step of decode rows alone at the LOWEST
-    rung of its ladder (``serving.step_ladder``): 32 rows in the Llama
+    rung of its ladder (``paged_layout.step_ladder``): 32 rows in the Llama
     family's two cells, where the capacity is 288 and 544, 128, one
     tile of the scan, in MiniCPM-SALA's, where it is 608, and 256, two
     tiles (``nemotron_h.STEP_TILES``), in Nemotron-H's, where it is
