@@ -81,10 +81,11 @@ _TILE_ROWS_MAX = 8
 _BLOCK_KEYS = 1024
 
 
-def sparse_tile_rows(heads: int, d_in: int, d_out: int = 0) -> int:
+def sparse_tile_rows(heads: int, d_in: int, d_out: int = 0,
+                     max_rows: int = _TILE_ROWS_MAX) -> int:
     """Packed rows a tile of a kernel whose row brings ``heads`` query
     heads of ``d_in`` numbers and takes ``d_out`` a head away: as many
-    as ``_TILE_ROWS_MAX``, halved while a row's share of the VMEM (its
+    as ``max_rows``, halved while a row's share of the VMEM (its
     heads are the matmul's rows) passes ``_TILE_VMEM``.  A layer gives
     both its kernels the attention's tile (128 heads of 640 in, 512
     out), so a step's units of work are the same in both and one count
@@ -92,7 +93,7 @@ def sparse_tile_rows(heads: int, d_in: int, d_out: int = 0) -> int:
     per_row = heads * (_BLOCK_KEYS * (4 + 4 + 2)      # scores, weights
                        + 2 * 2 * d_in + 2 * 2 * d_out  # q and out blocks
                        + 4 * d_out + 2 * 128 * 4)     # acc, m and l
-    rows = _TILE_ROWS_MAX
+    rows = max_rows
     while rows > 1 and rows * per_row > _TILE_VMEM:
         rows //= 2
     return rows
@@ -131,17 +132,23 @@ def _walk_tile(row0, slot_ref, cnt_ref, reach_ref, tab_ref, live_ref,
     # one past the launch's last live row: tiles past it have no work
     end = jnp.minimum(row0 + tile_rows, live_ref[0])
     n_rows = cnt_ref.shape[0]
+    # pools that share the page id (``dense_mla.py`` walks two), each
+    # with its own buffer and semaphores
+    if not isinstance(pool_hbm, tuple):
+        pool_hbm, buf, sem = (pool_hbm,), (buf,), (sem,)
 
     def start(slot, blk, half):
         for j in range(pp):
             phys = tab_ref[slot, jnp.minimum(blk * pp + j, max_pages - 1)]
-            pltpu.make_async_copy(pool_hbm.at[jnp.maximum(phys, 0)],
-                                  buf.at[half, j], sem.at[half, j]).start()
+            for pool, b, s in zip(pool_hbm, buf, sem):
+                pltpu.make_async_copy(pool.at[jnp.maximum(phys, 0)],
+                                      b.at[half, j], s.at[half, j]).start()
 
     def wait(half):
         for j in range(pp):
-            pltpu.make_async_copy(pool_hbm.at[0], buf.at[half, j],
-                                  sem.at[half, j]).wait()
+            for pool, b, s in zip(pool_hbm, buf, sem):
+                pltpu.make_async_copy(pool.at[0], b.at[half, j],
+                                      s.at[half, j]).wait()
 
     def walk(lo, n, slot, nblk, nxt, has_next, half, i0, rows):
         def body(blk, half):

@@ -64,7 +64,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .decode_attention import ragged_units
 
 __all__ = ["SSD_SCAN_KERNEL", "mamba2_ssd_scan", "ssd_scan_reference",
-           "ssd_max_units", "run_first"]
+           "ssd_max_units", "run_first", "scan_units"]
 
 #: the kernel's name in a device trace
 SSD_SCAN_KERNEL = "mamba2_ssd_scan"
@@ -85,6 +85,33 @@ def run_first(slot):
     slot's rows are consecutive)."""
     prev = jnp.concatenate([jnp.full((1,), -2, slot.dtype), slot[:-1]])
     return slot != prev
+
+
+def scan_units(slot, lens, src, dst, tile: int, U: int, trash: int):
+    """What a scan kernel over packed rows prefetches, ``U`` units of
+    work (``ragged_units``: a maximal run of one slot's rows inside one
+    tile of ``tile`` rows): a unit's first packed row, its rows (0:
+    padding), where its state comes from (0 the input block, 1 zeros, 2
+    the unit before it), whether it is its tile's first, the entries its
+    state is read from and left in (padding units: ``trash``) and its
+    tile.  Padding units stand at the last live unit's row."""
+    count, _ = ragged_units(slot, lens, tile, jnp)
+    n_units = jnp.sum(count > 0)
+    (row0,) = jnp.nonzero(count > 0, size=U, fill_value=0)
+    row0 = row0.astype(jnp.int32)
+    live = jnp.arange(U) < n_units
+    last = row0[jnp.maximum(n_units - 1, 0)]
+    row0 = jnp.where(live, row0, last)        # padding: the last live tile
+    cnt = jnp.where(live, count[row0], 0).astype(jnp.int32)
+    first = run_first(slot)[row0]
+    u_src, u_dst = src[row0], dst[row0]
+    mode = jnp.where(first, jnp.where(u_src < 0, 1, 0), 2).astype(jnp.int32)
+    u_in = jnp.where(live, jnp.where(u_src < 0, u_dst, u_src), trash)
+    u_out = jnp.where(live, u_dst, trash).astype(jnp.int32)
+    u_tile = (row0 // tile).astype(jnp.int32)
+    prev_tile = jnp.concatenate([jnp.full((1,), -1, jnp.int32), u_tile[:-1]])
+    tfirst = (live & (u_tile != prev_tile)).astype(jnp.int32)
+    return (row0, cnt, mode, tfirst, u_in.astype(jnp.int32), u_out, u_tile)
 
 
 def ssd_scan_reference(x, dt, a, B, C, pool, slot, src, dst):
@@ -279,23 +306,7 @@ def mamba2_ssd_scan(x, dt, a, B, C, pool, slot, lens, src, dst, *,
                          f"and divide the {H} heads")
     gb, G = hb // hpg, H // hb          # groups a block; blocks
     U = int(max_units or T)
-    trash = pool.shape[0] - 1
-    count, _ = ragged_units(slot, lens, tile, jnp)
-    n_units = jnp.sum(count > 0)
-    (row0,) = jnp.nonzero(count > 0, size=U, fill_value=0)
-    row0 = row0.astype(jnp.int32)
-    live = jnp.arange(U) < n_units
-    last = row0[jnp.maximum(n_units - 1, 0)]
-    row0 = jnp.where(live, row0, last)        # padding: the last live tile
-    cnt = jnp.where(live, count[row0], 0).astype(jnp.int32)
-    first = run_first(slot)[row0]
-    u_src, u_dst = src[row0], dst[row0]
-    mode = jnp.where(first, jnp.where(u_src < 0, 1, 0), 2).astype(jnp.int32)
-    u_in = jnp.where(live, jnp.where(u_src < 0, u_dst, u_src), trash)
-    u_out = jnp.where(live, u_dst, trash).astype(jnp.int32)
-    u_tile = (row0 // tile).astype(jnp.int32)
-    prev_tile = jnp.concatenate([jnp.full((1,), -1, jnp.int32), u_tile[:-1]])
-    tfirst = (live & (u_tile != prev_tile)).astype(jnp.int32)
+    units = scan_units(slot, lens, src, dst, tile, U, pool.shape[0] - 1)
 
     xT = x.reshape(T, H * P).T                                # [H P, T]
     a_r = a.reshape(T, G, hb).transpose(1, 0, 2)              # [G, T, hb]
@@ -339,8 +350,7 @@ def mamba2_ssd_scan(x, dt, a, B, C, pool, slot, lens, src, dst, *,
             vmem_limit_bytes=64 * 1024 * 1024),
         name=SSD_SCAN_KERNEL,
         interpret=interpret,
-    )(row0, cnt, mode, tfirst, u_in.astype(jnp.int32), u_out, u_tile,
-      xT, Bf, Cf, a_r, cs, pool)
+    )(*units, xT, Bf, Cf, a_r, cs, pool)
     # a tile with no unit was never visited: its block is not written
     y = jnp.where((slot >= 0)[:, None], yT.T, 0.0).reshape(T, H, P)
     return y, pool

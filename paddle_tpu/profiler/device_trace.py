@@ -38,7 +38,7 @@ from typing import (Any, Dict, Iterable, Iterator, List, NamedTuple, Optional,
 # every scope a serving step opens (``inference/paged_layout.py``,
 # ``models/generation.py``, ``models/llama_paged.py``,
 # ``models/deepseek_v32.py``, ``models/nemotron_h.py``,
-# ``models/minicpm_sala.py``);
+# ``models/minicpm_sala.py``, ``models/kimi_linear.py``);
 # tests/test_device_scopes.py holds each
 # compiled step's working instructions to it
 DEVICE_SCOPES = (
@@ -46,7 +46,8 @@ DEVICE_SCOPES = (
     "moe_route", "moe_experts", "shared_expert", "moe_latent_down",
     "moe_latent_up", "mla_qkv", "index_select", "sparse_attn", "mamba_in_proj",
     "mamba_conv", "ssd_scan", "state_snapshot", "mamba_out", "lm_head",
-    "sample", "lightning_qkv", "lightning_out", "block_select", "ckey_write")
+    "sample", "lightning_qkv", "lightning_out", "block_select", "ckey_write",
+    "kda_qkv", "kda_conv", "kda_scan", "kda_out", "latent_attn")
 UNSCOPED = "unscoped"       # a path, and no component of it in the set
 COMPILER = "compiler"       # no path: XLA's own operation
 

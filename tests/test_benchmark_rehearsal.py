@@ -139,3 +139,16 @@ def test_the_saturated_mix_runs_closed_loop_through_runner_serve(
     assert line["attempted"] >= 5       # (a tiny model cycles the pool)
     assert "ttft_p95_ms" not in line["metrics"]
     assert {"serve_tokens_per_s", "setup_s"} <= set(line["metrics"])
+
+
+# PR 46: the Kimi-Linear cell's rehearsal (runner ``serve_kimi_linear`` at
+# a tiny size with a KDA state beside latent pages, its five controls, a
+# state pool of another type, its files and its readers) and the
+# unshared chat cell's files run with the tier-1 tests too
+from benchmarks.tests.test_kimi_linear_cell import (  # noqa: E402,F401
+    kimi_root, test_a_kimi_control_comes_out_not_correct,
+    test_a_kimi_state_pool_of_another_type_is_not_correct,
+    test_kimi_roofline_readers_count_least_work,
+    test_kimi_sound_run_is_correct_and_restores_its_prompt,
+    test_the_real_kimi_cell_loads_with_its_readers,
+    test_the_unshared_chat_cell_is_data_beside_the_chat_cell)

@@ -385,6 +385,16 @@ def _minicpm_sala():
         state_snapshots=2)
 
 
+def _kimi_linear():
+    from paddle_tpu.models.kimi_linear import KimiLinearConfig
+
+    cfg = KimiLinearConfig.debug(experts_held=(4, 12))
+    return ContinuousBatchingEngine(
+        cfg, _draw(cfg), max_slots=2, num_pages=40, page_size=4,
+        max_seq_len=64, prefill_token_budget=8, enable_prefix_cache=True,
+        state_snapshots=2)
+
+
 WORKS = re.compile(r" (fusion|dot|convolution|custom-call|scatter|gather|sort"
                    r"|reduce|reduce-window|while|conditional|call)\(")
 
@@ -419,7 +429,12 @@ def hlo_scopes(text):
                      "state_snapshot", "attn_qkv", "kv_scatter", "ckey_write",
                      "paged_attn", "block_select", "sparse_attn", "attn_out",
                      "mlp", "lm_head", "sample"}),
-], ids=["llama", "mellum2", "deepseek_v32", "nemotron_h", "minicpm_sala"])
+    (_kimi_linear, {"embed", "kda_qkv", "kda_conv", "kda_scan", "kda_out",
+                    "state_snapshot", "mla_qkv", "latent_attn", "attn_out",
+                    "mlp", "moe_route", "moe_experts", "shared_expert",
+                    "lm_head", "sample"}),
+], ids=["llama", "mellum2", "deepseek_v32", "nemotron_h", "minicpm_sala",
+        "kimi_linear"])
 def test_every_working_instruction_of_a_step_is_under_a_scope(engine, must):
     """The compiled step at debug widths: every instruction of its entry
     computation that does work carries an ``op_name`` with a component
@@ -443,7 +458,8 @@ def test_the_steps_open_no_scope_outside_the_set():
     opened = set()
     for rel in ("inference/paged_layout.py", "models/generation.py",
                 "models/llama_paged.py", "models/deepseek_v32.py",
-                "models/nemotron_h.py", "models/minicpm_sala.py"):
+                "models/nemotron_h.py", "models/minicpm_sala.py",
+                "models/kimi_linear.py"):
         opened |= set(re.findall(r'jax\.named_scope\("(\w+)"\)',
                                  (ROOT / "paddle_tpu" / rel).read_text()))
     assert opened == set(dt.DEVICE_SCOPES)

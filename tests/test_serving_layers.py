@@ -1,8 +1,8 @@
 """Where a model meets the serving engine: every arrow points one way.
 
     models/llama_paged.py  deepseek_v32.py  nemotron_h.py  minicpm_sala.py
-                \\              |               |              /
-                 v             v               v             v
+                \\              |               |              /   kimi_linear.py
+                 v             v               v             v       v
                     inference/paged_layout.py   (the seam)
                                   ^
                                   |
@@ -80,6 +80,7 @@ def test_the_engine_holds_no_model():
 def _configs():
     from paddle_tpu.models import LlamaConfig
     from paddle_tpu.models.deepseek_v32 import DeepseekV32Config
+    from paddle_tpu.models.kimi_linear import KimiLinearConfig
     from paddle_tpu.models.mellum2 import Mellum2Config
     from paddle_tpu.models.minicpm_sala import MiniCPMSALAConfig
     from paddle_tpu.models.nemotron_h import NemotronHConfig
@@ -87,7 +88,8 @@ def _configs():
     return {"llama": LlamaConfig.debug, "mellum2": Mellum2Config.debug,
             "deepseek_v32": DeepseekV32Config.debug,
             "nemotron_h": NemotronHConfig.debug,
-            "minicpm_sala": MiniCPMSALAConfig.debug}
+            "minicpm_sala": MiniCPMSALAConfig.debug,
+            "kimi_linear": KimiLinearConfig.debug}
 
 
 #: ``PagedLayout.step``'s signature (``inference/paged_layout.py``)
@@ -97,7 +99,8 @@ STEP = ("params", "k_pages", "v_pages", "rows", "tables", "cos_tab",
 
 
 @pytest.mark.parametrize("name", ["llama", "mellum2", "deepseek_v32",
-                                  "nemotron_h", "minicpm_sala"])
+                                  "nemotron_h", "minicpm_sala",
+                                  "kimi_linear"])
 def test_every_layouts_step_takes_the_seams_signature(name):
     from paddle_tpu.inference.paged_layout import PagedLayout
 

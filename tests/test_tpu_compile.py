@@ -619,6 +619,43 @@ def test_ssd_scan_compiles_at_the_cells_geometry(one_chip):
              ids, ids, ids, ids, kernels=[SSD_SCAN_KERNEL])
 
 
+def test_kda_scan_compiles_at_the_cells_geometry(one_chip):
+    """The Kimi-Linear cell's scan: 640 packed rows (128 slots and a
+    512-token chunk) in tiles of 128, 32 heads of 128 with a decay a
+    channel, 161 state entries of 2.10 MB, blocks of 8 heads."""
+    from paddle_tpu.ops.pallas.kda_scan import KDA_SCAN_KERNEL, kda_delta_scan
+    from paddle_tpu.ops.pallas.ssd_scan import ssd_max_units
+
+    T, H, d, E = 640, 32, 128, 161
+    ids = ((T,), jnp.int32)
+    row = ((T, H, d), jnp.bfloat16)
+    _compile(lambda q, k, v, g, beta, pool, slot, lens, src, dst:
+             kda_delta_scan(q, k, v, g, beta, pool, slot, lens, src, dst,
+                            tile_rows=128,
+                            max_units=ssd_max_units(T, 128, 128)),
+             one_chip, row, row, row, ((T, H, d), jnp.float32),
+             ((T, H), jnp.float32), ((E, H, d, d), jnp.float32),
+             ids, ids, ids, ids, kernels=[KDA_SCAN_KERNEL])
+
+
+def test_dense_mla_attention_compiles_at_the_cells_geometry(one_chip):
+    """The Kimi-Linear cell's latent walk: 640 packed rows of 32 heads
+    in tiles of 32 over two pools a page (``c~`` 512 and ``k_p`` padded
+    to 128), 280 pages of 128 tokens a sequence, 16 pages a turn."""
+    from paddle_tpu.ops.pallas.dense_mla import (DENSE_MLA_KERNEL,
+                                                 dense_mla_attention_raw)
+
+    T, H, pages, slots = 640, 32, 4096, 128
+    ids = ((T,), jnp.int32)
+    _compile(lambda qc, qp, lat, pos, lens, slot, tables:
+             dense_mla_attention_raw(qc, qp, lat, pos, lens, slot, tables,
+                                     pages_per_step=16, interpret=False),
+             one_chip, ((T, H, 512), jnp.bfloat16), ((T, H, 128), jnp.bfloat16),
+             ((pages, 128, 512), jnp.bfloat16),
+             ((pages, 128, 128), jnp.bfloat16), ids, ids,
+             ((slots, 280), jnp.int32), kernels=[DENSE_MLA_KERNEL])
+
+
 def test_causal_conv_compiles_at_the_cells_geometry(one_chip):
     """The Nemotron-3 cell's convolution: 640 packed rows of 10240
     channels in tiles of 128, four taps, 161 tails of ``[3, 10240]``
@@ -813,14 +850,14 @@ def _rung_engine(layout, monkeypatch):
     cell's capacities (slots, prefill budget), with the kernels steered
     to their compiled form, and the kernels its step holds."""
     from paddle_tpu.inference.serving import ContinuousBatchingEngine
-    from paddle_tpu.models import minicpm_sala, nemotron_h
+    from paddle_tpu.models import kimi_linear, minicpm_sala, nemotron_h
     from paddle_tpu.models.deepseek_v32 import DeepseekV32Config
     from paddle_tpu.models.mellum2 import Mellum2Config
-    from paddle_tpu.ops.pallas import (decode_attention, grouped_matmul,
-                                       sparse_mla)
+    from paddle_tpu.ops.pallas import (decode_attention, dense_mla,
+                                       grouped_matmul, sparse_mla)
 
-    for mod in (minicpm_sala, nemotron_h, decode_attention, grouped_matmul,
-                sparse_mla):
+    for mod in (kimi_linear, minicpm_sala, nemotron_h, decode_attention,
+                dense_mla, grouped_matmul, sparse_mla):
         monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
     kw = dict(num_pages=24, page_size=128, enable_prefix_cache=True)
     if layout == "kv":
@@ -859,6 +896,16 @@ def _rung_engine(layout, monkeypatch):
         cap = dict(max_slots=96, max_seq_len=70656, prefill_token_budget=512,
                    state_snapshots=2)
         ladder = (128, 256, 384, 608)   # the tile is the scan's 128 rows
+    elif layout == "latent_state":
+        # a KDA layer with the dense FFN, then a MLA layer with experts
+        cfg = kimi_linear.KimiLinearConfig(
+            num_hidden_layers=2, kda_layers=(1,), full_attn_layers=(2,),
+            experts_held=(0, 32), vocab_size=20480)
+        kernels = ("mamba2_causal_conv", "kda_delta_scan",
+                   "dense_mla_attention", "grouped_matmul_blocks")
+        cap = dict(max_slots=128, max_seq_len=35840, prefill_token_budget=512,
+                   state_snapshots=2)
+        ladder = (128, 256, 384, 640)   # the tile is the scan's 128 rows
     else:
         cfg = nemotron_h.NemotronHConfig(
             num_hidden_layers=3, hybrid_override_pattern="ME*",
@@ -874,7 +921,7 @@ def _rung_engine(layout, monkeypatch):
 
 
 @pytest.mark.parametrize("layout", ["kv", "kinds", "latent", "state",
-                                    "pools_state"])
+                                    "pools_state", "latent_state"])
 def test_the_lowest_rung_of_each_layout_compiles(one_chip, monkeypatch,
                                                  layout):
     """The engine launches a step of decode rows alone at the LOWEST
@@ -882,7 +929,8 @@ def test_the_lowest_rung_of_each_layout_compiles(one_chip, monkeypatch,
     family's two cells, where the capacity is 288 and 544, 128, one
     tile of the scan, in MiniCPM-SALA's, where it is 608, and 256, two
     tiles (``nemotron_h.STEP_TILES``), in Nemotron-H's, where it is
-    640.  Each layout's step compiles at that size with every
+    640, and 128, one tile of the KDA scan, in Kimi-Linear's, where it
+    is 640 too.  Each layout's step compiles at that size with every
     kernel of its cell in it: the ragged kernel (one tile, or two of
     Mellum2's 16 rows) and the experts' grouped matmul; MiniCPM-SALA's
     two block-sparse kernels, its scan and the dense walk, with the
