@@ -33,10 +33,9 @@ key channel is a sublane of ``S``): they come from transposed copies of
 the rows (``[H, d, T]``), rolled so that the row's column stands in lane
 0, and broadcast along the lanes from there.
 
-A longer unit of ``C`` rows takes the chunked form, a head at a time on
-the MXU.  With ``G_i = sum_{s <= i} g_s`` a channel (from the unit's
-first row) and ``M[i, j] = sum_c a_ic b_jc exp(G_ic - G_jc)`` for ``i >=
-j``:
+A longer unit of ``C`` rows takes the chunked form on the MXU.  With
+``G_i = sum_{s <= i} g_s`` a channel (from the unit's first row) and
+``M[i, j] = sum_c a_ic b_jc exp(G_ic - G_jc)`` for ``i >= j``:
 
     T = (I + strict_lower(Diag(beta) M(K, K)))^-1
     W = T Diag(beta) (K * e^G),  U = T Diag(beta) V,  D = U - W S_0
@@ -59,6 +58,28 @@ inverses on the diagonal of ``T``, ``T <- T - T A_level T`` is the
 inverse of the whole segment (block forward substitution: no power of
 ``A`` is ever formed).  Rows of the tile that are not the unit's are
 zeroed on the way in and keep what their own unit wrote on the way out.
+
+The ORDER of a chunk unit's products is what its time hangs on: ``T``'s
+updates are a chain, each waiting for the one before, and a product of
+``[128, 128]`` tiles loads its weights for as long as it streams its
+rows.  So (1) everything that does not read ``T`` comes first.  The
+masked sums of ``g`` of ALL levels, ``G``'s triangle and the sums of the
+rows AFTER a row (what is left of its term at the unit's end) are ONE
+stacked product ``[(levels + 2) tile, tile] x [tile, d]`` a bf16 part of
+``g`` (``_level_masks``), and one ``exp`` over the result; then every
+level's ``M(K, K)`` and ``M(Q, K)``, ``k``'s and ``q``'s upper rows
+stacked against the lower rows of ``k``.  (2) At the first level ``T``
+is the identity, so ``T - T A T = I - A`` exactly and costs no product:
+the chain is two products a level from the second level on, and from
+the level whose halves are whole register tiles (8 rows) on they stream
+the UPPER rows of each segment alone (``A``'s lower rows are zero and
+``T``'s blocks are the halves', so the other rows' terms are exact
+zeros).  (3) The unit's whole decay a channel is a sum along the lanes
+of ``g``'s transposed copy, and the state's update contracts over the
+rows' dimension of ``K * e^{G_C - G}``: neither is a product with the
+transposed rows.  (4) The heads of a block share nothing, so a loop's
+turn takes TWO: both heads' loads, then both chains in one basic block
+for the compiler to run one under the other, then both stores.
 
 Precision: the state, ``g``, ``beta``, every exponent and ``T`` in
 float32 (``T``'s updates at the highest precision); the other matmuls'
@@ -90,6 +111,7 @@ KDA_SCAN_KERNEL = "kda_delta_scan"
 
 _HI = lax.Precision.HIGHEST
 _F32 = jnp.float32
+_SUBLANES = 8           # rows of a float32 register tile
 
 
 def kda_scan_reference(q, k, v, g, beta, pool, slot, src, dst):
@@ -143,71 +165,116 @@ def _parts(x, mm, n: int):
     return out
 
 
-def _dot_exact(mask, x, mm, mask_first=True, n: int = 3):
-    """The product of a 0/1 matrix and a float32 ``x`` (``mask @ x``, or
-    ``x @ mask``), to float32's precision: bf16 rows take ``x`` in three
-    bf16 parts (a 0/1 matrix is exact in bf16), not the six passes of a
-    float32 matmul."""
-    def one(p):
-        return _dot(mask, p, mm) if mask_first else _dot(p, mask, mm)
-
+def _dot_exact(mask, x, mm):
+    """``mask @ x`` for a 0/1 matrix and a float32 ``x``, to float32's
+    precision: bf16 rows take ``x`` in three bf16 parts (a 0/1 matrix is
+    exact in bf16), not the six passes of a float32 matmul."""
     if mm == _F32:
-        return one(x)
-    return sum(one(p) for p in _parts(x, mm, n))
+        return _dot(mask, x, mm)
+    return sum(_dot(mask, p, mm) for p in _parts(x, mm, 3))
 
 
-def _chunk(q, k, v, g, beta, kT, gT, s0, r, cnt, *, tile: int, mm):
+def _halves(x, half: int):
+    """``(lower rows, upper rows)`` of ``x``: the rows in the lower and in
+    the upper half of every segment of ``2 half`` rows, in order."""
+    return tuple(jnp.concatenate([x[n + at:n + at + half] for n in
+                                  range(0, x.shape[0], 2 * half)], axis=0)
+                 for at in (0, half))
+
+
+def _level_masks(tile: int, mm):
+    """The 0/1 matrices of the chunked form, which depend on the tile
+    alone.  ``sums`` ``[(L + 2) tile, tile]`` in ``mm``, ``L = log2(tile)``
+    levels: level ``l``'s block gives an upper row of a segment of
+    ``2^(l + 1)`` rows the rows from the segment's midpoint ``m`` to
+    itself and a lower row the rows after itself and before ``m``; block
+    ``L`` is the triangle (the rows up to a row: ``G``), block ``L + 1``
+    the rows after a row (what is left of its term at the tile's end).
+    ``pairs``, float32 ``[tile, tile]`` a level: the pairs (upper ``i``,
+    lower ``j``) of one segment, every pair ``i > j`` at exactly one
+    level."""
+    i0 = lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+    i1 = lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+    sums, pairs = [], []
+    s = 1
+    while s < tile:
+        up_0, up_1 = (i0 & (2 * s - 1)) >= s, (i1 & (2 * s - 1)) >= s
+        mid = (i0 & -(2 * s)) + s             # row i's segment's midpoint
+        sums.append((up_0 & (i1 >= mid) & (i1 <= i0))
+                    | (~up_0 & (i1 > i0) & (i1 < mid)))
+        pairs.append((((i0 & -(2 * s)) == (i1 & -(2 * s))) & up_0
+                      & ~up_1).astype(_F32))
+        s *= 2
+    sums += [i1 <= i0, i1 > i0]
+    return jnp.concatenate([m.astype(mm) for m in sums], axis=0), pairs
+
+
+def _chunk(q, k, v, g, beta, gT, s0, r, cnt, *, tile: int, mm):
     """The chunked form of one head over rows ``[r, r + cnt)`` of a tile
     (module docstring).  ``q``, ``k``, ``v``, ``g`` ``[tile, d]``,
-    ``beta`` ``[tile, 1]``, ``kT``, ``gT`` ``[d, tile]``, all float32;
-    ``s0`` ``[d, d]``.  Returns ``(o [tile, d], the state after the
-    unit)``; rows of ``o`` outside the unit mean nothing."""
-    d = q.shape[1]
+    ``beta`` ``[tile, 1]``, ``gT`` ``[d, tile]``, all float32; ``s0``
+    ``[d, d]``.  Returns ``(o [tile, d], the state after the unit)``; rows
+    of ``o`` outside the unit mean nothing."""
+    sums, pairs = _level_masks(tile, mm)
+    levels = len(pairs)
     sub = lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
     lane = lax.broadcasted_iota(jnp.int32, (1, tile), 1)
     m_col = (sub >= r) & (sub < r + cnt)
-    m_row = (lane >= r) & (lane < r + cnt)
     q, k, g = (jnp.where(m_col, a, 0.0) for a in (q, k, g))
     beta = jnp.where(m_col, beta, 0.0)
-    kT, gT = (jnp.where(m_row, a, 0.0) for a in (kT, gT))
+    gT = jnp.where((lane >= r) & (lane < r + cnt), gT, 0.0)
     i0 = lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
     i1 = lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
 
-    # M(K, K) below the diagonal and its triangle's inverse, M(Q, K) on
-    # and below it, by halving
-    T = (i0 == i1).astype(_F32)
-    mqk = jnp.where(i0 == i1, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
-    s = 1
-    while s < tile:
-        up_c = (sub & (2 * s - 1)) >= s                       # [tile, 1]
-        up_0, up_1 = (i0 & (2 * s - 1)) >= s, (i1 & (2 * s - 1)) >= s
-        mid = (i0 & -(2 * s)) + s             # row i's segment's midpoint
-        # an upper row sums g over [mid, i], a lower row over (i, mid)
-        span = (up_0 & (i1 >= mid) & (i1 <= i0)) \
-            | (~up_0 & (i1 > i0) & (i1 < mid))
-        e = jnp.exp(_dot_exact(span.astype(_F32), g, mm))     # [tile, d]
-        k_lo = jnp.where(up_c, 0.0, k * e)
-        pair = ((i0 & -(2 * s)) == (i1 & -(2 * s))) & up_0 & ~up_1
-        kk = jnp.where(pair, _dot(jnp.where(up_c, k * e, 0.0), k_lo, mm,
-                                  ((1,), (1,))), 0.0)
-        mqk = mqk + jnp.where(pair, _dot(jnp.where(up_c, q * e, 0.0), k_lo,
-                                         mm, ((1,), (1,))), 0.0)
-        T = T - _dot(_dot(T, beta * kk, _F32), T, _F32)
-        s *= 2
+    # every masked sum of g in ONE stacked product (a bf16 part of g is
+    # loaded once for all its blocks), and their exponentials
+    e = jnp.exp(_dot_exact(sums, g, mm))           # [(levels + 2) tile, d]
 
-    tri = (i1 <= i0).astype(_F32)
-    eg = jnp.exp(_dot_exact(tri, g, mm))                      # e^G
+    def block(n):
+        return e[n * tile:(n + 1) * tile]
+
+    # M(K, K) below the diagonal a level, M(Q, K) on and below it: they
+    # read k, q and the exponentials alone, so all come before T's chain
+    mqk = jnp.where(i0 == i1, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
+    a_levels = []
+    for lv in range(levels):
+        half = 1 << lv
+        up_c = (sub & (2 * half - 1)) >= half                 # [tile, 1]
+        ke = k * block(lv)
+        kq_up = jnp.concatenate([jnp.where(up_c, ke, 0.0),
+                                 jnp.where(up_c, q * block(lv), 0.0)], axis=0)
+        both = _dot(kq_up, jnp.where(up_c, 0.0, ke), mm, ((1,), (1,)))
+        a_levels.append(beta * (pairs[lv] * both[:tile]))
+        mqk = mqk + pairs[lv] * both[tile:]
+
+    # the triangle's inverse by halving: at level 1 T is the identity, so
+    # T - T A T = I - A and costs no product; then the chain, which holds
+    # nothing but its own products
+    T = (i0 == i1).astype(_F32) - a_levels[0]
+    for lv, a in enumerate(a_levels[1:], 1):
+        half = 1 << lv
+        if half < _SUBLANES:
+            T = T - _dot(_dot(T, a, _F32), T, _F32)
+            continue
+        # A's lower rows are zero and T's blocks are the halves', so the
+        # update moves each segment's UPPER rows alone: half the rows to
+        # stream, once a half is whole register tiles
+        lo, up = _halves(T, half)
+        up = up - _dot(_dot(up, a, _F32), T, _F32)
+        T = jnp.concatenate([x[n:n + half] for n in range(0, tile // 2, half)
+                             for x in (lo, up)], axis=0)
+
+    eg = block(levels)                                        # e^G
     w = _dot(T, beta * (k * eg), mm)
     u = _dot(T, beta * v, mm)
     s_parts = [s0] if mm == _F32 else _parts(s0, mm, 2)
     dlt = u - sum(_dot(w, p, mm) for p in s_parts)            # D
     o = sum(_dot(q * eg, p, mm) for p in s_parts) + _dot(mqk, dlt, mm)
-    # what of each row's term is left at the unit's end, channels down
-    after = jnp.exp(_dot_exact((i0 > i1).astype(_F32), gT, mm,
-                               mask_first=False))             # [d, tile]
-    total = jnp.exp(_dot_exact(jnp.ones((tile, d), _F32), gT, mm,
-                               mask_first=False))             # [d, d]
-    return o, s0 * total + _dot(kT * after, dlt, mm)
+    # what of each row's term is left at the unit's end, summed over the
+    # rows; the whole unit's decay a channel, channels down
+    left = _dot(k * block(levels + 1), dlt, mm, ((0,), (0,)))
+    total = jnp.exp(jnp.sum(gT, axis=1, keepdims=True))       # [d, 1]
+    return o, s0 * total + left
 
 
 def _kda_kernel(row0_ref, cnt_ref, mode_ref, tfirst_ref, in_ref, out_ref,
@@ -250,16 +317,27 @@ def _kda_kernel(row0_ref, cnt_ref, mode_ref, tfirst_ref, in_ref, out_ref,
 
     @pl.when(cnt > 1)
     def _():
-        def head(h, carry):
-            o, s = _chunk(q_ref[h], k_ref[h], v_ref[h], g_ref[h],
-                          b_ref[h][:, :1], kT_ref[h], gT_ref[h], state_in(h),
-                          r, cnt, tile=tile, mm=mm)
-            so_ref[0, h] = s
-            sub = lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
-            o_ref[h] = jnp.where((sub >= r) & (sub < r + cnt), o, o_ref[h])
+        sub = lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+        in_unit = (sub >= r) & (sub < r + cnt)
+
+        def run(heads):
+            # the heads share nothing: every load before the first
+            # product and every store after the last, so that one head's
+            # chain of products runs under the other's
+            done = [_chunk(q_ref[h], k_ref[h], v_ref[h], g_ref[h],
+                           b_ref[h][:, :1], gT_ref[h], state_in(h), r, cnt,
+                           tile=tile, mm=mm) for h in heads]
+            for h, (o, s) in zip(heads, done):
+                so_ref[0, h] = s
+                o_ref[h] = jnp.where(in_unit, o, o_ref[h])
+
+        def pair(p, carry):
+            run([2 * p, 2 * p + 1])
             return carry
 
-        lax.fori_loop(0, hb, head, 0)
+        lax.fori_loop(0, hb // 2, pair, 0)
+        if hb % 2:
+            run([hb - 1])
 
 
 @functools.partial(jax.jit, static_argnames=("tile_rows", "max_units",

@@ -50,24 +50,35 @@ ROW_LAYOUTS = {
     "restore": ([(2, 20, 5)], 24),                      # from a snapshot
     "mixed": ([(0, 1, 0), (1, 1, 1), (2, 11, -1), (3, 3, 4)], 24),
     "edges": ([(1, 1, 1), (0, 7, 0), (3, 9, 3), (2, 1, -1)], 24),
+    "two_rows": ([(0, 2, -1)], 8),                      # level 1 alone
+    "odd_start": ([(1, 3, 1), (0, 5, 0)], 16),          # 5 rows from row 3
 }
+#: (rows' layout, heads a grid step, rows a tile): the chunk unit takes a
+#: block's heads two at a time, so 1 head (one left over, no pair), 2 (a
+#: pair), 4 of 4; in tiles of 32 the halves of 8 and 16 rows are whole
+#: register tiles, where T's update takes a segment's upper rows alone
+CASES = {**{name: (*layout, 2, TILE) for name, layout in ROW_LAYOUTS.items()},
+         "mixed-1": (*ROW_LAYOUTS["mixed"], 1, TILE),
+         "mixed-4": (*ROW_LAYOUTS["mixed"], 4, TILE),
+         "tile_32": ([(2, 45, 5), (0, 1, 0), (1, 9, -1)], 64, 2, 32)}
 
 
-@pytest.mark.parametrize("runs, rows", list(ROW_LAYOUTS.values()),
-                         ids=list(ROW_LAYOUTS))
+@pytest.mark.parametrize("runs, rows, heads_per_step, tile",
+                         list(CASES.values()), ids=list(CASES))
 @pytest.mark.parametrize("hard", [False, True], ids=["typical", "hard"])
 @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5),
                                         (jnp.bfloat16, 2e-2)],
                          ids=["f32", "bf16"])
-def test_scan_kernel_matches_the_recurrence(runs, rows, hard, dtype, tol):
+def test_scan_kernel_matches_the_recurrence(runs, rows, heads_per_step, tile,
+                                            hard, dtype, tol):
     """float32 rows: float32 matmuls at the highest precision, the same
     sums in another order (1e-7 read).  bf16 rows: the chunked form's
     matmul operands are bf16, so a hundredth of the largest value."""
     args, (slot, lens, src, dst) = scan_case(runs, rows, hard, dtype)
     o0, p0 = kda_scan_reference(*args, slot, src, dst)
-    o1, p1 = kda_delta_scan(*args, slot, lens, src, dst, tile_rows=TILE,
-                            max_units=ssd_max_units(rows, TILE, 4),
-                            heads_per_step=2, interpret=True)
+    o1, p1 = kda_delta_scan(*args, slot, lens, src, dst, tile_rows=tile,
+                            max_units=ssd_max_units(rows, tile, 4),
+                            heads_per_step=heads_per_step, interpret=True)
     assert bool(jnp.isfinite(o1).all()) and bool(jnp.isfinite(p1[:-1]).all())
     assert float(jnp.abs(o0 - o1).max() / jnp.abs(o0).max()) < tol
     # the trash entry (the last) is the padding units' to scribble on
@@ -80,17 +91,21 @@ def test_scan_kernel_matches_the_recurrence(runs, rows, hard, dtype, tol):
     assert not np.asarray(o1)[pad].any()
 
 
-def test_a_unit_of_a_whole_tile_of_128_rows_at_the_published_head_size():
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5),
+                                        (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+def test_a_unit_of_a_whole_tile_of_128_rows_at_the_published_head_size(
+        dtype, tol):
     """The cell's tile and head size, two heads: seven levels of halving;
     one slot's 200 rows cross a tile and a decode row rides beside."""
-    args, cols = scan_case([(0, 200, -1), (1, 1, 1)], 256, hard=True, seed=5,
-                           H=2, D=128)
+    args, cols = scan_case([(0, 200, -1), (1, 1, 1)], 256, hard=True,
+                           dtype=dtype, seed=5, H=2, D=128)
     slot, lens, src, dst = cols
     o0, p0 = kda_scan_reference(*args, slot, src, dst)
     o1, p1 = kda_delta_scan(*args, slot, lens, src, dst, tile_rows=128,
                             heads_per_step=2, interpret=True)
-    assert float(jnp.abs(o0 - o1).max() / jnp.abs(o0).max()) < 1e-5
-    assert float(jnp.abs(p0[:2] - p1[:2]).max() / jnp.abs(p0[:2]).max()) < 1e-5
+    assert float(jnp.abs(o0 - o1).max() / jnp.abs(o0).max()) < tol
+    assert float(jnp.abs(p0[:2] - p1[:2]).max() / jnp.abs(p0[:2]).max()) < tol
 
 
 def test_dense_latent_walk_matches_dense_numpy():

@@ -619,14 +619,16 @@ def test_ssd_scan_compiles_at_the_cells_geometry(one_chip):
              ids, ids, ids, ids, kernels=[SSD_SCAN_KERNEL])
 
 
-def test_kda_scan_compiles_at_the_cells_geometry(one_chip):
-    """The Kimi-Linear cell's scan: 640 packed rows (128 slots and a
-    512-token chunk) in tiles of 128, 32 heads of 128 with a decay a
+@pytest.mark.parametrize("T", [640, 128])
+def test_kda_scan_compiles_at_the_cells_geometry(one_chip, T):
+    """The Kimi-Linear cell's scan at the highest and the lowest rung of
+    its ladder: 640 packed rows (128 slots and a 512-token chunk) and 128
+    (decode rows alone) in tiles of 128, 32 heads of 128 with a decay a
     channel, 161 state entries of 2.10 MB, blocks of 8 heads."""
     from paddle_tpu.ops.pallas.kda_scan import KDA_SCAN_KERNEL, kda_delta_scan
     from paddle_tpu.ops.pallas.ssd_scan import ssd_max_units
 
-    T, H, d, E = 640, 32, 128, 161
+    H, d, E = 32, 128, 161
     ids = ((T,), jnp.int32)
     row = ((T, H, d), jnp.bfloat16)
     _compile(lambda q, k, v, g, beta, pool, slot, lens, src, dst:
