@@ -10,11 +10,13 @@ business (``inference/paged_layout.py``).
 - ``PrefixCache`` (``_TrieNode``): the radix cache of committed full
   pages by their tokens, a page of each kind of page a block, an
   optional host tier, and the state snapshots a block may hold beside
-  its pages.  It keeps a snapshot at a block beside its pages (taken
-  where a prefill chunk ends on a page boundary: the engine's
-  ``_state_chunk`` cuts chunks so that they do) and serves a hit as far
-  as the deepest block that has BOTH; what the pages matched beyond it
-  is prefilled again (``state_lost_tokens``).  Snapshots have their own
+  its pages.  A prefill hands it the FIRST kind's full pages chunk by
+  chunk, as each is committed, and the rest (a window kind's pages, the
+  snapshots) when the prompt is done.  It keeps a snapshot at a block
+  beside its pages (taken where a prefill chunk ends on a page
+  boundary: the engine's ``_state_chunk`` cuts chunks so that they do)
+  and serves a hit as far as the deepest block that has BOTH; what the
+  pages matched beyond it is prefilled again (``state_lost_tokens``).  Snapshots have their own
   budget (the engine's ``state_snapshots``) and LRU.
 - ``_KindPages``: one kind of page as the host holds it: pool size,
   allocator, table, the pages each slot holds.
@@ -174,16 +176,24 @@ class PrefixCache:
 
     Keys are page-granular token chunks (``page_size`` tokens per edge),
     values are PHYSICAL page ids in the per-layer pools.  A node exists
-    only for pages whose prompt tokens were fully committed by a
-    completed prefill, and the trie holds its own allocator reference on
-    each node's page — so cached prefixes survive the requests that
+    only for pages whose prompt tokens were fully committed by a prefill
+    chunk whose launch the host has read (the engine inserts the full
+    pages of what a prompt has committed at EVERY chunk's commit, not
+    only at its last: a re-ask that waits behind its own document's
+    prefill finds them), and the trie holds its own allocator reference
+    on each node's page — so cached prefixes survive the requests that
     produced them, and ``lookup`` can hand the same physical pages to a
     new request copy-on-write (the new request only ever WRITES at
-    positions at or past its private suffix, so shared pages are
-    read-only by construction; the last partial prompt page is always
-    private because only full pages are keyed, and at least one suffix
-    token is always left to prefill so the hit request still produces
-    first-token logits).
+    positions at or past its private suffix, and the prefill that
+    committed a page writes only past what it has committed, so shared
+    pages are read-only by construction; the last partial prompt page
+    is always private because only full pages are keyed, and at least
+    one suffix token is always left to prefill so the hit request still
+    produces first-token logits).  A block committed before its prompt
+    was done holds its first-kind page alone: where the layout has a
+    window kind or a recurrent state it is not restorable until the
+    prompt's last insert gives it the rest, and ``lookup_all`` shrinks a
+    hit to what is whole.
 
     Eviction is LRU over refcount-0 leaves (allocator refcount 1 = the
     trie's own reference, no live request) under pool pressure — interior
@@ -242,6 +252,11 @@ class PrefixCache:
         self.hits = 0
         self.lookups = 0
         self.hit_tokens = 0
+        # hits the engine's SECOND look-up found (a slot that waited
+        # with nothing launched, matched again when its first chunk was
+        # packed), and the tokens they added to the admission's match
+        self.late_hits = 0
+        self.late_hit_tokens = 0
         self.inserted_pages = 0
         self.evicted_pages = 0
         # host tier (round 16)
@@ -375,16 +390,29 @@ class PrefixCache:
             node = child
         return matched
 
-    def record_hit(self, matched_tokens: int) -> None:
-        if matched_tokens > 0:
-            self.hits += 1
-            self.hit_tokens += matched_tokens
+    def record_hit(self, matched_tokens: int, before: Optional[int] = None
+                   ) -> None:
+        """Count a served hit of ``matched_tokens``.  ``before``: what
+        the request's admission had matched, where this is the hit of
+        its second look-up (a LATE hit: it counts the tokens it added,
+        and as a hit of its own only if the admission found nothing)."""
+        added = matched_tokens - (before or 0)
+        if before is not None:
+            self.late_hits += 1
+            self.late_hit_tokens += added
+        if added > 0:
+            self.hits += 0 if before else 1
+            self.hit_tokens += added
 
     def insert(self, prompt, pages, more=(), snaps=()) -> int:
-        """Commit a completed prefill's FULL prompt pages.  New nodes
-        acquire a trie reference on their page; existing nodes are left
-        untouched (a concurrent prefill of the same prefix keeps its
-        private copy, which simply frees when that request finishes).
+        """Commit the FULL pages of what a prefill has committed of its
+        prompt: called with a growing prefix as the prompt's chunks are
+        committed, with ``more`` and ``snaps`` when the last is.  New
+        nodes acquire a trie reference on their page; existing nodes are
+        left untouched, so a page takes ONE trie reference however often
+        its block is inserted (and a concurrent prefill of the same
+        prefix keeps its private copy, which simply frees when that
+        request finishes).
         ``more[k]`` is ``(first block, pages)``: the k-th further kind's
         pages the slot still holds, from that block on; a block that
         lacks its page of that kind, new or not, takes it.  ``snaps``
@@ -673,6 +701,8 @@ class PrefixCache:
             "snapshots_evicted": self.evicted_snapshots}
         return {"lookups": self.lookups, "hits": self.hits,
                 "hit_tokens": self.hit_tokens,
+                "late_hits": self.late_hits,
+                "late_hit_tokens": self.late_hit_tokens,
                 "cached_pages": self.cached_pages,
                 "inserted_pages": self.inserted_pages,
                 "evicted_pages": self.evicted_pages,

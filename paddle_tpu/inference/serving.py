@@ -35,8 +35,10 @@ launch depends on the rung.
 (``_step_unified``).  With launch n enqueued by the call before, a call
 is: admit (prefix-cache hits map shared full pages copy-on-write and
 skip their prefill), pack launch n+1 from the SCHEDULED state
-(``_schedule``: launch n counted as done), launch it, and only then
-fetch launch n's tokens and commit them.  The one thing launch n+1
+(``_schedule``: launch n counted as done; a slot that waited a call or
+more with nothing launched is matched against the prefix cache again
+before its first chunk is packed, ``_late_hit``), launch it, and only
+then fetch launch n's tokens and commit them.  The one thing launch n+1
 needs from launch n that the host does not know when it packs is one
 token a decode row: that input is a REFERENCE into the tokens launch n
 sampled, which never leave the device on their way
@@ -45,7 +47,9 @@ launch queued, and a token reaches the host when its own device step
 ends.  What is scheduled (a slot's position and budget as the packing
 sees them) advances at launch; what is committed (``out_tokens``,
 ``cur_tok``, ``finished``, pages and slots freed, the prefix cache's
-insert, the handoff record) changes at commit.  A slot that ends on
+inserts, one a prefill chunk: the full pages of what the prompt has
+committed, and with the last what a window kind and a recurrent state
+add, the handoff record) changes at commit.  A slot that ends on
 ``eos_id``, or is canceled, with a row enqueued runs that row STALE: it
 writes inside the slot's own pages, which are freed after it was
 enqueued and so cannot be reused under it (a device runs its launches
@@ -79,8 +83,9 @@ runs, whoever started it, holds them on the device's clock; one marker
 a call (``serving.step_counts``: the counts of the launch the call
 COMMITS, with ``ahead``, ``stale_rows`` and ``launch``, that launch's
 serial, which ``serving.launch`` carries too where it is enqueued) and
-one per request at admission and at its first token
-(``serving.admit_request``, ``serving.first_token``) carry the counts.
+one per request at admission, at a hit of its second look-up and at its
+first token (``serving.admit_request``, ``serving.late_hit``,
+``serving.first_token``) carry the counts.
 The same counts are summed in ``serving_stats()["steps"]`` whether or
 not anything traces.  A step's ``rows_cap`` count is the rows of the
 program LAUNCHED (the rung); ``engine.rows_cap`` stays the capacity.
@@ -414,6 +419,11 @@ class ContinuousBatchingEngine:
         self._init_spec_k = self.spec_k
         self.pending_prompt: Dict[int, np.ndarray] = {}
         self.prefill_order: List[int] = []       # FIFO over mid-prefill slots
+        # with a prefix cache: slot -> the call (``step_totals["steps"]``)
+        # that admitted it, until its first chunk is packed: such a slot
+        # has written no page of its own and may be matched again
+        # (``_late_hit``)
+        self.unlaunched: Dict[int, int] = {}
         self.req_info: Dict[int, Request] = {}   # slot -> live request
         # per-rid prefill accounting (the FLOPs-skip contract: warm
         # requests must show prefilled == prompt_len - cached; run-scoped
@@ -605,6 +615,7 @@ class ContinuousBatchingEngine:
         self.seq_lens[slot] = 0
         self.slot_rid[slot] = -1
         self.pending_prompt.pop(slot, None)
+        self.unlaunched.pop(slot, None)
         if slot in self.prefill_order:
             self.prefill_order.remove(slot)
         self.req_info.pop(slot, None)
@@ -878,7 +889,10 @@ class ContinuousBatchingEngine:
         generation budget is reserved up front (no mid-flight OOM).
         Prefix-cache hits map the shared full pages into the new table
         (copy-on-write: the request only ever writes at or past its
-        private suffix) and skip their prefill entirely."""
+        private suffix) and skip their prefill entirely (``_map_hit``).
+        The look-up is made again, once, for a slot that then WAITS a
+        call or more before its first chunk is packed (``_late_hit``):
+        what it waits behind may be the prefill of its own prefix."""
         admitted = []
         if (self.cache_dtype == jnp.int8 and self.kv_scales is None
                 and self.queue):
@@ -901,65 +915,27 @@ class ContinuousBatchingEngine:
                 shared, matched, snap, lost = self.prefix_cache.lookup_all(
                     req.prompt)
             if not self._reserve(need, shared):
-                for kp, pages in zip(self.pages, shared):
-                    kp.alloc.release(pages)   # aborted hit: refs back
-                if snap is not None:
-                    self.snap_alloc.release([snap])
+                self._release_hit(shared, snap)   # aborted hit: refs back
                 break               # head-of-line waits for pages
             self.queue.popleft()
             slot = free_slots[si]
             si += 1
-            for kp, pages in zip(self.pages, shared):
+            for kp in self.pages:
                 kp.claim[slot] = kp.claim_of(need)
-                kp.tables[slot] = -1
-                if kp.window is None:
-                    # every position is retained: the whole context's
-                    # pages now (no mid-flight OOM)
-                    kp.lo[slot] = 0
-                    kp.held[slot] = list(pages) + [
-                        kp.alloc.alloc() for _ in range(need - len(pages))]
-                else:
-                    # the hit's pages of the blocks its rows will read;
-                    # every later block is mapped when a launch first
-                    # writes into it (``_map_pages``)
-                    kp.lo[slot] = matched // self.page_size - len(pages)
-                    kp.held[slot] = list(pages)
-                    kp.tables[slot, :kp.lo[slot]] = kp.trash
-                lo = kp.lo[slot]
-                kp.tables[slot, lo:lo + len(kp.held[slot])] = kp.held[slot]
-            state_args = {}
-            if self.layout.state:
-                # the slot's first launch starts from the snapshot (the
-                # slot holds its reference until that launch is
-                # committed) or from zeros: no copy, no launch of its own
-                self.state_src[slot] = -1
-                if snap is not None:
-                    self.state_src[slot] = self.max_slots + snap
-                    self.slot_snap_ref[slot] = snap
-                self._admitted_state[0] += matched
-                self._admitted_state[1] += lost
-                # state_entry: where the request's state lives (its
-                # slot's own entry), and stays once it has ended until
-                # the slot's next tenant's first launch
-                state_args = {"state_restored_tokens": matched,
-                              "state_lost_tokens": lost,
-                              "state_entry": slot}
             self.active[slot] = True
-            self.seq_lens[slot] = matched
             self.cur_tok[slot] = 0
             self.budget[slot] = req.max_new_tokens
             self.slot_rid[slot] = req.rid
-            self.pending_prompt[slot] = np.asarray(req.prompt[matched:],
-                                                   np.int32)
             self.prefill_order.append(slot)
+            if self.prefix_cache is not None:
+                self.unlaunched[slot] = self.step_totals["steps"]
             req.rng = np.random.default_rng(req.seed)
             self.req_info[slot] = req
             self.prompt_lens[req.rid] = plen
-            self.prefill_stats[req.rid] = {
-                "prompt_len": plen, "cached_tokens": matched,
-                "prefilled": 0, **state_args}
-            if self.prefix_cache is not None:
-                self.prefix_cache.record_hit(matched)
+            self.prefill_stats[req.rid] = {"prompt_len": plen,
+                                           "cached_tokens": 0,
+                                           "prefilled": 0}
+            state_args = self._map_hit(slot, shared, matched, snap, lost)
             admitted.append((slot, plen))
             req.admitted = time.perf_counter()
             wait_us = int((req.admitted - req.submitted) * 1e6)
@@ -972,6 +948,106 @@ class ContinuousBatchingEngine:
                              cached_tokens=matched, **state_args):
                 pass
         return admitted
+
+    def _release_hit(self, shared, snap) -> None:
+        """Give back what a look-up acquired and nobody mapped."""
+        for kp, pages in zip(self.pages, shared):
+            kp.alloc.release(pages)
+        if snap is not None:
+            self.snap_alloc.release([snap])
+
+    def _map_hit(self, slot: int, shared, matched: int, snap, lost: int,
+                 before: Optional[int] = None) -> Dict[str, int]:
+        """Map a look-up's hit (``PrefixCache.lookup_all``'s four) into
+        ``slot``, whose claims are made: the tables and
+        held pages of every kind, the entry the slot's first launch
+        starts its state from, where its prefill begins and what is left
+        of its prompt, and the hit's accounting.  Used by the admission
+        and, for a slot that has launched nothing (no page of its own
+        written), by the second look-up (``_late_hit``), which says what
+        the admission had matched (``before``): there the slot gives
+        back what it held for the blocks now shared, its private pages
+        and the references of its admission's hit alike.  Returns the
+        state's arguments of the request's markers."""
+        req = self.req_info[slot]
+        stats = self.prefill_stats[req.rid]
+        for kp, pages in zip(self.pages, shared):
+            old = [] if before is None else kp.held[slot]
+            kp.tables[slot] = -1
+            if kp.window is None:
+                # every position is retained: the whole context's pages
+                # now (no mid-flight OOM); a slot mapped before keeps
+                # its private pages of the blocks the hit leaves it
+                kp.lo[slot] = 0
+                kp.held[slot] = list(pages) + (
+                    [kp.alloc.alloc()
+                     for _ in range(kp.claim[slot] - len(pages))]
+                    if before is None else old[len(pages):])
+                kp.alloc.release(old[:len(pages)])
+            else:
+                # the hit's pages of the blocks its rows will read;
+                # every later block is mapped when a launch first
+                # writes into it (``_map_pages``)
+                kp.lo[slot] = matched // self.page_size - len(pages)
+                kp.held[slot] = list(pages)
+                kp.tables[slot, :kp.lo[slot]] = kp.trash
+                kp.alloc.release(old)
+            lo = kp.lo[slot]
+            kp.tables[slot, lo:lo + len(kp.held[slot])] = kp.held[slot]
+        state_args = {}
+        if self.layout.state:
+            # the slot's first launch starts from the snapshot (the
+            # slot holds its reference until that launch is
+            # committed) or from zeros: no copy, no launch of its own
+            if slot in self.slot_snap_ref:
+                self.snap_alloc.release([self.slot_snap_ref.pop(slot)])
+            self.state_src[slot] = -1
+            if snap is not None:
+                self.state_src[slot] = self.max_slots + snap
+                self.slot_snap_ref[slot] = snap
+            self._admitted_state[0] += matched - (before or 0)
+            self._admitted_state[1] += lost - stats.get(
+                "state_lost_tokens", 0)
+            # state_entry: where the request's state lives (its
+            # slot's own entry), and stays once it has ended until
+            # the slot's next tenant's first launch
+            state_args = {"state_restored_tokens": matched,
+                          "state_lost_tokens": lost,
+                          "state_entry": slot}
+        self.seq_lens[slot] = matched
+        self.pending_prompt[slot] = np.asarray(req.prompt[matched:], np.int32)
+        stats.update(cached_tokens=matched, **state_args)
+        if self.prefix_cache is not None:
+            self.prefix_cache.record_hit(matched, before)
+        return state_args
+
+    def _late_hit(self, slot: int) -> None:
+        """The look-up again, for a slot that waited: admitted by an
+        earlier call, nothing launched yet, its first chunk about to be
+        packed.  What it waited behind may have been the prefill of its
+        own prefix (a document asked again while its first ask
+        prefills), whose full pages the trie has taken chunk by chunk
+        since the admission looked.  ``probe`` (no references, no LRU
+        change) says whether the trie is deeper than the slot's match;
+        only then is the prompt looked up again and, if the hit that
+        can be served is longer, mapped anew (``_map_hit``)."""
+        req, pc = self.req_info[slot], self.prefix_cache
+        matched = int(self.seq_lens[slot])
+        if pc.probe(req.prompt) <= matched:
+            return
+        shared, deeper, snap, lost = pc.lookup_all(req.prompt)
+        if deeper <= matched:
+            # the blocks are there and not restorable (a window kind's
+            # pages, a snapshot: they come with the prompt's last insert)
+            self._release_hit(shared, snap)
+            return
+        state_args = self._map_hit(slot, shared, deeper, snap, lost,
+                                   before=matched)
+        with RecordEvent("serving.late_hit", rid=req.rid,
+                         cached_tokens=deeper, waited_us=int(
+                             (time.perf_counter() - req.admitted) * 1e6),
+                         **state_args):
+            pass
 
     def _reserve(self, need: int, shared) -> bool:
         """Whether every kind of page can take a request of ``need``
@@ -1223,8 +1299,11 @@ class ContinuousBatchingEngine:
         the device never waits for the host between two steps, and a
         request sent between two calls is admitted by the next one.
         Slots, pages, ``out_tokens``, ``finished`` and the prefix
-        cache change at COMMIT; a slot's position and budget as the
-        packing sees them advance at LAUNCH.
+        cache change at COMMIT (the cache at every prefill chunk's: the
+        full pages of launch n's chunks are in the trie once n's tokens
+        are back, so the packing of launch n+2 may map them into a slot
+        that waited, ``_late_hit``); a slot's position and budget as
+        the packing sees them advance at LAUNCH.
 
         With nothing in flight (the first call, after an idle spell)
         the call enqueues two launches and commits the first.  Where
@@ -1475,9 +1554,15 @@ class ContinuousBatchingEngine:
             metas.append(("verify", s, gstart, len(window)))
         decode_rows = r
         left = self.prefill_budget
+        call = self.step_totals["steps"]
         for s, base, pend in prefill:
             if left <= 0:
                 break
+            if self.unlaunched.pop(s, call) < call:
+                # the slot's first chunk, a call or more after it was
+                # admitted: what it waited behind may be in the trie
+                self._late_hit(s)
+                base, pend = int(self.seq_lens[s]), self.pending_prompt[s]
             chunk = min(len(pend), left)
             if stateful:
                 chunk = self._state_chunk(base, chunk, len(pend))
@@ -1611,10 +1696,18 @@ class ContinuousBatchingEngine:
             pend = self.pending_prompt[s]
             if n < len(pend):
                 self.pending_prompt[s] = pend[n:]
+                if self.prefix_cache is not None:
+                    # the full pages of what the prompt has committed so
+                    # far, of the first kind alone: a window kind's pages
+                    # and the state's snapshots go over with the prompt's
+                    # last chunk, below (a block is restorable from then)
+                    self.prefix_cache.insert(
+                        req.prompt[:self.seq_lens[s]], self.slot_pages[s])
                 continue
             # prompt complete: the chunk's final row (gathered at
-            # ``gstart``) carries the first token; commit full pages to
-            # the prefix cache
+            # ``gstart``) carries the first token; commit the rest of
+            # its full pages to the prefix cache, and what the blocks
+            # committed chunk by chunk still lack
             del self.pending_prompt[s]
             self.prefill_order.remove(s)
             if self.prefix_cache is not None:
@@ -1728,8 +1821,11 @@ class ContinuousBatchingEngine:
         launches were enqueued before the one before them was read,
         ``ahead``, and how many rows ran for a slot that had ended,
         ``stale_rows``; how long requests queue and prefill, sum and max
-        in seconds).  The same numbers, per step and per request, ride
-        on the ``serving.step_counts``, ``serving.admit_request`` and
+        in seconds; under ``"prefix_cache"`` the hits the second look-up
+        found, ``late_hits``, and the tokens they added,
+        ``late_hit_tokens``).  The same numbers, per step and per
+        request, ride on the ``serving.step_counts``,
+        ``serving.admit_request``, ``serving.late_hit`` and
         ``serving.first_token`` markers of a profiler trace."""
         t = self.step_totals
         out: Dict[str, Any] = {

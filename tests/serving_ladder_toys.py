@@ -4,10 +4,11 @@ rungs for the Llama-shaped engine, two for Mellum2's, whose step
 compiles slowest here, three for Nemotron-H's, whose tile is two of
 its ``chunk_size`` of 8, and for MiniCPM-SALA's with its scan's tile
 cut to 16 rows) and the ONE that DeepSeek's layout keeps while it
-states no tile, the mixed trace they serve, and the check that a ladder
-serves what the top rung serves: shared by ``test_serving_ladder.py``
-and ``test_serving_ladder_kinds_state.py`` (two files, so that two
-workers share the compiles)."""
+states no tile, the mixed trace they serve, the check that a ladder
+serves what the top rung serves and the check that a re-ask behind its
+document's prefill is served what its layout can restore: shared by
+``test_serving_ladder.py`` and ``test_serving_ladder_kinds_state.py``
+(two files, so that two workers share the compiles)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -258,3 +259,97 @@ def check_a_ladder_serves_what_the_top_rung_serves(name, compiles):
         or len(want) == 1
     assert steps["rows_cap"] >= sum(n * c for n, c in by_rows.items())
     return steps, extras
+
+
+# ---- a re-ask behind its document's prefill ------------------------------
+
+def _serve_re_asks(eng, vocab):
+    """Document ``a`` of three chunks and three tokens; ``b``, the same,
+    added while ``a`` prefills; ``c``, the same, once both are done; then
+    ``d`` (three whole chunks), ``x`` (another document, a chunk and a
+    quarter) and ``e``, the same as ``d``, all three in one call: ``e``
+    waits behind ``x``, whose first chunk fills the launch after ``d``'s
+    last, and finds ``d`` done when its own first chunk is packed beside
+    ``x``'s second.  Returns the tokens, the cache's counters after
+    ``b``, after ``c`` and at the end, each request's ``prefill_stats``
+    and the two documents' lengths."""
+    rng = np.random.default_rng(11)
+    budget = eng.prefill_budget
+    doc = rng.integers(1, vocab, 3 * budget + 3)
+    doc2 = rng.integers(1, vocab, 3 * budget)
+    other = rng.integers(1, vocab, budget + budget // 4)
+    rids, seen = {}, {}
+
+    def add(name, prompt):
+        rids[name] = eng.add_request(prompt.astype(np.int32),
+                                     max_new_tokens=4)
+
+    def drain(name):
+        while eng.queue or eng.active.any():
+            eng.step()
+            eng.assert_balanced()
+        if eng.prefix_cache is not None:
+            seen[name] = eng.serving_stats()["prefix_cache"]
+
+    add("a", doc)
+    eng.step()
+    add("b", doc)
+    drain("b")
+    add("c", doc)
+    drain("c")
+    add("d", doc2), add("x", other), add("e", doc2)
+    drain("e")
+    tokens = {f.rid: f.tokens.tolist() for f in eng.run()}
+    return ({n: tokens[r] for n, r in rids.items()}, seen,
+            {n: eng.prefill_stats[r] for n, r in rids.items()},
+            {"doc": len(doc), "doc2": len(doc2)})
+
+
+def check_a_re_ask_late_hits_what_its_layout_can_restore(name):
+    """The prefix cache takes a prompt's first-kind pages chunk by
+    chunk, and a slot that waited is matched again when its first chunk
+    is packed (``serving._late_hit``).  Every layout serves the tokens
+    it serves with the prefix cache off.  One kind of page alone
+    (DeepSeek's): ``b`` late-hits what ``a`` has committed.  A window
+    kind or a recurrent state: a block committed before its prompt was
+    done is not restorable, so ``b`` finds nothing while ``a`` prefills
+    (as before) and ``c`` hits whole at its admission; ``e``, whose
+    document was DONE by the time its first chunk was packed, late-hits
+    in every layout, window pages, snapshot and all."""
+    make = LAYOUTS[name][0]()
+    eng = make()
+    vocab, budget, page = (eng.cfg.vocab_size, eng.prefill_budget,
+                           eng.page_size)
+    tokens, seen, stats, lens = _serve_re_asks(eng, vocab)
+    eng.assert_balanced()
+    eng.shutdown()
+
+    cold = make()
+    cold.prefix_cache = None            # the same engine, no cache
+    cold_tokens, _, cold_stats, _ = _serve_re_asks(cold, vocab)
+    cold.shutdown()
+    assert tokens == cold_tokens
+    assert all(s["cached_tokens"] == 0 for s in cold_stats.values())
+
+    whole = lens["doc"] - budget - page
+    one_kind = len(eng.pages) == 1 and not eng.layout.state
+    assert seen["b"]["late_hits"] == (1 if one_kind else 0)
+    assert (stats["b"]["cached_tokens"] >= whole) if one_kind \
+        else stats["b"]["cached_tokens"] == 0
+    # done, the document is a whole hit at admission for every layout
+    assert seen["c"]["late_hits"] == seen["b"]["late_hits"]
+    assert stats["c"]["cached_tokens"] >= whole
+    # and a late hit for every layout: the window kind's pages and the
+    # snapshot came with the prompt's last insert
+    assert seen["e"]["late_hits"] == seen["c"]["late_hits"] + 1
+    assert stats["d"]["cached_tokens"] == stats["x"]["cached_tokens"] == 0
+    cached = stats["e"]["cached_tokens"]
+    if eng.layout.state:
+        # as deep as the deepest snapshot ``d`` left on the chunks' grid
+        # that ``x``'s own did not displace (four entries in all)
+        assert cached == stats["e"]["state_restored_tokens"] > 0
+        assert cached % budget == 0
+    else:
+        assert cached >= lens["doc2"] - budget - page
+    for s in stats.values():
+        assert s["prefilled"] == s["prompt_len"] - s["cached_tokens"]

@@ -168,6 +168,9 @@ def test_memory_stats_logged_on_profiler_step():
         p.step()  # outside the active window: must NOT record
         assert len(prof._host_events) == n0
         p.start()
+        # start() clears the module's list: count from what it leaves,
+        # not from what an earlier file on this worker left behind
+        n0 = len(prof._host_events)
         p.step()
         p.stop()
         assert len(prof._host_events) == n0 + 1

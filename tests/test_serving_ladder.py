@@ -16,7 +16,7 @@ import pytest
 from paddle_tpu.inference.paged_layout import step_ladder
 from serving_ladder_toys import (  # noqa: F401 - compiles is a fixture
     LAYOUTS, _serve, check_a_ladder_serves_what_the_top_rung_serves,
-    compiles)
+    check_a_re_ask_late_hits_what_its_layout_can_restore, compiles)
 
 # (max_slots, speculative_k, prefill_token_budget, the kernels' row
 # tile) of the benchmark's five serving configurations
@@ -101,6 +101,13 @@ def test_the_cells_layouts_state_the_tiles_of_the_rule():
 @pytest.mark.parametrize("name", ["llama", "deepseek"])
 def test_a_ladder_serves_what_the_top_rung_serves(name, compiles):
     check_a_ladder_serves_what_the_top_rung_serves(name, compiles)
+
+
+def test_a_re_ask_late_hits_what_its_layout_can_restore():
+    """One kind of page and no state (DeepSeek's toy): a re-ask that
+    waits behind its document's prefill is served from the pages that
+    prefill has committed."""
+    check_a_re_ask_late_hits_what_its_layout_can_restore("deepseek")
 
 
 def test_step_counts_name_the_rung_launched(compiles):

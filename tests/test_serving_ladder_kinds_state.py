@@ -10,7 +10,7 @@ import pytest
 
 from serving_ladder_toys import (  # noqa: F401 - compiles is a fixture
     LAYOUTS, SALA_SCAN_TILE, check_a_ladder_serves_what_the_top_rung_serves,
-    compiles, one_rung)
+    check_a_re_ask_late_hits_what_its_layout_can_restore, compiles, one_rung)
 
 
 @pytest.mark.parametrize("name", ["mellum2", "nemotron", "minicpm_sala"])
@@ -40,6 +40,19 @@ def test_a_ladder_serves_what_the_top_rung_serves(name, compiles,
     assert steps["sel_blocks"] and steps["ckey_ctx"]
     assert steps["state_restored_tokens"] and steps["state_snapshots_taken"]
     assert any(len(e) == 1 and e[0].size for e in extras)
+
+
+@pytest.mark.parametrize("name", ["mellum2", "nemotron", "minicpm_sala"])
+def test_a_re_ask_late_hits_what_its_layout_can_restore(name, monkeypatch):
+    """A window kind of page (Mellum2's toy) and a recurrent state
+    (Nemotron-H's, MiniCPM-SALA's): a block the cache took before its
+    prompt was done is not restorable, so a re-ask finds nothing while
+    its document prefills, as before, and late-hits once it is done."""
+    if name == "minicpm_sala":
+        from paddle_tpu.models import minicpm_sala
+
+        monkeypatch.setattr(minicpm_sala, "SCAN_TILE_ROWS", SALA_SCAN_TILE)
+    check_a_re_ask_late_hits_what_its_layout_can_restore(name)
 
 
 def _serve_across_rungs(eng, compiles=None):

@@ -363,6 +363,36 @@ def test_each_request_is_stamped_once_at_admission_and_first_token(traced):
         assert a["chunks"] >= -(-n // 8) and a["prefill_us"] > 0
 
 
+def test_a_late_hit_leaves_one_marker_in_the_trace(tiny, tmp_path):
+    """A prompt of six chunks and the same prompt behind it, in a real
+    profiler trace: the second look-up's hit leaves ONE
+    ``serving.late_hit`` marker, inside the ``serving.pack`` that packs
+    the re-ask's first chunk, after the request's
+    ``serving.admit_request``, with the tokens ``prefill_stats`` and the
+    cache's counters show."""
+    cfg, params = tiny
+    eng = _engine(cfg, params)
+    prompt = np.random.default_rng(48).integers(1, 64, 50).astype(np.int32)
+    with _trace(tmp_path):
+        a = eng.add_request(prompt, max_new_tokens=2)
+        b = eng.add_request(prompt, max_new_tokens=2)
+        tokens = {f.rid: f.tokens.tolist() for f in eng.run()}
+    assert tokens[a] == tokens[b]
+    spans = _program_spans(tmp_path)
+    (late,) = [sp for sp in spans if sp[0] == "serving.late_hit"]
+    cached = eng.prefill_stats[b]["cached_tokens"]
+    cache = eng.serving_stats()["prefix_cache"]
+    assert (cache["late_hits"], cache["late_hit_tokens"]) == (1, cached)
+    assert (late[3]["rid"], late[3]["cached_tokens"]) == (b, cached)
+    assert cached >= 32 and late[3]["waited_us"] > 0
+    assert any(n == "serving.pack" and s <= late[1] and late[2] <= e
+               for n, s, e, _ in spans)
+    (admit,) = [sp for sp in spans if sp[0] == "serving.admit_request"
+                and sp[3]["rid"] == b]
+    assert admit[3]["cached_tokens"] == 0 and admit[2] <= late[1]
+    eng.shutdown()
+
+
 def test_greedy_outputs_do_not_depend_on_a_trace_running(traced):
     assert traced["tokens"] == traced["untraced_tokens"]
     assert all(len(t) == NEW_TOKENS for t in traced["tokens"].values())
